@@ -34,7 +34,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from mxnet_tpu.utils.platform import force_cpu  # noqa: E402
 
-force_cpu()  # wedge discipline: never let an incidental jax import dial TPU
+force_cpu()  # a host-only measurement: it must not take the chip
 
 from mxnet_tpu.recordio import IRHeader, MXRecordIO, pack_img  # noqa: E402
 from mxnet_tpu.utils import native  # noqa: E402

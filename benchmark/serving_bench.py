@@ -192,12 +192,11 @@ def _prompts(concurrency, prompt_lens, vocab):
 
 def _record(metric, vals, unit, vs_baseline, extra=None):
     import jax
-    platform = jax.default_backend()
+    d = jax.devices()[0]
     value = statistics.median(vals)
-    if platform != "tpu":
-        metric = f"{metric}_cpu_sanity"
     rec = {"metric": metric, "value": round(value, 1), "unit": unit,
-           "vs_baseline": vs_baseline, "platform": platform,
+           "vs_baseline": vs_baseline, "platform": d.platform,
+           "device_kind": d.device_kind, "device_count": len(jax.devices()),
            "trials": [round(v, 1) for v in vals],
            "spread_pct": round(100.0 * (max(vals) - min(vals)) / value, 2)
            if value else None}
@@ -1358,9 +1357,8 @@ def bench_sharded(concurrency: int = 8, trials: int = 3,
     n = mesh_devices or min(4, len(jax.devices()))
     if n < 2 or _devices(n) is None:
         raise SystemExit(
-            f"--workload sharded needs >= 2 XLA devices (have "
-            f"{len(jax.devices())}) — on CPU set XLA_FLAGS="
-            "--xla_force_host_platform_device_count=N")
+            f"--workload sharded needs >= 2 chips (have "
+            f"{len(jax.devices())})")
     net, prompt_lens, seq_buckets, max_new = _build_sharded_net(on_tpu)
     rs = onp.random.RandomState(0)
     prompts = [rs.randint(0, net.vocab_size,
@@ -1569,22 +1567,9 @@ def main():
                          "(default: min(4, local devices))")
     args = ap.parse_args()
 
-    if args.workload == "sharded" and "host_platform_device_count" \
-            not in os.environ.get("XLA_FLAGS", ""):
-        # the sharded workload needs virtual host devices, and the flag
-        # is read exactly ONCE at backend bring-up — set it before any
-        # jax initialization.  Harmless under a real TPU: it only
-        # affects the host (CPU) platform.
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=%d"
-            % max(args.mesh_devices or 4, 2))
-
-    from mxnet_tpu.utils.platform import init_backend
-    platform = init_backend()
-    if platform != "tpu":
-        print(f"serving_bench: accelerator unavailable; running on "
-              f"{platform}", file=sys.stderr)
+    from mxnet_tpu.utils.platform import enable_compile_cache, require_tpu
+    require_tpu()
+    enable_compile_cache()
 
     if args.workload == "prefix":
         recs = bench_prefix_cache(trials=args.trials)
@@ -1615,10 +1600,7 @@ def main():
         # the final registry snapshot rides each record, so the BENCH
         # json carries compile/bucket/prefix counters next to the
         # throughput they explain (docs/observability.md)
-        try:
-            rec["registry"] = flatten(prefix="mxtpu_serving")
-        except Exception:
-            pass
+        rec["registry"] = flatten(prefix="mxtpu_serving")
         print(json.dumps(rec), flush=True)
 
 

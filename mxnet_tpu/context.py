@@ -58,14 +58,20 @@ class Context:
         """Resolve to a concrete jax.Device.
 
         cpu→host backend; gpu/tpu→the default accelerator backend.  ``gpu`` is
-        an alias kept so GluonCV-era scripts keep working on TPU.
+        an alias kept so GluonCV-era scripts keep working on TPU.  With no
+        accelerator in the process a gpu/tpu context is an error, never a
+        CPU device under an accelerator's name.
         """
         if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             devs = _backend_devices("cpu")
         else:
             devs = accelerator_devices()
             if not devs:
-                devs = _backend_devices("cpu")
+                from .base import MXNetError
+                raise MXNetError(
+                    f"{self!r}: this process has no accelerator device "
+                    f"(jax backend {jax.default_backend()!r}) — use "
+                    "mx.cpu(), or run where the chip is attached")
         return devs[self.device_id % len(devs)]
 
     # convenience parity helpers
@@ -83,26 +89,13 @@ def _backend_devices(platform: str) -> List[jax.Device]:
     """PROCESS-LOCAL devices of a platform: MXNet context semantics are
     per-worker (each worker's cpu(0)/tpu(0) is its own), and in a
     multi-process job placing eager arrays on another process's device is
-    both wrong and unsupported.  Successful lookups are cached — device
-    enumeration sits on the eager dispatch hot path — but FAILURES are
-    not: a TPU plugin that initializes late relative to the first
-    tpu-context lookup must become visible on retry, not stay pinned to
-    the [] result for the life of the process.  utils.platform.force_cpu()
-    invalidates when it swaps the backend out."""
+    both wrong and unsupported.  Cached — device enumeration sits on the
+    eager dispatch hot path."""
     devs = _DEVICE_CACHE.get(platform)
     if devs is None:
-        try:
-            devs = list(jax.local_devices(backend=platform))
-        except RuntimeError:
-            return []
-        if devs:
-            _DEVICE_CACHE[platform] = devs
+        devs = _DEVICE_CACHE[platform] = list(
+            jax.local_devices(backend=platform))
     return devs
-
-
-# lru_cache-compatible invalidation shim: force_cpu() and older callers
-# invalidate via _backend_devices.cache_clear()
-_backend_devices.cache_clear = _DEVICE_CACHE.clear  # type: ignore[attr-defined]
 
 
 _ACCEL_CACHE: Optional[List[jax.Device]] = None
@@ -114,10 +107,7 @@ def accelerator_devices() -> List[jax.Device]:
     The result — INCLUDING an empty one — is cached: this sits on the
     eager dispatch hot path (``current_context`` consults it per op on
     an empty context stack), so a CPU-only host must not re-enumerate
-    devices forever.  The late-TPU-plugin case is handled by
-    invalidation instead: ``utils.platform`` clears the cache from
-    ``force_cpu()`` and whenever ``probe_accelerator``/``init_backend``
-    observe the backend coming up."""
+    devices forever."""
     global _ACCEL_CACHE
     if _ACCEL_CACHE is None:
         _ACCEL_CACHE = [d for d in jax.local_devices()

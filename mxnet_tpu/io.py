@@ -402,7 +402,9 @@ class ImageRecordIter(DataIter):
         self._path = path_imgrec
         # native C++ fast path: offset scan + threaded pread/decode/augment
         # pipeline (parity: src/io/iter_image_recordio_2.cc); Python-side
-        # records stay unloaded.  Falls back to the pure-Python pool.
+        # records stay unloaded.  Non-RGB shapes, an .idx the scan cannot
+        # honor and MXNET_TPU_NO_NATIVE take the pure-Python pool; a
+        # library that fails to build raises (utils/native.py).
         self._native = None
         self._offsets = self._lengths = None
         from .utils import native as _native_mod

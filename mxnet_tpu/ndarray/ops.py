@@ -64,11 +64,7 @@ def invoke(name, pure_fn, nd_inputs, nout=1, ctx=None, differentiable=True):
     in_nodes = [node_of(x) for x in nd_inputs] if recording else None
     needs_grad = recording and any(n is not None for n in in_nodes)
     ctx = ctx or (nd_inputs[0].context if nd_inputs else current_context())
-    try:
-        platform = ctx.jax_device.platform
-    except Exception:   # backend not up yet / device resolution failed
-        platform = None
-    with _base.executing_on(platform):
+    with _base.executing_on(ctx.jax_device.platform):
         if needs_grad:
             outs, vjp_fn = jax.vjp(pure_fn, *arrs)
         else:

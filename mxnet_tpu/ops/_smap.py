@@ -9,22 +9,6 @@ import jax
 __all__ = ["shard_mapped_qkv"]
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map moved twice across jax versions: top-level with
-    check_vma (new), top-level with check_rep, experimental with
-    check_rep (0.4.x) — probe in that order."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def shard_mapped_qkv(body, mesh, spec, q, k, v, *extra, extra_specs=()):
     """Run ``body(q, k, v, *extra)`` under shard_map.  ``extra`` carries
     side inputs with their own partition specs (e.g. packed segment-id
@@ -43,7 +27,9 @@ def shard_mapped_qkv(body, mesh, spec, q, k, v, *extra, extra_specs=()):
         q, k, v = (jax.device_put(x, sh) for x in (q, k, v))
         extra = tuple(jax.device_put(x, NamedSharding(mesh, s))
                       for x, s in zip(extra, extra_specs))
-    f = _shard_map(body, mesh, (spec, spec, spec, *extra_specs), spec)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(spec, spec, spec, *extra_specs),
+                      out_specs=spec, check_vma=False)
     out = f(q, k, v, *extra)
     if restore is not None:
         out = jax.device_put(out, restore)

@@ -14,9 +14,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .. import base as _base
 from .. import random as _random
+from ._smap import shard_mapped_qkv
 
 _NEG_INF = -1e30
 
@@ -73,21 +75,45 @@ def _use_flash(q_shape, causal, mask, dropout, k_shape=None,
         return False
     if t < 256 or t % 128 or d not in (64, 128, 256):
         return False
-    if (platform or jax.default_backend()) != "tpu":
-        return False
-    try:
-        from . import flash  # noqa: F401
-        return True
-    except ImportError:
-        return False
+    return (platform or jax.default_backend()) == "tpu"
+
+
+def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None):
+    """The Pallas kernel, run per device.  GSPMD cannot partition a Mosaic
+    kernel (jax refuses to lower one into a multi-device program), and
+    batch rows and heads attend independently — so under an ambient
+    multi-device mesh the call is shard_map'd: batch over ``dp`` and heads
+    over ``tp`` where the axis divides the dimension, replicated where it
+    does not.  Inside another shard_map body (ring / Ulysses) the operands
+    are local already."""
+    from ..parallel.mesh import axis_size, current_mesh
+    from .flash import flash_attention as _pallas
+
+    def body(q, k, v, *seg):
+        return _pallas(q, k, v, causal=causal, scale=scale,
+                       segment_ids=seg[0] if seg else None,
+                       kv_segment_ids=seg[1] if seg else None)
+
+    seg = () if q_seg is None else (q_seg, kv_seg)
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return body(q, k, v, *seg)
+
+    def over(axis, dim):
+        return axis if axis in mesh.axis_names and \
+            dim % axis_size(mesh, axis) == 0 else None
+
+    b_ax, h_ax = over("dp", q.shape[0]), over("tp", q.shape[2])
+    return shard_mapped_qkv(body, mesh, P(b_ax, None, h_ax, None), q, k, v,
+                            *seg, extra_specs=(P(b_ax, None),) * len(seg))
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None):
     """Jax-level flash attention entry (Pallas on TPU, reference on CPU)."""
     if _use_flash(q.shape, causal, None, 0.0, k.shape,
                   platform=_base.resolve_exec_platform(q)):
-        from .flash import flash_attention as _pallas
-        return _pallas(q, k, v, causal=causal, scale=scale)
+        return _pallas_flash(q, k, v, causal=causal, scale=scale)
     return _attention_ref(q, k, v, causal=causal, scale=scale)
 
 
@@ -171,10 +197,8 @@ def dot_product_attention(query, key, value, *, causal=False, mask=None,
         if impl != "ref" and _use_flash(q.shape, causal, mask_val, dropout,
                                         k.shape,
                                         platform=_base.resolve_exec_platform(q)):
-            from .flash import flash_attention as _pallas
-            return _pallas(q, k, v, causal=causal, scale=scale,
-                           segment_ids=q_seg,
-                           kv_segment_ids=kv_seg)
+            return _pallas_flash(q, k, v, causal=causal, scale=scale,
+                                 q_seg=q_seg, kv_seg=kv_seg)
         return _attention_ref(q, k, v, causal=causal, mask=_full_mask(),
                               scale=scale, dropout=dropout, dropout_key=dkey)
 

@@ -28,11 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (and will
-# eventually drop the old name); accept whichever this jax ships.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 # Measured on TPU v5e (B16 T1024 H12 D64, causal): 128x128 blocks run the
 # fwd kernel at 16.7 ms vs 1.6 ms at 1024x1024 — big tiles keep the MXU fed
 # (d=64 contractions are half-width already) and amortize grid/DMA overhead.
@@ -80,6 +75,21 @@ def _clamp_blocks(bq: int, bk: int, d: int, itemsize: int,
     return bq, bk
 
 
+def _dot(a, b, ca: int, cb: int):
+    """In-kernel 2D contraction of ``a`` dim ``ca`` with ``b`` dim ``cb``,
+    accumulated in f32.  The precision is stated HERE, per operand type,
+    so the process-wide ``jax_default_matmul_precision`` (the package sets
+    'highest') never reaches Mosaic — which refuses an fp32-precision
+    ``tpu.matmul`` on bf16 operands.  bf16 operands take the MXU's single
+    pass (exact products, f32 accumulation); float32 operands keep the
+    package's true-f32 contract (``mxnet_tpu/__init__.py``)."""
+    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(
+        a, b, (((ca,), (cb,)), ((), ())), precision=prec,
+        preferred_element_type=jnp.float32)
+
+
 def _default_interpret(x) -> bool:
     from ..base import resolve_exec_platform
     return resolve_exec_platform(x) != "tpu"
@@ -115,9 +125,7 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, block_k, nk):
     def _tile():
         q = q_ref[0]                                   # (bq, d)
         k = k_ref[0]                                   # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+        s = _dot(q, k, 1, 1) * scale                   # (bq, bk)
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -137,9 +145,7 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, block_k, nk):
         p = jnp.where(s <= _MASK * 0.5, 0.0, jnp.exp(s - m_next))
         corr = jnp.exp(m_prev - m_next)                # (bq, 1)
         l_next = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, d)
+        pv = _dot(p.astype(v_ref.dtype), v_ref[0], 1, 0)   # (bq, d)
         acc_ref[:] = acc_ref[:] * corr + pv
         m_ref[:] = jnp.broadcast_to(m_next, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_next, l_ref.shape)
@@ -231,7 +237,7 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * tq * tk * d, transcendentals=bh * tq * tk,
@@ -280,9 +286,7 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, block_k, nk):
     def _tile():
         q = q_ref[0]
         k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        s = _dot(q, k, 1, 1) * scale
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -294,13 +298,9 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, block_k, nk):
         lse = lse_ref[0, 0, :]
         delta = delta_ref[0, 0, :]
         p = jnp.where(s <= _MASK * 0.5, 0.0, jnp.exp(s - lse[:, None]))
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, bk)
+        dp = _dot(do_ref[0], v_ref[0], 1, 1)           # (bq, bk)
         ds = p * (dp - delta[:, None]) * scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] += _dot(ds.astype(k.dtype), k, 1, 0)
     run = _run_pred(causal, has_seg, qi, ki, block_q, block_k,
                         qseg_ref, kseg_ref)
     if run is not None:
@@ -335,9 +335,7 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_q, block_k, nq):
         q = q_ref[0]                                   # (bq, d)
         k = k_ref[0]                                   # (bk, d)
         do = do_ref[0]                                 # (bq, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+        s = _dot(q, k, 1, 1) * scale                   # (bq, bk)
         if causal:
             row = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -350,17 +348,11 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_q, block_k, nq):
         delta = delta_ref[0, 0, :]
         p = jnp.where(s <= _MASK * 0.5, 0.0, jnp.exp(s - lse[:, None]))
         # dV += P^T @ dO
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bk, d)
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, bk)
+        dv_acc[:] += _dot(p.astype(do.dtype), do, 0, 0)   # (bk, d)
+        dp = _dot(do, v_ref[0], 1, 1)                  # (bq, bk)
         ds = p * (dp - delta[:, None]) * scale
         # dK += dS^T @ Q
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dk_acc[:] += _dot(ds.astype(q.dtype), q, 0, 0)
     run = _run_pred(causal, has_seg, qi, ki, block_q, block_k,
                         qseg_ref, kseg_ref)
     if run is not None:
@@ -407,7 +399,7 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -440,7 +432,7 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

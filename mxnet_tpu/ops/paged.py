@@ -36,11 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (and will
-# eventually drop the old name); accept whichever this jax ships.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 _MASK = -1e30
 _LANES = 128
 
@@ -230,7 +225,7 @@ def paged_attention(q, k_pages, v_pages, table_rows, qpos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * tq * p * ps * h * d,

@@ -215,17 +215,16 @@ def ring_attention(q, k, v, *, causal: bool = False,
                 f"got {tuple(segment_ids.shape)}")
     if steps == 1:
         from .. import base as _base
-        from .attention import _attention_ref, _use_flash, flash_attention
+        from .attention import (_attention_ref, _pallas_flash, _use_flash,
+                                flash_attention)
         if segment_ids is None:
             return flash_attention(q, k, v, causal=causal, scale=scale)
         if _use_flash(q.shape, causal, None, 0.0, k.shape,
                       platform=_base.resolve_exec_platform(q)):
             # the Pallas kernel masks per-tile from the raw (B, T) ids —
             # never materialize the dense (B, 1, T, T) mask on TPU
-            from .flash import flash_attention as _pallas
-            return _pallas(q, k, v, causal=causal, scale=scale,
-                           segment_ids=segment_ids,
-                           kv_segment_ids=segment_ids)
+            return _pallas_flash(q, k, v, causal=causal, scale=scale,
+                                 q_seg=segment_ids, kv_seg=segment_ids)
         seg_mask = (segment_ids[:, None, :, None] ==
                     segment_ids[:, None, None, :])
         return _attention_ref(q, k, v, causal=causal, mask=seg_mask,

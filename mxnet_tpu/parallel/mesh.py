@@ -60,11 +60,9 @@ def current_mesh() -> Optional[Mesh]:
     """Innermost active mesh (set via ``with use_mesh(m):`` or default)."""
     if _current:
         return _current[-1]
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        m = jax.interpreters.pxla.thread_resources.env.physical_mesh
+    # jax's own ``with mesh:`` scope; 0.9.0 has no public accessor for it
+    from jax._src import mesh as _mesh_lib
+    m = _mesh_lib.thread_resources.env.physical_mesh
     if len(m.axis_names) > 0:
         return m
     return None

@@ -92,31 +92,31 @@ def _reshard_fn(sharding):
 
 def param_sharding(param, mesh: Mesh,
                    rules: Optional[ShardingRules] = None) -> NamedSharding:
+    """Where an initialized parameter lives on the mesh: its logical axes
+    through the rules, with any dimension its mesh axis does not divide
+    evenly REPLICATED (:func:`divisible_spec`) — GPT-2's 50,257-row
+    embedding on a ``tp`` axis — the same rule the serving mesh uses."""
     rules = rules or ShardingRules()
-    return NamedSharding(mesh, rules.spec(logical_axes_of(param)))
+    return NamedSharding(mesh, divisible_spec(
+        param._data.shape, logical_axes_of(param), mesh, rules))
 
 
 def divisible_spec(shape, logical_axes, mesh: Mesh, mapping) -> P:
     """PartitionSpec mapping each logical axis through ``mapping``
     (logical name → mesh axis name), REPLICATING any dimension whose
-    size does not divide its mesh axis — the pragmatic t5x-style
-    fallback a *serving* mesh wants: an odd-sized vocab table (97 on a
-    2-way mesh) replicates instead of erroring, while the axes that
-    MUST shard evenly (the KV head dimension) are validated separately
-    by the caller (`InferenceEngine`'s typed construction checks,
+    size its mesh axis does not divide — the pragmatic t5x-style
+    fallback: an odd-sized vocab table (GPT-2's 50,257 rows on a 2-way
+    ``tp``) replicates instead of erroring, on the training mesh
+    (:func:`param_sharding`) and the serving mesh alike.  Axes that MUST
+    shard evenly (the KV head dimension) are validated separately by the
+    caller (`InferenceEngine`'s typed construction checks,
     docs/serving.md "Sharded decode")."""
     from .mesh import axis_size
     spec = []
-    axes = tuple(logical_axes or ())
-    for i, dim in enumerate(shape):
-        a = axes[i] if i < len(axes) else None
+    for dim, a in zip(shape, tuple(logical_axes or ())):
         m = mapping.get(a) if a is not None else None
-        if m is not None:
-            sz = axis_size(mesh, m)
-            if sz > 1 and dim % sz == 0:
-                spec.append(m)
-                continue
-        spec.append(None)
+        fits = m in mesh.axis_names and dim % axis_size(mesh, m) == 0
+        spec.append(m if fits else None)
     return P(*spec)
 
 
@@ -129,7 +129,7 @@ def shard_params(block, mesh: Mesh, rules: Optional[ShardingRules] = None):
     for _, p in block.collect_params().items():
         if p._data is None:
             continue
-        sh = NamedSharding(mesh, rules.spec(logical_axes_of(p)))
+        sh = param_sharding(p, mesh, rules)
         p._sharding = sh
         p._data._rebind(mesh_device_put(p._data.jax, sh))
     return block

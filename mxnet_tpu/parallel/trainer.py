@@ -26,8 +26,9 @@ from ..observability.trace import active as _trace_active
 from ..resilience.faults import inject as _inject, poison as _poison
 from ..ndarray.ndarray import swap_values
 from .mesh import current_mesh, use_mesh
-from .sharding import (ShardingRules, batch_spec, logical_axes_of,
-                       mesh_device_put as _mesh_device_put, shard_params)
+from .sharding import (ShardingRules, batch_spec,
+                       mesh_device_put as _mesh_device_put, param_sharding,
+                       shard_params)
 
 
 def _flatten_state(state) -> Tuple[List[NDArray], Any]:
@@ -293,7 +294,7 @@ class ShardedTrainer:
         # a state leaf shards like its parameter when shapes match
         self._state_shardings = []
         for (name, p), st in zip(self._trainable, self._states):
-            psh = NamedSharding(self.mesh, self.rules.spec(logical_axes_of(p)))
+            psh = param_sharding(p, self.mesh, self.rules)
             repl = NamedSharding(self.mesh, P())
             for l in _state_leaves(st):
                 self._state_shardings.append(
@@ -600,9 +601,9 @@ class ShardedTrainer:
         def ns(spec):
             return NamedSharding(mesh, spec)
 
-        param_sh = tuple(ns(rules.spec(logical_axes_of(p)))
+        param_sh = tuple(param_sharding(p, mesh, rules)
                          for _, p in self._trainable)
-        aux_sh = tuple(ns(rules.spec(logical_axes_of(p)))
+        aux_sh = tuple(param_sharding(p, mesh, rules)
                        for _, p in self._aux)
         state_sh = tuple(self._state_shardings)
 
@@ -713,14 +714,8 @@ class ShardedTrainer:
         t = jnp.asarray(opt.num_update, jnp.int32)
         key = _random.next_key()
 
-        param_vals = tuple(p._data.jax for _, p in self._trainable)
-        aux_vals = tuple(p._data.jax for _, p in self._aux)
-        state_vals = tuple(l.jax for l in self._state_flat)
-        batch_vals = tuple(
-            _mesh_device_put(x.jax if isinstance(x, NDArray)
-                             else jnp.asarray(x), sh)
-            for x, sh in zip(tuple(data) + tuple(labels),
-                             self._batch_shardings))
+        param_vals, aux_vals, state_vals, batch_vals = \
+            self._device_args(data, labels)
 
         if self._guarded:
             lp = _poison("trainer.loss_nonfinite")
@@ -745,6 +740,37 @@ class ShardedTrainer:
         if self._guarded:
             return NDArray(loss), NDArray(flag)
         return NDArray(loss)
+
+    def _device_args(self, data, labels):
+        """The step's array arguments as they sit on the mesh: params,
+        aux, optimizer state, and the batch placed by its shardings."""
+        return (tuple(p._data.jax for _, p in self._trainable),
+                tuple(p._data.jax for _, p in self._aux),
+                tuple(l.jax for l in self._state_flat),
+                tuple(_mesh_device_put(x.jax if isinstance(x, NDArray)
+                                       else jnp.asarray(x), sh)
+                      for x, sh in zip(tuple(data) + tuple(labels),
+                                       self._batch_shardings)))
+
+    def lower_step(self, data, labels=()):
+        """The jitted step lowered for this batch (``jax.stages.Lowered``):
+        ``.compile()`` gives the program ``step()`` runs, for
+        ``as_text()`` / ``memory_analysis()``.  Builds the trainer if
+        needed; advances neither the optimizer nor the RNG, and consumes
+        no donated buffer."""
+        self.build(data, labels)
+        if not isinstance(data, (tuple, list)):
+            data = (data,)
+        if not isinstance(labels, (tuple, list)):
+            labels = (labels,)
+        args = self._device_args(data, labels) + (
+            jax.random.PRNGKey(0),
+            jnp.asarray(self.optimizer.learning_rate, jnp.float32),
+            jnp.asarray(self.optimizer.num_update, jnp.int32))
+        if self._guarded:
+            zero = jnp.asarray(0.0, jnp.float32)
+            args += (self._scale_arr, self._good_arr, zero, zero)
+        return self._step_fn.lower(*args)
 
     # ------------------------------------------------------------------
     @property
@@ -881,10 +907,10 @@ class ShardedTrainer:
         for i, l in enumerate(self._state_flat):
             _check(f"state:{i}", d[f"state:{i}"], l.shape, "opt state")
         for i, (_n, p) in enumerate(self._trainable):
-            sh = NamedSharding(self.mesh, self.rules.spec(logical_axes_of(p)))
+            sh = param_sharding(p, self.mesh, self.rules)
             p._data._rebind(_mesh_device_put(d[f"param:{i}"].jax, sh))
         for i, (_n, p) in enumerate(self._aux):
-            sh = NamedSharding(self.mesh, self.rules.spec(logical_axes_of(p)))
+            sh = param_sharding(p, self.mesh, self.rules)
             p._data._rebind(_mesh_device_put(d[f"aux:{i}"].jax, sh))
         for i, l in enumerate(self._state_flat):
             l._rebind(_mesh_device_put(d[f"state:{i}"].jax,

@@ -2199,6 +2199,24 @@ class InferenceEngine:
                                   self._jit_forward, params, xs)
             return self.metrics.counters["compiles"] - before
 
+    def lower_decode(self):
+        """The decode-step program lowered at the shapes ``warmup()``
+        and live traffic run it with (``jax.stages.Lowered``):
+        ``.compile()`` gives its ``as_text()`` / ``memory_analysis()``.
+        Consumes no donated buffer."""
+        import jax.numpy as jnp
+
+        if self.mode != "decode":
+            raise ServingError("lower_decode() needs a decode-mode engine")
+        with self._step_lock:
+            self._ensure_caches()
+            s1 = self.num_slots + 1
+            zeros = jnp.zeros((s1,), jnp.int32)
+            tbl = (self._table_arg(),) if self._paged else ()
+            return self._jit_step.lower(
+                self._params(), zeros, self._caches, zeros,
+                *self._zero_samp(s1), *tbl)
+
     # ------------------------------------------------- disaggregated serving
     def migrate_to(self, target) -> "InferenceEngine":
         """Attach this prefill-role engine's migration egress

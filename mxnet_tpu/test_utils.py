@@ -205,7 +205,7 @@ def default_context():
 def set_default_context(ctx):
     """Pin the process default context (parity: set_default_context —
     how the upstream GPU suite re-ran the CPU tests under another
-    device; pairs with MXNET_TPU_TEST_PLATFORM=tpu here)."""
+    device)."""
     from . import context as ctx_mod
     stack = getattr(ctx_mod._state, "stack", None)
     if stack:

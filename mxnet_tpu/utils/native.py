@@ -2,22 +2,23 @@
 
 Parity role: MXNet's C++ data plane (src/io/*, dmlc recordio) — the one
 host-side hot path XLA does not cover (SURVEY.md §7.1).  The library is
-compiled from ``mxnet_tpu/native/src/mxtpu_io.cc`` with g++ on first use
-(no pybind — plain C ABI), cached next to the source, and every consumer
-falls back to pure Python when it is unavailable
-(``MXNET_TPU_NO_NATIVE=1`` forces the fallback).
+compiled from the committed ``mxnet_tpu/native/src/mxtpu_io.cc`` with g++
+on first use (no pybind — plain C ABI) and cached next to the source.
+``MXNET_TPU_NO_NATIVE=1`` selects the pure-Python plane; otherwise a
+build or load failure raises to whoever asked for the library — it never
+turns into the Python path behind the caller's back.
 """
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
-import threading
 from typing import Optional
 
 import numpy as onp
 
 from ..analysis.lockwitness import named_lock as _named_lock
+from ..base import MXNetError
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
 _SRC = os.path.abspath(os.path.join(_NATIVE_DIR, "src", "mxtpu_io.cc"))
@@ -25,45 +26,49 @@ _LIB = os.path.abspath(os.path.join(_NATIVE_DIR, "libmxtpu_io.so"))
 
 _lock = _named_lock("native.build", "one-shot native lib build")
 _lib = None
-_tried = False
+_build_error: Optional[str] = None   # a failed build is not retried
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Compile the library; the compiler's complaint on failure."""
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            _SRC, "-o", _LIB, "-ljpeg"]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        return True
-    except Exception:
-        return False
+        subprocess.run(cmd, check=True, capture_output=True, text=True,
+                       timeout=300)
+    except subprocess.CalledProcessError as e:
+        return f"{' '.join(cmd)} exited {e.returncode}: {e.stderr[-2000:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{' '.join(cmd)}: {e}"
+    return None
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """The loaded native library, building it if needed; None when
-    disabled or unbuildable."""
-    global _lib, _tried
+    """The loaded native library, built from the committed source when
+    missing or older than it.  None only under ``MXNET_TPU_NO_NATIVE``;
+    raises :class:`MXNetError` when it cannot be built or loaded."""
+    global _lib, _build_error
+    if os.environ.get("MXNET_TPU_NO_NATIVE"):
+        return None
     if _lib is not None:
         return _lib
-    if _tried or os.environ.get("MXNET_TPU_NO_NATIVE"):
-        return _lib
     with _lock:
-        if _lib is not None or _tried:
+        if _lib is not None:
             return _lib
-        _tried = True
-        if not os.path.exists(_SRC):
-            # packaged without source: use the prebuilt .so if present
-            fresh = os.path.exists(_LIB)
-        else:
-            fresh = (os.path.exists(_LIB) and
-                     os.path.getmtime(_LIB) >= os.path.getmtime(_SRC))
-            if not fresh:
-                fresh = _build()
-        if not fresh:
-            return None
+        if _build_error is None and not (
+                os.path.exists(_LIB) and
+                os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
+            _build_error = _build()
+        if _build_error is not None:
+            raise MXNetError(
+                "native IO library (libmxtpu_io.so) failed to build — fix "
+                "the toolchain or set MXNET_TPU_NO_NATIVE=1 for the "
+                f"pure-Python data plane: {_build_error}")
         try:
             lib = ctypes.CDLL(_LIB)
-        except OSError:
-            return None
+        except OSError as e:
+            raise MXNetError(f"native IO library failed to load: {e}") \
+                from e
         lib.mxio_writer_open.restype = ctypes.c_void_p
         lib.mxio_writer_open.argtypes = [ctypes.c_char_p]
         lib.mxio_writer_tell.restype = ctypes.c_int64
@@ -103,6 +108,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 
 def available() -> bool:
+    """False only when ``MXNET_TPU_NO_NATIVE`` opts out (see get_lib)."""
     return get_lib() is not None
 
 
@@ -111,7 +117,7 @@ def available() -> bool:
 
 def scan_record_offsets(path):
     """(offsets, lengths) uint64 arrays of LOGICAL records, natively
-    scanned; None if the library is unavailable.
+    scanned; None if the library is opted out or the scan fails.
 
     Single-frame records: (payload offset, payload length).  Multipart
     records (dmlc cflag chains): bit 63 of the length is set, the offset

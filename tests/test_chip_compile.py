@@ -1,0 +1,121 @@
+"""The Pallas kernels of the main path, compiled by the chip's own compiler
+for a DESCRIBED TPU v5e (no chip attached): what interpret mode cannot show
+— a precision Mosaic refuses, a tile it cannot lay out, too much VMEM.
+
+These are compiles, not chip runs: they say nothing about results or
+times.  They run under the package-default matmul precision ('highest'),
+the setting every user process has.  Real widths of GPT-2 124M: 12 heads
+of 64.  No whole-model compile here (tier-1's time budget).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import mxnet_tpu  # noqa: F401  (sets the package-default matmul precision)
+from mxnet_tpu.ops.flash import flash_attention
+from mxnet_tpu.ops.paged import paged_attention
+
+
+@pytest.fixture(scope="module")
+def chips():
+    """The four described devices of a v5e 2x2 host; skips where this
+    installation cannot describe the topology."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot describe v5e
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    # such a compile is written to the persistent cache but cannot be read
+    # back without a chip: the next run would warn and compile again
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield list(topo.devices)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(chips):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(chips[0])
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((16, 1024, 12, 64), jnp.bfloat16),    # GPT-2 124M training
+    ((4, 4096, 12, 64), jnp.bfloat16),     # long-sequence training
+    ((2, 1024, 4, 128), jnp.float32),      # f32 operands: true-f32 contract
+])
+def test_flash_fwd_bwd_compiles_for_v5e(chip, shape, dtype):
+    assert jax.config.jax_default_matmul_precision == "highest"
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert _kernels(compiled) == 3          # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("tq", [1, 64])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_compiles_for_v5e(chip, tq, quant):
+    slots, heads, d, ps, pages, table = 4, 12, 64, 16, 256, 64
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    q = sds((slots, tq, heads, d), jnp.bfloat16)
+    page = sds((pages + 1, ps, heads, d),
+               jnp.int8 if quant else jnp.bfloat16)
+    scale = sds((pages + 1, ps, heads, 1), jnp.float32)
+    tbl = sds((slots, table), jnp.int32)
+    qpos = sds((slots, tq), jnp.int32)
+    if quant:
+        fn = lambda q, k, v, t, p, ks, vs: paged_attention(  # noqa: E731
+            q, k, v, t, p, k_scale=ks, v_scale=vs, interpret=False)
+        args = (q, page, page, tbl, qpos, scale, scale)
+    else:
+        fn = lambda q, k, v, t, p: paged_attention(  # noqa: E731
+            q, k, v, t, p, interpret=False)
+        args = (q, page, page, tbl, qpos)
+    assert _kernels(jax.jit(fn).lower(*args).compile()) == 1
+
+
+def test_flash_under_a_mesh_runs_per_device(chips, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel (jax refuses to lower one
+    into a multi-device program): under dp=2 x tp=2 the dispatcher must
+    shard_map it, each device on its 4 rows x 6 heads."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu import base, parallel as par
+    from mxnet_tpu.ops import attention
+
+    # a CPU process: steer the dispatch onto its TPU branch here
+    monkeypatch.setattr(base, "resolve_exec_platform", lambda x=None: "tpu")
+    mesh = par.make_mesh(dp=2, tp=2, devices=chips)
+    x = jax.ShapeDtypeStruct(
+        (8, 1024, 12, 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "tp", None)))
+
+    def loss(q, k, v):
+        out = attention.flash_attention(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    with par.use_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "bf16[24,1024,64]" in text       # 4 rows x 6 heads, flattened

@@ -267,3 +267,40 @@ def test_dispatcher_routes_segments_to_flash(monkeypatch):
     assert att._use_flash(q, True, None, 0.0, q, platform="tpu")
     # and an explicit dense mask still forces the ref path
     assert not att._use_flash(q, True, object(), 0.0, q, platform="tpu")
+
+
+def test_flash_under_a_mesh_is_shard_mapped_and_matches_ref(mesh_devices):
+    """Under an ambient dp x tp mesh the kernel runs per device on its
+    block of rows and heads (a Mosaic kernel cannot be GSPMD-partitioned
+    on the chip — tests/test_chip_compile.py); values and gradients are
+    those of the unsharded reference, packed segment ids included."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.ops.attention import _pallas_flash
+
+    mesh = par.make_mesh(dp=2, tp=2, devices=mesh_devices(4))
+    rs = onp.random.RandomState(3)
+    sh = NamedSharding(mesh, P("dp", None, "tp", None))
+    q, k, v = (jax.device_put(rs.randn(2, 256, 2, 64).astype("f"), sh)
+               for _ in range(3))
+    seg = jnp.asarray(onp.repeat([[0, 1], [0, 0]], 128, axis=1), jnp.int32)
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    for ids in (None, seg):
+        mask = None if ids is None else \
+            ids[:, None, :, None] == ids[:, None, None, :]
+        with par.use_mesh(mesh):
+            got = jax.jit(jax.value_and_grad(loss(
+                lambda q, k, v: _pallas_flash(
+                    q, k, v, causal=True, scale=None, q_seg=ids,
+                    kv_seg=ids)), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.value_and_grad(loss(
+            lambda q, k, v: _attention_ref(q, k, v, causal=True,
+                                           mask=mask)),
+            argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                        rtol=2e-4, atol=2e-4)

@@ -296,7 +296,7 @@ def test_engine_type_env_knob():
     code = ("import jax, mxnet_tpu; "
             "print(bool(jax.config.jax_disable_jit))")
     env = dict(os.environ, MXNET_ENGINE_TYPE="NaiveEngine",
-               MXNET_TPU_PLATFORM="cpu")
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=120, env=env,
                        cwd=os.path.dirname(os.path.dirname(

@@ -13,8 +13,8 @@ from mxnet_tpu.io import ImageRecordIter
 from mxnet_tpu.recordio import IRHeader, MXRecordIO, pack, pack_img, unpack
 from mxnet_tpu.utils import native
 
-pytestmark = pytest.mark.skipif(not native.available(),
-                                reason="native IO library unavailable")
+pytestmark = pytest.mark.skipif(
+    not native.available(), reason="MXNET_TPU_NO_NATIVE is set")
 
 
 def _write_img_rec(path, n=24, seed=0, label_width=1):
@@ -77,9 +77,6 @@ def test_image_record_iter_native_matches_python(tmp_path):
     assert it_native._native is not None
     os.environ["MXNET_TPU_NO_NATIVE"] = "1"
     try:
-        # fresh module state so the env gate is honored
-        native._tried = False
-        saved, native._lib = native._lib, None
         it_py = ImageRecordIter(**kw)
         assert it_py._native is None
         for b_nat, b_py in zip(it_native, it_py):
@@ -90,8 +87,6 @@ def test_image_record_iter_native_matches_python(tmp_path):
                                            b_py.label[0].asnumpy())
     finally:
         del os.environ["MXNET_TPU_NO_NATIVE"]
-        native._lib = saved
-        native._tried = True
 
 
 def test_image_record_iter_native_shuffle_epochs(tmp_path):

@@ -129,7 +129,7 @@ def test_example_scripts_run(tmp_path):
     import subprocess
     import sys
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=repo, MXNET_TPU_PLATFORM="cpu")
+    env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu")
     for script in ("train_mnist_gluon.py", "train_mnist_module.py"):
         r = subprocess.run(
             [sys.executable, os.path.join(repo, "example", script)],

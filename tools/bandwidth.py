@@ -33,8 +33,8 @@ def main(argv=None):
         from mxnet_tpu.utils.platform import force_cpu
         force_cpu(args.cpu_devices)
     else:
-        from mxnet_tpu.utils.platform import init_backend
-        init_backend()
+        from mxnet_tpu.utils.platform import require_tpu
+        require_tpu()
 
     import jax
     import jax.numpy as jnp

@@ -2218,8 +2218,8 @@ def main():
         from mxnet_tpu.analysis import lockwitness as _lw
         witness = _lw.active_witness() or _lw.enable()
 
-    from mxnet_tpu.utils.platform import init_backend
-    platform = init_backend()
+    import jax
+    platform = jax.default_backend()
 
     # forensics (docs/observability.md "Flight recorder"): every
     # scenario runs with a FRESH flight recorder; scenarios whose
