@@ -48,6 +48,7 @@ from ..analysis.lockwitness import named_condition as _named_condition
 from ..resilience.faults import inject as _inject
 from ..observability.flightrecorder import active as _fr_active
 from ..observability.registry import default_registry as _registry
+from ..observability.trace import host_range as _host_range
 
 __all__ = ["DevicePrefetcher", "DataPipelineError"]
 
@@ -295,6 +296,14 @@ class DevicePrefetcher:
 
     # ---------------------------------------------------------- consumer
     def next(self):
+        """The next batch, already on the mesh.  The whole call is the
+        host range ``span:input.next`` (a wait, it launches nothing);
+        ``last_wait_seconds`` stays the counter of the time spent on an
+        empty ring."""
+        with _host_range("input", "next", launches=False):
+            return self._next()
+
+    def _next(self):
         t0 = time.perf_counter()
         stalled = False
         with self._cond:

@@ -69,7 +69,7 @@ def collect_block_params(block):
     return items
 
 
-def make_pure_fn(block, fn):
+def make_pure_fn(block, fn, name):
     """Reuse CachedOp's functionalization for arbitrary INFERENCE entries
     (the serving engine's prefill/decode/forward steps): returns
     ``(params, pure)`` where ``pure(param_vals, *args)`` evaluates
@@ -77,7 +77,10 @@ def make_pure_fn(block, fn):
     corresponding entry of ``param_vals`` — inference mode, no autograd
     tape, live payloads re-captured at trace time (so reset_ctx/astype
     between traces can never bake stale weights in as constants).  The
-    caller jits ``pure``; jax caches one executable per shape bucket."""
+    caller jits ``pure``; jax caches one executable per shape bucket.
+    ``name`` is what the program is called wherever jax names it (the
+    device trace shows ``jit_<name>``): say what the entry is, so that
+    no two hot-path programs share a name."""
     items = collect_block_params(block)
     if not items:
         raise _base.MXNetError(
@@ -100,6 +103,7 @@ def make_pure_fn(block, fn):
                     finally:
                         _base.set_recording(rec)
 
+    pure.__name__ = pure.__qualname__ = name
     return items, pure
 
 

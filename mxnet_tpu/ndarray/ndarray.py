@@ -32,6 +32,7 @@ import numpy as onp
 
 from .. import base as _base
 from ..context import Context, current_context
+from ..observability.trace import host_range as _host_range
 
 __all__ = ["NDArray", "array", "from_jax", "zeros", "ones", "full", "empty",
            "arange", "eye", "linspace", "concatenate"]
@@ -141,7 +142,10 @@ class NDArray:
             raise _base.MXNetError(
                 "asnumpy() called inside a hybridized/jitted trace; this "
                 "graph-breaks. Use .item()/asnumpy() outside hybridize.")
-        a = onp.asarray(v)
+        # the blocking transfer: the host waits here for every program
+        # the value depends on (a wait, it launches nothing)
+        with _host_range("ndarray", "readback", launches=False):
+            a = onp.asarray(v)
         if a.base is not None or not a.flags.writeable:
             a = onp.array(a)
         return a
@@ -160,7 +164,8 @@ class NDArray:
     def wait_to_read(self):
         v = self.jax
         if not isinstance(v, jax.core.Tracer):
-            jax.block_until_ready(v)
+            with _host_range("ndarray", "readback", launches=False):
+                jax.block_until_ready(v)
 
     wait_to_write = wait_to_read
 

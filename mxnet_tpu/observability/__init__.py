@@ -13,8 +13,13 @@ resilience/guardrail/io counter dicts):
 - :mod:`.trace` — low-overhead span tracing with request-id/trace-id
   propagation across the serving scheduler thread boundary and around
   ``ResilientLoop`` / ``ShardedTrainer.step``; bounded ring buffer,
-  per-request timeline dump, zero-cost when disabled (one global +
-  ``None`` check — the FaultPlan pattern).
+  per-request timeline dump with explicit parents and self time,
+  zero-cost when disabled (one global + ``None`` check — the FaultPlan
+  pattern); ``host_range`` puts a host phase into any ``jax.profiler``
+  capture, tracer or no tracer.
+- :mod:`.compiles` — one process-wide count of XLA backend compiles
+  (``mxtpu_xla_compiles_total``) and what the calling thread's own
+  calls compiled.
 - :mod:`.export` — Prometheus text-format and JSON-lines exporters plus
   a :class:`BackgroundExporter` thread with graceful drain (wired into
   ``InferenceEngine.stop()`` and SIGTERM handling).
@@ -48,7 +53,8 @@ Quick start::
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        default_registry)
 from .trace import (Span, Tracer, active as active_tracer,
-                    disable as disable_tracing, enable as enable_tracing)
+                    disable as disable_tracing, enable as enable_tracing,
+                    host_range)
 from .export import (BackgroundExporter, flatten, parse_prometheus,
                      to_json_lines, to_prometheus)
 from .flightrecorder import (FlightRecorder,
@@ -61,7 +67,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "default_registry",
     "Span", "Tracer", "enable_tracing", "disable_tracing",
-    "active_tracer",
+    "active_tracer", "host_range",
     "BackgroundExporter", "to_prometheus", "to_json_lines",
     "parse_prometheus", "flatten",
     "FlightRecorder", "enable_flight_recorder",

@@ -21,10 +21,14 @@ Design rules (same contract as :mod:`mxnet_tpu.resilience.faults`):
   timeline includes the shared steps it rode.
 - **bounded memory**: spans land in a ``deque(maxlen=capacity)`` ring;
   a forgotten-enabled tracer can never OOM a serving host.
-- **device-trace bridge**: with ``profiler_markers=True`` each span
-  also opens a :class:`mxnet_tpu.profiler.Marker` range, so the same
-  span names land inside the ``jax.profiler`` device trace next to the
-  XLA ops they cover.
+- **parents are explicit**: a span names the span that caused it with
+  ``parent=`` at the call site (no thread-local stack), so a span's
+  self time — its duration less what its children cover — can be
+  computed from the ring (:meth:`Tracer.self_seconds`).
+- **device-trace bridge**: :func:`host_range` is the ONE way a host
+  phase reaches a ``jax.profiler`` capture.  It always opens a
+  ``TraceAnnotation`` (all but free while no profiler session is live)
+  and, when a tracer is active, records the span as well.
 """
 from __future__ import annotations
 
@@ -34,25 +38,43 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from ..analysis.lockwitness import named_lock as _named_lock
 
-__all__ = ["Span", "Tracer", "enable", "disable", "active"]
+__all__ = ["Span", "Tracer", "enable", "disable", "active", "host_range"]
+
+# process-unique span ids, shared by every tracer so that an id handed
+# out before a tracer was replaced can never collide with a later one
+_SPAN_IDS = itertools.count(1)
+
+
+def _id_of(parent) -> Optional[int]:
+    """``parent=`` takes a span (live or recorded) or a bare span id."""
+    if parent is None or isinstance(parent, int):
+        return parent
+    return parent.span_id
 
 
 class Span:
     """One recorded interval (or instant, when ``t1 == t0``)."""
 
-    __slots__ = ("name", "trace_id", "trace_ids", "t0", "t1", "attrs")
+    __slots__ = ("name", "trace_id", "trace_ids", "t0", "t1", "attrs",
+                 "span_id", "parent_id")
 
     def __init__(self, name: str, trace_id: Optional[int], t0: float,
                  t1: float, trace_ids: Optional[tuple] = None,
-                 attrs: Optional[dict] = None):
+                 attrs: Optional[dict] = None,
+                 span_id: Optional[int] = None,
+                 parent_id: Optional[int] = None):
         self.name = name
         self.trace_id = trace_id
         self.trace_ids = trace_ids or ()
         self.t0 = t0
         self.t1 = t1
         self.attrs = attrs or {}
+        self.span_id = next(_SPAN_IDS) if span_id is None else span_id
+        self.parent_id = parent_id      # the span that caused this one
 
     def in_trace(self, trace_id: int) -> bool:
         return self.trace_id == trace_id or trace_id in self.trace_ids
@@ -63,8 +85,9 @@ class Span:
 
     def as_dict(self) -> dict:
         return {"name": self.name, "trace_id": self.trace_id,
-                "trace_ids": list(self.trace_ids), "t0": self.t0,
-                "t1": self.t1,
+                "trace_ids": list(self.trace_ids),
+                "span_id": self.span_id, "parent_id": self.parent_id,
+                "t0": self.t0, "t1": self.t1,
                 "duration_ms": round(1e3 * (self.t1 - self.t0), 4),
                 "attrs": dict(self.attrs)}
 
@@ -81,32 +104,29 @@ class _LiveSpan:
     step simply never lands in the ring (no torn half-spans)."""
 
     __slots__ = ("_tracer", "name", "trace_id", "trace_ids", "t0",
-                 "attrs", "_marker")
+                 "attrs", "span_id", "parent_id")
 
     def __init__(self, tracer: "Tracer", name: str,
                  trace_id: Optional[int], trace_ids: Optional[tuple],
-                 attrs: dict):
+                 attrs: dict, parent=None):
         self._tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.trace_ids = trace_ids
         self.attrs = attrs
-        self._marker = None
-        if tracer.profiler_markers:
-            from .. import profiler as _profiler
-            self._marker = _profiler.device_span(name)
-            self._marker.start()
+        # the id exists from the start, so children that finish first
+        # can already name this span as their parent
+        self.span_id = next(_SPAN_IDS)
+        self.parent_id = _id_of(parent)
         self.t0 = time.monotonic()
 
     def finish(self, **attrs):
         t1 = time.monotonic()
-        if self._marker is not None:
-            self._marker.stop()
-            self._marker = None
         if attrs:
             self.attrs.update(attrs)
         self._tracer._record(Span(self.name, self.trace_id, self.t0, t1,
-                                  self.trace_ids, self.attrs))
+                                  self.trace_ids, self.attrs,
+                                  self.span_id, self.parent_id))
 
     def __enter__(self):
         return self
@@ -120,10 +140,8 @@ class _LiveSpan:
 class Tracer:
     """Ring-buffered span recorder.  Thread-safe throughout."""
 
-    def __init__(self, capacity: int = 4096,
-                 profiler_markers: bool = False):
+    def __init__(self, capacity: int = 4096):
         self.capacity = int(capacity)
-        self.profiler_markers = bool(profiler_markers)
         self._lock = _named_lock("obs.trace_ring",
                                  "tracer span ring buffer")
         self._ring: deque = deque(maxlen=self.capacity)
@@ -142,25 +160,40 @@ class Tracer:
                 self.dropped += 1
             self._ring.append(span)
 
+    @staticmethod
+    def new_span_id() -> int:
+        """A span id reserved ahead of its span: retrospective children
+        recorded first name it as ``parent=``, the parent then takes it
+        with ``record_span(..., span_id=)``."""
+        return next(_SPAN_IDS)
+
     def span(self, name: str, trace_id: Optional[int] = None,
-             trace_ids: Optional[tuple] = None, **attrs) -> _LiveSpan:
-        """Start a span; finish via ``with`` or ``.finish()``."""
+             trace_ids: Optional[tuple] = None, parent=None,
+             **attrs) -> _LiveSpan:
+        """Start a span; finish via ``with`` or ``.finish()``.
+        ``parent`` is the span (live, recorded, or its id) that caused
+        this one."""
         return _LiveSpan(self, name, trace_id,
-                         tuple(trace_ids) if trace_ids else None, attrs)
+                         tuple(trace_ids) if trace_ids else None, attrs,
+                         parent)
 
     def record_span(self, name: str, t0: float, t1: float,
                     trace_id: Optional[int] = None,
-                    trace_ids: Optional[tuple] = None, **attrs):
+                    trace_ids: Optional[tuple] = None, parent=None,
+                    span_id: Optional[int] = None, **attrs):
         """Record a RETROSPECTIVE span from timestamps the caller
         already holds (e.g. the queue phase, measured by request
         timestamps) — no live bookkeeping on the hot path."""
         self._record(Span(name, trace_id, t0, t1,
-                          tuple(trace_ids) if trace_ids else None, attrs))
+                          tuple(trace_ids) if trace_ids else None, attrs,
+                          span_id, _id_of(parent)))
 
-    def event(self, name: str, trace_id: Optional[int] = None, **attrs):
+    def event(self, name: str, trace_id: Optional[int] = None,
+              parent=None, **attrs):
         """Instant (zero-duration) span."""
         now = time.monotonic()
-        self._record(Span(name, trace_id, now, now, None, attrs))
+        self._record(Span(name, trace_id, now, now, None, attrs, None,
+                          _id_of(parent)))
 
     # --------------------------------------------------------------- queries
     def spans(self, trace_id: Optional[int] = None,
@@ -181,16 +214,30 @@ class Tracer:
         ring dressed up as one request."""
         if trace_id is None:
             return []
-        spans = sorted(self.spans(trace_id), key=lambda s: (s.t0, s.t1))
+        ring = self.spans()
+        spans = sorted((s for s in ring if s.in_trace(trace_id)),
+                       key=lambda s: (s.t0, s.t1))
         if not spans:
             return []
         base = spans[0].t0
+        own = _self_seconds(ring, spans)
         out = []
         for s in spans:
             d = s.as_dict()
             d["offset_ms"] = round(1e3 * (s.t0 - base), 4)
+            d["self_ms"] = round(1e3 * own[s.span_id], 4)
             out.append(d)
         return out
+
+    def self_seconds(self) -> Dict[int, float]:
+        """``{span_id: seconds}`` for every span in the ring: the span's
+        duration less the union of what its recorded children cover
+        (clipped to the span, overlapping children counted once).  A
+        span with no children keeps its whole duration; a parent whose
+        children were evicted by the ring bound reads too high, which
+        ``dropped`` gives away."""
+        ring = self.spans()
+        return _self_seconds(ring, ring)
 
     def trace_ids(self) -> List[int]:
         seen: Dict[int, None] = {}
@@ -210,6 +257,25 @@ class Tracer:
     def __len__(self):
         with self._lock:
             return len(self._ring)
+
+
+def _self_seconds(ring: List[Span], wanted: List[Span]) -> Dict[int, float]:
+    """Self time of each span of ``wanted``, its children looked up in
+    ``ring`` (one snapshot serves both)."""
+    kids: Dict[int, list] = {}
+    for s in ring:
+        if s.parent_id is not None:
+            kids.setdefault(s.parent_id, []).append(s)
+    out = {}
+    for s in wanted:
+        covered, at = 0.0, s.t0
+        for k in sorted(kids.get(s.span_id, ()), key=lambda k: k.t0):
+            lo, hi = max(k.t0, at), min(k.t1, s.t1)
+            if hi > lo:
+                covered += hi - lo
+                at = hi
+        out[s.span_id] = (s.t1 - s.t0) - covered
+    return out
 
 
 # The one active tracer.  Written under _LOCK; read lock-free on hot
@@ -255,13 +321,12 @@ def _register_ring_collector():
 _register_ring_collector()
 
 
-def enable(capacity: int = 4096,
-           profiler_markers: bool = False) -> Tracer:
+def enable(capacity: int = 4096) -> Tracer:
     """Install (or replace) the process-global tracer and return it.
     Replacing drops the previous ring — tracing config is a process
     decision, not a nesting scope like FaultPlan."""
     global _ACTIVE
-    tracer = Tracer(capacity=capacity, profiler_markers=profiler_markers)
+    tracer = Tracer(capacity=capacity)
     with _LOCK:
         _ACTIVE = tracer
     # re-register on every enable: a test that reset() the registry
@@ -282,3 +347,43 @@ def active() -> Optional[Tracer]:
     ``tr = active()`` / ``if tr is not None: ...`` and NOTHING else on
     the disabled path."""
     return _ACTIVE
+
+
+class host_range:
+    """The one bridge from a host phase to both timelines.
+
+    Entering ALWAYS opens a ``jax.profiler.TraceAnnotation`` — all but
+    free while no profiler session is live — so the phase shows on the
+    host thread of any device capture, tracer or no tracer.  When a
+    tracer is active (and ``span`` is left on) it also records the span
+    ``<layer>.<phase>`` with ``parent`` as the span that caused it.
+
+    The annotation's prefix follows one rule, because a trace reader
+    attributes a device program to the last ``marker:`` range opened
+    before the program started: a phase that **launches** device
+    programs is ``marker:<layer>:<phase>``; one that only waits or does
+    host bookkeeping is ``span:<layer>.<phase>``.
+
+    ``span=False`` is for a caller that records its own spans (the
+    serving engine: one retrospective span per batched call, carrying
+    every rider's trace id)."""
+
+    __slots__ = ("_ann", "_live")
+
+    def __init__(self, layer: str, phase: str, *, launches: bool,
+                 parent=None, span: bool = True, **attrs):
+        self._ann = _TraceAnnotation(
+            f"marker:{layer}:{phase}" if launches
+            else f"span:{layer}.{phase}")
+        tr = _ACTIVE if span else None
+        self._live = None if tr is None else \
+            tr.span(f"{layer}.{phase}", parent=parent, **attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self._ann.__exit__(etype, exc, tb)
+        if self._live is not None:
+            self._live.__exit__(etype, exc, tb)

@@ -218,6 +218,7 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, block_q, block_k,
         args += [q_seg, kv_seg]
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -394,6 +395,7 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           has_seg=has_seg,
                           block_q=block_q, block_k=block_k, nk=nk),
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -418,6 +420,7 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           has_seg=has_seg,
                           block_q=block_q, block_k=block_k, nq=nq),
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=dkv_in_specs,
         out_specs=[

@@ -223,6 +223,7 @@ def paged_attention(q, k_pages, v_pages, table_rows, qpos, *,
     itemsize = jnp.dtype(k_pages.dtype).itemsize
     out = pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -22,7 +22,10 @@ from .. import base as _base
 from .. import optimizer as opt_mod
 from .. import random as _random
 from ..ndarray import NDArray
+from ..observability.compiles import on_this_thread as _xla_compiles
+from ..observability.flightrecorder import active as _fr_active
 from ..observability.trace import active as _trace_active
+from ..observability.trace import host_range as _host_range
 from ..resilience.faults import inject as _inject, poison as _poison
 from ..ndarray.ndarray import swap_values
 from .mesh import current_mesh, use_mesh
@@ -341,8 +344,12 @@ class ShardedTrainer:
                 # collected (MoE router aux losses)
                 aux_prev = _base.set_aux_collection(True)
                 try:
-                    with swap_values([p._data for _, p in trainable],
-                                     pvals):
+                    # "fwd" names the forward and the loss in the
+                    # compiled program; the backward then reads
+                    # transpose(jvp(fwd)) by itself
+                    with jax.named_scope("fwd"), \
+                            swap_values([p._data for _, p in trainable],
+                                        pvals):
                         with _base.training_mode(True):
                             rec = _base.set_recording(False)
                             try:
@@ -424,7 +431,7 @@ class ShardedTrainer:
                     loss_val = lsum / accum
 
                 new_params, new_states = [], []
-                with optimizer.traced(lr, t):
+                with jax.named_scope("optimizer"), optimizer.traced(lr, t):
                     off = 0
                     for i, ((name, p), g) in enumerate(zip(trainable, grads)):
                         w_nd = NDArray(param_vals[i])
@@ -545,7 +552,7 @@ class ShardedTrainer:
                     for g in grads)
 
                 new_params, new_states = [], []
-                with optimizer.traced(lr, t):
+                with jax.named_scope("optimizer"), optimizer.traced(lr, t):
                     off = 0
                     for i, ((name, p), g) in enumerate(zip(trainable,
                                                            grads)):
@@ -597,6 +604,10 @@ class ShardedTrainer:
     def _compile(self, data, labels):
         mesh, rules = self.mesh, self.rules
         pure = self._make_pure(len(data))
+        # jit names the program after the function: guarded or not, the
+        # device trace shows the trainer's step as jit_trainer_step, and
+        # no other program of the package shares that name
+        pure.__name__ = pure.__qualname__ = "trainer_step"
 
         def ns(spec):
             return NamedSharding(mesh, spec)
@@ -684,19 +695,28 @@ class ShardedTrainer:
         if src is None:
             with tr.span("trainer.step",
                          step=self.optimizer.num_update + 1,
-                         guarded=self._guarded):
-                return self._step(data, labels)
+                         guarded=self._guarded) as sp:
+                return self._step(data, labels, sp)
         # per-step input-wait stamp: how long the caller's last batch
         # acquisition blocked on the prefetch ring (0 = fully hidden)
         with tr.span("trainer.step", step=self.optimizer.num_update + 1,
                      guarded=self._guarded,
                      input_wait=round(
-                         getattr(src, "last_wait_seconds", 0.0), 6)):
-            return self._step(data, labels)
+                         getattr(src, "last_wait_seconds", 0.0), 6)) as sp:
+            return self._step(data, labels, sp)
 
-    def _step(self, data, labels=()):
+    def _step(self, data, labels=(), span=None):
+        """One step in four host phases, each a ``host_range`` (and a
+        child of ``span``, the live ``trainer.step``, when tracing is
+        on): ``scalars`` and ``place`` launch small programs and
+        transfers, ``dispatch`` is the compiled step's call alone,
+        ``rebind`` is host bookkeeping.  The device's work and the wait
+        for it are in none of them: the step is asynchronous, the wait
+        happens where the caller reads the loss
+        (``span:ndarray.readback``)."""
         _inject("trainer.step")
         self._obs_steps.inc()
+        compiled0 = _xla_compiles()
         if not isinstance(data, (tuple, list)):
             data = (data,)
         if not isinstance(labels, (tuple, list)):
@@ -710,36 +730,64 @@ class ShardedTrainer:
             self._build(data, labels)
         opt = self.optimizer
         opt.num_update += 1
-        lr = jnp.asarray(opt.learning_rate, jnp.float32)
-        t = jnp.asarray(opt.num_update, jnp.int32)
-        key = _random.next_key()
+        with _host_range("trainer", "scalars", launches=True, parent=span):
+            lr = jnp.asarray(opt.learning_rate, jnp.float32)
+            t = jnp.asarray(opt.num_update, jnp.int32)
+            key = _random.next_key()
+            if self._guarded:
+                lp = _poison("trainer.loss_nonfinite")
+                gp = _poison("trainer.grad_nonfinite")
+                lp = jnp.asarray(0.0 if lp is None else lp, jnp.float32)
+                gp = jnp.asarray(0.0 if gp is None else gp, jnp.float32)
 
-        param_vals, aux_vals, state_vals, batch_vals = \
-            self._device_args(data, labels)
+        with _host_range("trainer", "place", launches=True, parent=span):
+            param_vals, aux_vals, state_vals, batch_vals = \
+                self._device_args(data, labels)
 
-        if self._guarded:
-            lp = _poison("trainer.loss_nonfinite")
-            gp = _poison("trainer.grad_nonfinite")
-            lp = jnp.asarray(0.0 if lp is None else lp, jnp.float32)
-            gp = jnp.asarray(0.0 if gp is None else gp, jnp.float32)
-            (loss, flag, new_scale, new_good, new_params, new_aux,
-             new_states) = self._step_fn(
-                param_vals, aux_vals, state_vals, batch_vals, key, lr, t,
-                self._scale_arr, self._good_arr, lp, gp)
-            self._scale_arr, self._good_arr = new_scale, new_good
-        else:
-            loss, new_params, new_aux, new_states = self._step_fn(
-                param_vals, aux_vals, state_vals, batch_vals, key, lr, t)
+        with _host_range("trainer", "dispatch", launches=True,
+                         parent=span):
+            if self._guarded:
+                (loss, flag, new_scale, new_good, new_params, new_aux,
+                 new_states) = self._step_fn(
+                    param_vals, aux_vals, state_vals, batch_vals, key, lr,
+                    t, self._scale_arr, self._good_arr, lp, gp)
+            else:
+                loss, new_params, new_aux, new_states = self._step_fn(
+                    param_vals, aux_vals, state_vals, batch_vals, key, lr,
+                    t)
 
-        for (_, p), v in zip(self._trainable, new_params):
-            p._data._rebind(v)
-        for (_, p), v in zip(self._aux, new_aux):
-            p._data._rebind(v)
-        for l, v in zip(self._state_flat, new_states):
-            l._rebind(v)
+        with _host_range("trainer", "rebind", launches=False,
+                         parent=span):
+            if self._guarded:
+                self._scale_arr, self._good_arr = new_scale, new_good
+            for (_, p), v in zip(self._trainable, new_params):
+                p._data._rebind(v)
+            for (_, p), v in zip(self._aux, new_aux):
+                p._data._rebind(v)
+            for l, v in zip(self._state_flat, new_states):
+                l._rebind(v)
+        compiled = _xla_compiles() - compiled0
+        if compiled:
+            self._note_compile(compiled, data, labels, span)
         if self._guarded:
             return NDArray(loss), NDArray(flag)
         return NDArray(loss)
+
+    def _note_compile(self, compiled, data, labels, span):
+        """This step sent ``compiled`` programs to XLA: say which step
+        and with what batch, to whoever is listening (the tracer's ring,
+        the flight recorder's).  Past the first step that is a new batch
+        shape or dtype, or an argument that changed how it is placed."""
+        attrs = {"step": int(self.optimizer.num_update),
+                 "compiles": int(compiled),
+                 "shapes": [f"{x.dtype}{list(x.shape)}"
+                            for x in tuple(data) + tuple(labels)]}
+        tr = _trace_active()
+        if tr is not None:
+            tr.event("trainer.compile", parent=span, **attrs)
+        fr = _fr_active()
+        if fr is not None:
+            fr.record("trainer.compile", **attrs)
 
     def _device_args(self, data, labels):
         """The step's array arguments as they sit on the mesh: params,

@@ -17,7 +17,7 @@ from typing import Optional
 from . import base as _base
 
 __all__ = ["set_config", "set_state", "dump", "dumps", "pause", "resume",
-           "Task", "Frame", "Marker", "scope", "device_span"]
+           "Task", "Frame", "Marker", "scope"]
 
 _config = {
     "filename": "profile.json",
@@ -128,39 +128,8 @@ class Marker:
         with jax.profiler.TraceAnnotation(name):
             pass
 
-    def span(self):
-        """The same marker as a named RANGE (context manager) — the
-        serving scheduler wraps each prefill/decode/forward batch in one
-        so per-batch host time is visible next to the device ops it
-        launched."""
-        return _Annotation(f"marker:{self.name}")
 
 
 def scope(name: str):
     """Context manager annotating a named range (jax.profiler bridge)."""
     return _Annotation(name)
-
-
-class _SafeAnnotation(_Annotation):
-    """An annotation that degrades to a no-op if jax (or its profiler)
-    is unusable — the observability trace bridge must never let a
-    device-trace decoration failure break the span it decorates."""
-
-    def start(self):
-        try:
-            super().start()
-        except Exception:
-            self._ann = None
-
-    def stop(self):
-        try:
-            super().stop()
-        except Exception:
-            self._ann = None
-
-
-def device_span(name: str) -> _SafeAnnotation:
-    """A named range for the jax device trace that NEVER raises — the
-    bridge :mod:`mxnet_tpu.observability.trace` uses to land its spans
-    inside ``jax.profiler`` captures next to the XLA ops they cover."""
-    return _SafeAnnotation(f"span:{name}")

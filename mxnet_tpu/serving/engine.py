@@ -147,6 +147,7 @@ import numpy as onp
 from ..analysis.lockwitness import (named_condition as _named_condition,
                                     named_lock as _named_lock,
                                     note_blocking as _note_blocking)
+from ..observability.compiles import on_this_thread as _xla_compiles
 from ..observability.flightrecorder import active as _fr_active
 from ..observability.trace import active as _trace_active
 from ..resilience.faults import (RetryableFault, inject as _inject,
@@ -1275,7 +1276,7 @@ class InferenceEngine:
                     return a.at[dst].set(jnp.where(m, a[src], a[dst]))
                 return pin_c(_jax.tree_util.tree_map(cp, caches))
 
-            self._items, pure_prefill = make_pure_fn(net, prefill)
+            self._items, pure_prefill = make_pure_fn(net, prefill, "serving_prefill")
             if self.mesh is not None:
                 # one NamedSharding per parameter, from the logical axes
                 # the model layer annotates (transformer.py): heads and
@@ -1295,12 +1296,12 @@ class InferenceEngine:
                     NamedSharding(self.mesh, divisible_spec(
                         p.shape, logical_axes_of(p), self.mesh, mapping))
                     for p in self._items)
-            _, pure_step = make_pure_fn(net, step)
-            _, pure_chunk = make_pure_fn(net, chunk)
+            _, pure_step = make_pure_fn(net, step, "serving_decode")
+            _, pure_chunk = make_pure_fn(net, chunk, "serving_chunk")
             pure_verify = pure_draft = None
             if spec_k:
-                _, pure_verify = make_pure_fn(net, verify)
-                _, pure_draft = make_pure_fn(net, draft)
+                _, pure_verify = make_pure_fn(net, verify, "serving_verify")
+                _, pure_draft = make_pure_fn(net, draft, "serving_draft")
             # donate the cache buffers on TPU (in-place update, no copy of
             # the S×Tmax×H×D arrays per step); CPU jax warns on donation.
             # The DRAFT never donates: it only reads the caches (its
@@ -1327,9 +1328,12 @@ class InferenceEngine:
             self._jit_parity_chunk = None
             self._jit_parity_step = None
             if self._paged and self.debug_parity:  # raceguard: unguarded(jit build: read once before the scheduler thread starts; later flips only disable the twin, never re-enable)
-                _, pure_pp = make_pure_fn(net, parity_prefill)
-                _, pure_pc = make_pure_fn(net, parity_chunk)
-                _, pure_ps = make_pure_fn(net, parity_step)
+                _, pure_pp = make_pure_fn(net, parity_prefill,
+                                          "serving_parity_prefill")
+                _, pure_pc = make_pure_fn(net, parity_chunk,
+                                          "serving_parity_chunk")
+                _, pure_ps = make_pure_fn(net, parity_step,
+                                          "serving_parity_decode")
                 if jax.default_backend() == "tpu":
                     self._jit_parity_prefill = jax.jit(
                         pure_pp, donate_argnums=(3,))
@@ -1352,7 +1356,7 @@ class InferenceEngine:
                     self._fwd_single = False
                 return tuple(o.jax for o in out)
 
-            self._items, pure_forward = make_pure_fn(net, forward)
+            self._items, pure_forward = make_pure_fn(net, forward, "serving_forward")
             self._jit_forward = jax.jit(pure_forward)
 
     def _params(self):
@@ -1386,30 +1390,38 @@ class InferenceEngine:
         return tuple(out)
 
     def _counted(self, key, fn, *args):
-        """Run a compiled entry, tracking engine-level bucket hits vs
-        compiles (mirrors jax's per-shape executable cache).  A first
-        call per key legitimately spends seconds-to-minutes in XLA
-        compilation, so the hang watchdog is suspended for its duration
-        (``_compiling``) — compile-time slowness must not condemn a
-        healthy engine."""
-        if key in self._shape_seen:
-            self.metrics.count("bucket_hits")
-            first = False
-        else:
+        """Run a compiled entry, counting it as a bucket hit or as the
+        XLA compiles it caused.  A first call per key legitimately
+        spends seconds-to-minutes in XLA compilation, so the hang
+        watchdog is suspended for its duration (``_compiling``) —
+        compile-time slowness must not condemn a healthy engine."""
+        first = key not in self._shape_seen
+        if first:
             self._shape_seen.add(key)
-            self.metrics.count("compiles")
-            # per-(bucket, mesh)-point accounting: one engine serves
-            # exactly one mesh point, so its compiles all land under
-            # its own key — stats()["compile"]["by_mesh_point"] merges
-            # across engines in a sharded-vs-1-device comparison
-            self._compiles_by_mesh[self._mesh_key] = \
-                self._compiles_by_mesh.get(self._mesh_key, 0) + 1
-            first = True
             self._compiling = True
+        compiled0 = _xla_compiles()
         try:
             with self.metrics.span(key[0]):
                 return fn(*args)
         finally:
+            # what XLA was actually asked for over THIS call (compiles
+            # run on the calling thread), not a guess from the first
+            # call per bucket: a program that compiles again behind a
+            # seen key — committed weights after set_data against the
+            # uncommitted ones warmup() saw — moves the counter too
+            compiled = _xla_compiles() - compiled0
+            if compiled:
+                self.metrics.count("compiles", compiled)
+                # per-(bucket, mesh)-point accounting: one engine serves
+                # exactly one mesh point, so its compiles all land under
+                # its own key — stats()["compile"]["by_mesh_point"]
+                # merges across engines in a sharded-vs-1-device
+                # comparison
+                self._compiles_by_mesh[self._mesh_key] = \
+                    self._compiles_by_mesh.get(self._mesh_key, 0) \
+                    + compiled
+            else:
+                self.metrics.count("bucket_hits")
             if first:
                 self._compiling = False
                 self._heartbeat = time.monotonic()
@@ -2132,12 +2144,17 @@ class InferenceEngine:
                     # (S+1, k) / (S+1, k+1) shapes — after this the
                     # compile counter must stay frozen through any mix
                     # of speculative and plain cycles
-                    self._counted(
+                    draft = self._counted(
                         ("draft",), self._jit_draft, params, zeros,
                         self._caches, zeros, *self._zero_samp(s1),
                         jnp.asarray(0.0, jnp.float32), *tbl)
-                    toks2 = jnp.zeros((s1, self.spec_tokens + 1),
-                                      jnp.int32)
+                    # the verify window as the live cycle builds it,
+                    # from the draft's own output: under a mesh that
+                    # output is a committed sharded array, and a window
+                    # of fresh zeros would warm a program the first
+                    # live cycle cannot use (it compiled verify again)
+                    toks2 = jnp.concatenate([zeros[:, None], draft],
+                                            axis=1)
                     _vt, _ok, self._caches = self._counted(
                         ("verify",), self._jit_verify, params, toks2,
                         self._caches, zeros, *self._zero_samp(s1),
@@ -2822,13 +2839,15 @@ class InferenceEngine:
             # phase spans are RETROSPECTIVE — rebuilt from the request
             # timestamps the engine keeps anyway, so a completing
             # request costs three ring appends, no live bookkeeping
+            root = tr.new_span_id()
             tr.record_span("serving.prefill_phase", req.t_schedule,
-                           t_first, trace_id=req.trace_id)
+                           t_first, trace_id=req.trace_id, parent=root)
             tr.record_span("serving.decode_phase", t_first, now,
-                           trace_id=req.trace_id,
+                           trace_id=req.trace_id, parent=root,
                            tokens=len(st.generated))
             tr.record_span("serving.request", req.t_submit, now,
-                           trace_id=req.trace_id, request=req.id)
+                           trace_id=req.trace_id, span_id=root,
+                           request=req.id)
             tr.event("serving.complete", trace_id=req.trace_id)
         req.future.set_result(seq)
 
@@ -4459,9 +4478,11 @@ class InferenceEngine:
             self.metrics.count("completed")
             self.metrics.count_served(r.priority_name)
             if tr is not None and r.trace_id is not None:
+                root = tr.new_span_id()
                 tr.record_span("serving.queue", r.t_submit, r.t_schedule,
-                               trace_id=r.trace_id)
+                               trace_id=r.trace_id, parent=root)
                 tr.record_span("serving.request", r.t_submit, done,
-                               trace_id=r.trace_id, request=r.id)
+                               trace_id=r.trace_id, span_id=root,
+                               request=r.id)
                 tr.event("serving.complete", trace_id=r.trace_id)
             r.future.set_result(res)

@@ -22,6 +22,7 @@ from typing import Dict, List, Optional
 
 from .. import profiler as _profiler
 from ..analysis.lockwitness import named_lock as _named_lock
+from ..observability.trace import host_range as _host_range
 
 __all__ = ["LatencyHistogram", "ServingMetrics"]
 
@@ -378,9 +379,11 @@ class ServingMetrics:
 
     # ------------------------------------------------- profiler integration
     def span(self, kind: str):
-        """Named range in the device trace around one scheduled batch
-        (shows up next to the XLA ops it launched)."""
-        return _profiler.Marker(f"{self.name}:{kind}").span()
+        """Named range ``marker:<engine>:<kind>`` in the device trace
+        around one scheduled batch (shows up next to the XLA ops it
+        launched).  The engine records the batch's span itself, with
+        every rider's trace id, so only the range is opened here."""
+        return _host_range(self.name, kind, launches=True, span=False)
 
     def mark(self, event: str, value=None):
         """Instant marker (e.g. admission, shed, timeout); ``value``

@@ -524,6 +524,232 @@ def test_trainer_and_loop_spans(tmp_path):
     assert commits[0].attrs["step"] == 2
 
 
+def _tiny_trainer():
+    import jax
+
+    from mxnet_tpu import gluon, nd
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.gluon import nn
+
+    mesh = par.make_mesh(devices=jax.devices()[:1])
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, activation="relu", in_units=4),
+            nn.Dense(2, in_units=8))
+    net.initialize()
+    trainer = par.ShardedTrainer(
+        net, "adam", loss=gluon.loss.SoftmaxCrossEntropyLoss(),
+        optimizer_params={"learning_rate": 0.01}, mesh=mesh)
+    rs = onp.random.RandomState(0)
+
+    def batch(rows=8):
+        return (nd.array(rs.randn(rows, 4).astype("float32")),
+                nd.array((rs.randn(rows) > 0).astype("int32")))
+
+    return trainer, batch
+
+
+@pytest.mark.parametrize("launches,annotation", [
+    (True, "marker:trainer:dispatch"), (False, "span:trainer.dispatch")])
+def test_host_range_prefix_rule(launches, annotation, monkeypatch):
+    """One rule for the range's name in a device capture: what launches
+    programs is ``marker:<layer>:<phase>`` (a trace reader attributes a
+    program to the last ``marker:`` opened before it started), what only
+    waits is ``span:<layer>.<phase>``.  The range is opened whether or
+    not a tracer is on; the span only when one is."""
+    from mxnet_tpu.observability import trace as obs_trace
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            opened.append("enter")
+
+        def __exit__(self, *a):
+            opened.append("exit")
+
+    monkeypatch.setattr(obs_trace, "_TraceAnnotation", Annotation)
+    with obs_trace.host_range("trainer", "dispatch", launches=launches):
+        pass
+    assert opened == [annotation, "enter", "exit"]      # tracer off
+    tr = obs.enable_tracing()
+    with tr.span("trainer.step") as step:
+        with obs_trace.host_range("trainer", "dispatch",
+                                  launches=launches, parent=step, k=1):
+            pass
+        with obs_trace.host_range("serving", "decode", launches=launches,
+                                  span=False):
+            pass                # the caller records its own span
+    assert opened.count("enter") == opened.count("exit") == 3
+    child, = tr.spans(name="trainer.dispatch")
+    assert child.parent_id == step.span_id and child.attrs == {"k": 1}
+    assert not tr.spans(name="serving.decode")
+
+
+def test_serving_metrics_span_keeps_its_printed_name(monkeypatch):
+    """``marker:<engine>:<kind>``: four committed trace readers match on
+    it, and it records no span of its own."""
+    from mxnet_tpu.observability import trace as obs_trace
+
+    opened = []
+    real = obs_trace._TraceAnnotation
+    monkeypatch.setattr(obs_trace, "_TraceAnnotation",
+                        lambda name: opened.append(name) or real(name))
+    tr = obs.enable_tracing()
+    with ServingMetrics("eng7").span("decode"):
+        pass
+    assert opened == ["marker:eng7:decode"] and len(tr) == 0
+
+
+def test_trainer_phases_are_children_of_step():
+    """The four host phases of a step are children of ``trainer.step``,
+    in order and without overlap, and self times add up: the children's
+    own time plus the step's own is the step's duration."""
+    trainer, batch = _tiny_trainer()
+    trainer.step(*batch()).asnumpy()             # compile outside
+    tr = obs.enable_tracing()
+    for _ in range(2):
+        trainer.step(*batch()).asnumpy()
+    steps = tr.spans(name="trainer.step")
+    assert len(steps) == 2
+    own = tr.self_seconds()
+    phases = ("trainer.scalars", "trainer.place", "trainer.dispatch",
+              "trainer.rebind")
+    for step in steps:
+        kids = [s for s in tr.spans() if s.parent_id == step.span_id]
+        assert tuple(k.name for k in kids) == phases
+        assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+        assert step.t0 <= kids[0].t0 and kids[-1].t1 <= step.t1
+        total = own[step.span_id] + sum(own[k.span_id] for k in kids)
+        assert total == pytest.approx(step.duration_s, abs=1e-9)
+        assert 0 <= own[step.span_id] < step.duration_s
+    # the wait for the loss is the caller's, outside the step's span
+    reads = tr.spans(name="ndarray.readback")
+    assert len(reads) == 2 and all(r.parent_id is None for r in reads)
+    assert all(r.t0 >= s.t1 for r, s in zip(reads, steps))
+
+
+def test_timeline_reports_self_time():
+    tr = obs.enable_tracing()
+    tid = tr.new_trace_id()
+    root = tr.new_span_id()
+    # retrospective children first, naming an id reserved for the parent
+    tr.record_span("serving.queue", 0.0, 1.0, trace_id=tid, parent=root)
+    tr.record_span("serving.prefill_phase", 1.0, 1.5, trace_id=tid,
+                   parent=root)
+    tr.record_span("serving.decode_phase", 1.25, 3.0, trace_id=tid,
+                   parent=root)                 # overlaps: counted once
+    tr.record_span("serving.request", 0.0, 4.0, trace_id=tid,
+                   span_id=root)
+    tr.record_span("elsewhere", 0.0, 4.0, trace_id=tr.new_trace_id())
+    tl = {d["name"]: d for d in tr.timeline(tid)}
+    assert tl["serving.request"]["span_id"] == root
+    assert tl["serving.request"]["parent_id"] is None
+    assert tl["serving.queue"]["parent_id"] == root
+    assert tl["serving.request"]["self_ms"] == 1000.0   # 4 s less [0, 3]
+    assert tl["serving.queue"]["self_ms"] == 1000.0     # no children
+    assert "elsewhere" not in tl
+
+
+def test_request_phases_name_the_request_as_parent(net):
+    tr = obs.enable_tracing(capacity=8192)
+    eng = _engine(net, name="trace_parent")
+    with eng:
+        fut = eng.submit(_prompts((5,))[0], max_new_tokens=3)
+        fut.result(timeout=120)
+    tl = {d["name"]: d for d in tr.timeline(fut.trace_id)}
+    root = tl["serving.request"]
+    for phase in ("serving.prefill_phase", "serving.decode_phase"):
+        assert tl[phase]["parent_id"] == root["span_id"]
+    assert root["self_ms"] == pytest.approx(
+        root["duration_ms"] - tl["serving.prefill_phase"]["duration_ms"]
+        - tl["serving.decode_phase"]["duration_ms"], abs=1e-3)
+
+
+def test_xla_compiles_total_says_which_step_compiled():
+    """``mxtpu_xla_compiles_total`` moves by one on a batch shape the
+    step has not seen and not on a repeated one, and the trainer records
+    which step it was, with the batch's shapes."""
+    def total():
+        return sum(s["value"] for s in default_registry().collect()
+                   ["samples"] if s["name"] == "mxtpu_xla_compiles_total")
+
+    trainer, batch = _tiny_trainer()
+    for _ in range(2):
+        trainer.step(*batch()).asnumpy()         # everything warm
+    tr = obs.enable_tracing()
+    fr = obs.enable_flight_recorder()
+    try:
+        before = total()
+        trainer.step(*batch()).asnumpy()         # a repeated shape
+        assert total() == before
+        assert not tr.spans(name="trainer.compile")
+        trainer.step(*batch(rows=16)).asnumpy()  # a new one
+        assert total() == before + 1
+        trainer.step(*batch(rows=16)).asnumpy()
+        assert total() == before + 1
+        ev, = tr.spans(name="trainer.compile")
+        step = tr.spans(name="trainer.step")[1]
+        assert ev.parent_id == step.span_id
+        assert ev.attrs == {"step": 4, "compiles": 1,
+                            "shapes": ["float32[16, 4]", "int32[16]"]}
+        rec = [e for e in fr.events() if e.name == "trainer.compile"]
+        assert len(rec) == 1 and rec[0].attrs["step"] == 4
+    finally:
+        obs.disable_flight_recorder()
+
+
+def test_names_inside_the_compiled_step():
+    """Names that no trace reader can see yet, checked where they can
+    be: the program is ``jit_trainer_step``; the forward and the loss
+    lower under the scope ``fwd`` (the backward reads
+    ``transpose(jvp(fwd))`` by itself) and the update under
+    ``optimizer``."""
+    trainer, batch = _tiny_trainer()
+    lowered = trainer.lower_step(*batch())
+    text = lowered.as_text(debug_info=True)
+    hlo = lowered.compile().as_text()
+    assert hlo.startswith("HloModule jit_trainer_step")
+    for scope in ("jvp(fwd)", "transpose(jvp(fwd))", "optimizer"):
+        assert f'"jit(trainer_step)/{scope}/' in text, scope
+        assert f'op_name="jit(trainer_step)/{scope}/' in hlo, scope
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv", "paged_decode"])
+def test_pallas_kernels_carry_their_names(kernel):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.flash import flash_attention
+    from mxnet_tpu.ops.paged import paged_attention
+
+    if kernel == "paged_decode":
+        q = jnp.zeros((2, 1, 4, 16), jnp.float32)
+        pages = jnp.zeros((7, 8, 4, 16), jnp.float32)
+        jaxpr = jax.make_jaxpr(paged_attention)(
+            q, pages, pages, jnp.zeros((2, 4), jnp.int32),
+            jnp.zeros((2, 1), jnp.int32))
+    else:
+        q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, causal=True, interpret=True))))(
+                q, q, q)
+
+    def names(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield str(eqn.params.get("name")
+                          or eqn.params["name_and_src_info"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from names(sub)
+
+    assert any(kernel in n for n in names(jaxpr.jaxpr)), \
+        list(names(jaxpr.jaxpr))
+
+
 # ---------------------------------------------- LatencyHistogram bounds
 
 def test_percentile_never_above_observed_max():
