@@ -4,36 +4,78 @@ Capability add over the reference (SURVEY.md §5.7: MXNet ships NO
 flash/ring attention; its fused BERT matmuls in
 src/operator/contrib/transformer.cc materialize the full (T, T) score
 matrix).  This kernel never materializes scores: the softmax is computed
-online per (block_q, block_k) tile held in VMEM, accumulating into an
-f32 VMEM scratch, so long sequences are bounded by HBM for Q/K/V only.
+online per tile held in VMEM, accumulating into an f32 VMEM scratch, so
+long sequences are bounded by HBM for Q/K/V only.
 
-Layout: public entry takes (B, T, H, D) and flattens to (B*H, T, D);
-grid = (batch*heads, q_blocks, kv_blocks) with the kv dimension innermost
-("arbitrary" semantics — it carries the online-softmax accumulator) and
-the first two parallel.  Causal blocks above the diagonal are predicated
-out with ``pl.when`` so the MXU never sees them.
+Layout: public entry takes (B, T, H, D) and flattens to (B*H, T, D).
+The grid is (groups of heads, blocks, major stretches).  One grid step
+owns one ``block_q``-row block of q (fwd, dq) or of k/v (dkv) and is
+handed the operand it walks — K and V for fwd/dq; Q, dO, lse and delta
+for dkv — as one *major* stretch of the sequence: all of it whenever
+that fits VMEM (at T = 1,024, D = 64, bf16 one head's K is 128 KB), so
+the third grid axis has one step and Pallas fetches the stretch once per
+head, not once per block.  Only a sequence too long for that walks the
+third ("arbitrary") axis, the accumulators carried in scratch across it.
+
+Inside the step the walk over that operand is code, not grid
+(:func:`_walk`, :func:`_schedule`).  Under ``causal`` the block's own
+square on the diagonal is cut into slabs, each run straight-line on the
+trapezoid of rows that can see it, and only a slab's diagonal tile builds
+the position mask (iota, compare, select); what the block sees whole is a
+``fori_loop`` over chunks whose trip count is the causal bound and whose
+body holds no mask code at all; what the mask hides is never visited.
+So the upper triangle is skipped at slab granularity at EVERY sequence
+length — at T = 1,024 a head runs 3 of 4 512-wide tiles forward and 36
+of 64 128-wide tiles backward — where a grid of one 1,024 x 1,024 tile a
+head skipped nothing.  With segment ids every slice masks by segment,
+and a slice whose id range cannot meet the block's is skipped.
 
 The backward pass is the standard flash-attention-2 split: a ``dq``
-kernel (grid over q blocks, reducing across kv) and a ``dkv`` kernel
-(grid over kv blocks, reducing across q), both re-computing the tile of
-probabilities from the saved per-row logsumexp.
+kernel (q blocks, walking kv) and a ``dkv`` kernel (kv blocks, walking
+q), both re-computing the tile of probabilities from the saved per-row
+logsumexp.  ``dkv`` holds its tiles transposed, (kv rows, q columns), so
+that dV = P^T dO and dK = dS^T Q are plain row-by-column products and the
+per-row lse/delta broadcast along sublanes as they are stored.
+
+Sizes (``block_q``, ``chunk``, slab widths, ``major``, heads a step) are
+computed in ONE place, :func:`tile_plan`, from what the call can see:
+sequence lengths, head dim, dtype, masks, head count.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Measured on TPU v5e (B16 T1024 H12 D64, causal): 128x128 blocks run the
-# fwd kernel at 16.7 ms vs 1.6 ms at 1024x1024 — big tiles keep the MXU fed
-# (d=64 contractions are half-width already) and amortize grid/DMA overhead.
-# 2048x2048 exceeds VMEM (the (bq, bk) f32 score tile alone is 16 MB).
+# Measured on TPU v5e, B8 T1024 H12 D64 bf16 causal, fwd / dq / dkv in ms a
+# call (PERF.md §6, PR 25): the one-tile-a-head grid this replaces 0.500 /
+# 0.439 / 0.616.  At D = 64 its dq and dkv were already bound by the MXU
+# (a 64-deep contraction or a 64-wide result fills half the array), so
+# the time is in the tiles run, and what a finer cut gains it must not
+# lose to work that runs beside no matmul.  Blocks of 256 rows on the
+# grid with a loop of 256-wide chunks: 0.369 / 0.330 / 0.479 with four
+# heads a step, 0.567 / 0.490 / 0.726 with one (a loop body of one small
+# tile leaves the matmul → exp → matmul chain's latencies bare).  One
+# 1,024-row block a head, its square cut into trapezoid slabs, all
+# straight-line: slabs of 512 / 256 / 128 give fwd 0.264 / 0.294 / 0.348
+# (each slab updates the per-row softmax state), dq 0.335 / 0.294 /
+# 0.280, dkv 0.459 / 0.412 / 0.356 (no such state; finer skips more).
+# Off the diagonal there is nothing to skip and the loop's chunk is
+# independent of the slabs: at T = 4,096 chunks of 256 run the three in
+# 6.4 ms, chunks of 512 in 7.0 (the parent: 8.5).  Packed documents of
+# 128-512 tokens want block and chunk both at 256 (1.16 ms; 1.35 at block
+# 512, 1.65 at 1,024).
 DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
+DEFAULT_CHUNK = 256
+DEFAULT_SLAB = 512
+DEFAULT_SLAB_BWD = 128
+DEFAULT_SEG = 256
+DEFAULT_GROUP = 4
 _MASK = -1e30
 _LANES = 128
 
@@ -42,37 +84,215 @@ _LANES = 128
 _VMEM_BUDGET = 12 * 2 ** 20
 
 
-def _vmem_bytes(bq: int, bk: int, d: int, itemsize: int,
-                has_seg: bool = False) -> int:
+class TilePlan(NamedTuple):
+    """Sizes of one flash call and how much of the score square they run.
+
+    ``block_q``: rows of the block a grid step owns (q rows in fwd/dq, kv
+    rows in dkv).  ``chunk``: rows of one slice of the walked operand in
+    the loop over what the block sees whole.  ``slab`` / ``slab_bwd``:
+    width of the slices the diagonal's own square is cut into, in fwd and
+    in dq/dkv.  ``major`` / ``major_q``: the stretch of kv (fwd, dq) / of
+    q (dkv) a grid step holds in VMEM.  ``group``: heads of one batch row
+    a grid step works on side by side.  ``tiles_*`` count (slab, slab)
+    tiles of one head's forward: in the whole square, visited, and
+    visited with a mask; ``tiles_*_bwd`` the same in (slab_bwd, slab_bwd)
+    tiles for dq and dkv."""
+    block_q: int
+    chunk: int
+    slab: int
+    slab_bwd: int
+    major: int
+    major_q: int
+    group: int
+    tiles_run: int
+    tiles_full: int
+    tiles_masked: int
+    tiles_run_bwd: int
+    tiles_full_bwd: int
+
+
+def _vmem_bytes(block: int, chunk: int, major: int, d: int, itemsize: int,
+                has_seg: bool = False, group: int = 1) -> int:
     """Working-set model of one grid step, sized for the WORST of the
-    three kernels (the bwd dq/dkv kernels stream four tiles — q, k, v,
-    do — where fwd streams three): two live (bq, bk) f32 score-tile
-    temporaries (s→p and dp→ds are reused in place), double-buffered
-    input tiles, double-buffered output tile(s), and the larger of the
-    fwd/dkv f32 accumulator scratch sets.  The segment path adds one
-    more (bq, bk)-sized temporary (the q==k equality mask materialized
-    by the ``jnp.where``) plus the double-buffered int32 seg-id tiles."""
-    score = 2 * 4 * bq * bk
-    tiles = 2 * itemsize * d * 2 * (bq + bk)      # dq/dkv stream 4 tiles
-    outs = 2 * itemsize * bq * d
-    scratch = 4 * max(bq * d + 2 * bq * _LANES,   # fwd: acc + m + l
-                      2 * bk * d)                 # dkv: dk_acc + dv_acc
-    seg = (4 * bq * bk + 2 * 4 * (bq + bk)) if has_seg else 0
-    return score + tiles + outs + scratch + seg
+    three kernels, per head of the step's ``group``: two live
+    (block, chunk) f32 score-tile temporaries (s→p and dp→ds are reused
+    in place) and one more for a mask; the walked operand resident and
+    double-buffered (dkv walks the most: q, do and the f32 lse/delta
+    rows, which pad to 8 sublanes); the block's own double-buffered input
+    and output tiles (dq reads q, do; dkv writes dk, dv); and the largest
+    f32 scratch set (fwd and dq: an accumulator and two lane-replicated
+    row tiles).  The minor dim pads to 128 lanes.  Segments add their
+    resident int32 row."""
+    dl = max(d, _LANES)
+    score = 3 * 4 * block * chunk
+    resident = 2 * (2 * major * dl * itemsize + 2 * 8 * major * 4)
+    tiles = 2 * (2 * block * dl * itemsize + 2 * 8 * block * 4)
+    outs = 2 * 2 * block * dl * itemsize
+    scratch = 4 * max(block * dl + 2 * block * _LANES,   # fwd, dq
+                      2 * block * dl)                    # dkv: dk + dv
+    seg = 2 * 8 * 4 * (major + block) if has_seg else 0
+    return group * (score + resident + tiles + outs + scratch) + seg
 
 
-def _clamp_blocks(bq: int, bk: int, d: int, itemsize: int,
-                  has_seg: bool = False):
-    """Shrink (block_q, block_k) until the working set fits the VMEM
-    budget — head-dim/dtype aware, so d=64 bf16 keeps the measured-fast
-    1024x1024 while d=256 f32 lands on a safe smaller tile."""
-    while _vmem_bytes(bq, bk, d, itemsize, has_seg) > _VMEM_BUDGET and \
-            (bq > 128 or bk > 128):
-        if bk >= bq and bk > 128:
-            bk //= 2
-        else:
-            bq //= 2
-    return bq, bk
+def _largest_stretch(t: int, chunk: int, fits) -> int:
+    """The longest stretch of a ``t``-row operand that divides it, is a
+    whole number of chunks, and ``fits``; one chunk at the least."""
+    for n in range(1, t // chunk + 1):
+        if t % n == 0 and (t // n) % chunk == 0 and fits(t // n):
+            return t // n
+    return chunk
+
+
+def _clamp_blocks(blocks, chunk: int, tq: int, tk: int, d: int,
+                  itemsize: int, has_seg: bool = False, group: int = 1,
+                  wide: Optional[int] = None):
+    """(block, major, major_q, group) that fit the VMEM budget, from the
+    candidate ``blocks`` (largest first).  What is kept longest is what
+    the chip showed to matter most: the largest block (fewest grid steps,
+    the longest regions to schedule, the biggest tiles in the loop) that
+    leaves room for a stretch of the walked operand at least two blocks
+    long, or the whole sequence if that is shorter; then the longest
+    stretch; then heads side by side — head-dim and dtype aware, so d=64
+    bf16 at T=1024 keeps a 1024-row block with the head resident while
+    d=256 f32 at T=8192 lands on one head, a 512-row block and a
+    1024-row stretch.  ``wide`` is the widest slice a step holds scores
+    of, where that is not the chunk."""
+    def fits(block, major, group=1):
+        return _vmem_bytes(block, wide or chunk, major, d, itemsize,
+                           has_seg, group) <= _VMEM_BUDGET
+
+    longest = max(tq, tk)
+    block = next((b for b in blocks if fits(b, min(longest, 2 * b))),
+                 blocks[-1])
+    major = _largest_stretch(tk, chunk, lambda m: fits(block, m))
+    major_q = _largest_stretch(tq, chunk, lambda m: fits(block, m))
+    while group > 1 and not fits(block, max(major, major_q), group):
+        group //= 2
+    return block, major, major_q, group
+
+
+def _schedule(i, block: int, chunk: int, slab: int, total: int,
+              causal: bool, up: bool):
+    """What block ``i`` visits of a walked operand of ``total`` chunks,
+    all of it in one stretch: ``(diagonal, plain)``.  ``i`` may be
+    traced; every size and row range is static.
+
+    ``plain`` is the (lo, hi) range of chunks that every row of the block
+    sees whole.  ``diagonal`` cuts the block's own square — the columns
+    of its own positions — into slabs ``(first column, width, rows,
+    masked rows)``: ``rows`` the (lo, hi) rows of the block that see any
+    of the slab, a trapezoid that leaves out the rows wholly above the
+    diagonal, and ``masked rows`` the rows among them that see it in part
+    and need the position mask.  ``up`` says the block is of q rows
+    walking kv at or before it (fwd, dq) rather than of kv rows walking q
+    at or after it (dkv).  Without ``causal`` there is no diagonal: chunk
+    0 leads, unmasked, so that something is assigned before the loop
+    adds."""
+    whole = (0, block)
+    if not causal:
+        return [(0, chunk, whole, None)], (1, total)
+    per = block // chunk
+    diagonal = [(i * block + lo, slab, (lo, block) if up else (0, lo + slab),
+                 (lo, lo + slab)) for lo in range(0, block, slab)]
+    return diagonal, ((0, i * per) if up else ((i + 1) * per, total))
+
+
+def _count_tiles(tq, tk, block, chunk, slab, causal, whole):
+    """(visited, whole square, masked) in (slab, slab) tiles of one head,
+    by the schedule the kernels run; without the ``whole`` operand in
+    one stretch every chunk the diagonal crosses takes every row."""
+    run = masked = 0
+    for i in range(tq // block):
+        diagonal, (lo, hi) = _schedule(i, block, chunk, slab, tk // chunk,
+                                       causal, True)
+        run += (hi - lo) * chunk // slab * (block // slab)
+        if causal and not whole:
+            run += (block // slab) ** 2
+            masked += (block // slab) ** 2
+            continue
+        for _, width, (r0, r1), rows in diagonal:
+            run += (r1 - r0) // slab * (width // slab)
+            masked += (rows[1] - rows[0]) // slab if rows else 0
+    return run, (tq // slab) * (tk // slab), masked
+
+
+def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
+              has_seg: bool = False, *, heads: int = 1,
+              block_q: Optional[int] = None,
+              chunk: Optional[int] = None) -> TilePlan:
+    """The one place a flash call's sizes are computed: from the sequence
+    lengths, head dim, dtype, the masks in play and the number of heads.
+    ``block_q`` / ``chunk`` override the starting sizes (tests only; a
+    given chunk is the slab width too).  Pure and static: no device, no
+    tracing.
+
+    The block is the largest whole number of chunks, up to
+    ``DEFAULT_BLOCK_Q`` rows, that divides the sequences and fits VMEM
+    with a fair stretch of the walked operand.  Its own square on the
+    diagonal is cut into slabs — the granularity of the causal skip —
+    coarser in fwd, whose every slab updates the per-row softmax state,
+    than in dq/dkv, which carry none; what lies off the diagonal is
+    walked in chunks.  Under segment ids the skip is per (block, chunk)
+    pair and wants both as fine as ``DEFAULT_SEG`` rows.  A grid step
+    takes up to ``DEFAULT_GROUP`` heads of one batch row where the head
+    count divides and VMEM allows."""
+    span = math.gcd(tq, tk)
+    fine = DEFAULT_SEG if has_seg else None
+
+    def fit(size, within):             # halve until it divides
+        size = min(size, within)
+        while size > 128 and within % size:
+            size //= 2
+        return size
+
+    slabs = (chunk or fine or DEFAULT_SLAB, chunk or fine or DEFAULT_SLAB_BWD)
+    # off the diagonal nothing is skipped; with no mask at all the chunk
+    # doubles (half as many updates of the per-row state)
+    chunk = fit(chunk or fine or DEFAULT_CHUNK * (1 if causal else 2), span)
+    cap = min(block_q or fine or DEFAULT_BLOCK_Q, span)
+    # whole numbers of chunks that divide both sequences, largest first;
+    # a block smaller than the chunk (tests) takes the chunk down with it
+    blocks = [b for b in range(cap - cap % chunk, 0, -chunk)
+              if span % b == 0]
+    if not blocks:
+        blocks = [fit(cap, span)]
+        chunk = fit(chunk, blocks[0])
+    if span % chunk or span % blocks[-1] or blocks[-1] % chunk:
+        raise ValueError(
+            f"seq lens ({tq}, {tk}) must divide by blocks "
+            f"({blocks[-1]}, {chunk})")
+    group = DEFAULT_GROUP
+    while heads % group:
+        group //= 2
+    # the widest slice a step holds scores of: a chunk, or a forward slab
+    # of the smallest block
+    wide = max(chunk, fit(slabs[0], blocks[-1]))
+    block, major, major_q, group = _clamp_blocks(
+        blocks, chunk, tq, tk, d, jnp.dtype(dtype).itemsize, has_seg, group,
+        wide)
+    slab, slab_bwd = (fit(x, block) for x in slabs)
+    run, full, masked = _count_tiles(tq, tk, block, chunk, slab, causal,
+                                     major == tk)
+    run_bwd, full_bwd, _ = _count_tiles(tq, tk, block, chunk, slab_bwd,
+                                        causal, major_q == tq)
+    return TilePlan(block, chunk, slab, slab_bwd, major, major_q, group,
+                    run, full, run if has_seg else masked, run_bwd,
+                    full_bwd)
+
+
+def _report_plan(plan: TilePlan, tq, tk, d, dtype, causal, has_seg):
+    """One ``flash.plan`` event per distinct plan in the tracer's ring,
+    recorded while the call is traced (never in a step's hot path);
+    nothing without a tracer."""
+    from ..observability.trace import active
+    tr = active()
+    if tr is None:
+        return
+    attrs = dict(plan._asdict(), tq=tq, tk=tk, d=d,
+                 dtype=jnp.dtype(dtype).name, causal=bool(causal),
+                 has_seg=bool(has_seg))
+    if not any(s.attrs == attrs for s in tr.spans(name="flash.plan")):
+        tr.event("flash.plan", **attrs)
 
 
 def _dot(a, b, ca: int, cb: int):
@@ -95,153 +315,340 @@ def _default_interpret(x) -> bool:
     return resolve_exec_platform(x) != "tpu"
 
 
+# ------------------------------------------------------- the walk, shared
+
+def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
+          seg):
+    """Run ``step(start, width, rows, masked, fresh)`` over the slices
+    ``[start, start + width)`` of this grid step's major stretch that
+    block ``i`` has to visit: ``rows`` the block's rows that take part,
+    ``masked`` those of them that need the position mask (None: none),
+    ``fresh`` those whose accumulators this visit is the first to touch,
+    so that it assigns them where later visits add (None: none).
+
+    With the whole operand in one stretch (``nm == 1``) the visits are
+    those of :func:`_schedule`: the diagonal's slabs — a static number —
+    run first and straight-line, each on the trapezoid of rows that sees
+    it, so that the scheduler overlaps them with each other and with the
+    step's prologue and (``fold``) nothing has to be zeroed; then one
+    loop, its trip count the causal bound, takes the chunks the block
+    sees whole with a body that holds no mask code.  Every row's own
+    diagonal column lies in the first slab it takes part in, so the
+    running max is finite from a row's first visit on in this order too.
+    Over several stretches every visit is a chunk and takes every row:
+    a loop over the chunks the diagonal crosses, masked, and one over the
+    plain ones, both clipped to the stretch.
+
+    ``seg`` is None or (the block's own segment ids, the walked
+    operand's (1, 1, major) id ref): a slice whose id range cannot meet
+    the block's is skipped — exact for the packed layout (ids
+    non-decreasing along the row), conservative (never skips a slice
+    that could match) for arbitrary ids."""
+    whole = (0, block)
+
+    def visit(start, width, rows, masked, fresh):
+        if seg is None:
+            return step(start, width, rows, masked, fresh)
+        mine, ref = seg
+        theirs = ref[0, :, pl.ds(start, width)]
+
+        @pl.when(jnp.logical_and(jnp.min(theirs) <= jnp.max(mine),
+                                 jnp.max(theirs) >= jnp.min(mine)))
+        def _():
+            step(start, width, rows, masked, fresh)
+
+    def loop(lo, hi, masked):
+        def body(c, carry):
+            visit(pl.multiple_of(c * chunk, chunk), chunk, whole, masked,
+                  None)
+            return carry
+        jax.lax.fori_loop(lo, hi, body, 0)
+
+    diagonal, plain = _schedule(i, block, chunk, slab, nm * cpm, causal, up)
+    if nm > 1:
+        lo, per = mi * cpm, block // chunk
+        crossed = (i * per, (i + 1) * per) if causal else (0, 0)
+        if not causal:
+            plain = (0, plain[1])
+        for (a, b), masked in ((crossed, whole), (plain, None)):
+            loop(jnp.clip(a - lo, 0, cpm), jnp.clip(b - lo, 0, cpm), masked)
+        return
+    for j, (start, width, rows, masked) in enumerate(diagonal):
+        if not fold:
+            fresh = None
+        elif up:                         # the first slab holds every row
+            fresh = rows if j == 0 else None
+        else:                            # each slab brings its last rows
+            fresh = masked or rows
+        if not isinstance(start, int):
+            start = pl.multiple_of(start, width)
+        visit(start, width, rows, masked, fresh)
+    loop(*plain, None)
+
+
+def _keep(rows, masked, width, own_is_q, own0, walk0, seg_own, seg_walk):
+    """The mask of one visit's score tile — (block rows ``rows``, ``width``
+    walked columns) — as ``(keep, sub)``: ``keep`` covers the tile's
+    rows ``sub`` = (lo, hi) only, or is None when nothing masks the tile.
+    Rows ``masked`` of the block (None: none) lie on the diagonal and
+    compare global positions, ``kv <= q``: the block's own run down the
+    tile from ``own0``, the walked operand's along it from ``walk0``, and
+    ``own_is_q`` says which of the two are q's.  Segment ids
+    (``seg_own`` a (block, 1) column, ``seg_walk`` a (1, width) row) mask
+    every row, where the call packs segments."""
+    if seg_own is None and masked is None:
+        return None, None
+    m0, m1 = rows if seg_own is not None else masked
+    keep = None
+    if masked is not None:
+        shape = (m1 - m0, width)
+        own = own0 + m0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        walk = walk0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keep = walk <= own if own_is_q else own <= walk
+    if seg_own is not None:
+        same = seg_own[m0:m1] == seg_walk
+        keep = same if keep is None else jnp.logical_and(keep, same)
+    return keep, (m0 - rows[0], m1 - rows[0])
+
+
+def _where_rows(keep, x, fill, sub):
+    """``where(keep, x, fill)`` on rows ``sub`` = (lo, hi) of ``x`` (the
+    mask covers just those), the other rows as they are."""
+    lo, hi = sub
+    parts = [x[:lo]] if lo else []
+    parts.append(jnp.where(keep, x[lo:hi], fill))
+    if hi < x.shape[0]:
+        parts.append(x[hi:])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _accumulate(ref, g, rows, fresh, val):
+    """Add ``val`` to rows ``rows`` of accumulator ``ref[g]``; rows
+    ``fresh`` (the last of them) are assigned: nothing was there yet."""
+    r0, r1 = rows
+    f0 = r1 if fresh is None else fresh[0]
+    if f0 > r0:
+        ref[g, r0:f0] += val[:f0 - r0]
+    if f0 < r1:
+        ref[g, f0:r1] = val[f0 - r0:]
+
+
 # --------------------------------------------------------------------- fwd
+# Per-row statistics (running max, running sum, lse, delta) live as
+# (rows, 128) tiles with the row's value in every lane.  A (rows, 1)
+# column costs as many vector registers and has to be broadcast along
+# lanes again at every use — an XLU permute per register per chunk, which
+# at these tile sizes cost more than the matmuls.  ``_lanes`` widens or
+# narrows such a tile to a tile's width for free (whole registers repeat).
 
-def _seg_mask(qseg_ref, kseg_ref, s):
-    """Mask score tile entries whose q/k tokens belong to different packed
-    segments.  The tile-skip predicate lives separately in
-    :func:`_run_pred` (shared by all three kernels) so the min/max
-    reductions are computed once per grid step."""
-    qs = qseg_ref[0, 0, :]                             # (bq,) int32
-    ks = kseg_ref[0, 0, :]                             # (bk,) int32
-    return jnp.where(qs[:, None] == ks[None, :], s, _MASK)
+def _lanes(x, n: int):
+    """A lane-replicated (rows, 128) tile as (rows, n)."""
+    if n <= _LANES:
+        return x[:, :n]
+    return jnp.tile(x, (1, n // _LANES))
 
 
-def _fwd_kernel(*refs, scale, causal, has_seg, block_q, block_k, nk):
+def _fold(x):
+    """Sum a (rows, n) tile's 128-lane column groups: (rows, 128) partial
+    sums, one VPU add per register and no cross-lane reduction."""
+    out = x[:, :_LANES]
+    for i in range(1, x.shape[1] // _LANES):
+        out = out + x[:, i * _LANES:(i + 1) * _LANES]
+    return out
+
+
+def _when(static, cond):
+    """``pl.when(cond)``, or no branch at all where ``static`` says the
+    condition always holds: the body then stays in the caller's region,
+    where the scheduler can overlap it with the neighbouring tiles."""
+    return (lambda f: f()) if static else pl.when(cond)
+
+
+def _rows_to_lanes(x):
+    """A lane-replicated (rows, 128) tile as the (1, rows) row vector it
+    is stored as: one XLU transpose of the tile (a rows-to-lanes relayout
+    of a column goes through memory a row at a time)."""
+    return x.T[:1]
+
+
+def _lanes_to_rows(row, rows: int):
+    """The (1, rows) row vector as a lane-replicated (rows, 128) tile:
+    broadcast down the sublanes, then one XLU transpose."""
+    return jnp.broadcast_to(row, (_LANES, rows)).T
+
+
+def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    mi = pl.program_id(2)
+    group, major, d = k_ref.shape
+    fold = nm == 1 and not has_seg
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _MASK)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    if not fold:
+        @pl.when(mi == 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, _MASK)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _tile():
-        q = q_ref[0]                                   # (bq, d)
-        k = k_ref[0]                                   # (bk, d)
-        s = _dot(q, k, 1, 1) * scale                   # (bq, bk)
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col <= row, s, _MASK)
-        if has_seg:
-            s = _seg_mask(qseg_ref, kseg_ref, s)
-        m_prev = m_ref[:, :1]                          # (bq, 1)
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        # masked-safe exp: a tile whose every entry is _MASK for some row
-        # (the row's segment starts in a LATER tile) has m_next == _MASK
-        # there, and bare exp(s - m_next) would contribute exp(0)=1 per
-        # masked entry.  Zero masked entries explicitly instead.
-        p = jnp.where(s <= _MASK * 0.5, 0.0, jnp.exp(s - m_next))
-        corr = jnp.exp(m_prev - m_next)                # (bq, 1)
-        l_next = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        pv = _dot(p.astype(v_ref.dtype), v_ref[0], 1, 0)   # (bq, d)
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = jnp.broadcast_to(m_next, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_next, l_ref.shape)
+    qs = qseg_ref[0, 0, :][:, None] if has_seg else None   # (bq, 1)
 
-    run = _run_pred(causal, has_seg, qi, ki, block_q, block_k,
-                    qseg_ref if has_seg else None,
-                    kseg_ref if has_seg else None)
-    if run is not None:
-        @pl.when(run)
-        def _():
-            _tile()
-    else:
-        _tile()
+    def step(start, width, rows, masked, fresh):
+        r0, r1 = rows
+        keep, sub = _keep(rows, masked, width, True, qi * block_q,
+                          mi * major + start, qs,
+                          kseg_ref[0, :, pl.ds(start, width)] if has_seg
+                          else None)
+        # the group's heads are independent chains of matmul → softmax →
+        # matmul: side by side in one region they fill each other's
+        # latencies
+        for g in range(group):
+            k = k_ref[g, pl.ds(start, width), :]       # (width, d)
+            s = _dot(q_ref[g, r0:r1, :], k, 1, 1) * scale  # (rows, width)
+            if keep is not None:
+                s = _where_rows(keep, s, _MASK, sub)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_prev = jnp.full((r1 - r0, _LANES), _MASK) if fresh \
+                else m_ref[g, r0:r1, :]                # (rows, 128)
+            m_next = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - _lanes(m_next, width))
+            if has_seg:
+                # masked-safe exp: a row whose every entry so far is
+                # masked (its segment starts in a LATER chunk) has
+                # m_next == _MASK, and bare exp(s - m_next) would
+                # contribute exp(0)=1 per masked entry.  Zero masked
+                # entries explicitly.  The causal mask alone never needs
+                # this: every row sees a column of the first chunk it
+                # visits (see _walk), so m_next is finite from then on
+                # and exp(_MASK - m_next) is exactly 0.
+                p = jnp.where(keep, p, 0.0)
+            v = v_ref[g, pl.ds(start, width), :]
+            pv = _dot(p.astype(v.dtype), v, 1, 0)      # (rows, d)
+            # l holds per-lane partial sums; _finish adds the lanes up
+            if fresh:
+                l_ref[g, r0:r1, :] = _fold(p)
+                acc_ref[g, r0:r1, :] = pv
+            else:
+                corr = jnp.exp(m_prev - m_next)        # (rows, 128)
+                l_ref[g, r0:r1, :] = corr * l_ref[g, r0:r1, :] + _fold(p)
+                acc_ref[g, r0:r1, :] = \
+                    acc_ref[g, r0:r1, :] * _lanes(corr, d) + pv
+            m_ref[g, r0:r1, :] = m_next
 
-    @pl.when(ki == nk - 1)
+    _walk(step, causal=causal, up=True, i=qi, mi=mi, nm=nm, block=block_q,
+          chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
+          seg=(qs, kseg_ref) if has_seg else None)
+
+    @_when(nm == 1, mi == nm - 1)
     def _finish():
-        l = l_ref[:, :1]
-        # rows with NO matching key anywhere (possible only in degenerate
-        # cross-segment cases) get zeros out and a finite lse of _MASK so
-        # the backward recompute exp(s - lse) stays 0, never inf
-        empty = l <= 0.0
-        o_ref[0] = jnp.where(
-            empty, 0.0, acc_ref[:] / jnp.where(empty, 1.0, l)
-        ).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(
-            empty[:, 0], _MASK, m_ref[:, 0] + jnp.log(
-                jnp.where(empty[:, 0], 1.0, l_ref[:, 0])))
+        for g in range(group):
+            l = jnp.sum(l_ref[g], axis=1, keepdims=True)   # (bq, 1)
+            # rows with NO matching key anywhere (possible only in
+            # degenerate cross-segment cases) get zeros out and a finite
+            # lse of _MASK so the backward recompute exp(s - lse) stays 0,
+            # never inf
+            empty = l <= 0.0
+            o_ref[g] = jnp.where(
+                empty, 0.0, acc_ref[g] / jnp.where(empty, 1.0, l)
+            ).astype(o_ref.dtype)
+            lse = jnp.where(empty, _MASK, m_ref[g] + jnp.log(
+                jnp.where(empty, 1.0, l)))                 # (bq, 128)
+            lse_ref[g] = _rows_to_lanes(lse)
 
 
-def _seg_specs(nheads, block_q, block_k):
-    """BlockSpecs for (B, 1, T) segment-id planes: the grid's flattened
-    batch*heads coordinate maps back to the batch row with b // nheads."""
+def _own_spec(shape):
+    """BlockSpec of an operand a grid step owns a block of: ``shape`` is
+    the block shape, the block axis the one of its last two that is not
+    the head dim (rows for (g, block, d), lanes for (g, 1, block))."""
+    if shape[1] == 1:
+        return pl.BlockSpec(shape, lambda b, i, m: (b, 0, i))
+    return pl.BlockSpec(shape, lambda b, i, m: (b, i, 0))
+
+
+def _walked_spec(shape, block, major, causal, up, row=lambda b: b):
+    """BlockSpec of a walked operand: its major stretch, with an index map
+    that ignores the block axis (so one head's stretch is fetched once),
+    clamped under ``causal`` to the stretches the block's rows can see —
+    a grid step that has nothing to visit names the stretch it already
+    holds and fetches nothing.  ``shape`` is the block shape with -1 for
+    the sequence axis; ``up`` says the walk ends at the diagonal (fwd,
+    dq) rather than starts there (dkv); ``row`` maps the grid's first
+    coordinate to the operand's."""
+    axis = shape.index(-1)
+
+    def index(b, i, m):
+        if causal and up:
+            m = jnp.minimum(m, ((i + 1) * block - 1) // major)
+        elif causal:
+            m = jnp.maximum(m, (i * block) // major)
+        return tuple(row(b) if a == 0 else m if a == axis else 0
+                     for a in range(len(shape)))
+    return pl.BlockSpec(tuple(major if n == -1 else n for n in shape), index)
+
+
+def _seg_specs(rows, block, major, causal, up):
+    """BlockSpecs for (B, 1, T) segment-id planes, (the block's own, the
+    walked operand's); ``rows`` maps the grid's first coordinate (a group
+    of heads of one batch row) to that batch row."""
     return [
-        pl.BlockSpec((1, 1, block_q),
-                     lambda b, i, j: (b // nheads, 0, i)),
-        pl.BlockSpec((1, 1, block_k),
-                     lambda b, i, j: (b // nheads, 0, j)),
+        pl.BlockSpec((1, 1, block), lambda b, i, m: (rows(b), 0, i)),
+        _walked_spec((1, 1, -1), block, major, causal, up, row=rows),
     ]
 
 
-def _dkv_seg_specs(nheads, block_q, block_k):
-    """Same as _seg_specs for the dkv grid, whose (b, j, i) coords carry
-    the kv block index second."""
-    return [
-        pl.BlockSpec((1, 1, block_q),
-                     lambda b, j, i: (b // nheads, 0, i)),
-        pl.BlockSpec((1, 1, block_k),
-                     lambda b, j, i: (b // nheads, 0, j)),
-    ]
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, block_q, block_k,
-         interpret):
+def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
     bh, tq, d = q.shape
     tk = k.shape[1]
-    nq = pl.cdiv(tq, block_q)
-    nk = pl.cdiv(tk, block_k)
+    block_q, chunk, major, group = (plan.block_q, plan.chunk, plan.major,
+                                    plan.group)
+    nm = tk // major
     has_seg = q_seg is not None
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_seg=has_seg,
-        block_q=block_q, block_k=block_k, nk=nk)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-    ]
+        block_q=block_q, chunk=chunk, slab=plan.slab, nm=nm)
+    walked = _walked_spec((group, -1, d), block_q, major, causal, True)
+    in_specs = [_own_spec((group, block_q, d)), walked, walked]
     args = [q, k, v]
     if has_seg:
-        in_specs += _seg_specs(nheads, block_q, block_k)
+        in_specs += _seg_specs(lambda b: b * group // nheads, block_q,
+                               major, causal, True)
         args += [q_seg, kv_seg]
+    half = 2 if causal else 1
     out, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",
-        grid=(bh, nq, nk),
+        grid=(bh // group, tq // block_q, nm),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            # lse is (bh, 1, tq) so each qi owns its own (1, 1, block_q)
+            _own_spec((group, block_q, d)),
+            # lse is (bh, 1, tq) so each qi owns its own (g, 1, block_q)
             # tile — TPU block rules demand last-two dims divisible by
             # (8, 128) or equal to the array dims, and a shared full-row
             # block would race across megacore's parallel qi partitions.
-            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            _own_spec((group, 1, block_q)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((group, block_q, d), jnp.float32),
+            pltpu.VMEM((group, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((group, block_q, _LANES), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
+        # what the algorithm needs: under a causal mask half the square
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * tq * tk * d, transcendentals=bh * tq * tk,
+            flops=4 * bh * tq * tk * d // half,
+            transcendentals=bh * tq * tk // half,
             bytes_accessed=2 * (q.size + k.size + v.size) * q.dtype.itemsize),
         interpret=interpret,
     )(*args)
@@ -250,193 +657,175 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, block_q, block_k,
 
 # --------------------------------------------------------------------- bwd
 
-def _run_pred(causal, has_seg, qi, ki, block_q, block_k,
-              qseg_ref, kseg_ref):
-    """Tile-skip predicate shared by all three kernels: the causal
-    above-diagonal test plus a range-disjointness test on the tile's
-    segment ids — exact for the packed layout (ids non-decreasing along
-    the row) and conservative (never skips a tile that could match) for
-    arbitrary ids."""
-    run = None
-    if causal:
-        run = ki * block_k < (qi + 1) * block_q
-    if has_seg:
-        qs = qseg_ref[0, 0, :]
-        ks = kseg_ref[0, 0, :]
-        overlap = jnp.logical_and(jnp.min(ks) <= jnp.max(qs),
-                                  jnp.max(ks) >= jnp.min(qs))
-        run = overlap if run is None else jnp.logical_and(run, overlap)
-    return run
-
-
-def _dq_kernel(*refs, scale, causal, has_seg, block_q, block_k, nk):
+def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         qseg_ref, kseg_ref, dq_ref, acc_ref) = refs
+         qseg_ref, kseg_ref, dq_ref, acc_ref, lse_b, delta_b) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, acc_ref) = refs
-        qseg_ref = kseg_ref = None
+         dq_ref, acc_ref, lse_b, delta_b) = refs
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    mi = pl.program_id(2)
+    group, major, _ = k_ref.shape
+    fold = nm == 1 and not has_seg
 
-    @pl.when(ki == 0)
+    @_when(nm == 1, mi == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        if not fold:
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+        # the block's lse and delta, stored along lanes, turned into
+        # lane-replicated row tiles once, not at every chunk
+        for g in range(group):
+            lse_b[g] = _lanes_to_rows(lse_ref[g], block_q)
+            delta_b[g] = _lanes_to_rows(delta_ref[g], block_q)
 
-    def _tile():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = _dot(q, k, 1, 1) * scale
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col <= row, s, _MASK)
-        if has_seg:
-            s = _seg_mask(qseg_ref, kseg_ref, s)
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        p = jnp.where(s <= _MASK * 0.5, 0.0, jnp.exp(s - lse[:, None]))
-        dp = _dot(do_ref[0], v_ref[0], 1, 1)           # (bq, bk)
-        ds = p * (dp - delta[:, None]) * scale
-        acc_ref[:] += _dot(ds.astype(k.dtype), k, 1, 0)
-    run = _run_pred(causal, has_seg, qi, ki, block_q, block_k,
-                        qseg_ref, kseg_ref)
-    if run is not None:
-        @pl.when(run)
-        def _():
-            _tile()
-    else:
-        _tile()
+    qs = qseg_ref[0, 0, :][:, None] if has_seg else None
 
-    @pl.when(ki == nk - 1)
+    def step(start, width, rows, masked, fresh):
+        r0, r1 = rows
+        keep, sub = _keep(rows, masked, width, True, qi * block_q,
+                          mi * major + start, qs,
+                          kseg_ref[0, :, pl.ds(start, width)] if has_seg
+                          else None)
+        for g in range(group):
+            k = k_ref[g, pl.ds(start, width), :]       # (width, d)
+            s = _dot(q_ref[g, r0:r1, :], k, 1, 1) * scale  # (rows, width)
+            p = jnp.exp(s - _lanes(lse_b[g, r0:r1, :], width))
+            if keep is not None:
+                p = _where_rows(keep, p, 0.0, sub)
+            dp = _dot(do_ref[g, r0:r1, :],
+                      v_ref[g, pl.ds(start, width), :], 1, 1)
+            ds = p * (dp - _lanes(delta_b[g, r0:r1, :], width)) * scale
+            _accumulate(acc_ref, g, rows, fresh,
+                        _dot(ds.astype(k.dtype), k, 1, 0))
+
+    _walk(step, causal=causal, up=True, i=qi, mi=mi, nm=nm, block=block_q,
+          chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
+          seg=(qs, kseg_ref) if has_seg else None)
+
+    @_when(nm == 1, mi == nm - 1)
     def _finish():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, has_seg, block_q, block_k, nq):
+def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm):
+    """Tiles are held transposed, (kv rows, q columns): lse and delta are
+    stored along lanes, so they broadcast down the tile as they are, and
+    both accumulating products contract the tile's columns with the rows
+    of dO and Q — no transposed operand."""
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        qseg_ref = kseg_ref = None
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    mi = pl.program_id(2)
+    group, major, _ = q_ref.shape
+    fold = nm == 1 and not has_seg
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    if not fold:
+        @pl.when(mi == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _tile():
-        q = q_ref[0]                                   # (bq, d)
-        k = k_ref[0]                                   # (bk, d)
-        do = do_ref[0]                                 # (bq, d)
-        s = _dot(q, k, 1, 1) * scale                   # (bq, bk)
-        if causal:
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            col = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(col <= row, s, _MASK)
-        if has_seg:
-            s = _seg_mask(qseg_ref, kseg_ref, s)
-        lse = lse_ref[0, 0, :]
-        delta = delta_ref[0, 0, :]
-        p = jnp.where(s <= _MASK * 0.5, 0.0, jnp.exp(s - lse[:, None]))
-        # dV += P^T @ dO
-        dv_acc[:] += _dot(p.astype(do.dtype), do, 0, 0)   # (bk, d)
-        dp = _dot(do, v_ref[0], 1, 1)                  # (bq, bk)
-        ds = p * (dp - delta[:, None]) * scale
-        # dK += dS^T @ Q
-        dk_acc[:] += _dot(ds.astype(q.dtype), q, 0, 0)
-    run = _run_pred(causal, has_seg, qi, ki, block_q, block_k,
-                        qseg_ref, kseg_ref)
-    if run is not None:
-        @pl.when(run)
-        def _():
-            _tile()
-    else:
-        _tile()
+    ks = kseg_ref[0, 0, :][:, None] if has_seg else None   # (bk, 1)
 
-    @pl.when(qi == nq - 1)
+    def step(start, width, rows, masked, fresh):
+        r0, r1 = rows
+        keep, sub = _keep(rows, masked, width, False, ki * block_k,
+                          mi * major + start, ks,
+                          qseg_ref[0, :, pl.ds(start, width)] if has_seg
+                          else None)
+        for g in range(group):
+            q = q_ref[g, pl.ds(start, width), :]       # (width, d)
+            do = do_ref[g, pl.ds(start, width), :]
+            lse = lse_ref[g, :, pl.ds(start, width)]   # (1, width)
+            delta = delta_ref[g, :, pl.ds(start, width)]
+            st = _dot(k_ref[g, r0:r1, :], q, 1, 1) * scale  # S^T: (rows,
+            p = jnp.exp(st - lse)                           #   width)
+            if keep is not None:
+                p = _where_rows(keep, p, 0.0, sub)
+            # dV += P^T @ dO
+            _accumulate(dv_acc, g, rows, fresh,
+                        _dot(p.astype(do.dtype), do, 1, 0))
+            dp = _dot(v_ref[g, r0:r1, :], do, 1, 1)    # dP^T
+            ds = p * (dp - delta) * scale
+            # dK += dS^T @ Q
+            _accumulate(dk_acc, g, rows, fresh,
+                        _dot(ds.astype(q.dtype), q, 1, 0))
+
+    _walk(step, causal=causal, up=False, i=ki, mi=mi, nm=nm, block=block_k,
+          chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
+          seg=(ks, qseg_ref) if has_seg else None)
+
+    @_when(nm == 1, mi == nm - 1)
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
-              block_q, block_k, interpret):
+              plan, interpret):
     bh, tq, d = q.shape
     tk = k.shape[1]
-    nq = pl.cdiv(tq, block_q)
-    nk = pl.cdiv(tk, block_k)
+    block, chunk, group = plan.block_q, plan.chunk, plan.group
     has_seg = q_seg is not None
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]               # (bh, 1, tq)
+    tile, row = _own_spec((group, block, d)), _own_spec((group, 1, block))
 
-    dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-        pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-    ]
+    def rows(b):
+        return b * group // nheads
+
+    kv_walk = _walked_spec((group, -1, d), block, plan.major, causal, True)
+    dq_in_specs = [tile, kv_walk, kv_walk, tile, row, row]
     args = [q, k, v, do, lse, delta]
     if has_seg:
-        dq_in_specs += _seg_specs(nheads, block_q, block_k)
+        dq_in_specs += _seg_specs(rows, block, plan.major, causal, True)
         args += [q_seg, kv_seg]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg,
-                          block_q=block_q, block_k=block_k, nk=nk),
+                          has_seg=has_seg, block_q=block, chunk=chunk,
+                          slab=plan.slab_bwd, nm=tk // plan.major),
         name="flash_bwd_dq",
-        grid=(bh, nq, nk),
+        grid=(bh // group, tq // block, tk // plan.major),
         in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((group, block, d), jnp.float32),
+                        pltpu.VMEM((group, block, _LANES), jnp.float32),
+                        pltpu.VMEM((group, block, _LANES), jnp.float32)],
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(*args)
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-        pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-    ]
+    q_walk = _walked_spec((group, -1, d), block, plan.major_q, causal,
+                          False)
+    row_walk = _walked_spec((group, 1, -1), block, plan.major_q, causal,
+                            False)
+    dkv_in_specs = [q_walk, tile, tile, q_walk, row_walk, row_walk]
     if has_seg:
-        dkv_in_specs += _dkv_seg_specs(nheads, block_q, block_k)
+        dkv_in_specs += _seg_specs(rows, block, plan.major_q, causal,
+                                   False)[::-1]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg,
-                          block_q=block_q, block_k=block_k, nq=nq),
+                          has_seg=has_seg, block_k=block, chunk=chunk,
+                          slab=plan.slab_bwd, nm=tq // plan.major_q),
         name="flash_bwd_dkv",
-        grid=(bh, nk, nq),
+        grid=(bh // group, tk // block, tq // plan.major_q),
         in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((group, block, d), jnp.float32),
+            pltpu.VMEM((group, block, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(*args)
     return dq, dk, dv
@@ -456,25 +845,24 @@ def _int_zero_cotangent(x):
     return _np.zeros(x.shape, _dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, q_seg, kv_seg, nheads, causal, scale, block_q, block_k,
-           interpret):
-    out, _ = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale,
-                  block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
+    out, _ = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
+                  interpret)
     return out
 
 
-def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, block_q,
-               block_k, interpret):
-    out, lse = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale,
-                    block_q, block_k, interpret)
+def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
+               interpret):
+    out, lse = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
+                    interpret)
     return out, (q, k, v, q_seg, kv_seg, out, lse)
 
 
-def _flash_bwd(nheads, causal, scale, block_q, block_k, interpret, res, do):
+def _flash_bwd(nheads, causal, scale, plan, interpret, res, do):
     q, k, v, q_seg, kv_seg, out, lse = res
     dq, dk, dv = _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads,
-                           causal, scale, block_q, block_k, interpret)
+                           causal, scale, plan, interpret)
     return (dq, dk, dv,
             _int_zero_cotangent(q_seg), _int_zero_cotangent(kv_seg))
 
@@ -485,21 +873,23 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     segment_ids=None, kv_segment_ids=None,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Flash attention on (B, T, H, D) inputs → (B, T, H, D).
 
-    T must be a multiple of the block sizes and D one of 64/128/256 (the
-    dispatcher in :mod:`mxnet_tpu.ops.attention` guarantees this before
-    routing here).  ``interpret`` defaults to True off-TPU so the same
-    kernel is unit-testable on the CPU backend.
+    T must be a multiple of 128 and D one of 64/128/256 (the dispatcher
+    in :mod:`mxnet_tpu.ops.attention` guarantees this before routing
+    here).  ``interpret`` defaults to True off-TPU so the same kernel is
+    unit-testable on the CPU backend.  ``block_q`` / ``block_k`` (the
+    block a grid step owns and the chunk its loop walks) are for tests:
+    a call leaves them to :func:`tile_plan`.
 
     ``segment_ids`` (B, Tq) int enables SEQUENCE PACKING in-kernel:
-    tokens attend only within their own segment; tiles whose q/k segment
-    ranges cannot overlap are skipped at block level (exact skip for the
-    packed non-decreasing layout), so packed long-context training keeps
-    the O(T) memory AND the sub-quadratic compute of the kernel.
+    tokens attend only within their own segment; chunks whose q/k segment
+    ranges cannot overlap are skipped (exact skip for the packed
+    non-decreasing layout), so packed long-context training keeps the
+    O(T) memory AND the sub-quadratic compute of the kernel.
     ``kv_segment_ids`` defaults to ``segment_ids``.  Degenerate rows with
     no matching key anywhere output zeros — as does the XLA reference
     path (``attention.py:_attention_ref`` zeroes fully-masked rows), so
@@ -511,26 +901,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError("causal flash attention requires tq == tk "
                          f"(got {tq} vs {tk})")
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    block_q, block_k = _clamp_blocks(block_q, block_k, d,
-                                     jnp.dtype(q.dtype).itemsize,
-                                     has_seg=segment_ids is not None)
-    # halve until the block divides the sequence (any T that is a multiple
-    # of 128 lands on a legal block by 128 at the latest)
-    while block_q > 128 and tq % block_q:
-        block_q //= 2
-    while block_k > 128 and tk % block_k:
-        block_k //= 2
-    if tq % block_q or tk % block_k:
-        raise ValueError(
-            f"seq lens ({tq}, {tk}) must divide by blocks "
-            f"({block_q}, {block_k})")
+    has_seg = segment_ids is not None
+    plan = tile_plan(tq, tk, d, q.dtype, causal, has_seg, heads=h,
+                     block_q=block_q, chunk=block_k)
+    _report_plan(plan, tq, tk, d, q.dtype, causal, has_seg)
     if interpret is None:
         interpret = _default_interpret(q)
 
     q_seg = kv_seg = None
-    if segment_ids is not None:
+    if has_seg:
         q_seg = jnp.asarray(segment_ids, jnp.int32)[:, None, :]  # (B,1,Tq)
         kv_seg = (jnp.asarray(kv_segment_ids, jnp.int32)[:, None, :]
                   if kv_segment_ids is not None else q_seg)
@@ -547,5 +926,5 @@ def flash_attention(q, k, v, *, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
     out = _flash(flat(q, tq), flat(k, tk), flat(v, tk), q_seg, kv_seg,
-                 h, causal, scale, block_q, block_k, bool(interpret))
+                 h, causal, scale, plan, bool(interpret))
     return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

@@ -51,22 +51,41 @@ def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("shape,dtype", [
-    ((16, 1024, 12, 64), jnp.bfloat16),    # GPT-2 124M training
-    ((4, 4096, 12, 64), jnp.bfloat16),     # long-sequence training
-    ((2, 1024, 4, 128), jnp.float32),      # f32 operands: true-f32 contract
+@pytest.mark.parametrize("shape,dtype,causal", [
+    ((16, 1024, 12, 64), jnp.bfloat16, True),   # GPT-2 124M training
+    ((4, 4096, 12, 64), jnp.bfloat16, True),    # long-sequence training
+    ((2, 1024, 4, 128), jnp.float32, True),     # f32: true-f32 contract
+    ((8, 1024, 12, 64), jnp.bfloat16, True),    # the benchmark's cell
+    ((8, 512, 16, 64), jnp.bfloat16, False),    # BERT-large training
+    # q and dO of one head do not fit VMEM: the walk's third grid axis
+    ((1, 8192, 2, 256), jnp.float32, True),
 ])
-def test_flash_fwd_bwd_compiles_for_v5e(chip, shape, dtype):
+def test_flash_fwd_bwd_compiles_for_v5e(chip, shape, dtype, causal):
     assert jax.config.jax_default_matmul_precision == "highest"
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, interpret=False)
+        out = flash_attention(q, k, v, causal=causal, interpret=False)
         return out.astype(jnp.float32).sum()
 
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     assert _kernels(compiled) == 3          # fwd, dq, dkv
+
+
+def test_flash_with_segments_compiles_for_v5e(chip):
+    """Packed sequences: the walked operand's segment ids are sliced per
+    chunk along lanes, the block's own broadcast down the tile."""
+    def loss(q, k, v, seg):
+        out = flash_attention(q, k, v, causal=True, segment_ids=seg,
+                              interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=chip)
+    seg = jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, seg).compile()
+    assert _kernels(compiled) == 3
 
 
 @pytest.mark.parametrize("tq", [1, 64])

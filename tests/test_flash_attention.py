@@ -109,19 +109,38 @@ def test_use_flash_rejects_cross_attention_shapes(monkeypatch):
 
 
 def test_vmem_clamp_head_dim_aware():
-    """Block policy: d=64 keeps the measured-fast 1024x1024; big head dims
-    shrink until the modeled working set fits the VMEM budget."""
+    """Size policy: d=64 keeps a 1024-row block with a whole head of the
+    walked operand resident; big head dims and long sequences give up
+    block rows (down to one that leaves room for a stretch two blocks
+    long), then the rest of the resident stretch, then heads side by
+    side, until the modeled working set fits the VMEM budget."""
     from mxnet_tpu.ops.flash import _VMEM_BUDGET, _clamp_blocks, _vmem_bytes
 
-    assert _clamp_blocks(1024, 1024, 64, 2) == (1024, 1024)
-    assert _clamp_blocks(1024, 1024, 64, 4) == (1024, 1024)
+    blocks = [1024, 512]
+    assert _clamp_blocks(blocks, 512, 1024, 1024, 64, 2, group=4) == \
+        (1024, 1024, 1024, 1)
+    # float32 operands: the head stays resident, the block halves
+    assert _clamp_blocks(blocks, 512, 1024, 1024, 64, 4, group=4)[:3] == \
+        (512, 1024, 1024)
     for d in (128, 256):
         for itemsize in (2, 4):
-            bq, bk = _clamp_blocks(1024, 1024, d, itemsize)
-            assert _vmem_bytes(bq, bk, d, itemsize) <= _VMEM_BUDGET
-            assert bq >= 128 and bk >= 128
-    # d=256 f32 must NOT run at the full 1024x1024
-    assert _clamp_blocks(1024, 1024, 256, 4) != (1024, 1024)
+            bq, major, major_q, group = _clamp_blocks(
+                blocks, 512, 8192, 8192, d, itemsize, group=4)
+            assert _vmem_bytes(bq, 512, major, d, itemsize,
+                               group=group) <= _VMEM_BUDGET
+            assert bq in blocks and major % 512 == 0
+            assert major == major_q and 8192 % major == 0
+    # d=64 bf16 at T=8192: the block stays, a quarter of K and V resident
+    assert _clamp_blocks(blocks, 512, 8192, 8192, 64, 2) == \
+        (1024, 2048, 2048, 1)
+    # d=256 f32 must NOT hold 8192 rows of q and dO, nor a 1024-row block
+    bq, major, _, group = _clamp_blocks(blocks, 512, 8192, 8192, 256, 4,
+                                        group=4)
+    assert (bq, group) == (512, 1) and major < 8192
+    # a short sequence leaves room for heads side by side
+    assert _clamp_blocks([256], 256, 256, 256, 64, 2, group=4)[3] == 4
+    # cross attention: each walked operand gets its own stretch
+    assert _clamp_blocks([128], 128, 256, 512, 64, 2)[1:3] == (512, 256)
 
 
 @pytest.mark.parametrize("d", [128, 256])
@@ -304,3 +323,180 @@ def test_flash_under_a_mesh_is_shard_mapped_and_matches_ref(mesh_devices):
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                         rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------------- the in-kernel walk
+
+def _fwd_and_grads(fn, *args):
+    out = fn(*args)
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a) ** 2),
+                     tuple(range(len(args))))(*args)
+    return (out,) + tuple(grads)
+
+
+def _assert_walk_matches_ref(q, k, v, *, causal, seg=None, kv_seg=None,
+                             block_q=None, chunk=None, tol=1e-4):
+    """Forward and all three gradients of the kernel (interpret mode)
+    against the XLA reference with the dense mask."""
+    mask = None
+    if seg is not None:
+        ks = seg if kv_seg is None else kv_seg
+        mask = seg[:, None, :, None] == ks[:, None, None, :]
+    got = _fwd_and_grads(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, segment_ids=seg, kv_segment_ids=kv_seg,
+            block_q=block_q, block_k=chunk, interpret=True), q, k, v)
+    want = _fwd_and_grads(
+        lambda q, k, v: _attention_ref(q, k, v, causal=causal, mask=mask),
+        q, k, v)
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        assert not onp.isnan(onp.asarray(a, onp.float32)).any(), name
+        onp.testing.assert_allclose(
+            onp.asarray(a, onp.float32), onp.asarray(r, onp.float32),
+            rtol=tol, atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("block_q,chunk", [(256, 256), (512, 256),
+                                           (256, 128), (1024, 512),
+                                           (512, 128), (1024, 256),
+                                           (None, None)])
+def test_flash_causal_walk_matches_ref(block_q, chunk):
+    """The cell's own sequence and head dim: the causal walk stops at the
+    diagonal, masks only the diagonal's own tiles, takes each slab of
+    the diagonal's square on the trapezoid of rows that sees it, and
+    block and chunk need not be equal.  With nothing said the slabs are
+    narrower in dq/dkv than in fwd.  Two heads: they share a grid step."""
+    q, k, v = (_rand((1, 1024, 2, 64), s) for s in (50, 51, 52))
+    _assert_walk_matches_ref(q, k, v, causal=True, block_q=block_q,
+                             chunk=chunk)
+
+
+def test_flash_noncausal_walk_matches_ref():
+    """Every chunk runs, none is masked: four chunks a block."""
+    q, k, v = (_rand((1, 512, 2, 64), s) for s in (53, 54, 55))
+    _assert_walk_matches_ref(q, k, v, causal=False, block_q=128, chunk=128)
+
+
+@pytest.mark.parametrize("docs,block_q,chunk", [
+    ((200, 312), 128, 128),       # a document boundary inside a chunk
+    ((256, 256), 256, 128),       # on a chunk edge
+    # rows 128.. of the first 256-row block belong to document 1, whose
+    # first chunk (columns 0..127, document 0) is visited for the block's
+    # other rows and is fully masked for these: m stays at _MASK there
+    ((128, 128, 256), 256, 128),
+    ((100, 28, 384), 256, 256),   # two boundaries inside the first chunk
+    ((200, 312), 512, 128),       # one block, four trapezoid slabs
+    ((200, 312), None, None),     # what a call gets with nothing said
+], ids=["inside", "edge", "first-chunk-masked", "two-in-one", "slabs",
+        "default"])
+def test_flash_causal_segment_walk_matches_ref(docs, block_q, chunk):
+    q, k, v = (_rand((1, 512, 2, 64), s) for s in (56, 57, 58))
+    seg = jnp.asarray([sum(([i] * n for i, n in enumerate(docs)), [])],
+                      jnp.int32)
+    _assert_walk_matches_ref(q, k, v, causal=True, seg=seg,
+                             block_q=block_q, chunk=chunk)
+
+
+@pytest.mark.parametrize("causal,tq,tk,seg", [
+    (False, 256, 512, False),     # cross attention: two stretches to walk
+    (True, 1024, 1024, False),    # the causal bound clipped to a stretch
+    (True, 512, 512, True),
+], ids=["cross", "causal", "causal-seg"])
+def test_flash_walk_over_several_major_stretches(monkeypatch, causal, tq,
+                                                 tk, seg):
+    """A walked operand too long for VMEM is handed over in major
+    stretches on the third grid axis, the accumulators carried across
+    it.  Forced here by a budget that holds one 128-row chunk."""
+    from mxnet_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_VMEM_BUDGET", 900 * 1024)
+    plan = flash.tile_plan(tq, tk, 64, jnp.float32, causal, seg,
+                           block_q=128, chunk=128)
+    assert plan.major < tk and plan.major_q < tq
+    q = _rand((1, tq, 2, 64), 60)
+    k, v = (_rand((1, tk, 2, 64), s) for s in (61, 62))
+    ids = jnp.asarray([[0] * 200 + [1] * (tq - 200)], jnp.int32) \
+        if seg else None
+    _assert_walk_matches_ref(q, k, v, causal=causal, seg=ids, block_q=128,
+                             chunk=128)
+
+
+@pytest.mark.parametrize("block_q,chunk", [(256, 128), (None, None)])
+def test_flash_walk_bf16(block_q, chunk):
+    q, k, v = (_rand((1, 512, 4, 64), s).astype(jnp.bfloat16)
+               for s in (63, 64, 65))
+    _assert_walk_matches_ref(q, k, v, causal=True, block_q=block_q,
+                             chunk=chunk, tol=1e-1)
+
+
+def test_tile_plan_counts_what_the_causal_walk_runs():
+    from mxnet_tpu.ops.flash import tile_plan
+
+    plan = tile_plan(1024, 1024, 64, jnp.bfloat16, True, block_q=256,
+                     chunk=256)
+    assert (plan.block_q, plan.chunk, plan.major) == (256, 256, 1024)
+    assert (plan.slab, plan.slab_bwd) == (256, 256)
+    assert (plan.tiles_run, plan.tiles_full, plan.tiles_masked) == \
+        (10, 16, 4)
+    fine = tile_plan(1024, 1024, 64, jnp.bfloat16, True, block_q=128,
+                     chunk=128)
+    assert (fine.tiles_run, fine.tiles_full, fine.tiles_masked) == \
+        (36, 64, 8)
+    # one 1024-row block whose square is cut into trapezoid slabs counts
+    # the same 10 of 16 tiles, 4 of them masked
+    slabs = tile_plan(1024, 1024, 64, jnp.bfloat16, True, block_q=1024,
+                      chunk=256)
+    assert (slabs.block_q, slabs.tiles_run, slabs.tiles_full,
+            slabs.tiles_masked) == (1024, 10, 16, 4)
+    # what a call gets with nothing said: that block with the whole head
+    # resident, slabs of 512 in fwd (3 of 4 tiles) and of 128 in dq/dkv
+    # (36 of 64): the skip engages at the length the chip trains at
+    default = tile_plan(1024, 1024, 64, jnp.bfloat16, True, heads=12)
+    assert (default.block_q, default.chunk, default.major,
+            default.group) == (1024, 256, 1024, 1)
+    assert (default.slab, default.slab_bwd) == (512, 128)
+    assert (default.tiles_run, default.tiles_full,
+            default.tiles_masked) == (3, 4, 2)
+    assert (default.tiles_run_bwd, default.tiles_full_bwd) == (36, 64)
+    # over several stretches the chunks the diagonal crosses take every
+    # row: the skip falls back to the block's granularity
+    long = tile_plan(8192, 8192, 64, jnp.bfloat16, True, heads=12)
+    assert long.major < 8192 and long.block_q == 1024
+    assert long.tiles_run == sum(2 * 2 * (i + 1) for i in range(8))
+    # non-causal runs every tile and masks none; segments mask every one
+    # and want block and chunk fine
+    flat = tile_plan(512, 512, 64, jnp.bfloat16, False)
+    assert flat.tiles_run == flat.tiles_full and flat.tiles_masked == 0
+    packed = tile_plan(1024, 1024, 64, jnp.bfloat16, True, True, heads=12)
+    assert (packed.block_q, packed.chunk, packed.group) == (256, 256, 4)
+    assert (packed.tiles_run, packed.tiles_masked) == (10, 10)
+    # sizes that do not divide the sequence give way to ones that do
+    odd = tile_plan(768, 768, 64, jnp.bfloat16, True, block_q=512,
+                    chunk=512)
+    assert 768 % odd.block_q == 0 and 768 % odd.chunk == 0
+    assert odd.major == 768
+    assert tile_plan(1152, 1152, 64, jnp.bfloat16, True).block_q == 384
+    assert tile_plan(768, 768, 64, jnp.bfloat16, True).chunk == 256
+    # a grid step takes heads side by side only where the count divides
+    assert tile_plan(256, 256, 64, jnp.bfloat16, True, heads=25).group == 1
+    assert tile_plan(256, 256, 64, jnp.bfloat16, True, heads=20).group == 4
+
+
+@pytest.mark.parametrize("d", [64, 128, 256])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_tile_plan_fits_vmem(d, dtype):
+    from mxnet_tpu.ops.flash import _VMEM_BUDGET, _vmem_bytes, tile_plan
+
+    for t in (256, 384, 512, 768, 1024, 2048, 4096, 8192):
+        for causal, has_seg in ((True, False), (True, True), (False, False)):
+            plan = tile_plan(t, t, d, dtype, causal, has_seg, heads=12)
+            assert _vmem_bytes(plan.block_q, plan.chunk,
+                               max(plan.major, plan.major_q), d,
+                               jnp.dtype(dtype).itemsize, has_seg,
+                               plan.group) <= _VMEM_BUDGET, (t, plan)
+            assert t % plan.major == 0 and plan.major % plan.chunk == 0
+            assert t % plan.block_q == 0 and plan.block_q % plan.chunk == 0
+            assert plan.block_q % plan.slab == 0 == plan.slab % plan.slab_bwd
+            assert plan.tiles_run <= plan.tiles_full
+            assert plan.tiles_run_bwd <= plan.tiles_full_bwd

@@ -858,3 +858,40 @@ def test_tracing_disabled_overhead_within_noise(net):
     # generous bound: CPU timing is noisy; the disabled path is one
     # global load + None check per site, nowhere near 1.5x
     assert off < base * 1.5, (base, off)
+
+
+def test_flash_plan_is_an_event_once_per_plan():
+    """``flash_attention`` says which sizes it chose and how much of the
+    score square they run: one ``flash.plan`` event per distinct plan
+    while a tracer is on (recorded as the call is traced: nothing in a
+    step's hot path), nothing with it off."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.flash import flash_attention, tile_plan
+
+    q = jnp.zeros((1, 512, 1, 64), jnp.float32)
+
+    def call(x, causal=True):
+        return flash_attention(x, x, x, causal=causal, block_q=128,
+                               block_k=128, interpret=True)
+
+    obs.disable_tracing()
+    call(q)
+    tr = obs.enable_tracing()
+    try:
+        assert not tr.spans(name="flash.plan")     # said nothing while off
+        fn = jax.jit(call)
+        fn(q), fn(q), call(q)                      # traced twice, one plan
+        ev, = tr.spans(name="flash.plan")
+        plan = tile_plan(512, 512, 64, jnp.float32, True, block_q=128,
+                         chunk=128)
+        assert ev.attrs == dict(plan._asdict(), tq=512, tk=512, d=64,
+                                dtype="float32", causal=True,
+                                has_seg=False)
+        assert (ev.attrs["tiles_run"], ev.attrs["tiles_full"]) == (10, 16)
+        call(q, causal=False)                      # another plan
+        assert [s.attrs["tiles_run"]
+                for s in tr.spans(name="flash.plan")] == [10, 16]
+    finally:
+        obs.disable_tracing()
