@@ -21,7 +21,7 @@ FP16_FUNCS = [
 
 FP32_FUNCS = [
     "softmax", "log_softmax", "softmax_cross_entropy", "softmin",
-    "BatchNorm", "LayerNorm", "GroupNorm", "InstanceNorm",
+    "BatchNorm", "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm",
     "L2Normalization", "norm", "exp", "expm1", "log", "log1p", "log2",
     "log10", "power", "rsqrt", "rcbrt", "erfinv", "gamma", "gammaln",
     "cosh", "sinh", "tan", "arccosh", "arcsinh", "arctanh", "mean", "sum",

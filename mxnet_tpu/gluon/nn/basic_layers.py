@@ -11,7 +11,8 @@ from ..block import Block, HybridBlock
 from ..parameter import Parameter
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "Embedding",
-           "BatchNorm", "LayerNorm", "GroupNorm", "InstanceNorm", "Flatten",
+           "BatchNorm", "LayerNorm", "RMSNorm", "GroupNorm", "InstanceNorm",
+           "Flatten",
            "Activation", "LeakyReLU", "PReLU", "ELU", "SELU", "GELU", "Swish",
            "SiLU", "Lambda", "HybridLambda", "Identity"]
 
@@ -251,6 +252,29 @@ class LayerNorm(HybridBlock):
 
     def __repr__(self):
         return f"LayerNorm(axis={self._axis}, eps={self._eps})"
+
+
+class RMSNorm(HybridBlock):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` over the last axis, computed
+    in float32 (no centring, no offset)."""
+
+    def __init__(self, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._eps = epsilon
+        self.gamma = self.params.get(
+            "gamma", shape=(in_channels,), init=gamma_initializer,
+            allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma._set_shape((x.shape[-1],))
+
+    def forward(self, x):
+        from ...ndarray import ops
+        return ops.RMSNorm(x, self.gamma.data(), eps=self._eps)
+
+    def __repr__(self):
+        return f"RMSNorm(eps={self._eps})"
 
 
 class GroupNorm(HybridBlock):

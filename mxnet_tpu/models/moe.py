@@ -11,9 +11,24 @@ into per-shard grouped matmuls with an all-to-all across ``ep``.
 
 Router aux losses (load-balancing) are recorded into an ambient collector
 during forward; loss functions drain it via :func:`pop_aux_losses`.
+
+A second routing, ``routing="dropless"``, is one chip's share of an
+expert-parallel deployment: the layer is told ``num_experts`` and which
+of them it holds (``experts_held=(first, count)``).  The router scores all
+``num_experts`` (sigmoid scores, a choice biased by a buffer that is not
+trained, top-k weights renormalised and scaled, as DeepSeek-V3 and
+Nemotron-H route); the token-expert pairs that fall on the experts held
+are sorted by expert into a buffer of the worst-case size and run as
+grouped matrix products (:mod:`mxnet_tpu.ops.gmm`) whose time follows the
+rows routed, so no token is dropped under any imbalance.  What the
+experts NOT held would add is left out: nothing stands in for the absent
+chips, and the partial result goes on.  Summed over the chips of the
+deployment (the shared expert counted once) the shares equal the whole
+layer.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -22,10 +37,12 @@ import jax.numpy as jnp
 from .. import parallel as _par
 from ..gluon.block import HybridBlock
 from ..ndarray.ops import invoke
+from ..ops.flash import matmul_precision as _prec
 from ..parallel.sharding import annotate
 
 __all__ = ["MoELayer", "MoETransformerBlock", "pop_aux_losses",
-           "aux_loss_scope"]
+           "aux_loss_scope", "dropless_ffn", "route_sigmoid_topk",
+           "read_routing_counters"]
 
 from .. import base as _base
 
@@ -95,6 +112,192 @@ def _moe_ffn(x, wg, w1, b1, w2, b2, *, num_experts, top_k, capacity,
     return y.reshape(b, t, d).astype(x.dtype), aux
 
 
+# ------------------------------------------------------- dropless routing
+
+def _zero_int(x):
+    import numpy as np
+    return np.zeros(x.shape, jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv``: the
+    backward is a gather too, never a scatter."""
+    return x[idx]
+
+
+def _permute_fwd(x, idx, inv):
+    return x[idx], (idx, inv)
+
+
+def _permute_bwd(res, g):
+    idx, inv = res
+    return g[inv], _zero_int(idx), _zero_int(inv)
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_of_pairs(x, order, inv, valid, top_k):
+    """The buffer of token rows in sorted-pair order: row r is the token
+    of pair ``order[r]``.  Backward: rows past the routed ones are
+    dropped (they hold whatever the kernel left), the rest return to
+    pair order and a token's ``top_k`` pairs are summed."""
+    return x[order // top_k]
+
+
+def _rows_fwd(x, order, inv, valid, top_k):
+    return x[order // top_k], (order, inv, valid, x.shape)
+
+
+def _rows_bwd(top_k, res, g):
+    order, inv, valid, shape = res
+    g = jnp.where(valid[:, None], g, jnp.zeros_like(g))[inv]
+    dx = g.reshape(shape[0], top_k, shape[1]).astype(jnp.float32).sum(axis=1)
+    return (dx.astype(g.dtype), _zero_int(order), _zero_int(inv),
+            _zero_int(valid))
+
+
+_rows_of_pairs.defvjp(_rows_fwd, _rows_bwd)
+
+
+def route_sigmoid_topk(x, w_router, choice_bias, *, top_k, norm_topk=True,
+                       scaling=1.0, chosen=None):
+    """Scores of all experts in float32 and the top-k choice: logits
+    ``x W_r^T``, ``s = sigmoid(logits)``, experts chosen by ``s +
+    choice_bias`` (no gradient reaches the bias), weights ``s`` at the
+    chosen, divided by their sum and scaled.  ``chosen`` (N, k) replaces
+    the choice (a reference following a program's discrete choice).
+    Returns (weights (N, k) float32, indices (N, k) int32)."""
+    logits = jnp.einsum("nd,ed->ne", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    if chosen is None:
+        _, chosen = jax.lax.top_k(
+            jax.lax.stop_gradient(s)
+            + choice_bias.astype(jnp.float32)[None, :], top_k)
+    chosen = chosen.astype(jnp.int32)
+    w = jnp.take_along_axis(s, chosen, axis=1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scaling, chosen
+
+
+def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
+                 norm_topk=True, scaling=1.0, compute_dtype=None,
+                 impl="auto", chosen=None):
+    """One chip's share of a routed ``relu(x W_up)^2 W_down`` expert layer.
+
+    ``x`` (N, D); ``w_router`` (E, D) over ALL experts; ``w_up`` (H, D, F)
+    and ``w_down`` (H, F, D) of the H experts held, which are experts
+    ``first .. first + H - 1``.  Returns ``(y (N, D) float32, chosen (N, k),
+    sizes (H,) int32)``: the sum over a token's chosen experts THAT ARE
+    HELD of weight x expert output, and how many pairs each held expert
+    got.  Every pair on a held expert is computed: the buffer holds
+    ``N * top_k`` rows."""
+    from ..ops.gmm import grouped_matmul
+    n, d = x.shape
+    held = w_up.shape[0]
+    cd = jnp.dtype(compute_dtype or x.dtype)
+    w, chosen = route_sigmoid_topk(x, w_router, choice_bias, top_k=top_k,
+                                   norm_topk=norm_topk, scaling=scaling,
+                                   chosen=chosen)
+    local = jnp.logical_and(chosen >= first, chosen < first + held)
+    key = jnp.where(local, chosen - first, held).reshape(-1)   # (N k,)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    valid = jnp.arange(n * top_k) < jnp.sum(sizes)
+    # every read of a buffer the kernel wrote masks the rows past the
+    # routed ones BEFORE anything else touches them
+    rows = _rows_of_pairs(x.astype(cd), order, inv, valid, top_k)
+    u = grouped_matmul(rows, w_up.astype(cd), sizes, impl=impl)
+    u = jnp.where(valid[:, None], u, jnp.zeros_like(u)).astype(jnp.float32)
+    h = jnp.square(jax.nn.relu(u)).astype(cd)
+    y = grouped_matmul(h, w_down.astype(cd), sizes, impl=impl)
+    y = jnp.where(valid[:, None], y, jnp.zeros_like(y))
+    y = _permute(y, inv, order).reshape(n, top_k, d)
+    out = jnp.sum(w[:, :, None] * y.astype(jnp.float32), axis=1)
+    return out, chosen, sizes
+
+
+def amp_compute_dtype(x):
+    """bf16 (or fp16) under ``amp.init``, else the input's own type: what
+    a layer that does its own casting computes its matmuls in."""
+    from .. import amp as _amp
+    pol = _amp.current_policy()
+    return pol.target_dtype if pol is not None else x.dtype
+
+
+def relu2_mlp(x, w_up, w_down, compute_dtype=None):
+    """``relu(x W_up^T)^2 W_down^T`` with (out, in) weights: the shared
+    expert, and the form of every routed one."""
+    cd = jnp.dtype(compute_dtype or x.dtype)
+    u = jnp.einsum("nd,fd->nf", x.astype(cd), w_up.astype(cd),
+                   precision=_prec(cd), preferred_element_type=jnp.float32)
+    h = jnp.square(jax.nn.relu(u)).astype(cd)
+    return jnp.einsum("nf,df->nd", h, w_down.astype(cd),
+                      precision=_prec(cd), preferred_element_type=jnp.float32)
+
+
+def read_routing_counters(net) -> dict:
+    """Routing counters of every dropless ``MoELayer`` under ``net``, read
+    from the payloads the last step left (no program is launched): summed
+    over the layers, ``moe.pairs_local`` (token-expert pairs computed
+    here in the last step), ``moe.pairs_total`` (top_k a token) and
+    ``moe.load_max`` (the largest count on one held expert, largest
+    layer), and the running sums since the layers were built
+    (``sum_*``, with ``steps``).  The registry counter
+    ``mxtpu_moe_pairs_local_total`` is brought up to the layers' running
+    sums: a step is counted once however often it is read, and a step no
+    read fell on is counted by the next."""
+    import numpy as np
+
+    out = {"moe.pairs_local": 0.0, "moe.pairs_total": 0.0,
+           "moe.load_max": 0.0, "sum_pairs_local": 0.0,
+           "sum_pairs_total": 0.0, "sum_load_max": 0.0, "steps": 0.0,
+           "layers": 0, "experts_held": 0, "per_layer_sum_pairs_local": []}
+    fresh = 0.0
+    for blk in _dropless_layers(net):
+        v = np.asarray(blk.routing_stats.data().asnumpy(), np.float64)
+        out["per_layer_sum_pairs_local"].append(float(v[3]))
+        # a sum below what was reported is a payload started afresh
+        fresh += v[3] - (blk._pairs_reported
+                         if v[3] >= blk._pairs_reported else 0.0)
+        blk._pairs_reported = float(v[3])
+        out["moe.pairs_local"] += v[0]
+        out["moe.pairs_total"] += v[1]
+        out["moe.load_max"] = max(out["moe.load_max"], v[2])
+        out["sum_pairs_local"] += v[3]
+        out["sum_pairs_total"] += v[4]
+        out["sum_load_max"] += v[5]
+        out["steps"] = max(out["steps"], v[6])
+        out["layers"] += 1
+        out["experts_held"] = blk._held[1]
+    if out["layers"]:
+        from ..observability.registry import default_registry
+        default_registry().counter(
+            "mxtpu_moe_pairs_local_total",
+            help="token-expert pairs computed on the experts this chip "
+                 "holds, up to the last read_routing_counters").inc(fresh)
+    return out
+
+
+def _dropless_layers(net):
+    found = []
+
+    def visit(b):
+        if isinstance(b, MoELayer) and b._routing == "dropless":
+            found.append(b)
+        for c in getattr(b, "_children", {}).values():
+            visit(c)
+    visit(net)
+    return found
+
+
 class MoELayer(HybridBlock):
     """Top-k routed expert FFN (drop-in for PositionwiseFFN).
 
@@ -104,7 +307,19 @@ class MoELayer(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts, top_k=2,
                  capacity_factor=1.25, activation="gelu", dropout=0.0,
-                 dtype="float32", **kwargs):
+                 dtype="float32", routing="capacity", experts_held=None,
+                 shared_hidden=0, routed_scaling=1.0, norm_topk=True,
+                 record_choice_rows=0, **kwargs):
+        """``routing="capacity"``: softmax top-k with a capacity per
+        expert over the ``ep`` axis (tokens over capacity are dropped).
+        ``routing="dropless"``: one chip's share (module docstring):
+        ``experts_held=(first, count)`` of the ``num_experts`` the router
+        scores (default: all), ``shared_hidden`` > 0 adds a shared expert
+        of that width to every token, ``record_choice_rows=N`` keeps the
+        last step's chosen expert indices of N tokens as a payload
+        (``last_choice``).  The grouped products choose their form by
+        platform (``ops.gmm``: Pallas on the TPU, ``ragged_dot``
+        elsewhere)."""
         super().__init__(**kwargs)
         from ..gluon.nn import Dropout
         self.dropout = Dropout(dropout) if dropout else None
@@ -114,10 +329,52 @@ class MoELayer(HybridBlock):
         self._top_k = min(top_k, num_experts)
         self._capacity_factor = capacity_factor
         self._act_name = activation
+        if routing not in ("capacity", "dropless"):
+            raise ValueError(f"routing must be capacity or dropless, got "
+                             f"{routing!r}")
+        self._routing = routing
         self.gate = self.params.get(
             "gate", shape=(num_experts, units), dtype=dtype,
             init="xavier", allow_deferred_init=True)
         annotate(self.gate, None, "embed")
+        if routing == "dropless":
+            first, count = experts_held or (0, num_experts)
+            if first < 0 or count < 1 or first + count > num_experts:
+                raise ValueError(f"experts_held {(first, count)} is not a "
+                                 f"range of {num_experts} experts")
+            self._held = (int(first), int(count))
+            self._scaling = float(routed_scaling)
+            self._norm_topk = bool(norm_topk)
+            g = self.params.get
+            # chooses, is not trained, and no step changes it
+            self.e_score_correction_bias = g(
+                "e_score_correction_bias", shape=(num_experts,),
+                dtype="float32", init="zeros", differentiable=False)
+            self.w1 = g("w1", shape=(count, units, hidden_size),
+                        dtype=dtype, init="xavier")
+            self.w2 = g("w2", shape=(count, hidden_size, units),
+                        dtype=dtype, init="xavier")
+            self.shared_up = self.shared_down = None
+            if shared_hidden:
+                self.shared_up = g("shared_up",
+                                   shape=(shared_hidden, units),
+                                   dtype=dtype, init="xavier")
+                self.shared_down = g("shared_down",
+                                     shape=(units, shared_hidden),
+                                     dtype=dtype, init="xavier")
+            # [pairs_local, pairs_total, load_max] of the last step, their
+            # running sums, steps: rewritten by every forward, read
+            # without a launch (read_routing_counters)
+            self.routing_stats = g("routing_stats", shape=(7,),
+                                   dtype="float32", init="zeros",
+                                   differentiable=False)
+            self._pairs_reported = 0.0   # of the running sum, in the registry
+            self.last_choice = None
+            if record_choice_rows:
+                self.last_choice = g(
+                    "last_choice", shape=(record_choice_rows, self._top_k),
+                    dtype="int32", init="zeros", differentiable=False)
+            return
         self.w1 = self.params.get(
             "w1", shape=(num_experts, units, hidden_size), dtype=dtype,
             init="xavier", allow_deferred_init=True)
@@ -135,12 +392,50 @@ class MoELayer(HybridBlock):
             init="zeros", allow_deferred_init=True)
         annotate(self.b2, "expert", "embed")
 
+    def _forward_dropless(self, x):
+        lead = x.shape[:-1]
+        first, _count = self._held
+        shared = self.shared_up is not None
+        ins = [x, self.gate.data(), self.e_score_correction_bias.data(),
+               self.w1.data(), self.w2.data(), self.routing_stats.data()]
+        if shared:
+            ins += [self.shared_up.data(), self.shared_down.data()]
+
+        def f(xv, wr, bias, w1, w2, stats, *sh):
+            cd = amp_compute_dtype(xv)
+            xf = xv.reshape(-1, xv.shape[-1])
+            y, chosen, sizes = dropless_ffn(
+                xf, wr, bias, w1, w2, top_k=self._top_k, first=first,
+                norm_topk=self._norm_topk, scaling=self._scaling,
+                compute_dtype=cd)
+            if sh:
+                y = y + relu2_mlp(xf, sh[0], sh[1], cd)
+            now = jnp.stack([jnp.sum(sizes), xf.shape[0] * self._top_k,
+                             jnp.max(sizes)]).astype(jnp.float32)
+            stats = jnp.concatenate(
+                [now, stats[3:6] + now, stats[6:] + 1.0])
+            return (y.reshape(lead + (y.shape[-1],)).astype(cd),
+                    jax.lax.stop_gradient(stats),
+                    chosen.astype(jnp.float32))   # ids are exact in f32
+
+        y, stats, chosen = invoke("moe_dropless", f, ins, nout=3)
+        if not _base.is_training():
+            return y        # as BatchNorm: payloads move in training only
+        self.routing_stats.data()._rebind(stats.jax)
+        if self.last_choice is not None and \
+                tuple(chosen.shape) == tuple(self.last_choice.shape):
+            self.last_choice.data()._rebind(chosen.jax.astype(jnp.int32))
+        return y
+
     def capacity(self, n_tokens: int) -> int:
         cap = int(math.ceil(self._top_k * n_tokens / self._num_experts
                             * self._capacity_factor))
         return max(cap, self._top_k)
 
     def forward(self, x):
+        if self._routing == "dropless":
+            y = self._forward_dropless(x)
+            return self.dropout(y) if self.dropout is not None else y
         b, t = x.shape[0], x.shape[1]
         act = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
                "silu": jax.nn.silu}[self._act_name]

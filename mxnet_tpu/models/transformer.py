@@ -735,18 +735,37 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
             base_key = providers[-1].key if providers else None
 
             for i, blk in enumerate(blocks):
-                def f(h, _blk=blk, _i=i):
+                # payloads the layer rebinds in its forward (a buffer
+                # that is not trained: running statistics, routing
+                # counters) leave the checkpointed function as outputs
+                # and are rebound outside it, so no tracer of the inner
+                # trace outlives it
+                aux = [p._data for p in blk.collect_params().values()
+                       if p.grad_req == "null" and p._data is not None]
+
+                moved = []      # which of them this layer rebound
+
+                def f(h, _blk=blk, _i=i, _aux=aux, _moved=moved):
                     if base_key is not None:
                         _random.push_trace_key(
                             jax.random.fold_in(base_key, _i))
+                    saved = [(a._data, a._node) for a in _aux]
                     try:
-                        return _blk(NDArray(h), mask).jax
+                        out = _blk(NDArray(h), mask).jax
+                        _moved[:] = [j for j, a in enumerate(_aux)
+                                     if a._data is not saved[j][0]]
+                        return out, tuple(_aux[j]._data for j in _moved)
                     finally:
+                        for a, (d, n) in zip(_aux, saved):
+                            a._data, a._node = d, n
                         if base_key is not None:
                             _random.pop_trace_key()
                 policy = (jax.checkpoint_policies.checkpoint_dots
                           if remat == "dots" else None)
-                x = NDArray(jax.checkpoint(f, policy=policy)(x.jax))
+                out, new_aux = jax.checkpoint(f, policy=policy)(x.jax)
+                for j, v in zip(moved, new_aux):
+                    aux[j]._rebind(v)
+                x = NDArray(out)
             return x
     for blk in blocks:
         x = blk(x, mask)

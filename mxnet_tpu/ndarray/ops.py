@@ -1244,6 +1244,18 @@ def LayerNorm(data, gamma, beta, axis=-1, eps=1e-5, **kw):
 
 
 @_export
+def RMSNorm(data, gamma, eps=1e-5, **kw):
+    """Root-mean-square norm over the last axis (float32 under AMP)."""
+    nds = [_as_nd(x) for x in (data, gamma)]
+
+    def f(x, g):
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * lax.rsqrt(ms + eps) * g
+
+    return invoke("RMSNorm", f, nds)
+
+
+@_export
 def GroupNorm(data, gamma, beta, num_groups=1, eps=1e-5, **kw):
     nds = [_as_nd(x) for x in (data, gamma, beta)]
 

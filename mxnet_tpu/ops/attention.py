@@ -28,8 +28,12 @@ _NEG_INF = -1e30
 def _attention_ref(q, k, v, *, causal=False, mask=None, scale=None,
                    dropout=0.0, dropout_key=None):
     """Pure-jax attention; q/k/v are (B, T, H, D).  XLA fuses this well for
-    moderate T; the Pallas kernel takes over for long sequences."""
+    moderate T; the Pallas kernel takes over for long sequences.  Fewer
+    K/V heads than query heads are repeated here (grouped queries)."""
     d = q.shape[-1]
+    if k.shape[2] != q.shape[2]:
+        share = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, share, axis=2), jnp.repeat(v, share, axis=2)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
@@ -72,7 +76,11 @@ def _use_flash(q_shape, causal, mask, dropout, k_shape=None,
         return False
     b, t, h, d = q_shape
     if k_shape is not None and tuple(k_shape) != tuple(q_shape):
-        return False
+        # fewer key/value heads than query heads (grouped queries) is
+        # the kernel's; another length or head dim is not
+        kb, kt, kh, kd = k_shape
+        if (kb, kt, kd) != (b, t, d) or kh == 0 or h % kh:
+            return False
     if t < 256 or t % 128 or d not in (64, 128, 256):
         return False
     return (platform or jax.default_backend()) == "tpu"
@@ -105,6 +113,8 @@ def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None):
             dim % axis_size(mesh, axis) == 0 else None
 
     b_ax, h_ax = over("dp", q.shape[0]), over("tp", q.shape[2])
+    if over("tp", k.shape[2]) is None:   # fewer K/V heads than tp shards
+        h_ax = None
     return shard_mapped_qkv(body, mesh, P(b_ax, None, h_ax, None), q, k, v,
                             *seg, extra_specs=(P(b_ax, None),) * len(seg))
 

@@ -218,6 +218,7 @@ def _count_tiles(tq, tk, block, chunk, slab, causal, whole):
 
 def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
               has_seg: bool = False, *, heads: int = 1,
+              kv_heads: Optional[int] = None,
               block_q: Optional[int] = None,
               chunk: Optional[int] = None) -> TilePlan:
     """The one place a flash call's sizes are computed: from the sequence
@@ -235,7 +236,9 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     walked in chunks.  Under segment ids the skip is per (block, chunk)
     pair and wants both as fine as ``DEFAULT_SEG`` rows.  A grid step
     takes up to ``DEFAULT_GROUP`` heads of one batch row where the head
-    count divides and VMEM allows."""
+    count divides and VMEM allows.  With fewer key/value heads than query
+    heads (``kv_heads``) a step takes one query head, and reads the K/V
+    head it shares by an index map."""
     span = math.gcd(tq, tk)
     fine = DEFAULT_SEG if has_seg else None
 
@@ -264,6 +267,11 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     group = DEFAULT_GROUP
     while heads % group:
         group //= 2
+    if kv_heads is not None and kv_heads != heads:
+        if heads % kv_heads:
+            raise ValueError(f"{heads} query heads do not divide over "
+                             f"{kv_heads} key/value heads")
+        group = 1
     # the widest slice a step holds scores of: a chunk, or a forward slab
     # of the smallest block
     wide = max(chunk, fit(slabs[0], blocks[-1]))
@@ -280,19 +288,33 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
                     full_bwd)
 
 
-def _report_plan(plan: TilePlan, tq, tk, d, dtype, causal, has_seg):
-    """One ``flash.plan`` event per distinct plan in the tracer's ring,
-    recorded while the call is traced (never in a step's hot path);
-    nothing without a tracer."""
+def plan_event(name: str, **attrs):
+    """One ``name`` event per distinct set of attributes in the tracer's
+    ring, recorded while a call is traced (never in a step's hot path);
+    nothing without a tracer.  Shared by ``flash.plan``, ``ssd.plan`` and
+    ``moe.plan``."""
     from ..observability.trace import active
     tr = active()
-    if tr is None:
-        return
-    attrs = dict(plan._asdict(), tq=tq, tk=tk, d=d,
-                 dtype=jnp.dtype(dtype).name, causal=bool(causal),
-                 has_seg=bool(has_seg))
-    if not any(s.attrs == attrs for s in tr.spans(name="flash.plan")):
-        tr.event("flash.plan", **attrs)
+    if tr is not None and not any(s.attrs == attrs
+                                  for s in tr.spans(name=name)):
+        tr.event(name, **attrs)
+
+
+def _report_plan(plan: TilePlan, tq, tk, d, dtype, causal, has_seg):
+    plan_event("flash.plan", **plan._asdict(), tq=tq, tk=tk, d=d,
+               dtype=jnp.dtype(dtype).name, causal=bool(causal),
+               has_seg=bool(has_seg))
+
+
+def matmul_precision(dtype):
+    """The precision a matrix product of ``dtype`` operands states for
+    itself: float32 operands keep the package's true-f32 contract
+    (``HIGHEST``); bf16 ones take the MXU's single pass — AND SO DO THE
+    TRANSPOSED PRODUCTS of the backward pass, whose float32 cotangents
+    would otherwise run under the package default ('highest': six
+    passes)."""
+    return (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else jax.lax.Precision.DEFAULT)
 
 
 def _dot(a, b, ca: int, cb: int):
@@ -303,10 +325,8 @@ def _dot(a, b, ca: int, cb: int):
     ``tpu.matmul`` on bf16 operands.  bf16 operands take the MXU's single
     pass (exact products, f32 accumulation); float32 operands keep the
     package's true-f32 contract (``mxnet_tpu/__init__.py``)."""
-    prec = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
     return jax.lax.dot_general(
-        a, b, (((ca,), (cb,)), ((), ())), precision=prec,
+        a, b, (((ca,), (cb,)), ((), ())), precision=matmul_precision(a.dtype),
         preferred_element_type=jnp.float32)
 
 
@@ -560,13 +580,23 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
             lse_ref[g] = _rows_to_lanes(lse)
 
 
-def _own_spec(shape):
+def _own_spec(shape, row=lambda b: b):
     """BlockSpec of an operand a grid step owns a block of: ``shape`` is
     the block shape, the block axis the one of its last two that is not
-    the head dim (rows for (g, block, d), lanes for (g, 1, block))."""
+    the head dim (rows for (g, block, d), lanes for (g, 1, block));
+    ``row`` maps the grid's first coordinate to the operand's."""
     if shape[1] == 1:
-        return pl.BlockSpec(shape, lambda b, i, m: (b, 0, i))
-    return pl.BlockSpec(shape, lambda b, i, m: (b, i, 0))
+        return pl.BlockSpec(shape, lambda b, i, m: (row(b), 0, i))
+    return pl.BlockSpec(shape, lambda b, i, m: (row(b), i, 0))
+
+
+def _kv_row(nheads: int, kv_heads: int):
+    """Flat query-head index -> flat index of the K/V head it reads
+    (query head h of a batch row shares K/V head h // (H / H_kv))."""
+    if kv_heads == nheads:
+        return lambda b: b
+    share = nheads // kv_heads
+    return lambda b: (b // nheads) * kv_heads + (b % nheads) // share
 
 
 def _walked_spec(shape, block, major, causal, up, row=lambda b: b):
@@ -606,6 +636,7 @@ _PARAMS = pltpu.CompilerParams(
 
 def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
     bh, tq, d = q.shape
+    kv_row = _kv_row(nheads, k.shape[0] * nheads // bh)
     tk = k.shape[1]
     block_q, chunk, major, group = (plan.block_q, plan.chunk, plan.major,
                                     plan.group)
@@ -614,7 +645,8 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_seg=has_seg,
         block_q=block_q, chunk=chunk, slab=plan.slab, nm=nm)
-    walked = _walked_spec((group, -1, d), block_q, major, causal, True)
+    walked = _walked_spec((group, -1, d), block_q, major, causal, True,
+                          row=kv_row)
     in_specs = [_own_spec((group, block_q, d)), walked, walked]
     args = [q, k, v]
     if has_seg:
@@ -772,6 +804,8 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     tk = k.shape[1]
     block, chunk, group = plan.block_q, plan.chunk, plan.group
     has_seg = q_seg is not None
+    kv_heads = k.shape[0] * nheads // bh
+    kv_row = _kv_row(nheads, kv_heads)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]               # (bh, 1, tq)
     tile, row = _own_spec((group, block, d)), _own_spec((group, 1, block))
@@ -779,7 +813,8 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     def rows(b):
         return b * group // nheads
 
-    kv_walk = _walked_spec((group, -1, d), block, plan.major, causal, True)
+    kv_walk = _walked_spec((group, -1, d), block, plan.major, causal, True,
+                           row=kv_row)
     dq_in_specs = [tile, kv_walk, kv_walk, tile, row, row]
     args = [q, k, v, do, lse, delta]
     if has_seg:
@@ -805,7 +840,11 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
                           False)
     row_walk = _walked_spec((group, 1, -1), block, plan.major_q, causal,
                             False)
-    dkv_in_specs = [q_walk, tile, tile, q_walk, row_walk, row_walk]
+    # each query head reads the K/V head it shares and writes that head's
+    # dK/dV of its own, in float32; the heads of a share are summed after
+    kv_tile = _own_spec((group, block, d), row=kv_row)
+    shared = kv_heads != nheads
+    dkv_in_specs = [q_walk, kv_tile, kv_tile, q_walk, row_walk, row_walk]
     if has_seg:
         dkv_in_specs += _seg_specs(rows, block, plan.major_q, causal,
                                    False)[::-1]
@@ -818,8 +857,10 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
         in_specs=dkv_in_specs,
         out_specs=[tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, tk, d),
+                                 jnp.float32 if shared else k.dtype),
+            jax.ShapeDtypeStruct((bh, tk, d),
+                                 jnp.float32 if shared else v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, block, d), jnp.float32),
@@ -828,6 +869,11 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
         compiler_params=_PARAMS,
         interpret=interpret,
     )(*args)
+    if shared:
+        def over_share(x, like):
+            x = x.reshape(bh // nheads, kv_heads, nheads // kv_heads, tk, d)
+            return x.sum(axis=2).reshape(like.shape).astype(like.dtype)
+        dk, dv = over_share(dk, k), over_share(dv, v)
     return dq, dk, dv
 
 
@@ -880,7 +926,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     T must be a multiple of 128 and D one of 64/128/256 (the dispatcher
     in :mod:`mxnet_tpu.ops.attention` guarantees this before routing
-    here).  ``interpret`` defaults to True off-TPU so the same kernel is
+    here).  ``k`` and ``v`` may carry fewer heads than ``q`` (grouped
+    queries): query head ``h`` reads K/V head ``h // (H / H_kv)`` through
+    the kernels' index maps, nothing is repeated in memory, and dK/dV are
+    summed over the query heads of a share after the ``dkv`` kernel.  ``interpret`` defaults to True off-TPU so the same kernel is
     unit-testable on the CPU backend.  ``block_q`` / ``block_k`` (the
     block a grid step owns and the chunk its loop walks) are for tests:
     a call leaves them to :func:`tile_plan`.
@@ -896,14 +945,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
     the two paths are comparable row-for-row.
     """
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, h_kv = k.shape[1], k.shape[2]
     if causal and tq != tk:
         raise ValueError("causal flash attention requires tq == tk "
                          f"(got {tq} vs {tk})")
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     has_seg = segment_ids is not None
     plan = tile_plan(tq, tk, d, q.dtype, causal, has_seg, heads=h,
-                     block_q=block_q, chunk=block_k)
+                     kv_heads=h_kv, block_q=block_q, chunk=block_k)
     _report_plan(plan, tq, tk, d, q.dtype, causal, has_seg)
     if interpret is None:
         interpret = _default_interpret(q)
@@ -923,7 +972,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError("kv_segment_ids requires segment_ids")
 
     def flat(x, t):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, d)
 
     out = _flash(flat(q, tq), flat(k, tk), flat(v, tk), q_seg, kv_seg,
                  h, causal, scale, plan, bool(interpret))

@@ -138,3 +138,64 @@ def test_flash_under_a_mesh_runs_per_device(chips, monkeypatch):
             x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") == 3
     assert "bf16[24,1024,64]" in text       # 4 rows x 6 heads, flattened
+
+
+# ---------------------------------------- the hybrid stack's kernels (PR 26)
+
+def test_ssd_chunk_kernel_compiles_for_v5e(chip):
+    """One Mamba-2 layer's scan at the hybrid cell's size: 64 heads of 64
+    in 8 groups, state 128, chunks of 128 over 8,192 steps; forward by the
+    kernel, backward by the chunked XLA form."""
+    from mxnet_tpu.ops.ssd import ssd_scan
+
+    def loss(x, dt, a, bm, cm):
+        return ssd_scan(x, dt, a, bm, cm, chunk=128, impl="pallas",
+                        interpret=False).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = (sds((1, 8192, 64, 64), jnp.bfloat16),
+            sds((1, 8192, 64), jnp.float32), sds((64,), jnp.float32),
+            sds((1, 8192, 8, 128), jnp.bfloat16),
+            sds((1, 8192, 8, 128), jnp.bfloat16))
+    # the value keeps the forward alive: a sum's gradient needs no output
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    assert _kernels(compiled) == 1          # ssd_chunk_fwd
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_moe_gmm_compiles_for_v5e(chip, dtype):
+    """up -> relu^2 -> down over the worst-case buffer of 6 x 8,192 rows
+    with 8 experts held, published widths: two products forward, four in
+    the backward pass, the grid's tile axis dynamic."""
+    from mxnet_tpu.ops.gmm import grouped_matmul
+
+    m, u, f, held = 49152, 2688, 1856, 8
+
+    def loss(rows, w_up, w_down, sizes):
+        valid = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+        h = grouped_matmul(rows, w_up, sizes, impl="pallas",
+                           interpret=False)
+        h = jnp.square(jax.nn.relu(jnp.where(valid, h, 0)))
+        y = grouped_matmul(h, w_down, sizes, impl="pallas", interpret=False)
+        return jnp.where(valid, y, 0).astype(jnp.float32).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds((m, u), dtype), sds((held, u, f), dtype),
+        sds((held, f, u), dtype), sds((held,), jnp.int32)).compile()
+    assert _kernels(compiled) == 6
+
+
+def test_flash_grouped_queries_compile_for_v5e(chip):
+    """32 query heads over 2 key/value heads of 128 at T 8,192."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16, sharding=chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert _kernels(compiled) == 3
+    assert "bf16[2,8192,128]" in compiled.as_text()   # K/V never repeated

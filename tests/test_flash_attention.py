@@ -500,3 +500,71 @@ def test_tile_plan_fits_vmem(d, dtype):
             assert plan.block_q % plan.slab == 0 == plan.slab % plan.slab_bwd
             assert plan.tiles_run <= plan.tiles_full
             assert plan.tiles_run_bwd <= plan.tiles_full_bwd
+
+
+# ------------------------------------------------- grouped queries (PR 26)
+
+@pytest.mark.parametrize("h,h_kv,causal", [(8, 2, True), (4, 1, True),
+                                           (4, 2, False)])
+def test_flash_grouped_queries_match_repeated_kv(h, h_kv, causal):
+    """Fewer key/value than query heads: the kernels read K/V head
+    ``head // share`` through their index maps; equal to K/V repeated."""
+    b, t, d = 2, 256, 64
+    q = _rand((b, t, h, d), 0)
+    k, v = _rand((b, t, h_kv, d), 1), _rand((b, t, h_kv, d), 2)
+    w = _rand((b, t, h, d), 3)
+    share = h // h_kv
+
+    def ours(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=128,
+                                       block_k=128, interpret=True) * w)
+
+    def repeated(q, k, v):
+        return jnp.sum(_attention_ref(q, jnp.repeat(k, share, axis=2),
+                                      jnp.repeat(v, share, axis=2),
+                                      causal=causal) * w)
+
+    out = flash_attention(q, k, v, causal=causal, block_q=128, block_k=128,
+                          interpret=True)
+    onp.testing.assert_allclose(
+        onp.asarray(out), onp.asarray(_attention_ref(q, k, v, causal=causal)),
+        rtol=2e-3, atol=2e-3)
+    got = jax.grad(ours, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(repeated, argnums=(0, 1, 2))(q, k, v)
+    for a, r in zip(got, want):
+        assert a.shape == r.shape            # dK, dV: (B, T, H_kv, D), summed
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(r),
+                                    rtol=2e-3, atol=2e-3)
+
+
+def test_grouped_queries_go_to_the_kernel_and_plan_one_head_a_step():
+    from mxnet_tpu.ops.attention import _use_flash
+    from mxnet_tpu.ops.flash import tile_plan
+
+    # the hybrid cell's call: T 8,192, D 128, 32 heads over 2
+    assert _use_flash((1, 8192, 32, 128), True, None, 0.0,
+                      (1, 8192, 2, 128), platform="tpu")
+    # another length or head dim is still not the kernel's
+    assert not _use_flash((1, 8192, 32, 128), True, None, 0.0,
+                          (1, 4096, 2, 128), platform="tpu")
+    assert not _use_flash((1, 8192, 32, 128), True, None, 0.0,
+                          (1, 8192, 3, 128), platform="tpu")
+    plan = tile_plan(8192, 8192, 128, jnp.bfloat16, True, heads=32,
+                     kv_heads=2)
+    assert plan.group == 1 and plan.block_q == 1024 and plan.major == 4096
+    with pytest.raises(ValueError):
+        tile_plan(1024, 1024, 64, jnp.bfloat16, True, heads=12, kv_heads=5)
+
+
+def test_tile_plan_of_the_gpt2_cell_is_pinned():
+    """``train_124m_seq1024``'s flash calls (12 equal heads of 64, T 1,024,
+    bf16, causal) take the plan they took before grouped queries."""
+    from mxnet_tpu.ops.flash import TilePlan, tile_plan
+
+    want = TilePlan(block_q=1024, chunk=256, slab=512, slab_bwd=128,
+                    major=1024, major_q=1024, group=1, tiles_run=3,
+                    tiles_full=4, tiles_masked=2, tiles_run_bwd=36,
+                    tiles_full_bwd=64)
+    assert tile_plan(1024, 1024, 64, jnp.bfloat16, True, heads=12) == want
+    assert tile_plan(1024, 1024, 64, jnp.bfloat16, True, heads=12,
+                     kv_heads=12) == want

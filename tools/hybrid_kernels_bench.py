@@ -1,0 +1,117 @@
+#!/usr/bin/env python3
+"""Time the hybrid stack's kernels alone on the chip, at the sizes of
+``train_nemotron_tt_seq8192`` (builder's tool; fails without a TPU):
+
+* ``moe_gmm``: up -> relu^2 -> down over the worst-case buffer of 49,152
+  rows with 8 experts held, forward and backward, as the ROUTED rows vary:
+  even and skewed at the expected 3,072 rows, then 6,144, 12,288 and the
+  full buffer — time has to follow the rows routed, not the buffer;
+* ``ssd_chunk_fwd`` against the chunked XLA form, and the XLA backward;
+* the flash kernels with 32 query heads over 2 key/value heads at T 8,192.
+
+    chiprun -- python3 tools/hybrid_kernels_bench.py
+"""
+from __future__ import annotations
+
+import json
+import statistics
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+sys.path.insert(0, ".")
+
+
+def timed(fn, *args, n=10):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    ts = []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        ts.append(time.perf_counter() - t0)
+    return 1e3 * statistics.median(ts)
+
+
+def main():
+    if jax.devices()[0].platform != "tpu":
+        print("no TPU", file=sys.stderr)
+        return 2
+    import mxnet_tpu  # noqa: F401
+    from mxnet_tpu.ops.attention import flash_attention
+    from mxnet_tpu.ops.gmm import gmm_plan, grouped_matmul
+    from mxnet_tpu.ops.ssd import ssd_chunked, ssd_scan
+
+    key = jax.random.PRNGKey(0)
+    m, u, f, held = 49152, 2688, 1856, 8
+    bf = jnp.bfloat16
+    rows = jax.random.normal(key, (m, u), bf)
+    w_up = 0.02 * jax.random.normal(jax.random.fold_in(key, 1),
+                                    (held, u, f), bf)
+    w_dn = 0.02 * jax.random.normal(jax.random.fold_in(key, 2),
+                                    (held, f, u), bf)
+
+    def ffn(rows, w_up, w_dn, sizes):
+        valid = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+        h = grouped_matmul(rows, w_up, sizes)
+        h = jnp.square(jax.nn.relu(jnp.where(valid, h, 0)))
+        y = grouped_matmul(h, w_dn, sizes)
+        return jnp.where(valid, y, 0)
+
+    fwd = jax.jit(ffn)
+    both = jax.jit(jax.grad(
+        lambda r, a, b, s: ffn(r, a, b, s).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+    print(json.dumps({"gmm_plan_up": gmm_plan(m, u, f),
+                      "gmm_plan_down": gmm_plan(m, f, u)}))
+    cases = {"even_3072": [384] * 8, "skewed_3072": [3072] + [0] * 7,
+             "ragged_3072": [1000, 24, 700, 0, 500, 348, 300, 200],
+             "even_6144": [768] * 8, "even_12288": [1536] * 8,
+             "even_49152": [6144] * 8, "none": [0] * 8}
+    for name, sizes in cases.items():
+        s = jnp.asarray(sizes, jnp.int32)
+        print(json.dumps({"moe_gmm": name, "rows": sum(sizes),
+                          "fwd_ms": timed(fwd, rows, w_up, w_dn, s),
+                          "fwd_bwd_ms": timed(both, rows, w_up, w_dn, s)}),
+              flush=True)
+
+    b, t, h, p, g, n = 1, 8192, 64, 64, 8, 128
+    ks = jax.random.split(key, 5)
+    x = jax.random.normal(ks[0], (b, t, h, p), bf)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, t, h)) - 4.0)
+    a = -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.7))
+    bm = jax.random.normal(ks[3], (b, t, g, n), bf)
+    cm = jax.random.normal(ks[4], (b, t, g, n), bf)
+    for impl in ("pallas", "xla"):
+        fn = jax.jit(lambda *v, impl=impl: ssd_scan(*v, chunk=128,
+                                                    impl=impl))
+        gr = jax.jit(jax.grad(lambda *v, impl=impl: ssd_scan(
+            *v, chunk=128, impl=impl).sum(), argnums=(0, 1, 2, 3, 4)))
+        print(json.dumps({"ssd": impl,
+                          "fwd_ms": timed(fn, x, dt, a, bm, cm),
+                          "fwd_bwd_ms": timed(gr, x, dt, a, bm, cm)}),
+              flush=True)
+    y1 = jax.jit(lambda *v: ssd_scan(*v, impl="pallas"))(x, dt, a, bm, cm)
+    y2 = jax.jit(lambda *v: ssd_chunked(*v))(x, dt, a, bm, cm)
+    print(json.dumps({"ssd_pallas_vs_xla_max_gap":
+                      float(jnp.max(jnp.abs(y1 - y2))),
+                      "max": float(jnp.max(jnp.abs(y2)))}))
+
+    q = jax.random.normal(ks[0], (1, 8192, 32, 128), bf)
+    for hk in (2, 32):
+        k = jax.random.normal(ks[1], (1, 8192, hk, 128), bf)
+        v = jax.random.normal(ks[2], (1, 8192, hk, 128), bf)
+        fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+        gr = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))
+        print(json.dumps({"flash_kv_heads": hk,
+                          "fwd_ms": timed(fn, q, k, v),
+                          "fwd_bwd_ms": timed(gr, q, k, v)}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
