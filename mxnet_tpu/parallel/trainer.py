@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import base as _base
@@ -148,6 +149,9 @@ class ShardedTrainer:
         self._donate_batch = bool(donate_batch)
         self._guard_nonfinite = bool(guard_nonfinite)
         self._data_source = None   # attach_data_source: stats()/span stamp
+        self._batch_puts = 0       # batch arrays this trainer placed itself
+        self._scalar_slots = {}    # position -> (host value, its device copy)
+        self._replicated = NamedSharding(self.mesh, P())
         if clip_global_norm is not None and clip_global_norm <= 0:
             raise _base.MXNetError(
                 f"clip_global_norm must be > 0, got {clip_global_norm}")
@@ -204,6 +208,18 @@ class ShardedTrainer:
         if self._loss_scaler is not None:
             return float(self._loss_scaler.loss_scale)
         return 1.0
+
+    def _set_carried(self, scale=None, good=None):
+        """The guarded step's carried loss scale and run of finite steps,
+        placed as the step itself returns them: replicated on the mesh.
+        To jit an array that sits on no mesh is of another type than the
+        step's own output, and the second step would trace and compile
+        again."""
+        repl = self._replicated
+        if scale is not None:
+            self._scale_arr = _mesh_device_put(onp.float32(scale), repl)
+        if good is not None:
+            self._good_arr = _mesh_device_put(onp.int32(good), repl)
 
     # ------------------------------------------------------------------
     def _build(self, data, labels):
@@ -309,8 +325,7 @@ class ShardedTrainer:
         if self._guarded:
             init_scale = (self._loss_scaler.loss_scale
                           if self._loss_scaler is not None else 1.0)
-            self._scale_arr = jnp.asarray(init_scale, jnp.float32)
-            self._good_arr = jnp.asarray(0, jnp.int32)
+            self._set_carried(scale=init_scale, good=0)
         self._compile(data, labels)
         self._built = True
         if self._pending_states is not None:
@@ -708,12 +723,22 @@ class ShardedTrainer:
     def _step(self, data, labels=(), span=None):
         """One step in four host phases, each a ``host_range`` (and a
         child of ``span``, the live ``trainer.step``, when tracing is
-        on): ``scalars`` and ``place`` launch small programs and
-        transfers, ``dispatch`` is the compiled step's call alone,
-        ``rebind`` is host bookkeeping.  The device's work and the wait
-        for it are in none of them: the step is asynchronous, the wait
-        happens where the caller reads the loss
-        (``span:ndarray.readback``)."""
+        on).  Between the caller's read of the last loss and the launch
+        of the compiled step the device stands idle, so nothing is
+        launched there but the step, and nothing moved that could move
+        earlier: ``scalars`` makes the learning rate, the step count,
+        the poisons and the key as HOST values (numpy scalars, two key
+        words) and takes their copies on the device, which the last step
+        shipped ahead (a transfer happens here only for a value nobody
+        could foretell: a new rate, a reseed, a poison); ``place``
+        gathers the arrays as they sit on the mesh and places only a
+        batch array that is not already where the step wants it
+        (``stats()["batch_puts"]``); ``dispatch`` is the compiled step's
+        call alone, the one launch of a step; ``rebind`` is host
+        bookkeeping beside the running step, and ships the next step's
+        count and key.  The device's work and the wait for it are in
+        none of them: the step is asynchronous, the wait happens where
+        the caller reads the loss (``span:ndarray.readback``)."""
         _inject("trainer.step")
         self._obs_steps.inc()
         compiled0 = _xla_compiles()
@@ -731,14 +756,11 @@ class ShardedTrainer:
         opt = self.optimizer
         opt.num_update += 1
         with _host_range("trainer", "scalars", launches=True, parent=span):
-            lr = jnp.asarray(opt.learning_rate, jnp.float32)
-            t = jnp.asarray(opt.num_update, jnp.int32)
-            key = _random.next_key()
-            if self._guarded:
-                lp = _poison("trainer.loss_nonfinite")
-                gp = _poison("trainer.grad_nonfinite")
-                lp = jnp.asarray(0.0 if lp is None else lp, jnp.float32)
-                gp = jnp.asarray(0.0 if gp is None else gp, jnp.float32)
+            poisons = (_poison("trainer.loss_nonfinite"),
+                       _poison("trainer.grad_nonfinite")) \
+                if self._guarded else ()
+            scalars = self._on_device(self._host_scalars(
+                _random.next_key_words(), opt.num_update, *poisons))
 
         with _host_range("trainer", "place", launches=True, parent=span):
             param_vals, aux_vals, state_vals, batch_vals = \
@@ -749,12 +771,10 @@ class ShardedTrainer:
             if self._guarded:
                 (loss, flag, new_scale, new_good, new_params, new_aux,
                  new_states) = self._step_fn(
-                    param_vals, aux_vals, state_vals, batch_vals, key, lr,
-                    t, self._scale_arr, self._good_arr, lp, gp)
+                    param_vals, aux_vals, state_vals, batch_vals, *scalars)
             else:
                 loss, new_params, new_aux, new_states = self._step_fn(
-                    param_vals, aux_vals, state_vals, batch_vals, key, lr,
-                    t)
+                    param_vals, aux_vals, state_vals, batch_vals, *scalars)
 
         with _host_range("trainer", "rebind", launches=False,
                          parent=span):
@@ -766,6 +786,11 @@ class ShardedTrainer:
                 p._data._rebind(v)
             for l, v in zip(self._state_flat, new_states):
                 l._rebind(v)
+            # the device is busy with the step: ship now what the next
+            # step's scalars will be if nobody reseeds, draws or changes
+            # the rate in between (what they then are is compared again)
+            self._on_device(self._host_scalars(
+                _random.next_key_words(advance=False), opt.num_update + 1))
         compiled = _xla_compiles() - compiled0
         if compiled:
             self._note_compile(compiled, data, labels, span)
@@ -789,16 +814,59 @@ class ShardedTrainer:
         if fr is not None:
             fr.record("trainer.compile", **attrs)
 
+    def _host_scalars(self, key, t, loss_poison=None, grad_poison=None):
+        """What the step takes after its arrays, as host values of fixed
+        type (never a weak one: a type that changed between calls would
+        compile again): the key, ``lr`` float32, ``t`` int32 and, guarded,
+        the carried scale and counter with the two poisons float32."""
+        out = (key, onp.float32(self.optimizer.learning_rate), onp.int32(t))
+        if self._guarded:
+            out += (self._scale_arr, self._good_arr,
+                    onp.float32(0.0 if loss_poison is None else loss_poison),
+                    onp.float32(0.0 if grad_poison is None else grad_poison))
+        return out
+
+    def _on_device(self, scalars):
+        """``scalars`` with each host value replaced by a copy of it on
+        the mesh (a transfer, never a program).  A copy is kept per
+        position and serves again while the value is the same: the rate
+        between its changes, the poisons' zeros, and the count and key
+        that the last step shipped ahead, behind its own launch, so that
+        a step in the usual run transfers nothing before its launch.  (A
+        host value in the call itself costs that call a third of a
+        millisecond on the chip's host, each.)"""
+        repl = self._replicated
+        out = []
+        for i, v in enumerate(scalars):
+            if v is None or isinstance(v, jax.Array):
+                out.append(v)
+                continue
+            slot = self._scalar_slots.get(i)
+            if slot is None or slot[0].tobytes() != v.tobytes():
+                slot = self._scalar_slots[i] = (v, _mesh_device_put(v, repl))
+            out.append(slot[1])
+        return tuple(out)
+
     def _device_args(self, data, labels):
         """The step's array arguments as they sit on the mesh: params,
-        aux, optimizer state, and the batch placed by its shardings."""
+        aux, optimizer state, and the batch.  A batch array that is
+        already committed with the step's sharding (what a
+        ``DevicePrefetcher`` built with ``batch_shardings`` delivers)
+        passes through untouched; any other is placed here and counted
+        in ``stats()["batch_puts"]``."""
+        batch = []
+        for x, sh in zip(tuple(data) + tuple(labels), self._batch_shardings):
+            v = x.jax if isinstance(x, NDArray) else x
+            if not (isinstance(v, jax.Array) and v.committed
+                    and v.sharding == sh):
+                v = _mesh_device_put(
+                    v if isinstance(x, NDArray) else jnp.asarray(v), sh)
+                self._batch_puts += 1
+            batch.append(v)
         return (tuple(p._data.jax for _, p in self._trainable),
                 tuple(p._data.jax for _, p in self._aux),
                 tuple(l.jax for l in self._state_flat),
-                tuple(_mesh_device_put(x.jax if isinstance(x, NDArray)
-                                       else jnp.asarray(x), sh)
-                      for x, sh in zip(tuple(data) + tuple(labels),
-                                       self._batch_shardings)))
+                tuple(batch))
 
     def lower_step(self, data, labels=()):
         """The jitted step lowered for this batch (``jax.stages.Lowered``):
@@ -811,13 +879,9 @@ class ShardedTrainer:
             data = (data,)
         if not isinstance(labels, (tuple, list)):
             labels = (labels,)
-        args = self._device_args(data, labels) + (
-            jax.random.PRNGKey(0),
-            jnp.asarray(self.optimizer.learning_rate, jnp.float32),
-            jnp.asarray(self.optimizer.num_update, jnp.int32))
-        if self._guarded:
-            zero = jnp.asarray(0.0, jnp.float32)
-            args += (self._scale_arr, self._good_arr, zero, zero)
+        args = self._device_args(data, labels) + self._on_device(
+            self._host_scalars(onp.zeros(2, onp.uint32),
+                               self.optimizer.num_update))
         return self._step_fn.lower(*args)
 
     # ------------------------------------------------------------------
@@ -839,11 +903,14 @@ class ShardedTrainer:
 
     def stats(self) -> dict:
         """Point-in-time trainer facts (the engine-``stats()`` shape):
-        step counter plus a ``data`` section from the attached input
-        pipeline when one is present."""
+        step counter, ``batch_puts`` (batch arrays the trainer had to
+        place itself since it was built: 0 behind a ``DevicePrefetcher``
+        that ships against ``batch_shardings``), plus a ``data`` section
+        from the attached input pipeline when one is present."""
         out = {"num_update": int(self.optimizer.num_update),
                "built": self._built,
-               "guarded": self._guarded}
+               "guarded": self._guarded,
+               "batch_puts": self._batch_puts}
         src = self._data_source
         if src is not None and hasattr(src, "stats"):
             out["data"] = src.stats()
@@ -968,11 +1035,9 @@ class ShardedTrainer:
         if self._guarded:
             # optional (a checkpoint from an unguarded run lacks them)
             if "meta:loss_scale" in d:
-                self._scale_arr = jnp.asarray(
-                    float(d["meta:loss_scale"].asnumpy()[0]), jnp.float32)
+                self._set_carried(scale=d["meta:loss_scale"].asnumpy()[0])
             if "meta:good_steps" in d:
-                self._good_arr = jnp.asarray(
-                    int(d["meta:good_steps"].asnumpy()[0]), jnp.int32)
+                self._set_carried(good=d["meta:good_steps"].asnumpy()[0])
 
     # -------------------------------------------------- sharded checkpoints
     def _checkpoint_tree(self):
@@ -1048,21 +1113,17 @@ class ShardedTrainer:
             l._rebind(restored["states"][f"s{i}"])
         self.optimizer.num_update = int(restored["num_update"])
         if self._guarded:
-            self._scale_arr = jnp.asarray(float(restored["loss_scale"]),
-                                          jnp.float32)
-            self._good_arr = jnp.asarray(int(restored["good_steps"]),
-                                         jnp.int32)
+            self._set_carried(scale=restored["loss_scale"],
+                              good=restored["good_steps"])
 
     def _apply_loaded_states(self, loaded):
         if "num_update" in loaded:
             self.optimizer.num_update = int(loaded["num_update"].asnumpy()[0])
         if self._guarded:
             if "loss_scale" in loaded:
-                self._scale_arr = jnp.asarray(
-                    float(loaded["loss_scale"].asnumpy()[0]), jnp.float32)
+                self._set_carried(scale=loaded["loss_scale"].asnumpy()[0])
             if "good_steps" in loaded:
-                self._good_arr = jnp.asarray(
-                    int(loaded["good_steps"].asnumpy()[0]), jnp.int32)
+                self._set_carried(good=loaded["good_steps"].asnumpy()[0])
         flat_idx = 0
         for i, st in enumerate(self._states):
             for j, l in enumerate(_state_leaves(st)):

@@ -606,7 +606,13 @@ def test_serving_metrics_span_keeps_its_printed_name(monkeypatch):
 def test_trainer_phases_are_children_of_step():
     """The four host phases of a step are children of ``trainer.step``,
     in order and without overlap, and self times add up: the children's
-    own time plus the step's own is the step's duration."""
+    own time plus the step's own is the step's duration.  ``scalars``
+    builds host values (numpy scalars and the key's two words; no
+    program) and finds their copies on the device, ``place`` gathers the
+    arrays on the mesh and places a batch array only if it is not where
+    the step wants it, ``dispatch`` is the one launch of the step,
+    ``rebind`` binds its outputs and ships the next step's count and
+    key."""
     trainer, batch = _tiny_trainer()
     trainer.step(*batch()).asnumpy()             # compile outside
     tr = obs.enable_tracing()
