@@ -14,8 +14,8 @@ zero-cost-when-disabled pattern (docs/static_analysis.md):
 - Project locks are constructed through :func:`named_lock` /
   :func:`named_rlock` / :func:`named_condition` with a stable *site*
   name (``"serving.engine.cond"``).  **Disabled (the default), these
-  return plain ``threading`` primitives** — the witness costs nothing
-  you could measure on the serving bench, exactly like a
+  return plain ``threading`` primitives** — the witness adds no code
+  to a lock's acquire or release, exactly like a
   :func:`~mxnet_tpu.resilience.faults.inject` site with no plan active.
 - Enabled (:func:`enable`, or ``MXTPU_LOCKWITNESS=1`` before import),
   locks come back wrapped: every acquisition pushes onto a per-thread

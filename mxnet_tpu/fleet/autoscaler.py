@@ -142,7 +142,7 @@ class FleetAutoscaler:
         self.deadline_safety_max = float(deadline_safety_max)
         # decision state: streak counters, cooldown stamp, fleet cap.
         # tick() may be driven by the policy thread or directly by
-        # tests/benches, so the state is lock-guarded.
+        # tests and tools, so the state is lock-guarded.
         self._lock = _named_lock("fleet.autoscaler",
                                  "autoscaler decision state")
         self._up_streak = 0
@@ -253,7 +253,7 @@ class FleetAutoscaler:
     def tick(self) -> dict:
         """One policy evaluation; at most one membership action.
         Returns the decision record (also appended to ``actions`` when
-        an action fired) — benches and tests drive this directly."""
+        an action fired) — tests and tools drive this directly."""
         with self._lock:
             return self._tick_locked()
 

@@ -369,7 +369,7 @@ class FleetRouter:
     num_replicas : fleet size when building from ``factory``.
     routing : ``'affinity'`` (default — prefix-affinity with
         least-loaded spill), ``'least_loaded'``, or ``'random'``
-        (seeded; the control arm for the fleet benchmark).
+        (seeded; the control that tests/test_fleet.py compares against).
     affinity_min_tokens / affinity_window / tracker_entries : the
         :class:`~.policy.RoutingPolicy` knobs.
     spill_queue_depth : affinity target counts as SATURATED when its

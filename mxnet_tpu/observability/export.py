@@ -170,9 +170,9 @@ def flatten(snapshot: Optional[dict] = None,
             prefix: Optional[str] = None,
             include_zero: bool = False) -> Dict[str, float]:
     """Compact ``{"name{label=value}": value}`` flattening of counters
-    and gauges (histograms contribute their count/sum) — the form bench
-    records embed so perf numbers and process counters travel in one
-    JSON line.  ``snapshot=None`` collects the default registry."""
+    and gauges (histograms contribute their count/sum) — one JSON-able
+    dict a caller can embed beside its own results so process counters
+    travel with them.  ``snapshot=None`` collects the default registry."""
     if snapshot is None:
         snapshot = default_registry().collect()
     out: Dict[str, float] = {}

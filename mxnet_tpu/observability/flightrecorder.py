@@ -16,7 +16,7 @@ gone.  The **flight recorder** is the blackbox for that moment:
   rule);
 - **zero-cost when disabled** — every instrumentation site is one
   module-global load plus a ``None`` check, the
-  :mod:`~mxnet_tpu.resilience.faults` contract; the serving bench
+  :mod:`~mxnet_tpu.resilience.faults` contract; the engine's decode
   medians must stay inside the host-noise band with the recorder off;
 - on a **trigger** — watchdog trip, :class:`EngineCrashedError`
   condemnation, a :class:`NonFiniteOutputError` burst, a replica

@@ -12,7 +12,7 @@ Design rules (same contract as :mod:`mxnet_tpu.resilience.faults`):
 - **zero-cost when disabled** — every instrumentation site does ONE
   module-global load plus a ``None`` check and nothing else.  The
   serving engine's decode medians must stay within trial noise with
-  tracing off (asserted by the ``obs`` bench contract).
+  tracing off (the ``obs``-marked tests in tests/test_observability.py).
 - **propagation crosses threads by value**: the caller thread stamps
   ``trace_id`` on the request at ``submit``; the scheduler thread reads
   it — no thread-locals to lose across the queue boundary.  Batched

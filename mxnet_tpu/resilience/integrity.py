@@ -110,9 +110,9 @@ def file_digest(path: str) -> str:
     are stable across processes/hosts; multi-leaf files hash their
     leaves on a small thread pool (hashlib releases the GIL for large
     updates), keeping save/restore verification near memory-bandwidth
-    instead of single-core hash speed — this is what keeps the
-    verification overhead inside checkpoint-timing trial noise
-    (bench.py ``--workload checkpoint``)."""
+    instead of single-core hash speed.  What verification costs a
+    save or a restore on the chip's host: not measured (no benchmark
+    cell saves a checkpoint yet; PERF.md §7 row 12)."""
     root = hashlib.blake2b(digest_size=_DIGEST_SIZE)
     with open(path, "rb") as f:
         first = f.read(_TREE_CHUNK)

@@ -395,7 +395,7 @@ class InferenceEngine:
     num_pages : physical KV pages in the pool (paged layout).  Default
         ``num_slots * max_length / page_size`` — the dense-equivalent
         footprint; provision FEWER to serve the same concurrency in
-        less memory (the paged-vs-dense bench's working point).  Must
+        less memory (what tests/test_paged_kv.py provisions).  Must
         cover at least one worst-case request
         (``max_length / page_size``).  In the paged layout the prefix
         cache reserves nothing (``prefix_pool_rows`` is ignored):

@@ -117,8 +117,8 @@ class SlotAllocator:
         self.scratch = num_slots           # row S of the (S+1, ...) cache
         self._free: List[int] = list(range(num_slots - 1, -1, -1))
         self._active: Dict[int, SlotState] = {}
-        # deepest concurrency ever reached — the paged-vs-dense bench's
-        # headline (max sustainable concurrency at fixed KV memory)
+        # deepest concurrency ever reached (max sustainable concurrency
+        # at fixed KV memory: what the paged layout is for)
         self.active_highwater = 0
 
     @property

@@ -192,7 +192,7 @@ class HostKVTier:
                 self._resolve(job[2], None)
 
     def drain(self, timeout: float = 5.0) -> bool:
-        """Test/bench helper: wait until the worker queue is empty and
+        """Test helper: wait until the worker queue is empty and
         the worker idle.  True on success, False on timeout."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:

@@ -223,8 +223,8 @@ class ServingMetrics:
         # quantized KV divergence: max-abs logit delta of each sampled
         # step vs the fp32 reference arm (debug_parity= on).  The
         # bounds cover float32-epsilon noise up to an outright-broken
-        # 1e3 delta — the divergence CONTRACT is asserted by tests/
-        # bench against this histogram's max.
+        # 1e3 delta — the divergence CONTRACT is asserted by the tests
+        # (tests/test_paged_attn.py) against this histogram's max.
         self.kv_quant_error = LatencyHistogram(lo=1e-9, hi=1e3,
                                                buckets_per_decade=2)
         self.queue = LatencyHistogram()

@@ -22,7 +22,7 @@ a typed :class:`~.errors.MigrationDigestError` and the decode pool
 stays pristine — a corrupt bundle is never adopted.
 
 The transport is host-side by design: bundles are plain numpy, so the
-same bytes work in-process (the CPU-sanity benches and tests), over
+same bytes work in-process (the tests and the chaos scenarios), over
 shared memory, or pickled across an RPC boundary.  Device placement is
 the *importing* engine's job (it installs pages under its own mesh
 sharding), which is what lets a prefill replica and a decode replica

@@ -99,8 +99,8 @@ class OverloadController:
     ----------
     capacity : admission-queue capacity the pressure fractions are
         relative to.
-    enabled : ``False`` pins ``factor`` at 1.0 forever (the blind-
-        shedding baseline arm of the overload benchmark).
+    enabled : ``False`` pins ``factor`` at 1.0 forever (an engine
+        that sheds and never shortens service).
     enter_fraction : queue depth at or above this fraction of capacity
         counts as pressure (as does any deadline miss since the last
         cycle).

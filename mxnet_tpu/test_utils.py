@@ -28,8 +28,8 @@ def mesh_devices(n):
     instead of re-forcing: the flag is read exactly once at backend
     bring-up, so forcing it from inside a test would either be a no-op
     or poison the already-initialized platform for the rest of the
-    process.  Callers (the ``mesh_devices`` pytest fixture, the bench
-    workloads) skip or degrade when ``None`` comes back."""
+    process.  Callers (the ``mesh_devices`` pytest fixture) skip or
+    degrade when ``None`` comes back."""
     import jax
 
     devs = jax.devices()

@@ -449,7 +449,7 @@ def test_hedged_request_completes_on_second_replica(net):
 @pytest.mark.fleet
 @pytest.mark.slow
 def test_affinity_beats_random_routing_ttft():
-    """Perf contract (CPU sanity of --workload fleet): on a repeated-
+    """Perf contract (a CPU timing, never a device number): on a repeated-
     system-prompt workload over 3 replicas, prefix-affinity routing
     yields a strictly higher fleet prefix hit rate than seeded random
     routing, and cuts mean TTFT.  Needs a compute-bound prefill, so it

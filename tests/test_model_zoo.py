@@ -73,8 +73,8 @@ def test_model_zoo_save_load_roundtrip(tmp_path):
 
 @pytest.mark.parametrize("version,layers", [(1, 18), (2, 50)])
 def test_resnet_nhwc_matches_nchw(version, layers):
-    """layout='NHWC' (the TPU channels-last fast path, bench.py default
-    on chip) must be numerically identical to NCHW given the same OIHW
+    """layout='NHWC' (the channels-last form; its speed on the chip is
+    not measured) must be numerically identical to NCHW given the same OIHW
     weights (docs/resnet_roofline_r05.md)."""
     from mxnet_tpu import autograd
     from mxnet_tpu.models.vision import get_resnet

@@ -386,8 +386,7 @@ def test_consistency(name):
     """On a TPU host: cpu-vs-tpu f32 with gradients.  On a CPU-only host
     the default single config compares nothing, so force an f32-vs-bf16
     dtype axis (forward-only; bf16 grads of norm-style ops are
-    legitimately loose) — the same degraded mode tools/tpu_consistency.py
-    uses."""
+    legitimately loose)."""
     fn, inputs, _ = SPECS[name]
 
     def first(*xs):

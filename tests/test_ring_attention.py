@@ -165,7 +165,7 @@ def test_ring_segment_ids_shape_guard():
 
 def test_smap_extra_specs_arity_guard():
     """len(extra) != len(extra_specs) must fail loudly at entry, not
-    zip-truncate (ADVICE.md finding)."""
+    zip-truncate."""
     from mxnet_tpu.ops._smap import shard_mapped_qkv
     mesh = par.make_mesh(dp=2, sp=4)
     q, k, v = _qkv()

@@ -427,7 +427,7 @@ def test_phase_latency_and_ttft_reported(net):
 @pytest.mark.slow
 @pytest.mark.serving_perf
 def test_prefix_cache_cuts_ttft():
-    """Perf contract (CPU sanity of the --workload prefix bench): on a
+    """Perf contract (a CPU timing, never a device number): on a
     repeated-system-prompt workload the cache cuts median TTFT >= 25%
     at a >= 80% hit rate.  Needs a COMPUTE-bound prefill (the module
     fixture's model is dispatch-bound — a 120-token prefill there costs

@@ -732,7 +732,7 @@ def serving_quant_storm(net):
     claim NaN-poisons a live page's scale sidecar.  Invariants: ZERO
     tokens beyond contract (every completer is token-identical to the
     same int8 engine run fault-free — the divergence contract between
-    int8 and fp32 is the bench/test layer's job; chaos asserts the
+    int8 and fp32 is the tests' job; chaos asserts the
     storm itself changes nothing), zero stranded futures (scale-poison
     victims fail TYPED via the in-graph NaN guard, detected at the
     first dequant that read the rot), the quantize fault degraded to a

@@ -7,7 +7,7 @@ shift), records them as JSONL, replays recorded traces against any
 ``submit(...)``-shaped target (an ``InferenceEngine``, a
 ``FleetRouter``, or a stub), and reports what happened: issued /
 completed / typed-error counts, per-request latency, and — the number
-the autoscaler benches live on — whether anything was LOST (submitted
+the autoscaler's tests live on — whether anything was LOST (submitted
 but never resolved).
 
 Trace events are plain dicts::
@@ -20,7 +20,7 @@ Trace events are plain dicts::
 
 Determinism contract: the same builder arguments + seed produce the
 same trace, byte-for-byte after JSONL round-trip — replay-driven
-benches and chaos scenarios compare runs on identical arrivals, so
+tests and chaos scenarios compare runs on identical arrivals, so
 the generator must never consult wall-clock or global RNG state.
 
 Usage::
@@ -297,7 +297,7 @@ def main(argv=None) -> int:
                              sort_keys=True))
             return 0
         print("replay needs a programmatic target — import "
-              "tools.loadgen.replay() from a bench or test",
+              "tools.loadgen.replay() from a test or a tool",
               file=sys.stderr)
         return 2
     if args.shape == "diurnal":
