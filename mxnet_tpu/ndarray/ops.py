@@ -877,6 +877,32 @@ def softmax_cross_entropy(data, label):
     return invoke("softmax_cross_entropy", f, [data, label])
 
 
+@jax.custom_vjp
+def _gelu_erf(h):
+    """Exact (erf) GELU whose backward works from the input alone, as
+    mshadow_op::gelu_grad does; autodiff of ``jax.nn.gelu`` keeps erfc's
+    branches and their predicate besides (PERF.md, PR 29).  The backward
+    takes Phi from erf, one rational form, where erfc computes two
+    branches: on the chip it runs inside a matmul's fusion and is bound
+    by the vector unit, not by memory."""
+    return jax.nn.gelu(h, approximate=False)
+
+
+def _gelu_erf_fwd(h):
+    return _gelu_erf(h), h
+
+
+def _gelu_erf_bwd(h, dy):
+    ct = jnp.promote_types(h.dtype, jnp.float32)
+    hf = h.astype(ct)
+    cdf = 0.5 + 0.5 * lax.erf(hf * math.sqrt(0.5))
+    pdf = jnp.exp(-0.5 * hf * hf) * (1.0 / math.sqrt(2.0 * math.pi))
+    return ((dy.astype(ct) * (cdf + hf * pdf)).astype(h.dtype),)
+
+
+_gelu_erf.defvjp(_gelu_erf_fwd, _gelu_erf_bwd)
+
+
 ACTIVATION_FNS = {
     "relu": jax.nn.relu, "sigmoid": jax.nn.sigmoid,
     "tanh": jnp.tanh, "softrelu": jax.nn.softplus,
@@ -905,7 +931,7 @@ def LeakyReLU(data, gamma=None, act_type="leaky", slope=0.25,
     if act_type == "selu":
         return invoke("selu", jax.nn.selu, [data])
     if act_type == "gelu":
-        return invoke("gelu", functools.partial(jax.nn.gelu, approximate=False), [data])
+        return invoke("gelu", _gelu_erf, [data])
     if act_type == "prelu":
         g = _as_nd(gamma)
         return invoke("prelu",
@@ -1228,19 +1254,67 @@ def BatchNorm(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
     return (out, mean, var) if kw.get("_internal_stats") else out
 
 
+def _row_stats(x, axis, eps):
+    mean = jnp.mean(x, axis=axis, keepdims=True)
+    var = jnp.var(x, axis=axis, keepdims=True)
+    return mean, lax.rsqrt(var + eps)
+
+
+def _along(x, axis):
+    """The shape that lays a vector along ``axis`` of ``x``."""
+    shape = [1] * x.ndim
+    shape[axis] = x.shape[axis]
+    return shape
+
+
+def _normalise(x, mean, rstd, g, b, axis):
+    shape = _along(x, axis)
+    return (x - mean) * rstd * g.reshape(shape) + b.reshape(shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _layer_norm(x, g, b, axis, eps):
+    """LayerNorm with the backward MXNet's operator has
+    (src/operator/nn/layer_norm.cc): it keeps the input and two numbers a
+    row, where autodiff of the formula keeps three arrays of the input's
+    size (PERF.md, PR 29)."""
+    return _normalise(x, *_row_stats(x, axis, eps), g, b, axis)
+
+
+def _layer_norm_fwd(x, g, b, axis, eps):
+    mean, rstd = _row_stats(x, axis, eps)
+    y = _normalise(x, mean, rstd, g, b, axis)
+    ct = jnp.promote_types(x.dtype, jnp.float32)
+    if x.dtype != ct:
+        # the backward works in float32: it gets float32's statistics,
+        # not the forward's rounded ones
+        mean, rstd = _row_stats(x.astype(ct), axis, eps)
+    return y, (x, g, b, mean, rstd)
+
+
+def _layer_norm_bwd(axis, eps, res, dy):
+    x, g, b, mean, rstd = res
+    ct = rstd.dtype
+    ax = axis % x.ndim
+    others = tuple(i for i in range(x.ndim) if i != ax)
+    dy = dy.astype(ct)
+    xh = (x.astype(ct) - mean) * rstd
+    dxh = dy * g.astype(ct).reshape(_along(x, ax))
+    dx = (dxh - jnp.mean(dxh, axis=ax, keepdims=True)
+          - xh * jnp.mean(dxh * xh, axis=ax, keepdims=True)) * rstd
+    dg = jnp.sum(dy * xh, axis=others).reshape(g.shape)
+    db = jnp.sum(dy, axis=others).reshape(b.shape)
+    return dx.astype(x.dtype), dg.astype(g.dtype), db.astype(b.dtype)
+
+
+_layer_norm.defvjp(_layer_norm_fwd, _layer_norm_bwd)
+
+
 @_export
 def LayerNorm(data, gamma, beta, axis=-1, eps=1e-5, **kw):
     nds = [_as_nd(x) for x in (data, gamma, beta)]
-
-    def f(x, g, b):
-        mean = jnp.mean(x, axis=axis, keepdims=True)
-        var = jnp.var(x, axis=axis, keepdims=True)
-        shape = [1] * x.ndim
-        shape[axis] = x.shape[axis]
-        return (x - mean) * lax.rsqrt(var + eps) * g.reshape(shape) \
-            + b.reshape(shape)
-
-    return invoke("LayerNorm", f, nds)
+    return invoke("LayerNorm",
+                  lambda x, g, b: _layer_norm(x, g, b, axis, eps), nds)
 
 
 @_export
@@ -1741,8 +1815,7 @@ def selu(data):
 @_export
 def gelu(data):
     data = _as_nd(data)
-    return invoke("gelu",
-                  functools.partial(jax.nn.gelu, approximate=False), [data])
+    return invoke("gelu", _gelu_erf, [data])
 
 
 @_export
