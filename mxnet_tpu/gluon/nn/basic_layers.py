@@ -256,25 +256,28 @@ class LayerNorm(HybridBlock):
 
 class RMSNorm(HybridBlock):
     """``x * rsqrt(mean(x^2) + eps) * gamma`` over the last axis, computed
-    in float32 (no centring, no offset)."""
+    in float32 (no centring).  ``unit_offset=True``: the gain is
+    ``1 + gamma`` and ``gamma`` starts at zero."""
 
-    def __init__(self, epsilon=1e-5, gamma_initializer="ones",
-                 in_channels=0, **kwargs):
+    def __init__(self, epsilon=1e-5, gamma_initializer=None,
+                 in_channels=0, unit_offset=False, **kwargs):
         super().__init__(**kwargs)
         self._eps = epsilon
+        self._unit_offset = bool(unit_offset)
         self.gamma = self.params.get(
-            "gamma", shape=(in_channels,), init=gamma_initializer,
-            allow_deferred_init=True)
+            "gamma", shape=(in_channels,), allow_deferred_init=True,
+            init=gamma_initializer or ("zeros" if unit_offset else "ones"))
 
     def infer_shape(self, x, *args):
         self.gamma._set_shape((x.shape[-1],))
 
     def forward(self, x):
         from ...ndarray import ops
-        return ops.RMSNorm(x, self.gamma.data(), eps=self._eps)
+        return ops.RMSNorm(x, self.gamma.data(), eps=self._eps,
+                           unit_offset=self._unit_offset)
 
     def __repr__(self):
-        return f"RMSNorm(eps={self._eps})"
+        return f"RMSNorm(eps={self._eps}, unit_offset={self._unit_offset})"
 
 
 class GroupNorm(HybridBlock):

@@ -9,6 +9,7 @@ from .bert import BERTForPretrain, BERTModel, get_bert
 from .gpt2 import GPT2Model, get_gpt2, gpt2_lm_loss
 from .moe import MoELayer, MoETransformerBlock, pop_aux_losses
 from .nemotron_h import NemotronHModel, get_nemotron_h
+from .qwen3_next import Qwen3NextModel, get_qwen3_next
 from .nmt import TransformerDecoderBlock, TransformerNMT, get_nmt, nmt_loss
 from .stacked import StackedGPT2Model, get_stacked_gpt2
 from .transformer import (MultiHeadAttention, PositionwiseFFN,
@@ -21,4 +22,5 @@ __all__ = ["vision", "get_model", "BERTModel", "BERTForPretrain", "get_bert",
            "get_stacked_gpt2", "MultiHeadAttention", "PositionwiseFFN",
            "TransformerBlock", "TransformerEncoderLayer",
            "TransformerNMT", "TransformerDecoderBlock", "get_nmt",
-           "nmt_loss", "NemotronHModel", "get_nemotron_h"]
+           "nmt_loss", "NemotronHModel", "get_nemotron_h", "Qwen3NextModel",
+           "get_qwen3_next"]

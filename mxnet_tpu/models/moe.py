@@ -15,12 +15,17 @@ during forward; loss functions drain it via :func:`pop_aux_losses`.
 A second routing, ``routing="dropless"``, is one chip's share of an
 expert-parallel deployment: the layer is told ``num_experts`` and which
 of them it holds (``experts_held=(first, count)``).  The router scores all
-``num_experts`` (sigmoid scores, a choice biased by a buffer that is not
-trained, top-k weights renormalised and scaled, as DeepSeek-V3 and
-Nemotron-H route); the token-expert pairs that fall on the experts held
-are sorted by expert into a buffer of the worst-case size and run as
-grouped matrix products (:mod:`mxnet_tpu.ops.gmm`) whose time follows the
-rows routed, so no token is dropped under any imbalance.  What the
+``num_experts`` by the scoring it was built with (``"sigmoid"``: sigmoid
+scores, a choice biased by a buffer that is not trained, top-k weights
+renormalised and scaled, as DeepSeek-V3 and Nemotron-H route;
+``"softmax"``: a softmax over all experts, top-k, renormalised, no
+buffer and no scale, as Qwen3-Next routes); the token-expert pairs that
+fall on the experts held are sorted by expert into a buffer of the
+worst-case size and run as grouped matrix products
+(:mod:`mxnet_tpu.ops.gmm`) whose time follows the rows routed, so no
+token is dropped under any imbalance.  An expert is ``relu(x W_up)^2
+W_down`` (``"relu2"``, two matrices) or ``(silu(x W_gate) * x W_up)
+W_down`` (``"swiglu"``, three: one more grouped product a pass).  What the
 experts NOT held would add is left out: nothing stands in for the absent
 chips, and the partial result goes on.  Summed over the chips of the
 deployment (the shared expert counted once) the shares equal the whole
@@ -42,7 +47,7 @@ from ..parallel.sharding import annotate
 
 __all__ = ["MoELayer", "MoETransformerBlock", "pop_aux_losses",
            "aux_loss_scope", "dropless_ffn", "route_sigmoid_topk",
-           "read_routing_counters"]
+           "route_softmax_topk", "read_routing_counters"]
 
 from .. import base as _base
 
@@ -185,25 +190,58 @@ def route_sigmoid_topk(x, w_router, choice_bias, *, top_k, norm_topk=True,
     return w * scaling, chosen
 
 
+def route_softmax_topk(x, w_router, *, top_k, norm_topk=True, chosen=None):
+    """Scores of all experts in float32 and the top-k choice: ``p =
+    softmax(x W_r^T)`` over ALL experts, the ``top_k`` largest, their
+    weights ``p`` at the chosen, divided by their sum.  No buffer, no
+    scale.  ``chosen`` as in :func:`route_sigmoid_topk`."""
+    logits = jnp.einsum("nd,ed->ne", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.softmax(logits, axis=-1)
+    if chosen is None:
+        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(p), top_k)
+    chosen = chosen.astype(jnp.int32)
+    w = jnp.take_along_axis(p, chosen, axis=1)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, chosen
+
+
 def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
                  norm_topk=True, scaling=1.0, compute_dtype=None,
-                 impl="auto", chosen=None):
-    """One chip's share of a routed ``relu(x W_up)^2 W_down`` expert layer.
+                 impl="auto", chosen=None, scoring="sigmoid", w_gate=None):
+    """One chip's share of a routed expert layer.
 
     ``x`` (N, D); ``w_router`` (E, D) over ALL experts; ``w_up`` (H, D, F)
     and ``w_down`` (H, F, D) of the H experts held, which are experts
-    ``first .. first + H - 1``.  Returns ``(y (N, D) float32, chosen (N, k),
-    sizes (H,) int32)``: the sum over a token's chosen experts THAT ARE
-    HELD of weight x expert output, and how many pairs each held expert
-    got.  Every pair on a held expert is computed: the buffer holds
-    ``N * top_k`` rows."""
+    ``first .. first + H - 1``.  ``scoring``: "sigmoid"
+    (:func:`route_sigmoid_topk`, with ``choice_bias`` and ``scaling``) or
+    "softmax" (:func:`route_softmax_topk`, which has neither).  An expert
+    is ``relu(x W_up)^2 W_down``, or with ``w_gate`` (H, D, F)
+    ``(silu(x W_gate) * x W_up) W_down``.  Returns ``(y (N, D) float32,
+    chosen (N, k), sizes (H,) int32)``: the sum over a token's chosen
+    experts THAT ARE HELD of weight x expert output, and how many pairs
+    each held expert got.  Every pair on a held expert is computed: the
+    buffer holds ``N * top_k`` rows."""
+    from ..ops.flash import plan_event
     from ..ops.gmm import grouped_matmul
     n, d = x.shape
     held = w_up.shape[0]
     cd = jnp.dtype(compute_dtype or x.dtype)
-    w, chosen = route_sigmoid_topk(x, w_router, choice_bias, top_k=top_k,
-                                   norm_topk=norm_topk, scaling=scaling,
-                                   chosen=chosen)
+    plan_event("moe.plan", form="relu2" if w_gate is None else "swiglu",
+               scoring=scoring, top_k=top_k, buffer_rows=n * top_k,
+               experts_held=held)
+    if scoring == "sigmoid":
+        w, chosen = route_sigmoid_topk(x, w_router, choice_bias, top_k=top_k,
+                                       norm_topk=norm_topk, scaling=scaling,
+                                       chosen=chosen)
+    elif scoring == "softmax":
+        w, chosen = route_softmax_topk(x, w_router, top_k=top_k,
+                                       norm_topk=norm_topk, chosen=chosen)
+    else:
+        raise ValueError(f"scoring must be sigmoid or softmax, got "
+                         f"{scoring!r}")
     local = jnp.logical_and(chosen >= first, chosen < first + held)
     key = jnp.where(local, chosen - first, held).reshape(-1)   # (N k,)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
@@ -213,12 +251,18 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
     valid = jnp.arange(n * top_k) < jnp.sum(sizes)
     # every read of a buffer the kernel wrote masks the rows past the
     # routed ones BEFORE anything else touches them
+    def product(lhs, rhs):
+        out = grouped_matmul(lhs, rhs.astype(cd), sizes, impl=impl)
+        return jnp.where(valid[:, None], out, jnp.zeros_like(out))
+
     rows = _rows_of_pairs(x.astype(cd), order, inv, valid, top_k)
-    u = grouped_matmul(rows, w_up.astype(cd), sizes, impl=impl)
-    u = jnp.where(valid[:, None], u, jnp.zeros_like(u)).astype(jnp.float32)
-    h = jnp.square(jax.nn.relu(u)).astype(cd)
-    y = grouped_matmul(h, w_down.astype(cd), sizes, impl=impl)
-    y = jnp.where(valid[:, None], y, jnp.zeros_like(y))
+    u = product(rows, w_up).astype(jnp.float32)
+    if w_gate is None:
+        h = jnp.square(jax.nn.relu(u)).astype(cd)
+    else:
+        h = (jax.nn.silu(product(rows, w_gate).astype(jnp.float32))
+             * u).astype(cd)
+    y = product(h, w_down)
     y = _permute(y, inv, order).reshape(n, top_k, d)
     out = jnp.sum(w[:, :, None] * y.astype(jnp.float32), axis=1)
     return out, chosen, sizes
@@ -241,6 +285,21 @@ def relu2_mlp(x, w_up, w_down, compute_dtype=None):
     h = jnp.square(jax.nn.relu(u)).astype(cd)
     return jnp.einsum("nf,df->nd", h, w_down.astype(cd),
                       precision=_prec(cd), preferred_element_type=jnp.float32)
+
+
+def swiglu_mlp(x, w_gate, w_up, w_down, compute_dtype=None):
+    """``(silu(x W_gate^T) * x W_up^T) W_down^T`` with (out, in) weights:
+    the gated form of a shared expert."""
+    cd = jnp.dtype(compute_dtype or x.dtype)
+
+    def dense(a, w, spec):
+        return jnp.einsum(spec, a.astype(cd), w.astype(cd),
+                          precision=_prec(cd),
+                          preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(dense(x, w_gate, "nd,fd->nf"))
+         * dense(x, w_up, "nd,fd->nf")).astype(cd)
+    return dense(h, w_down, "nf,df->nd")
 
 
 def read_routing_counters(net) -> dict:
@@ -309,15 +368,20 @@ class MoELayer(HybridBlock):
                  capacity_factor=1.25, activation="gelu", dropout=0.0,
                  dtype="float32", routing="capacity", experts_held=None,
                  shared_hidden=0, routed_scaling=1.0, norm_topk=True,
-                 record_choice_rows=0, **kwargs):
+                 record_choice_rows=0, scoring="sigmoid",
+                 expert_form="relu2", shared_gate=False, **kwargs):
         """``routing="capacity"``: softmax top-k with a capacity per
         expert over the ``ep`` axis (tokens over capacity are dropped).
         ``routing="dropless"``: one chip's share (module docstring):
         ``experts_held=(first, count)`` of the ``num_experts`` the router
-        scores (default: all), ``shared_hidden`` > 0 adds a shared expert
-        of that width to every token, ``record_choice_rows=N`` keeps the
-        last step's chosen expert indices of N tokens as a payload
-        (``last_choice``).  The grouped products choose their form by
+        scores (default: all), by ``scoring`` ("sigmoid": with the
+        correction buffer and ``routed_scaling``; "softmax": neither);
+        ``expert_form`` "relu2" (two matrices) or "swiglu" (three), for
+        the routed and the shared expert alike; ``shared_hidden`` > 0
+        adds a shared expert of that width to every token, times
+        ``sigmoid(x . w)`` of a trained vector with ``shared_gate``;
+        ``record_choice_rows=N`` keeps the last step's chosen expert
+        indices of N tokens as a payload (``last_choice``).  The grouped products choose their form by
         platform (``ops.gmm``: Pallas on the TPU, ``ragged_dot``
         elsewhere)."""
         super().__init__(**kwargs)
@@ -342,32 +406,58 @@ class MoELayer(HybridBlock):
             if first < 0 or count < 1 or first + count > num_experts:
                 raise ValueError(f"experts_held {(first, count)} is not a "
                                  f"range of {num_experts} experts")
+            if scoring not in ("sigmoid", "softmax"):
+                raise ValueError(f"scoring must be sigmoid or softmax, got "
+                                 f"{scoring!r}")
+            if expert_form not in ("relu2", "swiglu"):
+                raise ValueError(f"expert_form must be relu2 or swiglu, "
+                                 f"got {expert_form!r}")
             self._held = (int(first), int(count))
             self._scaling = float(routed_scaling)
             self._norm_topk = bool(norm_topk)
+            self._scoring = scoring
+            glu = expert_form == "swiglu"
             g = self.params.get
-            # chooses, is not trained, and no step changes it
-            self.e_score_correction_bias = g(
-                "e_score_correction_bias", shape=(num_experts,),
-                dtype="float32", init="zeros", differentiable=False)
-            self.w1 = g("w1", shape=(count, units, hidden_size),
-                        dtype=dtype, init="xavier")
-            self.w2 = g("w2", shape=(count, hidden_size, units),
-                        dtype=dtype, init="xavier")
+            # what the layer computes with, in the order its step takes it
+            ins = {"gate": self.gate}
+            if scoring == "sigmoid":
+                # chooses, is not trained, and no step changes it
+                self.e_score_correction_bias = ins["bias"] = g(
+                    "e_score_correction_bias", shape=(num_experts,),
+                    dtype="float32", init="zeros", differentiable=False)
+            self.w1 = ins["w1"] = g(
+                "w1", shape=(count, units, hidden_size), dtype=dtype,
+                init="xavier")
+            self.w2 = ins["w2"] = g(
+                "w2", shape=(count, hidden_size, units), dtype=dtype,
+                init="xavier")
+            if glu:
+                self.w_gate = ins["w_gate"] = g(
+                    "w_gate", shape=(count, units, hidden_size),
+                    dtype=dtype, init="xavier")
             self.shared_up = self.shared_down = None
             if shared_hidden:
-                self.shared_up = g("shared_up",
-                                   shape=(shared_hidden, units),
-                                   dtype=dtype, init="xavier")
-                self.shared_down = g("shared_down",
-                                     shape=(units, shared_hidden),
-                                     dtype=dtype, init="xavier")
+                self.shared_up = ins["shared_up"] = g(
+                    "shared_up", shape=(shared_hidden, units), dtype=dtype,
+                    init="xavier")
+                self.shared_down = ins["shared_down"] = g(
+                    "shared_down", shape=(units, shared_hidden),
+                    dtype=dtype, init="xavier")
+                if glu:
+                    self.shared_gate_proj = ins["shared_gate_proj"] = g(
+                        "shared_gate_proj", shape=(shared_hidden, units),
+                        dtype=dtype, init="xavier")
+                if shared_gate:
+                    self.shared_expert_gate = ins["shared_expert_gate"] = g(
+                        "shared_expert_gate", shape=(units,), dtype=dtype,
+                        init="zeros")
             # [pairs_local, pairs_total, load_max] of the last step, their
             # running sums, steps: rewritten by every forward, read
             # without a launch (read_routing_counters)
-            self.routing_stats = g("routing_stats", shape=(7,),
-                                   dtype="float32", init="zeros",
-                                   differentiable=False)
+            self.routing_stats = ins["stats"] = g(
+                "routing_stats", shape=(7,), dtype="float32", init="zeros",
+                differentiable=False)
+            self._dropless_ins = ins
             self._pairs_reported = 0.0   # of the running sum, in the registry
             self.last_choice = None
             if record_choice_rows:
@@ -395,21 +485,33 @@ class MoELayer(HybridBlock):
     def _forward_dropless(self, x):
         lead = x.shape[:-1]
         first, _count = self._held
-        shared = self.shared_up is not None
-        ins = [x, self.gate.data(), self.e_score_correction_bias.data(),
-               self.w1.data(), self.w2.data(), self.routing_stats.data()]
-        if shared:
-            ins += [self.shared_up.data(), self.shared_down.data()]
+        names = list(self._dropless_ins)
+        ins = [x] + [p.data() for p in self._dropless_ins.values()]
 
-        def f(xv, wr, bias, w1, w2, stats, *sh):
+        def f(xv, *values):
+            p = dict(zip(names, values))
             cd = amp_compute_dtype(xv)
             xf = xv.reshape(-1, xv.shape[-1])
             y, chosen, sizes = dropless_ffn(
-                xf, wr, bias, w1, w2, top_k=self._top_k, first=first,
-                norm_topk=self._norm_topk, scaling=self._scaling,
-                compute_dtype=cd)
-            if sh:
-                y = y + relu2_mlp(xf, sh[0], sh[1], cd)
+                xf, p["gate"], p.get("bias"), p["w1"], p["w2"],
+                top_k=self._top_k, first=first, norm_topk=self._norm_topk,
+                scaling=self._scaling, compute_dtype=cd,
+                scoring=self._scoring, w_gate=p.get("w_gate"))
+            if "shared_up" in p:
+                if "shared_gate_proj" in p:
+                    shared = swiglu_mlp(xf, p["shared_gate_proj"],
+                                        p["shared_up"], p["shared_down"], cd)
+                else:
+                    shared = relu2_mlp(xf, p["shared_up"], p["shared_down"],
+                                       cd)
+                if "shared_expert_gate" in p:
+                    shared = shared * jax.nn.sigmoid(jnp.einsum(
+                        "nd,d->n", xf.astype(cd),
+                        p["shared_expert_gate"].astype(cd),
+                        precision=_prec(cd),
+                        preferred_element_type=jnp.float32))[:, None]
+                y = y + shared
+            stats = p["stats"]
             now = jnp.stack([jnp.sum(sizes), xf.shape[0] * self._top_k,
                              jnp.max(sizes)]).astype(jnp.float32)
             stats = jnp.concatenate(

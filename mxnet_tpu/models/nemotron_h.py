@@ -35,7 +35,7 @@ from ..gluon.nn import Embedding, RMSNorm
 from ..ndarray import ops as F
 from ..ndarray.ops import invoke
 from ..parallel.sharding import annotate
-from ..ops.flash import matmul_precision as _prec
+from .hybrid_common import dense as _dense, lm_loss, rms as _rms
 from .moe import MoELayer, amp_compute_dtype as _compute_dtype
 
 __all__ = ["NemotronHModel", "Mamba2Mixer", "GroupedQueryAttention",
@@ -51,19 +51,6 @@ _CONFIGS = {
         top_k=6, expert_hidden=1856, shared_hidden=3712,
         routed_scaling=2.5, norm_topk=True, eps=1e-5),
 }
-
-
-def _rms(x, gain, eps):
-    x = x.astype(jnp.float32)
-    return (x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
-                              + eps) * gain.astype(jnp.float32))
-
-
-def _dense(x, w, cd):
-    """``x W^T`` with an (out, in) weight, operands in ``cd``, f32 sums."""
-    return jnp.einsum("...i,oi->...o", x.astype(cd), w.astype(cd),
-                      precision=_prec(cd),
-                      preferred_element_type=jnp.float32)
 
 
 class Mamba2Mixer(HybridBlock):
@@ -255,13 +242,6 @@ class NemotronHModel(HybridBlock):
                                   num_hidden=self.vocab_held, no_bias=True,
                                   flatten=False)
         return _par.with_sharding_constraint(logits, "batch", None, "vocab")
-
-
-def lm_loss(logits, labels):
-    """Next-token cross entropy over the vocabulary rows held; labels
-    (B, T) already shifted, every one of them a row held."""
-    lse = F.logsumexp(logits, axis=-1)
-    return (lse - F.pick(logits, labels, axis=-1)).mean()
 
 
 def get_nemotron_h(name="nemotron_h_tt_30b_a3b", **kwargs):

@@ -1318,13 +1318,15 @@ def LayerNorm(data, gamma, beta, axis=-1, eps=1e-5, **kw):
 
 
 @_export
-def RMSNorm(data, gamma, eps=1e-5, **kw):
-    """Root-mean-square norm over the last axis (float32 under AMP)."""
+def RMSNorm(data, gamma, eps=1e-5, unit_offset=False, **kw):
+    """Root-mean-square norm over the last axis (float32 under AMP);
+    ``unit_offset``: the gain is ``1 + gamma`` (a gamma that starts at
+    zero)."""
     nds = [_as_nd(x) for x in (data, gamma)]
 
     def f(x, g):
         ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * lax.rsqrt(ms + eps) * g
+        return x * lax.rsqrt(ms + eps) * (1.0 + g if unit_offset else g)
 
     return invoke("RMSNorm", f, nds)
 

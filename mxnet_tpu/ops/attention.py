@@ -62,6 +62,30 @@ def _attention_ref(q, k, v, *, causal=False, mask=None, scale=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def rotary_embedding(x, positions=None, *, theta=10000.0, rotary_dim=None):
+    """Rotary positions on the first ``rotary_dim`` of x's (B, T, H, D)
+    head dimensions (default: all D), the others untouched, in the
+    half-rotation layout: dimensions ``i`` and ``i + rotary_dim / 2`` are
+    a pair turned by ``position * theta^(-2 i / rotary_dim)``.  Float32
+    angles; returns x's dtype.  ``positions`` (T,), default 0..T-1."""
+    d = x.shape[-1]
+    rd = d if rotary_dim is None else int(rotary_dim)
+    if rd % 2 or not 0 < rd <= d:
+        raise ValueError(f"rotary_dim {rd} is not an even part of {d}")
+    half = rd // 2
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                * 2.0 / rd))
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:rd]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            xf[..., rd:]], axis=-1).astype(x.dtype)
+
+
 # ------------------------------------------------------------------ dispatch
 
 def _use_flash(q_shape, causal, mask, dropout, k_shape=None,

@@ -80,13 +80,14 @@ def _report_plan(plan: SsdPlan, shape, n, dtype, impl):
 def causal_conv1d(x, w, bias):
     """Depthwise causal convolution along time as shifted multiply-adds:
     ``y_t = bias + sum_k w[:, k] x_{t - (K - 1 - k)}``.  ``x`` (B, T, C),
-    ``w`` (C, K), ``bias`` (C,)."""
+    ``w`` (C, K), ``bias`` (C,) or None."""
     k = w.shape[1]
     t = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = bias.astype(x.dtype)
+    y = None if bias is None else bias.astype(x.dtype)
     for i in range(k):
-        y = y + xp[:, i:i + t, :] * w[:, i].astype(x.dtype)
+        tap = xp[:, i:i + t, :] * w[:, i].astype(x.dtype)
+        y = tap if y is None else y + tap
     return y
 
 

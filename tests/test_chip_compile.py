@@ -199,3 +199,66 @@ def test_flash_grouped_queries_compile_for_v5e(chip):
         q, kv, kv).compile()
     assert _kernels(compiled) == 3
     assert "bf16[2,8192,128]" in compiled.as_text()   # K/V never repeated
+
+
+# --------------------------- the Gated DeltaNet stack's kernels (PR 30)
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_gdn_chunk_kernel_compiles_for_v5e(chip, dtype):
+    """One Gated DeltaNet layer's rule at the cell's size: 32 value heads
+    over 16 key heads of 128, chunks of 64 over 8,192 steps, the state in
+    VMEM across a head's chunks; forward by the kernel (float32 `highest`
+    products in the triangular inverse whatever the operands are),
+    backward by the chunked XLA form."""
+    from mxnet_tpu.ops.gdn import gdn_scan
+
+    def loss(q, k, v, g, beta):
+        return gdn_scan(q, k, v, g, beta, chunk=64, impl="pallas",
+                        interpret=False).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = (sds((1, 8192, 16, 128), dtype), sds((1, 8192, 16, 128), dtype),
+            sds((1, 8192, 32, 128), dtype), sds((1, 8192, 32), jnp.float32),
+            sds((1, 8192, 32), jnp.float32))
+    # the value keeps the forward alive: a sum's gradient needs no output
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    assert _kernels(compiled) == 1          # gdn_chunk_fwd
+    assert "gdn_chunk_bwd" in compiled.as_text()
+
+
+def test_moe_gmm_gated_experts_compile_for_v5e(chip):
+    """up, gate -> silu * -> down over the worst-case buffer of 10 x 8,192
+    rows with 32 experts of 2,048 x 512 held: three products forward, six
+    in the backward pass."""
+    from mxnet_tpu.ops.gmm import grouped_matmul
+
+    m, u, f, held = 81920, 2048, 512, 32
+
+    def loss(rows, w_up, w_gate, w_down, sizes):
+        valid = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+        product = lambda a, b: jnp.where(valid, grouped_matmul(  # noqa: E731
+            a, b, sizes, impl="pallas", interpret=False), 0)
+        h = (jax.nn.silu(product(rows, w_gate).astype(jnp.float32))
+             * product(rows, w_up)).astype(rows.dtype)
+        return product(h, w_down).astype(jnp.float32).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    bf = jnp.bfloat16
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        sds((m, u), bf), sds((held, u, f), bf), sds((held, u, f), bf),
+        sds((held, f, u), bf), sds((held,), jnp.int32)).compile()
+    assert _kernels(compiled) == 9
+
+
+def test_flash_head_size_256_grouped_queries_compile_for_v5e(chip):
+    """16 query heads over 2 key/value heads of 256 at T 8,192."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16, sharding=chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    assert _kernels(compiled) == 3
