@@ -38,20 +38,46 @@ the state are float32 whatever the operands are.
 On the TPU the whole of this is the Pallas kernel ``gdn_chunk_fwd``: one
 grid step takes one chunk of one key head with the value heads it serves,
 walks the chunks of a head in order and keeps the ``(d_k, d_v)`` state in
-VMEM, which XLA's between-chunk scan cannot fuse.  The backward pass is
-the chunked form written in ``jax.numpy`` (:func:`gdn_chunked`),
-differentiated by JAX under a ``custom_vjp`` (scope ``gdn_chunk_bwd``).
-Off the TPU the chunked XLA form is the forward too; ``impl="pallas"``
-forces the kernel (interpreted off the TPU, for tests).
+VMEM, which XLA's between-chunk scan cannot fuse.  Off the TPU the chunked
+XLA form (:func:`gdn_chunked`) is the forward; ``impl="pallas"`` forces
+the kernel (interpreted off the TPU, for tests).
+
+**The backward** is written out, one for both forms (``custom_vjp``, scope
+``gdn_chunk_bwd``, in ``jax.numpy``).  A forward pass that will be
+differentiated also leaves ``T`` and each chunk's ``S_0`` (float32); ``W``
+and ``U`` are made again from them, three products a chunk and no walk.
+With ``Sc`` the state as the products take it (cast to the operands'
+type), ``D`` the decay mask and ``Kd = k exp(G_last - G)``:
+
+* the chunks in reverse, ``dS`` (of the chunk's last state) carried:
+  ``dU = (qk * D)^T dO + Kd dS``;
+  ``dS_0 = exp(G_last) dS + (q exp(G))^T dO - W^T dU``; beside them
+  ``dKd = U dS^T`` and ``d exp(G_last) = <dS, S_0>``;
+* every chunk at once: ``d(qk * D) = dO U^T``, ``d(q exp(G)) = dO Sc^T``,
+  ``dW = -dU Sc^T``; ``dT = dU (beta V)^T + dW (beta exp(G) K)^T``,
+  ``d(beta V) = T^T dU``, ``d(beta exp(G) K) = T^T dW``;
+* the inverse by its identity, ``dA = -T^T dT T^T`` on the strict lower
+  triangle: two float32 products, where the derivative of the log2(chunk)
+  doublings is six for each of them;
+* from there by elements to ``beta``, to ``k k^T`` and ``q k^T`` (and
+  through them to q and k, summed over the value heads a key head
+  serves), and to ``G``: a decay ``exp(G_i - G_j)`` hands its cotangent
+  times itself to row i and takes it from column j, and ``g`` gets the
+  sum of what the ``G`` after it in the chunk got.
+
+Matmul operands are cast as the forward casts them; no exponent is
+positive, so a decay that underflows gives zeros and never a NaN.
 """
 from __future__ import annotations
 
 import functools
+from types import SimpleNamespace
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.custom_dce import custom_dce
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash import (_default_interpret, _dot, matmul_precision as _prec,
@@ -85,9 +111,27 @@ def gdn_plan(b: int, t: int, hk: int, hv: int, chunk: int) -> GdnPlan:
 def _report_plan(plan: GdnPlan, q, v, impl):
     """One ``gdn.plan`` event per distinct plan (as ``ssd.plan``)."""
     b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    kept = _kept_shapes(b, plan.chunks, hk, hv, plan.chunk, dk, dv)
     plan_event("gdn.plan", **plan._asdict(), batch=b, seq=t, key_heads=hk,
-               value_heads=v.shape[2], key_dim=dk, value_dim=v.shape[3],
-               dtype=jnp.dtype(v.dtype).name, impl=impl)
+               value_heads=hv, key_dim=dk, value_dim=dv,
+               dtype=jnp.dtype(v.dtype).name, impl=impl, backward="explicit",
+               bwd_key_heads=_bwd_key_heads(b, t, hk, hv, plan.chunk, dk, dv),
+               residual_bytes=_nbytes(kept))
+
+
+def _kept_shapes(b, nc, hk, hv, c, dk, dv):
+    """What a forward pass leaves for the backward beside its inputs, both
+    float32, heads on axis 2 like the inputs': ``T`` (b, nc, hk, c, r c),
+    a key head's value heads side by side (a row of 128 lanes where c is
+    64 and r 2), and the state each chunk starts from (b, nc, hv, d_k,
+    d_v)."""
+    return (jax.ShapeDtypeStruct((b, nc, hk, c, hv // hk * c), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, hv, dk, dv), jnp.float32))
+
+
+def _nbytes(arrays) -> int:
+    return sum(x.size * jnp.dtype(x.dtype).itemsize for x in arrays)
 
 
 def gdn_recurrence(q, k, v, g, beta):
@@ -128,18 +172,19 @@ def _unit_lower_inverse(a, eye, matmul):
 
 # --------------------------------------------------------- the chunked form
 
-def gdn_chunked(q, k, v, g, beta, *, chunk=64):
-    """The chunked rule in ``jax.numpy`` (arguments as
-    :func:`gdn_recurrence`).  Returns o (B, T, H_v, d_v) float32.  Matmul
-    operands keep ``v.dtype``; decays, ``T`` and the state are float32."""
+def _chunk_operands(q, k, v, g, beta, chunk):
+    """What both passes make of the inputs before anything depends on the
+    state: everything a head's chunk a row, ``(b, nc, hv, c, ...)``.  The
+    float32 pieces the backward differentiates through (running sums,
+    ``beta``, ``k k^T``, ``q k^T``, the decay mask, q / k / v beside their
+    value head) and the operands the products take, cast to ``v.dtype``."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, c, nc = hv // hk, chunk, t // chunk
     f32, cd = jnp.float32, v.dtype
     pr = _prec(cd)
-    # (b, nc, hv, c): a head's chunk is a row
     gs = jnp.cumsum(g.astype(f32).reshape(b, nc, c, hv), axis=2)
-    gs = gs.transpose(0, 1, 3, 2)
+    gs = gs.transpose(0, 1, 3, 2)                            # (b,nc,hv,c)
     bt = beta.astype(f32).reshape(b, nc, c, hv).transpose(0, 1, 3, 2)
     qr = q.astype(cd).reshape(b, nc, c, hk, dk)
     kr = k.astype(cd).reshape(b, nc, c, hk, dk)
@@ -150,48 +195,165 @@ def gdn_chunked(q, k, v, g, beta, *, chunk=64):
     kk, qk = (jnp.repeat(x, r, axis=2) for x in (kk, qk))   # (b,nc,hv,c,c)
     rows = jnp.arange(c)
     lower = rows[:, None] >= rows[None, :]
-    strict = rows[:, None] > rows[None, :]
     decay = jnp.exp(jnp.where(lower, gs[..., :, None] - gs[..., None, :],
                               _MASK))
-    a = jnp.where(strict, bt[..., :, None] * kk * decay, 0.0)
-    inv = _unit_lower_inverse(
-        a, jnp.eye(c, dtype=f32),
-        lambda x, y: jnp.einsum("...ij,...jk->...ik", x, y, precision=_HI))
-    inv = inv.astype(cd)
     # value heads beside their key head: (b, nc, hv, c, d)
     kv = jnp.repeat(kr.astype(f32), r, axis=3).transpose(0, 1, 3, 2, 4)
     qv = jnp.repeat(qr.astype(f32), r, axis=3).transpose(0, 1, 3, 2, 4)
     vv = v.astype(f32).reshape(b, nc, c, hv, dv).transpose(0, 1, 3, 2, 4)
-    eg = jnp.exp(gs)[..., None]
-    mm = functools.partial(jnp.einsum, precision=pr,
+    eg = jnp.exp(gs)
+    to_end = jnp.exp(gs[..., -1:] - gs)                      # exp(G_last - G)
+    end_decay = eg[..., -1]                                  # (b,nc,hv)
+    return SimpleNamespace(
+        bt=bt, kk=kk, qk=qk, decay=decay, kv=kv, qv=qv, vv=vv,
+        eg=eg, to_end=to_end, end_decay=end_decay,
+        strict=rows[:, None] > rows[None, :],
+        bv=(vv * bt[..., None]).astype(cd),
+        bk=(kv * (bt * eg)[..., None]).astype(cd),
+        qg=(qv * eg[..., None]).astype(cd),
+        attn=(qk * decay).astype(cd),
+        kdec=(kv * to_end[..., None]).astype(cd))
+
+
+def _chunks_first(x):         # (b, nc, ...) -> (nc, b, ...), for a scan
+    return x.swapaxes(0, 1)
+
+
+def gdn_chunked(q, k, v, g, beta, *, chunk=64):
+    """The chunked rule in ``jax.numpy`` (arguments as
+    :func:`gdn_recurrence`).  Returns o (B, T, H_v, d_v) float32 and what
+    the backward keeps (``T`` and the chunks' first states, laid out as
+    :func:`_kept_shapes` says).  Matmul operands keep ``v.dtype``; decays,
+    ``T`` and the state are float32."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, c, nc = hv // hk, chunk, t // chunk
+    f32, cd = jnp.float32, v.dtype
+    x = _chunk_operands(q, k, v, g, beta, chunk)
+    a = jnp.where(x.strict, x.bt[..., None] * x.kk * x.decay, 0.0)
+    inv = _unit_lower_inverse(
+        a, jnp.eye(c, dtype=f32),
+        lambda m, n: jnp.einsum("...ij,...jk->...ik", m, n, precision=_HI))
+    mm = functools.partial(jnp.einsum, precision=_prec(cd),
                            preferred_element_type=f32)
-    u0 = mm("bchij,bchjd->bchid", inv, (vv * bt[..., None]).astype(cd))
-    w = mm("bchij,bchjd->bchid", inv,
-           (kv * (bt[..., None] * eg)).astype(cd)).astype(cd)
-    qg = (qv * eg).astype(cd)
-    attn = (qk * decay).astype(cd)
-    to_end = gs[..., -1:]                                    # (b,nc,hv,1)
-    kdec = (kv * jnp.exp(to_end - gs)[..., None]).astype(cd)
-    end_decay = jnp.exp(to_end)[..., None]                   # (b,nc,hv,1,1)
+    invc = inv.astype(cd)
+    u0 = mm("bchij,bchjd->bchid", invc, x.bv)
+    w = mm("bchij,bchjd->bchid", invc, x.bk).astype(cd)
 
     def step(s, xs):
         u0c, wc, qgc, ac, kc, dc = xs
         sc = s.astype(cd)
         u = (u0c - mm("bhik,bhkv->bhiv", wc, sc)).astype(cd)
         o = mm("bhik,bhkv->bhiv", qgc, sc) + mm("bhij,bhjv->bhiv", ac, u)
-        return dc * s + mm("bhik,bhiv->bhkv", kc, u), o
+        return dc[..., None, None] * s + mm("bhik,bhiv->bhkv", kc, u), (o, s)
 
     s0 = jnp.zeros((b, hv, dk, dv), f32)
-    _, o = jax.lax.scan(step, s0, tuple(
-        x.swapaxes(0, 1) for x in (u0, w, qg, attn, kdec, end_decay)))
-    # (nc, b, hv, c, dv) -> (b, t, hv, dv)
-    return o.transpose(1, 0, 3, 2, 4).reshape(b, t, hv, dv)
+    _, (o, s) = jax.lax.scan(step, s0, tuple(
+        _chunks_first(y) for y in (u0, w, x.qg, x.attn, x.kdec,
+                                   x.end_decay)))
+    inv = inv.reshape(b, nc, hk, r, c, c).swapaxes(3, 4)
+    # o: (nc, b, hv, c, dv) -> (b, t, hv, dv)
+    return (o.transpose(1, 0, 3, 2, 4).reshape(b, t, hv, dv),
+            (inv.reshape(b, nc, hk, c, r * c), _chunks_first(s)))
+
+
+def _gdn_backward(q, k, v, g, beta, kept, do, chunk):
+    """The cotangents of q, k, v, g, beta from ``do`` (B, T, H_v, d_v), by
+    the equations of the module's docstring; ``kept`` as
+    :func:`gdn_chunked` returns it."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, c, nc = hv // hk, chunk, t // chunk
+    f32, cd = jnp.float32, v.dtype
+    inv, s = kept
+    inv = inv.reshape(b, nc, hk, c, r, c).swapaxes(3, 4).reshape(
+        b, nc, hv, c, c)
+    x = _chunk_operands(q, k, v, g, beta, chunk)
+    bt, eg, kv, decay = x.bt, x.eg, x.kv, x.decay
+    mm = functools.partial(jnp.einsum, precision=_prec(cd),
+                           preferred_element_type=f32)
+    hi = functools.partial(jnp.einsum, precision=_HI)
+    doc = do.astype(cd).reshape(b, nc, c, hv, dv).transpose(0, 1, 3, 2, 4)
+    sc, invc = s.astype(cd), inv.astype(cd)
+    # U and W again from T and the states: three products, no walk
+    w = mm("bchij,bchjk->bchik", invc, x.bk).astype(cd)
+    u = (mm("bchij,bchjv->bchiv", invc, x.bv)
+         - mm("bchik,bchkv->bchiv", w, sc)).astype(cd)
+
+    # the chunks in reverse, dS carried: what has to wait for it
+    def step(ds, xs):
+        doc_, ac, qgc, kc, wc, uc, s0, dc = xs
+        dsc = ds.astype(cd)
+        du = (mm("bhij,bhiv->bhjv", ac, doc_)
+              + mm("bhik,bhkv->bhiv", kc, dsc)).astype(cd)
+        d_kdec = mm("bhiv,bhkv->bhik", uc, dsc)
+        d_end = jnp.sum(ds * s0, axis=(-2, -1))
+        ds = (dc[..., None, None] * ds + mm("bhik,bhiv->bhkv", qgc, doc_)
+              - mm("bhik,bhiv->bhkv", wc, du))
+        return ds, (du, d_kdec, d_end)
+
+    _, (du, d_kdec, d_end) = jax.lax.scan(
+        step, jnp.zeros((b, hv, dk, dv), f32), tuple(
+            _chunks_first(y) for y in (doc, x.attn, x.qg, x.kdec,
+                                       w, u, s, x.end_decay)),
+        reverse=True)
+    du, d_kdec, d_end = (_chunks_first(y) for y in (du, d_kdec, d_end))
+
+    # every chunk at once: what the products inside a chunk hand back
+    d_attn = mm("bchiv,bchjv->bchij", doc, u)
+    d_qg = mm("bchiv,bchkv->bchik", doc, sc)
+    dw = (-mm("bchiv,bchkv->bchik", du, sc)).astype(cd)
+    d_inv = (mm("bchiv,bchjv->bchij", du, x.bv)
+             + mm("bchik,bchjk->bchij", dw, x.bk))
+    d_bv = mm("bchji,bchjv->bchiv", invc, du)
+    d_bk = mm("bchji,bchjk->bchik", invc, dw)
+    # the inverse by its identity: d(I + A)^-1 = -T dA T
+    da = -hi("bchil,bchjl->bchij", hi("bchki,bchkl->bchil", inv, d_inv), inv)
+    da = jnp.where(x.strict, da, 0.0) * decay
+    d_qk = d_attn * decay
+    # a decay exp(G_i - G_j) hands its row +, its column -
+    through = da * bt[..., None] * x.kk + d_qk * x.qk
+    d_bk_k = jnp.sum(d_bk * kv, -1)                 # to beta exp(G), a row
+    d_kd_k = jnp.sum(d_kdec * kv, -1) * x.to_end
+    d_gs = (jnp.sum(through, -1) - jnp.sum(through, -2)
+            + (d_bk_k * bt + jnp.sum(d_qg * x.qv, -1)) * eg - d_kd_k)
+    d_last = jnp.sum(d_kd_k, -1) + d_end * x.end_decay
+    d_gs = d_gs.at[..., -1].add(d_last)
+    d_g = jnp.cumsum(d_gs[..., ::-1], -1)[..., ::-1]        # G is a cumsum
+    d_beta = (jnp.sum(da * x.kk, -1) + jnp.sum(d_bv * x.vv, -1)
+              + d_bk_k * eg)
+    d_vv = d_bv * bt[..., None]
+    d_kv = d_bk * (bt * eg)[..., None] + d_kdec * x.to_end[..., None]
+    d_qv = d_qg * eg[..., None]
+
+    def per_key_head(y):      # summed over the value heads a key head serves
+        return jnp.sum(y.reshape((b, nc, hk, r) + y.shape[3:]), axis=3)
+
+    d_kk = per_key_head(da * bt[..., None])
+    d_kk = (d_kk + d_kk.swapaxes(-1, -2)).astype(cd)
+    d_qk = per_key_head(d_qk).astype(cd)
+    # a key head's chunk a row, like everything they meet here (and XLA's
+    # CPU backend has no bf16 product of the other layout transposed)
+    qh, kh = (y.astype(cd).reshape(b, nc, c, hk, dk).transpose(0, 1, 3, 2, 4)
+              for y in (q, k))
+    d_k = (mm("bchij,bchjd->bchid", d_kk, kh)
+           + mm("bchji,bchjd->bchid", d_qk, qh) + per_key_head(d_kv))
+    d_q = mm("bchij,bchjd->bchid", d_qk, kh) + per_key_head(d_qv)
+
+    def steps(y, like):       # (b, nc, h, c, ..) -> (b, t, h, ..)
+        y = jnp.moveaxis(y, 3, 2)
+        return y.reshape((b, t) + y.shape[3:]).astype(like.dtype)
+
+    return (steps(d_q, q), steps(d_k, k), steps(d_vv, v), steps(d_g, g),
+            steps(d_beta, beta))
 
 
 # ------------------------------------------------------------- the kernel
 
-def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, *, r,
-                  dv):
+def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, r, dv):
+    # rest: the two arrays the backward keeps (when asked for), the state
+    *kept, s_ref = rest
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
@@ -213,8 +375,9 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, *, r,
         g_col, b_col = column(g_row), column(b_row)
         decay = jnp.exp(jnp.where(row >= col, g_col - g_row, _MASK))
         a = jnp.where(row > col, b_col * kk * decay, 0.0)
-        inv = _unit_lower_inverse(
-            a, eye.astype(f32), lambda x, y: _dot(x, y, 1, 0)).astype(cd)
+        inv32 = _unit_lower_inverse(
+            a, eye.astype(f32), lambda x, y: _dot(x, y, 1, 0))
+        inv = inv32.astype(cd)
         vj = v_ref[0, :, j * dv:(j + 1) * dv].astype(f32)     # (c, dv)
         eg = jnp.exp(g_col)
         u0 = _dot(inv, (vj * b_col).astype(cd), 1, 0)
@@ -222,6 +385,9 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, *, r,
         s = s_ref[j]                                          # (dk, dv)
         sc = s.astype(cd)
         u = (u0 - _dot(w, sc, 1, 0)).astype(cd)
+        if kept:
+            kept[0][0, 0, 0, :, j * c:(j + 1) * c] = inv32
+            kept[1][0, 0, j] = s
         o_ref[0, :, j * dv:(j + 1) * dv] = (
             _dot((qf * eg).astype(cd), sc, 1, 0)
             + _dot((qk * decay).astype(cd), u, 1, 0))
@@ -232,9 +398,11 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, *, r,
                     + _dot(kdec, u, 0, 0))
 
 
-def _gdn_pallas(q, k, v, g, beta, chunk, interpret):
+def _gdn_pallas(q, k, v, g, beta, chunk, interpret, keep):
     """``gdn_chunk_fwd``: the whole chunked rule, one key head's chunk a
-    grid step, the chunks of a head in order."""
+    grid step, the chunks of a head in order.  Returns o and, with
+    ``keep``, what the backward keeps (else an empty tuple: a forward pass
+    nobody differentiates writes o alone)."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, c, nc = hv // hk, chunk, t // chunk
@@ -245,21 +413,24 @@ def _gdn_pallas(q, k, v, g, beta, chunk, interpret):
 
     gs = rows(jnp.cumsum(g.astype(f32).reshape(b, nc, c, hv), axis=2))
     small = pl.BlockSpec((1, 1, 1, r, c), lambda i, h, n: (i, n, h, 0, 0))
+    steps = pl.BlockSpec((1, c, r * dv), lambda i, h, n: (i, n, h))
+    kept = _kept_shapes(b, nc, hk, hv, c, dk, dv) if keep else ()
     per_head = 2 * c * c * (2 * dk + dv + dk) + 2 * c * dk * dv * 3
     # two (c, c) products a doubling, log2(c) - 1 doublings
     inverse = 2 * c ** 3 * 2 * max(c.bit_length() - 2, 0)
-    o = pl.pallas_call(
+    o, *kept = pl.pallas_call(
         functools.partial(_chunk_kernel, r=r, dv=dv),
         name="gdn_chunk_fwd",
         grid=(b, hk, nc),
         in_specs=[
             pl.BlockSpec((1, c, dk), lambda i, h, n: (i, n, h)),
             pl.BlockSpec((1, c, dk), lambda i, h, n: (i, n, h)),
-            pl.BlockSpec((1, c, r * dv), lambda i, h, n: (i, n, h)),
-            small, small,
+            steps, small, small,
         ],
-        out_specs=pl.BlockSpec((1, c, r * dv), lambda i, h, n: (i, n, h)),
-        out_shape=jax.ShapeDtypeStruct((b, t, hv * dv), f32),
+        out_specs=[steps] + [
+            pl.BlockSpec((1, 1, x.shape[2] // hk) + x.shape[3:],
+                         lambda i, h, n: (i, n, h, 0, 0)) for x in kept],
+        out_shape=[jax.ShapeDtypeStruct((b, t, hv * dv), f32), *kept],
         scratch_shapes=[pltpu.VMEM((r, dk, dv), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -267,51 +438,87 @@ def _gdn_pallas(q, k, v, g, beta, chunk, interpret):
             flops=b * nc * hv * (per_head + inverse),
             transcendentals=b * t * hv * (c + 3),
             bytes_accessed=(2 * b * t * hk * dk + b * t * hv * dv)
-            * cd.itemsize + 4 * b * t * hv * (dv + 2)),
+            * cd.itemsize + 4 * b * t * hv * (dv + 2) + _nbytes(kept)),
         interpret=interpret,
     )(q.astype(cd).reshape(b, t, hk * dk), k.astype(cd).reshape(b, t, hk * dk),
       v.reshape(b, t, hv * dv), gs, rows(beta.astype(f32)))
-    return o.reshape(b, t, hv, dv)
+    return o.reshape(b, t, hv, dv), tuple(kept)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _gdn(q, k, v, g, beta, chunk, interpret):
-    return _gdn_pallas(q, k, v, g, beta, chunk, interpret)
+# ----------------------------------------------- one backward for both forms
+
+@functools.partial(custom_dce, static_argnums=(5, 6))
+def _kernel_keeping(q, k, v, g, beta, chunk, interpret):
+    return _gdn_pallas(q, k, v, g, beta, chunk, interpret, True)
 
 
-def _gdn_fwd(q, k, v, g, beta, chunk, interpret):
-    return (_gdn_pallas(q, k, v, g, beta, chunk, interpret),
-            (q, k, v, g, beta))
+@_kernel_keeping.def_dce
+def _kernel_keeping_dce(chunk, interpret, used, *args):
+    # a pass that is differentiated only later (the first pass over a
+    # recomputed block) reads o alone: it gets the kernel that writes o alone
+    o, kept = _gdn_pallas(*args, chunk, interpret, any(used[1]))
+    return o, kept or (None,) * len(used[1])
 
 
-# key heads differentiated together: the backward's residuals (a state a
-# chunk, T and the powers it was made from) are 3.5 GB for 16 key heads at
-# T 8,192 (compiler, PR 30); four at a time need a quarter of that
-_BWD_KEY_HEADS = 4
+def _forward(q, k, v, g, beta, chunk, impl, interpret, keep):
+    if impl == "xla":
+        return gdn_chunked(q, k, v, g, beta, chunk=chunk)
+    if keep:
+        return _kernel_keeping(q, k, v, g, beta, chunk, interpret)
+    return _gdn_pallas(q, k, v, g, beta, chunk, interpret, False)
 
 
-def _gdn_bwd(chunk, interpret, res, ct):
-    # the chunked XLA form recomputed and differentiated by JAX, a group
-    # of key heads (with the value heads they serve) at a time
-    hk = res[0].shape[2]
-    groups = hk // _BWD_KEY_HEADS if hk % _BWD_KEY_HEADS == 0 else 1
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _gdn(q, k, v, g, beta, chunk, impl, interpret):
+    return _forward(q, k, v, g, beta, chunk, impl, interpret, False)[0]
 
-    def split(x):             # (b, t, h, ..) -> (groups, b, t, h / groups, ..)
-        return jnp.moveaxis(x.reshape(
-            x.shape[:2] + (groups, x.shape[2] // groups) + x.shape[3:]), 2, 0)
 
-    def merge(x):
+def _gdn_fwd(q, k, v, g, beta, chunk, impl, interpret):
+    o, kept = _forward(q, k, v, g, beta, chunk, impl, interpret, True)
+    return o, (q, k, v, g, beta, kept)
+
+
+# what the backward of one group of key heads may hold: the chip's fast
+# memory, in which the compiler then keeps a group's arrays between fusions
+_BWD_GROUP_BYTES = 128 << 20
+
+
+def _bwd_key_heads(b, t, hk, hv, c, dk, dv) -> int:
+    """Key heads (with the value heads they serve) differentiated at once:
+    every one, unless their working set would pass ``_BWD_GROUP_BYTES``.
+    That set is about a dozen float32 arrays with a row a step and value
+    head, a row no narrower than a register's 128 lanes: 101 MB a key head
+    at T 8,192 with two value heads a key head, so one at a time there
+    (the rule alone, forward + backward: 20.3 ms, against 24.3, 29.1, 27.6
+    and 26.7 ms two, four, eight and all 16 at a time; my chip run, PR
+    31).  What the forward keeps is not divided."""
+    a_head = 12 * 4 * b * t * (hv // hk) * max(c, dk, dv, 128)
+    fit = max(_BWD_GROUP_BYTES // a_head, 1)
+    return max(n for n in range(1, hk + 1) if hk % n == 0 and n <= fit)
+
+
+def _gdn_bwd(chunk, impl, interpret, res, ct):
+    *inputs, kept = res
+    (b, t, hk, dk), (hv, dv) = inputs[0].shape, inputs[2].shape[2:]
+    heads = _bwd_key_heads(b, t, hk, hv, chunk, dk, dv)
+    # the cotangent as the products take it; heads are on axis 2 of all
+    whole = (*inputs, kept, ct.astype(inputs[2].dtype))
+
+    def group(i):         # key heads i * heads .. and what belongs to them
+        def part(x):
+            n = heads * x.shape[2] // hk
+            return jax.lax.dynamic_slice_in_dim(x, i * n, n, axis=2)
+        return _gdn_backward(*jax.tree.map(part, whole), chunk)
+
+    def merge(x):         # (groups, b, t, h / groups, ..) -> (b, t, h, ..)
         x = jnp.moveaxis(x, 0, 2)
         return x.reshape(x.shape[:2] + (-1,) + x.shape[4:])
 
-    def one(args):
-        _, vjp = jax.vjp(functools.partial(gdn_chunked, chunk=chunk),
-                         *args[:-1])
-        return vjp(args[-1])
-
     with jax.named_scope("gdn_chunk_bwd"):
-        grads = jax.lax.map(one, tuple(split(x) for x in res + (ct,)))
-        return tuple(merge(x) for x in grads)
+        if heads == hk:
+            return _gdn_backward(*whole, chunk)
+        return tuple(merge(x) for x in
+                     jax.lax.map(group, jnp.arange(hk // heads)))
 
 
 _gdn.defvjp(_gdn_fwd, _gdn_bwd)
@@ -323,16 +530,15 @@ def gdn_scan(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto",
     (B, T, H_k, d_k), v (B, T, H_v, d_v), log decays g <= 0 and write
     strengths beta (B, T, H_v).  ``impl``: "pallas" (the kernel;
     interpreted off the TPU), "xla" (the chunked ``jax.numpy`` form) or
-    "auto" (the kernel on the TPU, the XLA form elsewhere)."""
+    "auto" (the kernel on the TPU, the XLA form elsewhere).  Both are
+    differentiated by the one explicit backward."""
     plan = gdn_plan(q.shape[0], q.shape[1], q.shape[2], v.shape[2], chunk)
     off_tpu = _default_interpret(v)
     if impl == "auto":
         impl = "xla" if off_tpu else "pallas"
-    _report_plan(plan, q, v, impl)
-    if impl == "xla":
-        return gdn_chunked(q, k, v, g, beta, chunk=chunk)
-    if impl != "pallas":
+    if impl not in ("xla", "pallas"):
         raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
+    _report_plan(plan, q, v, impl)
     if interpret is None:
         interpret = off_tpu
-    return _gdn(q, k, v, g, beta, chunk, bool(interpret))
+    return _gdn(q, k, v, g, beta, chunk, impl, bool(interpret))

@@ -1,6 +1,11 @@
 """The gated delta rule: the chunked ``jax.numpy`` form and the Pallas
 kernel (interpret mode on the CPU) against the step-by-step recurrence,
-forward and gradients."""
+forward and gradients; the backward the rule states for itself against
+JAX's own derivative of the chunked arithmetic, which is written out HERE
+(the parent commit's ``gdn_chunked``) so that nothing under test is its own
+reference."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -38,7 +43,7 @@ def test_chunked_rule_matches_recurrence(impl, chunk, heads):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("hk,hv", [(2, 4), (8, 8)])   # 8: two groups behind
+@pytest.mark.parametrize("hk,hv", [(2, 4), (8, 8)])
 def test_chunked_rule_gradients_match_recurrence(impl, hk, hv):
     args = _inputs(seed=1, t=64, hk=hk, hv=hv, decay=0.3)
     w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
@@ -141,3 +146,215 @@ def test_plan_and_event():
     assert events[0].attrs["heads_a_step"] == 2
     assert events[0].attrs["grid_steps"] == 2 * 2 * 8
     assert events[0].attrs["seq"] == 128 and events[0].attrs["value_heads"] == 4
+    # the backward: written out, every key head at once at this size, and
+    # what one call's forward keeps for it beside its inputs: T (float32,
+    # (c, c) a chunk and value head) and a float32 state a chunk
+    assert events[0].attrs["backward"] == "explicit"
+    assert events[0].attrs["bwd_key_heads"] == 2
+    assert events[0].attrs["residual_bytes"] == 4 * 2 * 8 * 4 * (
+        16 * 16 + 16 * 8)
+    assert all(e.attrs["residual_bytes"] == events[0].attrs["residual_bytes"]
+               for e in events)
+
+
+# ------------------------------------------------- the backward, written out
+
+def plain_chunked(q, k, v, g, beta, *, chunk):
+    """The chunked arithmetic as plain ``jax.numpy`` (``gdn_chunked`` as
+    PR 30 had it, the inverse by its doublings): JAX differentiates
+    THROUGH it, which is what the rule's own backward has to equal."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r, c, nc = hv // hk, chunk, t // chunk
+    f32, cd = jnp.float32, v.dtype
+    hi = jax.lax.Precision.HIGHEST
+    pr = hi if cd == f32 else jax.lax.Precision.DEFAULT
+    gs = jnp.cumsum(g.astype(f32).reshape(b, nc, c, hv), axis=2)
+    gs = gs.transpose(0, 1, 3, 2)
+    bt = beta.astype(f32).reshape(b, nc, c, hv).transpose(0, 1, 3, 2)
+    qr = q.astype(cd).reshape(b, nc, c, hk, dk)
+    kr = k.astype(cd).reshape(b, nc, c, hk, dk)
+    mm = functools.partial(jnp.einsum, precision=pr,
+                           preferred_element_type=f32)
+    kk, qk = (jnp.repeat(mm("bcihd,bcjhd->bchij", x, kr), r, axis=2)
+              for x in (kr, qr))
+    rows = jnp.arange(c)
+    decay = jnp.exp(jnp.where(rows[:, None] >= rows[None, :],
+                              gs[..., :, None] - gs[..., None, :], -1e30))
+    a = jnp.where(rows[:, None] > rows[None, :],
+                  bt[..., :, None] * kk * decay, 0.0)
+    inv, power, n = jnp.eye(c, dtype=f32) - a, a, 2
+    while n < c:
+        power = jnp.matmul(power, power, precision=hi)
+        inv = inv + jnp.matmul(inv, power, precision=hi)
+        n *= 2
+    inv = inv.astype(cd)
+    kv = jnp.repeat(kr.astype(f32), r, axis=3).transpose(0, 1, 3, 2, 4)
+    qv = jnp.repeat(qr.astype(f32), r, axis=3).transpose(0, 1, 3, 2, 4)
+    vv = v.astype(f32).reshape(b, nc, c, hv, dv).transpose(0, 1, 3, 2, 4)
+    eg = jnp.exp(gs)[..., None]
+    u0 = mm("bchij,bchjd->bchid", inv, (vv * bt[..., None]).astype(cd))
+    w = mm("bchij,bchjd->bchid", inv,
+           (kv * (bt[..., None] * eg)).astype(cd)).astype(cd)
+    to_end = gs[..., -1:]
+    xs = (u0, w, (qv * eg).astype(cd), (qk * decay).astype(cd),
+          (kv * jnp.exp(to_end - gs)[..., None]).astype(cd),
+          jnp.exp(to_end)[..., None])
+
+    def step(s, xs):
+        u0c, wc, qgc, ac, kc, dc = xs
+        sc = s.astype(cd)
+        u = (u0c - mm("bhik,bhkv->bhiv", wc, sc)).astype(cd)
+        o = mm("bhik,bhkv->bhiv", qgc, sc) + mm("bhij,bhjv->bhiv", ac, u)
+        return dc * s + mm("bhik,bhiv->bhkv", kc, u), o
+
+    _, o = jax.lax.scan(step, jnp.zeros((b, hv, dk, dv), f32),
+                        tuple(x.swapaxes(0, 1) for x in xs))
+    return o.transpose(1, 0, 3, 2, 4).reshape(b, t, hv, dv)
+
+
+def _worst(got, want):
+    """Largest gap over the largest wanted entry."""
+    got, want = (onp.asarray(x, onp.float32) for x in (got, want))
+    return float(onp.max(onp.abs(got - want)) / onp.max(onp.abs(want)))
+
+
+ALL = (0, 1, 2, 3, 4)
+
+
+def _grads(fn, args, w, jit=False):
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=ALL)
+    return (jax.jit(grad) if jit else grad)(*args)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("ratio", [1, 2, 3])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_the_written_out_backward_is_jaxs_derivative_of_the_chunked_form(
+        chunk, ratio, dtype, impl):
+    args = _inputs(seed=5 + chunk + ratio, t=128, hk=2, hv=2 * ratio,
+                   decay=0.3, dtype=jnp.dtype(dtype))
+    w = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+    # as one compiled program, which is how every model runs it
+    got = _grads(lambda *a: gdn.gdn_scan(*a, chunk=chunk, impl=impl), args, w,
+                 jit=True)
+    want = _grads(functools.partial(plain_chunked, chunk=chunk), args, w)
+    for gt, wt, src in zip(got, want, args):
+        assert gt.shape == src.shape and gt.dtype == src.dtype
+        assert bool(jnp.all(jnp.isfinite(gt.astype(jnp.float32))))
+    if dtype == "float32":
+        assert max(_worst(gt, wt) for gt, wt in zip(got, want)) < 1e-4
+        return
+    # bf16 operands: both round every product's operands, each in its own
+    # places, so both are held against float32 arithmetic on the same
+    # (rounded) inputs, and the written-out one may be no coarser
+    exact = _grads(functools.partial(plain_chunked, chunk=chunk),
+                   [a.astype(jnp.float32) for a in args], w)
+    for gt, wt, ex in zip(got, want, exact):
+        assert _worst(gt, ex) <= 1.5 * _worst(wt, ex) + 2e-3
+        assert _worst(gt, ex) < 4e-2
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_backward_through_decays_that_underflow(impl, chunk):
+    """Running sums near -7,000 and one step whose decay kills the state:
+    every gradient is finite and equals JAX's derivative of the chunked
+    form, and nothing before the killing step receives a gradient from
+    what comes after it (the forward there is zero)."""
+    q, k, v, g, beta = _inputs(decay=80.0, seed=2)
+    kill = 70
+    g = g.at[:, kill].set(-1e4)
+    args = (q, k, v, g, beta)
+    w = jax.random.normal(jax.random.PRNGKey(8), v.shape)
+    w = w.at[:, :kill].set(0.0)                 # only what comes after
+    got = _grads(lambda *a: gdn.gdn_scan(*a, chunk=chunk, impl=impl), args, w)
+    want = _grads(functools.partial(plain_chunked, chunk=chunk), args, w)
+    for i, (gt, wt) in enumerate(zip(got, want)):
+        assert bool(jnp.all(jnp.isfinite(gt)))
+        assert float(jnp.max(jnp.abs(gt - wt))) <= 1e-4 * float(
+            jnp.max(jnp.abs(wt))) + 1e-6
+        # a decay's gradient is a running sum over its chunk, the steps
+        # after the killing one included: what cancels there leaves a
+        # rounding's worth, in JAX's derivative as in this one
+        assert float(jnp.max(jnp.abs(gt[:, :kill]))) <= (
+            1e-5 * float(jnp.max(jnp.abs(gt))) if i == 3 else 0.0)
+    assert float(jnp.max(jnp.abs(got[2][:, kill:]))) > 0.0
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_key_heads_differentiated_in_groups_give_the_same_gradients(
+        impl, monkeypatch):
+    """At the cell's size one key head's working set is what the chip's
+    fast memory holds, so the key heads are taken one at a time; here a
+    budget of nothing forces that on a small call."""
+    args = _inputs(seed=6, t=64, hk=4, hv=8, decay=0.3)
+    w = jax.random.normal(jax.random.PRNGKey(3), args[2].shape)
+    fn = lambda *a: gdn.gdn_scan(*a, chunk=16, impl=impl)  # noqa: E731
+    assert gdn._bwd_key_heads(2, 64, 4, 8, 16, 16, 8) == 4
+    assert gdn._bwd_key_heads(1, 8192, 16, 32, 64, 128, 128) == 1
+    assert gdn._bwd_key_heads(1, 2048, 6, 6, 64, 128, 128) == 6
+    assert gdn._bwd_key_heads(1, 4096, 6, 6, 64, 128, 128) == 3
+    whole = _grads(fn, args, w)
+    monkeypatch.setattr(gdn, "_BWD_GROUP_BYTES", 0)
+    assert gdn._bwd_key_heads(2, 64, 4, 8, 16, 16, 8) == 1
+    for gt, wt in zip(_grads(fn, args, w, jit=True), whole):
+        assert _worst(gt, wt) < 1e-6
+
+
+def _dots(jaxpr, found):
+    """Every ``dot_general`` of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _dots(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_inverse_is_differentiated_by_its_identity(impl):
+    """One call's forward and backward, from the jaxpr: the float32
+    `highest` products of two (c, c) operands are the forward's ten
+    doublings and the identity's two, where JAX's derivative of the
+    doublings ran thirty; and what the forward keeps holds T and a state a
+    chunk, no power of A."""
+    c, ratio = 64, 2
+
+    def inputs(ratio):
+        return _inputs(t=128, hk=2, hv=2 * ratio, dk=32, dv=16,
+                       dtype=jnp.bfloat16)
+
+    # one value head a key head: the kernel's body holds a head's products
+    # once, as the batched XLA form does
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jnp.sum(gdn.gdn_scan(*a, chunk=c, impl=impl)),
+        argnums=ALL))(*inputs(1))
+
+    def square_highest(eqns):
+        def is_highest(p):
+            return p is not None and all(
+                x == jax.lax.Precision.HIGHEST
+                for x in (p if isinstance(p, tuple) else (p,)))
+        return [e for e in eqns if is_highest(e.params["precision"])
+                and all(v.aval.shape[-2:] == (c, c) for v in e.invars)]
+
+    assert len(square_highest(_dots(jaxpr.jaxpr, []))) == 12
+    plain = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jnp.sum(plain_chunked(*a, chunk=c)),
+        argnums=ALL))(*inputs(1))
+    assert len(square_highest(_dots(plain.jaxpr, []))) == 30
+    args = inputs(ratio)
+    _o, kept = jax.eval_shape(
+        lambda *a: gdn._gdn_fwd(*a, c, impl, True), *args)
+    *inputs, (inv, states) = kept
+    assert [x.shape for x in inputs] == [a.shape for a in args]
+    b, t, hk, dk = args[0].shape
+    hv, dv = args[2].shape[2:]
+    assert inv.shape == (b, t // c, hk, c, ratio * c)
+    assert states.shape == (b, t // c, hv, dk, dv)
+    assert inv.dtype == states.dtype == jnp.float32

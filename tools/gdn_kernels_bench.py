@@ -4,7 +4,7 @@ of ``train_qwen3_next_seq8192`` (builder's tool; fails without a TPU):
 
 * the gated delta rule at (1, 8192, 32 value heads over 16 key heads,
   128): ``gdn_chunk_fwd`` against the chunked XLA form, forward, and
-  forward + backward (the kernel's backward is the XLA form recomputed);
+  forward + backward (one written-out backward for both: PERF.md §5);
 * ``moe_gmm`` with gated experts: up, gate -> silu * -> down over the
   worst-case buffer of 81,920 rows with 32 experts of 2,048 x 512 held,
   forward and backward, as the ROUTED rows vary;
