@@ -135,10 +135,30 @@ class Parameter:
     def grad(self, ctx=None) -> NDArray:
         d = self.data()
         if d.grad is None:
-            raise _base.MXNetError(
-                f"Parameter {self._name} grad_req='{self.grad_req}' — no "
-                "gradient buffer")
+            if self.grad_req == "null":
+                raise _base.MXNetError(
+                    f"Parameter {self._name} grad_req='{self.grad_req}' — "
+                    "no gradient buffer")
+            # released by a trainer that owns the step: zero until an
+            # eager backward writes it (the tape's leaf node stays)
+            node = d._node
+            self._attach_grad()
+            if node is not None:
+                d._node = node
         return d.grad
+
+    def _release_grad(self) -> int:
+        """Drop the attached gradient buffer and return its bytes.  The
+        tape's leaf node stays, so an eager ``backward`` (or ``grad()``)
+        afterwards attaches a fresh zero buffer: a released buffer reads
+        as the zeros ``zero_grad`` would have left."""
+        d = self._data
+        if d is None or d._grad is None:
+            return 0
+        g, d._grad = d._grad, None
+        # a row-sparse buffer holds its rows present, not (rows, dim)
+        held = g._sp_data if self.grad_stype == "row_sparse" else g.jax
+        return int(held.nbytes)
 
     def list_grad(self) -> List[NDArray]:
         return [self.grad()]

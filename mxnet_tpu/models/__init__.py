@@ -7,6 +7,7 @@ transformer) — all built on TP/SP-aware blocks (see models.transformer).
 from . import vision
 from .bert import BERTForPretrain, BERTModel, get_bert
 from .gpt2 import GPT2Model, get_gpt2, gpt2_lm_loss
+from .granite_hybrid import GraniteHybridModel, get_granite_hybrid
 from .moe import MoELayer, MoETransformerBlock, pop_aux_losses
 from .nemotron_h import NemotronHModel, get_nemotron_h
 from .qwen3_next import Qwen3NextModel, get_qwen3_next
@@ -23,4 +24,4 @@ __all__ = ["vision", "get_model", "BERTModel", "BERTForPretrain", "get_bert",
            "TransformerBlock", "TransformerEncoderLayer",
            "TransformerNMT", "TransformerDecoderBlock", "get_nmt",
            "nmt_loss", "NemotronHModel", "get_nemotron_h", "Qwen3NextModel",
-           "get_qwen3_next"]
+           "get_qwen3_next", "GraniteHybridModel", "get_granite_hybrid"]

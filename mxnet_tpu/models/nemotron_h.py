@@ -124,11 +124,13 @@ class Mamba2Mixer(HybridBlock):
 
 class GroupedQueryAttention(HybridBlock):
     """Causal attention, ``num_kv_heads`` <= ``num_heads``, no bias, no
-    rotary embedding."""
+    rotary embedding.  ``scale`` multiplies the scores before the softmax
+    (``head_dim ** -0.5`` when None)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 dtype="float32", **kwargs):
+                 scale=None, dtype="float32", **kwargs):
         super().__init__(**kwargs)
+        self._scale = scale
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads do not divide over "
                              f"{num_kv_heads} key/value heads")
@@ -149,7 +151,7 @@ class GroupedQueryAttention(HybridBlock):
         q = _dense(hn, wq, cd).astype(cd).reshape(b, t, self._h, self._d)
         k = _dense(hn, wk, cd).astype(cd).reshape(b, t, self._hk, self._d)
         v = _dense(hn, wv, cd).astype(cd).reshape(b, t, self._hk, self._d)
-        a = flash_attention(q, k, v, causal=True)
+        a = flash_attention(q, k, v, causal=True, scale=self._scale)
         return _dense(a.reshape(b, t, self._h * self._d), wo, cd)
 
     def params_in_order(self):

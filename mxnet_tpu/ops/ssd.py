@@ -19,7 +19,9 @@ sequence is cut into chunks of ``chunk`` steps:
   is a difference ``cum_i - cum_j <= 0``, so no decay overflows and one
   that underflows is an honest zero.  On the TPU this part is the Pallas
   kernel ``ssd_chunk_fwd``: one grid step takes one chunk of one group,
-  computes ``C B^T`` once and walks the group's heads.
+  computes ``C B^T`` once and walks the group's heads; a group too wide
+  for a step's VMEM (one group of 64 heads) is cut into head blocks,
+  each a grid step of its own (:func:`ssd_plan`).
 * **between chunks** the (heads, N, P) states follow a T / chunk step
   recurrence, and each chunk reads the state it starts from:
   ``y_i += exp(cum_i) C_i S_prev``.  Both stay in XLA.
@@ -54,27 +56,81 @@ _MASK = -1e30
 
 class SsdPlan(NamedTuple):
     """Sizes of one scan call: steps a chunk, chunks, heads a grid step
-    (one group's), grid steps of ``ssd_chunk_fwd``."""
+    (a whole group's, or one of the equal head blocks a group is cut
+    into), grid steps of ``ssd_chunk_fwd``."""
     chunk: int
     chunks: int
     heads_a_step: int
     grid_steps: int
 
 
-def ssd_plan(b: int, t: int, h: int, g: int, chunk: int) -> SsdPlan:
+# what one grid step's blocks, double-buffered, may take of the 16 MiB of
+# scoped VMEM a kernel gets by default: the rest is for the (chunk, chunk)
+# square, its decay and the products' results (one group of 64 heads of 64
+# at chunk 256, 20.6 MB by this count, is refused by the chip's compiler;
+# the same at chunk 128, 14.3 MB, compiles)
+VMEM_A_STEP = 10 << 20
+# the most heads a grid step walks: the kernel's loop over a step's heads
+# is unrolled, and at 64 heads of 64 in one group the step was fastest at
+# 8 heads and slower with every doubling (1.60 / 1.76 / 1.79 / 1.79 ms at
+# 8 / 16 / 32 / 64 heads, chunk 128; my chip run, PR 32), whatever fitted
+HEADS_A_STEP = 8
+
+
+def step_vmem_bytes(chunk: int, heads: int, p: int, n: int,
+                    itemsize: int) -> int:
+    """Bytes of one ``ssd_chunk_fwd`` grid step's blocks, each twice (the
+    pipeline's double buffer): x, B, C in the operand type, both decay
+    operands, y and the heads' states in float32.  A block's last two
+    dimensions are counted padded to float32's (8, 128) tile."""
+    def tile(rows, cols, size):
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128 * size
+
+    blocks = (tile(chunk, heads * p, itemsize) + 2 * tile(chunk, n, itemsize)
+              + tile(chunk, heads, 4) + tile(heads, chunk, 4)
+              + tile(chunk, heads * p, 4) + heads * tile(n, p, 4))
+    return 2 * blocks
+
+
+def _whole_chunks_and_groups(t: int, h: int, g: int, chunk: int) -> None:
     if t % chunk:
         raise ValueError(f"sequence {t} is not a whole number of chunks "
                          f"of {chunk}")
     if h % g:
         raise ValueError(f"{h} heads do not divide into {g} groups")
-    return SsdPlan(chunk, t // chunk, h // g, b * (t // chunk) * g)
 
 
-def _report_plan(plan: SsdPlan, shape, n, dtype, impl):
-    """One ``ssd.plan`` event per distinct plan (as ``flash.plan``)."""
+def ssd_plan(b: int, t: int, h: int, g: int, chunk: int, *,
+             head_dim: int = 64, state: int = 128,
+             itemsize: int = 2) -> SsdPlan:
+    """A grid step takes one chunk of one group when the group has at
+    most ``HEADS_A_STEP`` heads and its blocks fit ``VMEM_A_STEP``; else
+    the group is cut into the fewest equal head blocks that do (each reads
+    the group's B and C; a block's lanes are then whole tiles of 128)."""
+    _whole_chunks_and_groups(t, h, g, chunk)
+    hpg = h // g
+    for heads in range(min(hpg, HEADS_A_STEP), 0, -1):
+        if hpg % heads or (heads != hpg and (heads * head_dim) % 128):
+            continue
+        if step_vmem_bytes(chunk, heads, head_dim, state,
+                           itemsize) <= VMEM_A_STEP:
+            return SsdPlan(chunk, t // chunk, heads,
+                           b * (t // chunk) * g * (hpg // heads))
+    raise ValueError(
+        f"no head block of a group of {hpg} heads of {head_dim} at chunk "
+        f"{chunk}, state {state} fits {VMEM_A_STEP} B of VMEM a grid step")
+
+
+def _report_plan(plan: SsdPlan, shape, g, n, dtype, impl):
+    """One ``ssd.plan`` event per distinct plan (as ``flash.plan``);
+    ``vmem_bytes`` is 0 where no kernel is launched."""
     b, t, h, p = shape
+    vmem = 0 if impl == "xla" else step_vmem_bytes(
+        plan.chunk, plan.heads_a_step, p, n, jnp.dtype(dtype).itemsize)
     plan_event("ssd.plan", **plan._asdict(), batch=b, seq=t, heads=h,
-               head_dim=p, state=n, dtype=jnp.dtype(dtype).name, impl=impl)
+               head_dim=p, state=n, dtype=jnp.dtype(dtype).name, impl=impl,
+               groups=g, blocks_a_group=h // g // plan.heads_a_step,
+               vmem_bytes=vmem)
 
 
 def causal_conv1d(x, w, bias):
@@ -189,13 +245,13 @@ def ssd_recurrence(x, dt, a, b_mat, c_mat):
 # ------------------------------------------------------------- the kernel
 
 def _chunk_kernel(x_ref, b_ref, c_ref, cc_ref, cr_ref, y_ref, s_ref, *,
-                  hpg, p):
+                  heads, p):
     bm, cm = b_ref[0], c_ref[0]                               # (q, n)
     cb = _dot(cm, bm, 1, 1)                                   # (q, q) f32
     q = cb.shape[0]
     lower = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
              >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
-    for j in range(hpg):
+    for j in range(heads):
         col = cc_ref[0, 0, :, j:j + 1]                        # (q, 1)
         row = cr_ref[0, 0, j:j + 1, :]                        # (1, q)
         decay = jnp.exp(jnp.where(lower, col - row, _MASK))
@@ -208,31 +264,36 @@ def _chunk_kernel(x_ref, b_ref, c_ref, cc_ref, cr_ref, y_ref, s_ref, *,
 
 
 def _chunks_pallas(xdt, b_mat, c_mat, cum, interpret):
-    """``ssd_chunk_fwd``: per chunk and group, the masked quadratic form
-    and the chunk's own state.  Returns y (b, t, h, p) float32 and states
+    """``ssd_chunk_fwd``: per chunk and head block (a group, or one of the
+    blocks :func:`ssd_plan` cut it into), the masked quadratic form and
+    the chunk's own state.  Returns y (b, t, h, p) float32 and states
     (b, nc, h, n, p) float32."""
     b, t, h, p = xdt.shape
     g, n = b_mat.shape[2], b_mat.shape[3]
     nc, q = cum.shape[1], cum.shape[2]
-    hpg = h // g
-    cg = cum.reshape(b, t, g, hpg)
-    cum_col = cg.transpose(0, 2, 1, 3)                        # (b,g,t,hpg)
-    cum_row = cg.transpose(0, 2, 3, 1)                        # (b,g,hpg,t)
-    flops = b * nc * g * (2 * q * q * n + hpg * 4 * q * q * p)
+    hb = ssd_plan(b, t, h, g, q, head_dim=p, state=n,
+                  itemsize=xdt.dtype.itemsize).heads_a_step
+    per_group = h // g // hb          # head blocks reading one B and C
+    blocks = g * per_group
+    cg = cum.reshape(b, t, blocks, hb)
+    cum_col = cg.transpose(0, 2, 1, 3)                        # (b,G,t,hb)
+    cum_row = cg.transpose(0, 2, 3, 1)                        # (b,G,hb,t)
+    flops = b * nc * blocks * (2 * q * q * n + hb * 4 * q * q * p)
+    of_group = lambda i, c, k: (i, c, k // per_group)         # noqa: E731
     y, states = pl.pallas_call(
-        functools.partial(_chunk_kernel, hpg=hpg, p=p),
+        functools.partial(_chunk_kernel, heads=hb, p=p),
         name="ssd_chunk_fwd",
-        grid=(b, nc, g),
+        grid=(b, nc, blocks),
         in_specs=[
-            pl.BlockSpec((1, q, hpg * p), lambda i, c, k: (i, c, k)),
-            pl.BlockSpec((1, q, n), lambda i, c, k: (i, c, k)),
-            pl.BlockSpec((1, q, n), lambda i, c, k: (i, c, k)),
-            pl.BlockSpec((1, 1, q, hpg), lambda i, c, k: (i, k, c, 0)),
-            pl.BlockSpec((1, 1, hpg, q), lambda i, c, k: (i, k, 0, c)),
+            pl.BlockSpec((1, q, hb * p), lambda i, c, k: (i, c, k)),
+            pl.BlockSpec((1, q, n), of_group),
+            pl.BlockSpec((1, q, n), of_group),
+            pl.BlockSpec((1, 1, q, hb), lambda i, c, k: (i, k, c, 0)),
+            pl.BlockSpec((1, 1, hb, q), lambda i, c, k: (i, k, 0, c)),
         ],
         out_specs=[
-            pl.BlockSpec((1, q, hpg * p), lambda i, c, k: (i, c, k)),
-            pl.BlockSpec((1, 1, hpg, n, p), lambda i, c, k: (i, c, k, 0, 0)),
+            pl.BlockSpec((1, q, hb * p), lambda i, c, k: (i, c, k)),
+            pl.BlockSpec((1, 1, hb, n, p), lambda i, c, k: (i, c, k, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, t, h * p), jnp.float32),
@@ -288,15 +349,21 @@ def ssd_scan(x, dt, a, b_mat, c_mat, *, chunk: int = 128,
     TPU), "xla" (the chunked ``jax.numpy`` form) or "auto" (the kernel
     on the TPU, the XLA form elsewhere)."""
     b, t, h, p = x.shape
-    plan = ssd_plan(b, t, h, b_mat.shape[2], chunk)
+    g, n = b_mat.shape[2], b_mat.shape[3]
     off_tpu = _default_interpret(x)
     if impl == "auto":
         impl = "xla" if off_tpu else "pallas"
-    _report_plan(plan, x.shape, b_mat.shape[3], x.dtype, impl)
     if impl == "xla":
+        # no kernel, so no VMEM to fit: a group's heads go together
+        _whole_chunks_and_groups(t, h, g, chunk)
+        plan = SsdPlan(chunk, t // chunk, h // g, b * (t // chunk) * g)
+        _report_plan(plan, x.shape, g, n, x.dtype, impl)
         return ssd_chunked(x, dt, a, b_mat, c_mat, chunk=chunk)
     if impl != "pallas":
         raise ValueError(f"impl must be auto, pallas or xla, got {impl!r}")
+    _report_plan(ssd_plan(b, t, h, g, chunk, head_dim=p, state=n,
+                          itemsize=x.dtype.itemsize),
+                 x.shape, g, n, x.dtype, impl)
     if interpret is None:
         interpret = off_tpu
     return _ssd(x, dt, a, b_mat, c_mat, chunk, bool(interpret))

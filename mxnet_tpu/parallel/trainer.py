@@ -301,6 +301,7 @@ class ShardedTrainer:
                 self._trainable.append((name, p))
             else:
                 self._aux.append((name, p))
+        self._release_grad_buffers()
         # optimizer states (NDArray pytrees, kept for save/load parity)
         self.optimizer.param_dict = {
             i: p for i, (_, p) in enumerate(self._trainable)}
@@ -331,6 +332,24 @@ class ShardedTrainer:
         if self._pending_states is not None:
             self._apply_loaded_states(self._pending_states)
             self._pending_states = None
+
+    def _release_grad_buffers(self):
+        """The step computes and consumes its gradients inside its own
+        program, so the buffer ``initialize`` attached to each trainable
+        (4 B a float32 parameter) is never read or written here: give it
+        back.  ``Parameter.grad()`` and an eager ``backward`` attach a
+        zero one again on demand."""
+        released = sum(p._release_grad() for _, p in self._trainable)
+        from ..observability.registry import default_registry
+        from ..observability.trace import active
+        default_registry().gauge(
+            "mxtpu_trainer_grad_buffer_bytes_released",
+            help="gradient buffers ShardedTrainer released at build, "
+                 "last trainer built").set(released)
+        tr = active()
+        if tr is not None:
+            tr.event("trainer.grad_buffers", released_bytes=released,
+                     parameters=len(self._trainable))
 
     # ------------------------------------------------------------------
     def _make_pure(self, n_data):

@@ -111,3 +111,176 @@ def test_plan_and_event():
         obs.disable_tracing()
     assert len(events) == 1
     assert events[0].attrs["chunk"] == 16 and events[0].attrs["heads"] == 4
+
+
+# ---------------------------------------------- one group cut into head blocks
+
+def test_plan_cuts_a_group_that_does_not_fit():
+    """One B/C group of 64 heads of 64 at state 128: a grid step owning the
+    whole group would hold 14 MB (chunk 128) or 21 MB (chunk 256) of
+    double-buffered blocks and walk 64 heads unrolled; the plan cuts the
+    group into head blocks of at most ``HEADS_A_STEP`` heads that fit what
+    it states, and the plan of a group that fits is what it was."""
+    assert ssd.ssd_plan(1, 8192, 64, 8, 128) == ssd.SsdPlan(128, 64, 8, 512)
+    assert ssd.ssd_plan(1, 8192, 64, 1, 128) == ssd.SsdPlan(128, 64, 8, 512)
+    assert ssd.ssd_plan(1, 8192, 64, 1, 256) == ssd.SsdPlan(256, 32, 8, 256)
+    assert ssd.step_vmem_bytes(128, 64, 64, 128, 2) > 14e6
+    assert ssd.step_vmem_bytes(256, 64, 64, 128, 2) > 20e6
+    assert ssd.step_vmem_bytes(256, 8, 64, 128, 2) < 4e6
+
+
+def test_vmem_cuts_further_than_the_loop_length(monkeypatch):
+    """Where eight heads' blocks do not fit, the plan halves on: the
+    fewest blocks that fit, never one that does not."""
+    need8 = ssd.step_vmem_bytes(256, 8, 64, 128, 2)
+    need4 = ssd.step_vmem_bytes(256, 4, 64, 128, 2)
+    monkeypatch.setattr(ssd, "VMEM_A_STEP", need8 - 1)
+    plan = ssd.ssd_plan(1, 8192, 64, 1, 256)
+    assert plan.heads_a_step == 4 and plan.grid_steps == 32 * 16
+    assert need4 <= ssd.VMEM_A_STEP < need8
+
+
+@pytest.mark.parametrize("b,t,h,g,chunk,p,n,itemsize", [
+    (1, 8192, 64, 1, 128, 64, 128, 2), (1, 8192, 64, 1, 256, 64, 128, 4),
+    (2, 4096, 128, 2, 256, 64, 128, 2), (1, 8192, 64, 8, 128, 64, 128, 2),
+    (1, 1024, 32, 1, 512, 128, 256, 4), (4, 64, 4, 2, 16, 8, 16, 4)])
+def test_no_plan_passes_the_stated_vmem(b, t, h, g, chunk, p, n, itemsize):
+    try:
+        plan = ssd.ssd_plan(b, t, h, g, chunk, head_dim=p, state=n,
+                            itemsize=itemsize)
+    except ValueError as e:
+        assert "fits" in str(e)
+        return
+    assert (h // g) % plan.heads_a_step == 0
+    assert plan.heads_a_step <= ssd.HEADS_A_STEP
+    assert ssd.step_vmem_bytes(chunk, plan.heads_a_step, p, n,
+                               itemsize) <= ssd.VMEM_A_STEP
+    if plan.heads_a_step != h // g:                # a cut keeps whole lanes
+        assert (plan.heads_a_step * p) % 128 == 0
+
+
+def test_plan_refuses_what_cannot_fit(monkeypatch):
+    monkeypatch.setattr(ssd, "VMEM_A_STEP", 1 << 16)
+    with pytest.raises(ValueError, match="fits"):
+        ssd.ssd_plan(1, 1024, 8, 1, 128)
+
+
+def _grid_of(monkeypatch, *args, chunk):
+    """The grid and block shapes ``ssd_chunk_fwd`` is launched with."""
+    seen = {}
+    real = ssd.pl.pallas_call
+
+    def record(kernel, **kw):
+        seen.update(grid=kw["grid"],
+                    blocks=[s.block_shape for s in kw["in_specs"]],
+                    out_blocks=[s.block_shape for s in kw["out_specs"]],
+                    b_index=kw["in_specs"][1].index_map,
+                    heads=kernel.keywords["heads"])
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(ssd.pl, "pallas_call", record)
+    jax.eval_shape(lambda *a: ssd.ssd_scan(*a, chunk=chunk, impl="pallas",
+                                           interpret=True), *args)
+    return seen
+
+
+def test_a_group_that_fits_is_launched_as_it_was(monkeypatch):
+    """h 64, g 8, chunk 128 (the Nemotron cell's scan): the grid and every
+    block shape of the parent's kernel."""
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    seen = _grid_of(
+        monkeypatch, sds((1, 8192, 64, 64), bf),
+        sds((1, 8192, 64), jnp.float32), sds((64,), jnp.float32),
+        sds((1, 8192, 8, 128), bf), sds((1, 8192, 8, 128), bf), chunk=128)
+    assert seen["grid"] == (1, 64, 8) and seen["heads"] == 8
+    assert seen["blocks"] == [(1, 128, 512), (1, 128, 128), (1, 128, 128),
+                              (1, 1, 128, 8), (1, 1, 8, 128)]
+    assert seen["out_blocks"] == [(1, 128, 512), (1, 1, 8, 128, 64)]
+    assert seen["b_index"](0, 5, 7) == (0, 5, 7)
+
+
+def test_one_group_is_launched_in_head_blocks(monkeypatch):
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    seen = _grid_of(
+        monkeypatch, sds((1, 8192, 64, 64), bf),
+        sds((1, 8192, 64), jnp.float32), sds((64,), jnp.float32),
+        sds((1, 8192, 1, 128), bf), sds((1, 8192, 1, 128), bf), chunk=128)
+    hb = ssd.ssd_plan(1, 8192, 64, 1, 128).heads_a_step
+    assert seen["grid"] == (1, 64, 64 // hb) and seen["heads"] == hb
+    assert seen["blocks"][0] == (1, 128, hb * 64)
+    assert seen["out_blocks"] == [(1, 128, hb * 64), (1, 1, hb, 128, 64)]
+    # every head block reads the one B and C
+    assert {seen["b_index"](0, 3, k)
+            for k in range(64 // hb)} == {(0, 3, 0)}
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_head_blocks_match_recurrence(monkeypatch, g):
+    """A group cut into blocks of 2 heads (the VMEM a step may take shrunk
+    so that the tiny sizes cut as the real ones do), interpreted: forward
+    and gradients against the recurrence."""
+    monkeypatch.setattr(ssd, "VMEM_A_STEP", 130_000)
+    args = _inputs(b=2, t=64, h=8, p=64, g=g, n=16, seed=4)
+    plan = ssd.ssd_plan(2, 64, 8, g, 16, head_dim=64, state=16, itemsize=4)
+    assert plan.heads_a_step == 2 and plan.grid_steps == 2 * 4 * 4
+    want = ssd.ssd_recurrence(*args)
+    got = ssd.ssd_scan(*args, chunk=16, impl="pallas")
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
+                                rtol=1e-4, atol=2e-4)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    grad = lambda fn: jax.grad(lambda *a: jnp.sum(fn(*a) * w),  # noqa: E731
+                               argnums=(0, 1, 2, 3, 4))(*args)
+    for a, r in zip(grad(lambda *a: ssd.ssd_scan(*a, chunk=16,
+                                                 impl="pallas")),
+                    grad(ssd.ssd_recurrence)):
+        assert float(jnp.max(jnp.abs(a - r))) <= 1e-4 * float(
+            jnp.max(jnp.abs(r))) + 1e-5
+
+
+def test_one_group_xla_form_matches_recurrence():
+    args = _inputs(b=1, t=64, h=8, p=8, g=1, n=16, seed=5)
+    want = ssd.ssd_recurrence(*args)
+    onp.testing.assert_allclose(
+        onp.asarray(ssd.ssd_scan(*args, chunk=16, impl="xla")),
+        onp.asarray(want), rtol=1e-4, atol=2e-4)
+
+
+def test_plan_event_says_how_a_group_was_cut():
+    from mxnet_tpu import observability as obs
+
+    args = _inputs(t=32, g=1, seed=6)
+    tr = obs.enable_tracing()
+    try:
+        ssd.ssd_scan(*args, chunk=16, impl="pallas")
+        attrs = tr.spans(name="ssd.plan")[-1].attrs
+    finally:
+        obs.disable_tracing()
+    assert attrs["groups"] == 1 and attrs["blocks_a_group"] == 1
+    assert attrs["heads_a_step"] == 4
+    assert attrs["vmem_bytes"] == ssd.step_vmem_bytes(16, 4, 8, 16, 4)
+
+
+def test_xla_form_has_no_vmem_to_fit(monkeypatch):
+    """The refusal is the kernel's: where none is launched, a scan that
+    no head block could fit still runs, and its event says 0 bytes."""
+    from mxnet_tpu import observability as obs
+
+    args = _inputs(t=32, g=1, seed=7)
+    monkeypatch.setattr(ssd, "VMEM_A_STEP", 1 << 10)
+    with pytest.raises(ValueError, match="fits"):
+        ssd.ssd_scan(*args, chunk=16, impl="pallas")
+    tr = obs.enable_tracing()
+    try:
+        got = ssd.ssd_scan(*args, chunk=16, impl="xla")
+        attrs = tr.spans(name="ssd.plan")[-1].attrs
+    finally:
+        obs.disable_tracing()
+    assert attrs["impl"] == "xla" and attrs["vmem_bytes"] == 0
+    assert attrs["heads_a_step"] == 4 and attrs["blocks_a_group"] == 1
+    onp.testing.assert_allclose(
+        onp.asarray(got), onp.asarray(ssd.ssd_recurrence(*args)),
+        rtol=1e-4, atol=2e-4)
+    with pytest.raises(ValueError, match="whole number of chunks"):
+        ssd.ssd_scan(*_inputs(t=24, g=1), chunk=16, impl="xla")
