@@ -35,12 +35,38 @@ Every exponent is a difference of running sums that is <= 0, so no decay
 overflows and one that underflows is an honest zero.  Decays, ``T`` and
 the state are float32 whatever the operands are.
 
-On the TPU the whole of this is the Pallas kernel ``gdn_chunk_fwd``: one
-grid step takes one chunk of one key head with the value heads it serves,
-walks the chunks of a head in order and keeps the ``(d_k, d_v)`` state in
-VMEM, which XLA's between-chunk scan cannot fuse.  Off the TPU the chunked
-XLA form (:func:`gdn_chunked`) is the forward; ``impl="pallas"`` forces
-the kernel (interpreted off the TPU, for tests).
+On the TPU the whole of this is the Pallas kernel ``gdn_chunk_fwd``, which
+keeps the ``(d_k, d_v)`` states in VMEM (XLA's between-chunk scan cannot
+fuse that).  Its time is the float32 inverse's: a ``highest`` product is
+six MXU passes, each pushing its own right operand and counted by the
+(8, 128) register whether 64 of its lanes are used or 128, and the MXU
+answers in the order it was asked.  So (:func:`gdn_plan` says how far, from
+the shapes):
+
+* a key head's value heads go SIDE BY SIDE on a row of lanes while that is
+  no wider than 128 (two of chunk 64): ``[a_0 | a_1] @ diag(b_0, b_1)`` is
+  both heads' product in one, on full registers, and ``T`` comes out as
+  the backward keeps it;
+* the two products of a doubling level, ``inv @ power`` and ``power @
+  power``, share their right operand and go as ONE of twice the rows: six
+  products where there were ten; likewise ``W S`` with ``(q e^G) S``, and
+  the value heads' ``T (beta V)``, ``T (beta e^G K)`` and ``attn U`` with
+  each head's rows masked to its own lanes;
+* a grid step takes one chunk of several key heads (four) and WRITES
+  THEIR CHAINS ALTERNATELY (:func:`_in_step`), product by product: they
+  do not wait for each other, so one's operands go in while another's
+  results come out.  Written one after the other they run one after the
+  other.  Only the four products that read ``S_0`` wait for the chunk
+  before, which is the grid step before.
+
+A step's VMEM at the cell's shape (T 8,192, 32 value heads over 16 key
+heads of 128, bf16; :func:`step_vmem_bytes`): blocks of 64 steps by 4 key
+heads, each twice: q and k 64 KB each, v 128, o (float32) 256, the running
+sums and beta 16 each, ``T`` 128 and the 8 first states 512 when kept:
+2.3 MB, and 0.5 MB of states: 2.8 of the 10 allowed.  A shape whose
+blocks do not fit gets fewer key heads a step, at worst one.  Off the
+TPU the chunked XLA form (:func:`gdn_chunked`) is the forward;
+``impl="pallas"`` forces the kernel (interpreted off the TPU, for tests).
 
 **The backward** is written out, one for both forms (``custom_vjp``, scope
 ``gdn_chunk_bwd``, in ``jax.numpy``).  A forward pass that will be
@@ -89,23 +115,82 @@ _MASK = -1e30
 _HI = jax.lax.Precision.HIGHEST
 
 
+# what one grid step's blocks, double-buffered, and its states may take of
+# the 16 MiB of scoped VMEM a kernel gets by default (as ``ssd.VMEM_A_STEP``:
+# the rest is for what the body spills)
+VMEM_A_STEP = 10 << 20
+# key heads a grid step takes, their chains written alternately.  The rule
+# alone at the cell's shape (ms a call that writes o alone / also keeps T and
+# the states; my chip run, PR 33): 1 key head 4.7 / 5.1, 2: 2.4 / 2.8,
+# 4: 1.9 / 2.3, 8: 1.8 / 2.2, 16: 1.8 / 2.2, and in the cell 18,778 tokens/s
+# at 2, 18,893 at 4.  Several CHUNKS a step, walked in a loop, bought nothing
+# at any of these (a grid step's own cost is 0.35 us of its 10), so a step
+# takes one
+KEY_HEADS_A_STEP = 4
+
+
 class GdnPlan(NamedTuple):
-    """Sizes of one call: steps a chunk, chunks, value heads a grid step
-    (those one key head serves), grid steps of ``gdn_chunk_fwd``."""
+    """Sizes of one call of ``gdn_chunk_fwd``: steps a chunk, chunks; value
+    heads a grid step and grid steps a call; key heads a grid step takes
+    (one chunk of each); value heads of a key head held side by side on a
+    row of lanes; chains the step writes alternately (key heads x groups of
+    heads side by side); bytes of VMEM the step's blocks and states were
+    reckoned at."""
     chunk: int
     chunks: int
     heads_a_step: int
     grid_steps: int
+    key_heads_a_step: int
+    side_by_side: int
+    chains_a_step: int
+    vmem_bytes: int
 
 
-def gdn_plan(b: int, t: int, hk: int, hv: int, chunk: int) -> GdnPlan:
+def step_vmem_bytes(chunk: int, key_heads: int, r: int, p: int, dk: int,
+                    dv: int, itemsize: int) -> int:
+    """Bytes of one grid step of one chunk of ``key_heads`` key heads with
+    ``r`` value heads each, ``p`` side by side: its blocks, each twice (the
+    pipeline's double buffer): q, k, v in the operand type, the running
+    sums and beta, o, and what the backward keeps (``T`` and a state a
+    value head) in float32; and once the value heads' states.  A block's
+    last two dimensions are counted padded to float32's (8, 128) tile."""
+    def tile(rows, cols, size):
+        return -(-rows // 8) * 8 * -(-cols // 128) * 128 * size
+
+    states = key_heads * r * tile(dk, dv, 4)
+    blocks = (2 * tile(chunk, key_heads * dk, itemsize)
+              + tile(chunk, key_heads * r * dv, itemsize + 4)
+              + 2 * key_heads * tile(r // p, p * chunk, 4)
+              + key_heads * tile(chunk, r * chunk, 4) + states)
+    return 2 * blocks + states
+
+
+def gdn_plan(b: int, t: int, hk: int, hv: int, chunk: int, *,
+             key_dim: int = 128, value_dim: int = 128,
+             itemsize: int = 2) -> GdnPlan:
+    """A grid step takes one chunk of the most key heads, up to
+    ``KEY_HEADS_A_STEP``, that divide the call's and whose blocks fit
+    ``VMEM_A_STEP``; where nothing wider fits, of one key head.  Value
+    heads go side by side while a row of them is no wider than a
+    register's 128 lanes."""
     if t % chunk:
         raise ValueError(f"sequence {t} is not a whole number of chunks "
                          f"of {chunk}")
     if hv % hk:
         raise ValueError(f"{hv} value heads do not divide over {hk} key "
                          f"heads")
-    return GdnPlan(chunk, t // chunk, hv // hk, b * hk * (t // chunk))
+    r, nc = hv // hk, t // chunk
+    p = max(n for n in range(1, r + 1)
+            if r % n == 0 and (n == 1 or n * chunk <= 128))
+
+    def vmem(heads):
+        return step_vmem_bytes(chunk, heads, r, p, key_dim, value_dim,
+                               itemsize)
+
+    heads = next((h for h in range(min(hk, KEY_HEADS_A_STEP), 0, -1)
+                  if hk % h == 0 and vmem(h) <= VMEM_A_STEP), 1)
+    return GdnPlan(chunk, nc, heads * r, b * (hk // heads) * nc, heads, p,
+                   heads * (r // p), vmem(heads))
 
 
 def _report_plan(plan: GdnPlan, q, v, impl):
@@ -113,8 +198,10 @@ def _report_plan(plan: GdnPlan, q, v, impl):
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     kept = _kept_shapes(b, plan.chunks, hk, hv, plan.chunk, dk, dv)
-    plan_event("gdn.plan", **plan._asdict(), batch=b, seq=t, key_heads=hk,
-               value_heads=hv, key_dim=dk, value_dim=dv,
+    # walk_handover_bytes: what does not wait for the state reaches the
+    # walk in registers and VMEM, inside one grid step; nothing through HBM
+    plan_event("gdn.plan", **plan._asdict(), walk_handover_bytes=0, batch=b,
+               seq=t, key_heads=hk, value_heads=hv, key_dim=dk, value_dim=dv,
                dtype=jnp.dtype(v.dtype).name, impl=impl, backward="explicit",
                bwd_key_heads=_bwd_key_heads(b, t, hk, hv, plan.chunk, dk, dv),
                residual_bytes=_nbytes(kept))
@@ -350,7 +437,44 @@ def _gdn_backward(q, k, v, g, beta, kept, do, chunk):
 
 # ------------------------------------------------------------- the kernel
 
-def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, r, dv):
+def _packed_inverse(a, eye, diag):
+    """``(I + a_j)^-1`` of p strictly lower triangular (c, c) matrices held
+    side by side, ``a`` = [a_0 | a_1 | ..] (c, p c), by the doublings of
+    :func:`_unit_lower_inverse`.  ``diag(x)`` is the (p c, p c) matrix with
+    the ``x_j`` on its diagonal, so ``x @ diag(y)`` is every ``x_j y_j`` in
+    one product; the two products of a level, ``inv @ power`` and ``power
+    @ power``, share their right operand and go as one of twice the rows.
+    A generator: it yields after each product (see :func:`_in_step`) and
+    returns the inverse."""
+    c = a.shape[0]
+    inv, n = eye - a, 2
+    if n < c:
+        power = _dot(a, diag(a), 1, 0)
+        yield
+    while n < c:
+        n *= 2
+        if n < c:
+            both = _dot(jnp.concatenate([inv, power], axis=0), diag(power),
+                        1, 0)
+            inv, power = inv + both[:c], both[c:]
+        else:
+            inv = inv + _dot(inv, diag(power), 1, 0)
+        yield
+    return inv
+
+
+def _in_step(chains):
+    """Run generators side by side: each to its next ``yield`` in turn,
+    until all have ended.  The MXU answers in the order it was asked, and
+    the compiler keeps that order, so chains that do not wait for each
+    other overlap only if their products are WRITTEN alternately: one
+    chain's product goes in while the other's comes out."""
+    chains, ended = list(chains), object()
+    while chains:
+        chains = [x for x in chains if next(x, ended) is not ended]
+
+
+def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
     # rest: the two arrays the backward keeps (when asked for), the state
     *kept, s_ref = rest
 
@@ -358,80 +482,144 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, r, dv):
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    f32 = jnp.float32
-    q, k = q_ref[0], k_ref[0]                                 # (c, dk)
-    c, cd = q.shape[0], q.dtype
-    kk, qk = _dot(k, k, 1, 1), _dot(q, k, 1, 1)               # (c, c) f32
-    qf, kf = q.astype(f32), k.astype(f32)
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    eye = row == col
+    f32, cd = jnp.float32, q_ref.dtype
+    # key heads; groups of p value heads side by side, a row of lanes each
+    hb, groups, width = g_ref.shape[2:]
+    c = q_ref.shape[1]
+    p, dk = width // c, q_ref.shape[2] // hb
+    dv = o_ref.shape[2] // (hb * groups * p)
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, width), 1)
+    # lanes [j c, (j + 1) c) are value head j's of the p side by side
+    mine = [(lane >= j * c) & (lane < (j + 1) * c) for j in range(p)]
+    col = lane
+    for j in range(1, p):
+        col = jnp.where(mine[j], lane - j * c, col)
+    eye, lower, strict = row == col, row >= col, row > col
+    eye32 = eye.astype(f32)
 
-    def column(x):                    # (1, c) -> (c, 1) with no transpose
-        return jnp.sum(jnp.where(eye, x, 0.0), axis=1, keepdims=True)
+    def column(x, j):         # head j's (1, c) of a row -> (c, 1), no transpose
+        return jnp.sum(jnp.where(eye & mine[j], x, 0.0), axis=1,
+                       keepdims=True)
 
-    for j in range(r):
-        g_row, b_row = g_ref[0, 0, 0, j:j + 1, :], b_ref[0, 0, 0, j:j + 1, :]
-        g_col, b_col = column(g_row), column(b_row)
-        decay = jnp.exp(jnp.where(row >= col, g_col - g_row, _MASK))
-        a = jnp.where(row > col, b_col * kk * decay, 0.0)
-        inv32 = _unit_lower_inverse(
-            a, eye.astype(f32), lambda x, y: _dot(x, y, 1, 0))
-        inv = inv32.astype(cd)
-        vj = v_ref[0, :, j * dv:(j + 1) * dv].astype(f32)     # (c, dv)
-        eg = jnp.exp(g_col)
-        u0 = _dot(inv, (vj * b_col).astype(cd), 1, 0)
-        w = _dot(inv, (kf * (b_col * eg)).astype(cd), 1, 0).astype(cd)
-        s = s_ref[j]                                          # (dk, dv)
-        sc = s.astype(cd)
-        u = (u0 - _dot(w, sc, 1, 0)).astype(cd)
+    def spread(cols):         # p columns (c, 1) -> (c, p c), each over its lanes
+        out = cols[0]
+        for j in range(1, p):
+            out = jnp.where(mine[j], cols[j], out)
+        return out
+
+    def stack(x):             # [x_0 | x_1 | ..] -> rows [x_0 0; 0 x_1; ..]
+        if p == 1:
+            return x
+        return jnp.concatenate([jnp.where(m, x, 0) for m in mine], axis=0)
+
+    def chain(kh, gi):
+        """The chunk of p value heads of key head ``kh``; a ``yield`` after
+        each product or group of products whose results the next needs."""
+        q = q_ref[0, :, kh * dk:(kh + 1) * dk]                    # (c, dk)
+        k = k_ref[0, :, kh * dk:(kh + 1) * dk]
+        kcat = jnp.concatenate([k] * p, axis=0)
+        kk, qk = _dot(k, kcat, 1, 1), _dot(q, kcat, 1, 1)         # (c, p c)
+        g_row = g_ref[0, 0, kh, gi:gi + 1, :]
+        b_row = b_ref[0, 0, kh, gi:gi + 1, :]
+        g_cols = [column(g_row, j) for j in range(p)]
+        b_cols = [column(b_row, j) for j in range(p)]
+        yield
+        decay = jnp.exp(jnp.where(lower, spread(g_cols) - g_row, _MASK))
+        a = jnp.where(strict, spread(b_cols) * kk * decay, 0.0)
+        # what does not wait for the state ...
+        inv32 = yield from _packed_inverse(a, eye32, stack)
         if kept:
-            kept[0][0, 0, 0, :, j * c:(j + 1) * c] = inv32
-            kept[1][0, 0, j] = s
-        o_ref[0, :, j * dv:(j + 1) * dv] = (
-            _dot((qf * eg).astype(cd), sc, 1, 0)
-            + _dot((qk * decay).astype(cd), u, 1, 0))
-        g_end = g_row[:, c - 1:c]                             # (1, 1)
-        kdec = (kf * jnp.exp(g_end - g_col)).astype(cd)
-        # (1, 1) -> lanes first: Mosaic does not broadcast both ways at once
-        s_ref[j] = (jnp.exp(jnp.broadcast_to(g_end, (1, dv))) * s
-                    + _dot(kdec, u, 0, 0))
+            kept[0][0, 0, kh, :, gi * width:(gi + 1) * width] = inv32
+        inv = stack(inv32).astype(cd)                             # (p c, p c)
+        qf, kf = q.astype(f32), k.astype(f32)
+        egs = [jnp.exp(y) for y in g_cols]
+        heads = [(kh * groups + gi) * p + j for j in range(p)]
+        vs = [v_ref[0, :, h * dv:(h + 1) * dv].astype(f32) for h in heads]
+        u0 = _dot(inv, jnp.concatenate(
+            [(vs[j] * b_cols[j]).astype(cd) for j in range(p)], axis=0),
+            1, 0)                                                 # (p c, dv)
+        w = _dot(inv, jnp.concatenate(
+            [(kf * (b_cols[j] * egs[j])).astype(cd) for j in range(p)],
+            axis=0), 1, 0).astype(cd)
+        yield
+        # ... and what does
+        us, read, old = [], [], []
+        for j, h in enumerate(heads):
+            s = s_ref[h]                                          # (dk, dv)
+            if kept:
+                kept[1][0, 0, h] = s
+            # W S and (q e^G) S: one product of twice the rows
+            both = _dot(jnp.concatenate(
+                [w[j * c:(j + 1) * c], (qf * egs[j]).astype(cd)], axis=0),
+                s.astype(cd), 1, 0)
+            us.append((u0[j * c:(j + 1) * c] - both[:c]).astype(cd))
+            read.append(both[c:])
+            old.append(s)
+        yield
+        within = _dot(stack((qk * decay).astype(cd)),
+                      jnp.concatenate(us, axis=0), 1, 0)
+        for j, h in enumerate(heads):
+            g_end = g_row[:, (j + 1) * c - 1:(j + 1) * c]         # (1, 1)
+            kdec = (kf * jnp.exp(g_end - g_cols[j])).astype(cd)
+            # (1, 1) -> lanes first: Mosaic does not broadcast both ways at
+            # once
+            s_ref[h] = (jnp.exp(jnp.broadcast_to(g_end, (1, dv))) * old[j]
+                        + _dot(kdec, us[j], 0, 0))
+        yield
+        for j, h in enumerate(heads):
+            o_ref[0, :, h * dv:(h + 1) * dv] = (
+                read[j] + within[j * c:(j + 1) * c])
+
+    _in_step(chain(kh, gi) for kh in range(hb) for gi in range(groups))
 
 
+def _plan_of(q, v, chunk) -> GdnPlan:
+    return gdn_plan(q.shape[0], q.shape[1], q.shape[2], v.shape[2], chunk,
+                    key_dim=q.shape[3], value_dim=v.shape[3],
+                    itemsize=jnp.dtype(v.dtype).itemsize)
+
+
+# jitted, so that a step's layers, their recomputation and the pass that
+# writes o alone trace and lower the kernel's body once a shape and not once
+# a call (a step's trace and lowering: 6.0-6.5 s at the parent of PR 33,
+# 7.1-8.0 with four chains in the body, 5.7-5.8 so; here, off the chip)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _gdn_pallas(q, k, v, g, beta, chunk, interpret, keep):
-    """``gdn_chunk_fwd``: the whole chunked rule, one key head's chunk a
-    grid step, the chunks of a head in order.  Returns o and, with
-    ``keep``, what the backward keeps (else an empty tuple: a forward pass
-    nobody differentiates writes o alone)."""
+    """``gdn_chunk_fwd``: the whole chunked rule, a grid step as
+    :func:`gdn_plan` sizes it, the chunks of a head in order.  Returns o
+    and, with ``keep``, what the backward keeps (else an empty tuple: a
+    forward pass nobody differentiates writes o alone)."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, c, nc = hv // hk, chunk, t // chunk
     f32, cd = jnp.float32, v.dtype
+    plan = _plan_of(q, v, chunk)
+    hb, p = plan.key_heads_a_step, plan.side_by_side
 
-    def rows(x):          # (b, t, hv) -> (b, nc, hk, r, c): a head a row
-        return x.reshape(b, nc, c, hk, r).transpose(0, 1, 3, 4, 2)
+    def rows(x):      # (b, t, hv) -> (b, nc, hk, r / p, p c): p heads a row
+        return x.reshape(b, nc, c, hk, r // p, p).transpose(
+            0, 1, 3, 4, 5, 2).reshape(b, nc, hk, r // p, p * c)
 
     gs = rows(jnp.cumsum(g.astype(f32).reshape(b, nc, c, hv), axis=2))
-    small = pl.BlockSpec((1, 1, 1, r, c), lambda i, h, n: (i, n, h, 0, 0))
-    steps = pl.BlockSpec((1, c, r * dv), lambda i, h, n: (i, n, h))
+    small = pl.BlockSpec((1, 1, hb, r // p, p * c),
+                         lambda i, h, n: (i, n, h, 0, 0))
+    keys = pl.BlockSpec((1, c, hb * dk), lambda i, h, n: (i, n, h))
+    steps = pl.BlockSpec((1, c, hb * r * dv), lambda i, h, n: (i, n, h))
     kept = _kept_shapes(b, nc, hk, hv, c, dk, dv) if keep else ()
     per_head = 2 * c * c * (2 * dk + dv + dk) + 2 * c * dk * dv * 3
     # two (c, c) products a doubling, log2(c) - 1 doublings
     inverse = 2 * c ** 3 * 2 * max(c.bit_length() - 2, 0)
     o, *kept = pl.pallas_call(
-        functools.partial(_chunk_kernel, r=r, dv=dv),
+        _chunk_kernel,
         name="gdn_chunk_fwd",
-        grid=(b, hk, nc),
-        in_specs=[
-            pl.BlockSpec((1, c, dk), lambda i, h, n: (i, n, h)),
-            pl.BlockSpec((1, c, dk), lambda i, h, n: (i, n, h)),
-            steps, small, small,
-        ],
+        grid=(b, hk // hb, nc),
+        in_specs=[keys, keys, steps, small, small],
         out_specs=[steps] + [
-            pl.BlockSpec((1, 1, x.shape[2] // hk) + x.shape[3:],
+            pl.BlockSpec((1, 1, hb * x.shape[2] // hk) + x.shape[3:],
                          lambda i, h, n: (i, n, h, 0, 0)) for x in kept],
         out_shape=[jax.ShapeDtypeStruct((b, t, hv * dv), f32), *kept],
-        scratch_shapes=[pltpu.VMEM((r, dk, dv), f32)],
+        scratch_shapes=[pltpu.VMEM((hb * r, dk, dv), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -532,7 +720,7 @@ def gdn_scan(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto",
     interpreted off the TPU), "xla" (the chunked ``jax.numpy`` form) or
     "auto" (the kernel on the TPU, the XLA form elsewhere).  Both are
     differentiated by the one explicit backward."""
-    plan = gdn_plan(q.shape[0], q.shape[1], q.shape[2], v.shape[2], chunk)
+    plan = _plan_of(q, v, chunk)
     off_tpu = _default_interpret(v)
     if impl == "auto":
         impl = "xla" if off_tpu else "pallas"

@@ -30,11 +30,17 @@ def _inputs(b=2, t=128, hk=2, hv=4, dk=16, dv=8, seed=0, decay=1.0,
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
+# beside the first four (T 128): one, two and three value heads a key head
+# at chunks of 16 and 64; 1, 3, 5 and 22 chunks; 1 to 6 key heads, so a grid
+# step (at most gdn.KEY_HEADS_A_STEP = 4 key heads, a divisor of the call's)
+# takes all of them, or three of six, or one of five
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("chunk,heads", [(8, (2, 4)), (16, (2, 2)),
-                                         (64, (2, 4)), (32, (1, 3))])
-def test_chunked_rule_matches_recurrence(impl, chunk, heads):
-    args = _inputs(hk=heads[0], hv=heads[1])
+@pytest.mark.parametrize("chunk,heads,t", [
+    (8, (2, 4), 128), (16, (2, 2), 128), (64, (2, 4), 128), (32, (1, 3), 128),
+    (16, (3, 3), 16), (16, (6, 12), 48), (16, (1, 3), 80), (64, (5, 5), 64),
+    (64, (4, 8), 192), (64, (1, 3), 320), (16, (2, 4), 352)])
+def test_chunked_rule_matches_recurrence(impl, chunk, heads, t):
+    args = _inputs(hk=heads[0], hv=heads[1], t=t)
     want = gdn.gdn_recurrence(*args)
     got = gdn.gdn_scan(*args, chunk=chunk, impl=impl)
     assert got.dtype == jnp.float32 and got.shape == want.shape
@@ -60,13 +66,37 @@ def test_chunked_rule_gradients_match_recurrence(impl, hk, hv):
             jnp.max(jnp.abs(r))) + 1e-5
 
 
-def test_kernel_and_xla_form_agree_to_rounding():
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("ratio", [1, 2, 3])
+@pytest.mark.parametrize("chunk,chunks,hk", [(16, 5, 6), (64, 3, 4),
+                                             (64, 10, 5)])
+def test_kernel_and_xla_form_agree_to_rounding(chunk, chunks, hk, ratio,
+                                               dtype):
     """The kernel computes the chunked form's own arithmetic: interpreted,
-    it differs from it by float32 rounding alone."""
-    args = _inputs(seed=3)
-    a = gdn.gdn_scan(*args, chunk=64, impl="xla")
-    b = gdn.gdn_scan(*args, chunk=64, impl="pallas")
-    assert float(jnp.max(jnp.abs(a - b))) <= 1e-6
+    it differs from it by rounding alone, in what it returns and in what it
+    keeps for the backward (``T`` and each chunk's first state); and the
+    call that keeps them and the call that writes o alone give the same
+    o."""
+    args = _inputs(seed=3 + ratio, b=1, t=chunk * chunks, hk=hk,
+                   hv=hk * ratio, dtype=jnp.dtype(dtype))
+    plan = gdn._plan_of(args[0], args[2], chunk)
+    assert plan.side_by_side == (ratio if ratio * chunk <= 128 else 1)
+    assert plan.key_heads_a_step == {6: 3, 4: 4, 5: 1}[hk]
+    want, (inv, states) = gdn.gdn_chunked(*args, chunk=chunk)
+    got, kept = gdn._gdn_pallas(*args, chunk, True, True)
+    alone, nothing = gdn._gdn_pallas(*args, chunk, True, False)
+    assert nothing == () and bool(jnp.all(got == alone))
+    assert [(x.shape, x.dtype) for x in kept] == [
+        (x.shape, x.dtype) for x in gdn._kept_shapes(
+            1, chunks, hk, hk * ratio, chunk, 16, 8)]
+    # float32: rounding; bf16: a state that differs in its last float32
+    # bit is cast to another bf16 now and then, one operand's last bit
+    eps = 1e-6 if dtype == "float32" else 1e-2
+    assert float(jnp.max(jnp.abs(kept[0] - inv))) <= 1e-6
+    assert _worst(kept[1], states) <= eps
+    assert _worst(got, want) <= eps
+    assert float(jnp.max(jnp.abs(
+        gdn.gdn_scan(*args, chunk=chunk, impl="pallas") - alone))) == 0.0
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -96,11 +126,13 @@ def test_decays_that_underflow_give_zeros_not_nan(impl):
         rtol=1e-4, atol=1e-6)
 
 
-def test_bf16_operands_keep_float32_decays_and_state():
-    args = _inputs(dtype=jnp.bfloat16, seed=4)
+@pytest.mark.parametrize("chunk,heads", [(32, (2, 4)), (16, (3, 3)),
+                                         (64, (2, 4)), (64, (1, 3))])
+def test_bf16_operands_keep_float32_decays_and_state(chunk, heads):
+    args = _inputs(dtype=jnp.bfloat16, seed=4, hk=heads[0], hv=heads[1])
     want = gdn.gdn_recurrence(*args)
     for impl in ("xla", "pallas"):
-        got = gdn.gdn_scan(*args, chunk=32, impl=impl)
+        got = gdn.gdn_scan(*args, chunk=chunk, impl=impl)
         assert got.dtype == jnp.float32
         assert float(jnp.max(jnp.abs(got - want))) <= 0.03 * float(
             jnp.max(jnp.abs(want)))
@@ -131,7 +163,28 @@ def test_the_triangular_inverse_is_exact_for_a_nilpotent_matrix():
 def test_plan_and_event():
     from mxnet_tpu import observability as obs
 
-    assert gdn.gdn_plan(1, 8192, 16, 32, 64) == (64, 128, 2, 2048)
+    # the cell's call: four key heads' chunk a grid step, the two value
+    # heads of each side by side on 128 lanes: 512 grid steps where one key
+    # head a step made 2,048
+    cell = gdn.gdn_plan(1, 8192, 16, 32, 64)
+    assert cell[:4] == (64, 128, 8, 512) and cell.grid_steps < 2048
+    assert cell.key_heads_a_step == gdn.KEY_HEADS_A_STEP == 4
+    assert (cell.side_by_side, cell.chains_a_step) == (2, 4)
+    assert cell.vmem_bytes == gdn.step_vmem_bytes(
+        64, 4, 2, 2, 128, 128, 2) == 2949120 <= gdn.VMEM_A_STEP
+    # three value heads of 64 steps do not share a row of 128 lanes: each
+    # is a chain of its own; of 6 key heads a step takes 3, of 5 one
+    assert gdn.gdn_plan(1, 320, 3, 9, 64)[4:7] == (3, 1, 9)
+    assert gdn.gdn_plan(1, 64 * 22, 6, 12, 64)[3:7] == (2 * 22, 3, 2, 3)
+    assert gdn.gdn_plan(1, 64, 5, 5, 64)[3:7] == (5, 1, 1, 1)
+    # states of 256 x 256 a value head, four to a key head: two key heads'
+    # blocks fit; of 512 x 512: not one key head's, and one is what is
+    # planned (the step every call had before PR 33)
+    assert gdn.gdn_plan(1, 8192, 16, 64, 64, key_dim=256,
+                        value_dim=256).key_heads_a_step == 2
+    wide = gdn.gdn_plan(1, 8192, 16, 64, 64, key_dim=512, value_dim=512)
+    assert wide.key_heads_a_step == 1 and wide.vmem_bytes > gdn.VMEM_A_STEP
+    assert wide.grid_steps == 16 * 128 and wide.heads_a_step == 4
     args = _inputs()
     tr = obs.enable_tracing()
     try:
@@ -143,8 +196,14 @@ def test_plan_and_event():
         obs.disable_tracing()
     assert [e.attrs["impl"] for e in events] == ["xla", "pallas"]
     assert events[0].attrs["chunk"] == 16 and events[0].attrs["chunks"] == 8
-    assert events[0].attrs["heads_a_step"] == 2
-    assert events[0].attrs["grid_steps"] == 2 * 2 * 8
+    assert events[0].attrs["heads_a_step"] == 4
+    assert events[0].attrs["key_heads_a_step"] == 2
+    assert events[0].attrs["side_by_side"] == 2
+    assert events[0].attrs["chains_a_step"] == 2
+    assert events[0].attrs["grid_steps"] == 2 * 1 * 8
+    assert events[0].attrs["vmem_bytes"] == gdn.step_vmem_bytes(
+        16, 2, 2, 2, 16, 8, 4)
+    assert events[0].attrs["walk_handover_bytes"] == 0
     assert events[0].attrs["seq"] == 128 and events[0].attrs["value_heads"] == 4
     # the backward: written out, every key head at once at this size, and
     # what one call's forward keeps for it beside its inputs: T (float32,
@@ -319,35 +378,45 @@ def _dots(jaxpr, found):
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_the_inverse_is_differentiated_by_its_identity(impl):
     """One call's forward and backward, from the jaxpr: the float32
-    `highest` products of two (c, c) operands are the forward's ten
+    `highest` products against a (c, c) right operand are the forward's ten
     doublings and the identity's two, where JAX's derivative of the
     doublings ran thirty; and what the forward keeps holds T and a state a
     chunk, no power of A."""
     c, ratio = 64, 2
 
-    def inputs(ratio):
-        return _inputs(t=128, hk=2, hv=2 * ratio, dk=32, dv=16,
+    def inputs(ratio, hk=2):
+        return _inputs(t=128, hk=hk, hv=hk * ratio, dk=32, dv=16,
                        dtype=jnp.bfloat16)
 
-    # one value head a key head: the kernel's body holds a head's products
-    # once, as the batched XLA form does
+    # one key head of one value head: the kernel's body holds a head's
+    # products once, as the batched XLA form does
     jaxpr = jax.make_jaxpr(jax.value_and_grad(
         lambda *a: jnp.sum(gdn.gdn_scan(*a, chunk=c, impl=impl)),
-        argnums=ALL))(*inputs(1))
+        argnums=ALL))(*inputs(1, hk=1))
 
     def square_highest(eqns):
+        """(c, c) by (c, c) products, counted by the rows of the left
+        operand: the kernel gives a level's two products, which share
+        their right operand, as one of 2 c rows."""
         def is_highest(p):
             return p is not None and all(
                 x == jax.lax.Precision.HIGHEST
                 for x in (p if isinstance(p, tuple) else (p,)))
-        return [e for e in eqns if is_highest(e.params["precision"])
-                and all(v.aval.shape[-2:] == (c, c) for v in e.invars)]
+        found = [[v.aval.shape[-2:] for v in e.invars] for e in eqns
+                 if is_highest(e.params["precision"])]
+        assert all(right == (c, c) and left[1] == c for left, right in found)
+        return sum(left[0] // c for left, _right in found)
 
-    assert len(square_highest(_dots(jaxpr.jaxpr, []))) == 12
+    dots = _dots(jaxpr.jaxpr, [])
+    assert square_highest(dots) == 12
+    if impl == "pallas":
+        assert sum(e.params["precision"] is not None and all(
+            x == jax.lax.Precision.HIGHEST for x in e.params["precision"])
+            for e in dots) == 6 + 2
     plain = jax.make_jaxpr(jax.value_and_grad(
         lambda *a: jnp.sum(plain_chunked(*a, chunk=c)),
         argnums=ALL))(*inputs(1))
-    assert len(square_highest(_dots(plain.jaxpr, []))) == 30
+    assert square_highest(_dots(plain.jaxpr, [])) == 30
     args = inputs(ratio)
     _o, kept = jax.eval_shape(
         lambda *a: gdn._gdn_fwd(*a, c, impl, True), *args)
