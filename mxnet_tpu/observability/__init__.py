@@ -17,9 +17,12 @@ resilience/guardrail/io counter dicts):
   zero-cost when disabled (one global + ``None`` check — the FaultPlan
   pattern); ``host_range`` puts a host phase into any ``jax.profiler``
   capture, tracer or no tracer.
-- :mod:`.compiles` — one process-wide count of XLA backend compiles
-  (``mxtpu_xla_compiles_total``) and what the calling thread's own
-  calls compiled.
+- :mod:`.compiles` — what ``jax.monitoring`` tells of getting programs:
+  a process-wide count of XLA backend compiles
+  (``mxtpu_xla_compiles_total``) with the seconds of tracing, lowering
+  and compiling and the persistent cache's hits and misses, what the
+  calling thread's own calls compiled, and a bounded log of each event
+  with its instant (``log()``), always on.
 - :mod:`.export` — Prometheus text-format and JSON-lines exporters plus
   a :class:`BackgroundExporter` thread with graceful drain (wired into
   ``InferenceEngine.stop()`` and SIGTERM handling).

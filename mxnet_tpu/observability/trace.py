@@ -379,6 +379,12 @@ class host_range:
         self._live = None if tr is None else \
             tr.span(f"{layer}.{phase}", parent=parent, **attrs)
 
+    @property
+    def span(self):
+        """The live span this range records, for a child's ``parent=``
+        (None with no tracer on, which ``parent=`` takes as well)."""
+        return self._live
+
     def __enter__(self):
         self._ann.__enter__()
         return self

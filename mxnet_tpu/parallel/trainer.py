@@ -12,6 +12,7 @@ flows; ShardedTrainer is the pjit path that scales it to a pod.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -23,6 +24,7 @@ from .. import base as _base
 from .. import optimizer as opt_mod
 from .. import random as _random
 from ..ndarray import NDArray
+from ..observability import compiles as _compile_log
 from ..observability.compiles import on_this_thread as _xla_compiles
 from ..observability.flightrecorder import active as _fr_active
 from ..observability.trace import active as _trace_active
@@ -161,6 +163,7 @@ class ShardedTrainer:
         self._scale_arr = None     # traced loss-scale state (device)
         self._good_arr = None      # consecutive-finite-step counter
         self._built = False
+        self._build_stats = None   # seconds of the build and its phases
         self._step_fn = None
         self._trainable: List[Tuple[str, Any]] = []
         self._aux: List[Tuple[str, Any]] = []
@@ -223,6 +226,34 @@ class ShardedTrainer:
 
     # ------------------------------------------------------------------
     def _build(self, data, labels):
+        """Everything before the first step, in three host phases, each a
+        ``host_range`` under ``trainer.build``: ``settle`` (deferred
+        shapes, by an abstract forward), ``state`` (the optimizer's
+        leaves made, gradient buffers given back), ``place`` (parameters
+        and state put on the mesh).  The step is only WRAPPED in
+        ``jax.jit`` here: it is traced and compiled by the first
+        ``step``.  With no tracer on, the phases' seconds still reach
+        ``stats()["build"]`` and ``observability.compiles.builds()``."""
+        start, phases = time.monotonic(), {}
+        with _host_range("trainer", "build", launches=True) as build:
+            for phase, run in (("settle", lambda: self._settle(data)),
+                               ("state", self._make_state),
+                               ("place", self._place)):
+                began = time.monotonic()
+                with _host_range("trainer", "build." + phase,
+                                 launches=True, parent=build.span):
+                    run()
+                phases[phase + "_s"] = time.monotonic() - began
+            self._compile(data, labels)
+            self._built = True
+            if self._pending_states is not None:
+                self._apply_loaded_states(self._pending_states)
+                self._pending_states = None
+        end = time.monotonic()
+        self._build_stats = {"seconds": end - start, **phases}
+        _compile_log.note_build(start, end, **phases)
+
+    def _settle(self, data):
         net = self.net
         # settle deferred shapes with one forward — in inference mode so
         # BatchNorm running stats / dropout are untouched by shape
@@ -290,8 +321,10 @@ class ShardedTrainer:
                 _base.set_recording(rec)
                 _base.set_aux_collection(aux_prev)
                 _base.pop_aux_losses()
+
+    def _make_state(self):
         seen = set()
-        for name, p in net.collect_params().items():
+        for name, p in self.net.collect_params().items():
             if id(p) in seen:
                 continue
             seen.add(id(p))
@@ -309,8 +342,10 @@ class ShardedTrainer:
             st = self.optimizer.create_state_multi_precision(i, p.data())
             self._states.append(st)
             self._state_flat.extend(_state_leaves(st))
+
+    def _place(self):
         # place params on the mesh
-        shard_params(net, self.mesh, self.rules)
+        shard_params(self.net, self.mesh, self.rules)
         # a state leaf shards like its parameter when shapes match
         self._state_shardings = []
         for (name, p), st in zip(self._trainable, self._states):
@@ -327,11 +362,6 @@ class ShardedTrainer:
             init_scale = (self._loss_scaler.loss_scale
                           if self._loss_scaler is not None else 1.0)
             self._set_carried(scale=init_scale, good=0)
-        self._compile(data, labels)
-        self._built = True
-        if self._pending_states is not None:
-            self._apply_loaded_states(self._pending_states)
-            self._pending_states = None
 
     def _release_grad_buffers(self):
         """The step computes and consumes its gradients inside its own
@@ -697,10 +727,13 @@ class ShardedTrainer:
 
     # ------------------------------------------------------------------
     def build(self, data, labels=()):
-        """Settle shapes, shard params and compile WITHOUT stepping —
-        params are untouched, so a resume can restore a checkpoint into
-        a freshly built trainer before any optimizer update runs
-        (ResilientLoop's resume path)."""
+        """Settle shapes, make the optimizer's state and place both on
+        the mesh WITHOUT stepping — params are untouched, so a resume can
+        restore a checkpoint into a freshly built trainer before any
+        optimizer update runs (ResilientLoop's resume path).  Nothing is
+        compiled here: the step is wrapped in ``jax.jit`` and the first
+        ``step`` traces and compiles it (``trainer.compile`` says for how
+        long)."""
         if not isinstance(data, (tuple, list)):
             data = (data,)
         if not isinstance(labels, (tuple, list)):
@@ -820,12 +853,16 @@ class ShardedTrainer:
     def _note_compile(self, compiled, data, labels, span):
         """This step sent ``compiled`` programs to XLA: say which step
         and with what batch, to whoever is listening (the tracer's ring,
-        the flight recorder's).  Past the first step that is a new batch
-        shape or dtype, or an argument that changed how it is placed."""
+        the flight recorder's), and what they cost this thread: seconds
+        of tracing, lowering and waiting for the backend, and how many
+        the persistent cache served or took.  Past the first step that
+        is a new batch shape or dtype, or an argument that changed how
+        it is placed."""
         attrs = {"step": int(self.optimizer.num_update),
                  "compiles": int(compiled),
                  "shapes": [f"{x.dtype}{list(x.shape)}"
                             for x in tuple(data) + tuple(labels)]}
+        attrs.update(_compile_log.of_last(compiled))
         tr = _trace_active()
         if tr is not None:
             tr.event("trainer.compile", parent=span, **attrs)
@@ -924,12 +961,16 @@ class ShardedTrainer:
         """Point-in-time trainer facts (the engine-``stats()`` shape):
         step counter, ``batch_puts`` (batch arrays the trainer had to
         place itself since it was built: 0 behind a ``DevicePrefetcher``
-        that ships against ``batch_shardings``), plus a ``data`` section
-        from the attached input pipeline when one is present."""
+        that ships against ``batch_shardings``), once built ``build``
+        (``seconds`` of the build and of its phases ``settle_s``,
+        ``state_s``, ``place_s``), plus a ``data`` section from the
+        attached input pipeline when one is present."""
         out = {"num_update": int(self.optimizer.num_update),
                "built": self._built,
                "guarded": self._guarded,
                "batch_puts": self._batch_puts}
+        if self._build_stats is not None:
+            out["build"] = dict(self._build_stats)
         src = self._data_source
         if src is not None and hasattr(src, "stats"):
             out["data"] = src.stats()

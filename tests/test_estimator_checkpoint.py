@@ -29,6 +29,9 @@ def _net():
 
 
 def test_estimator_fit():
+    # the draw is the process's: unseeded, the accuracy depended on which
+    # test files had shared this worker (0.797 against > 0.8 was seen)
+    mx.random.seed(0)
     net = _net()
     est = Estimator(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                     trainer=gluon.Trainer(net.collect_params(), "adam",

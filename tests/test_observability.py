@@ -21,6 +21,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import observability as obs
+from mxnet_tpu.observability import compiles
 from mxnet_tpu.observability import (BackgroundExporter, MetricsRegistry,
                                      default_registry, flatten,
                                      parse_prometheus, to_json_lines,
@@ -699,12 +700,224 @@ def test_xla_compiles_total_says_which_step_compiled():
         ev, = tr.spans(name="trainer.compile")
         step = tr.spans(name="trainer.step")[1]
         assert ev.parent_id == step.span_id
+        cost = {k: ev.attrs.pop(k) for k in (
+            "trace_s", "lower_s", "compile_s", "cache_hits",
+            "cache_misses")}
         assert ev.attrs == {"step": 4, "compiles": 1,
                             "shapes": ["float32[16, 4]", "int32[16]"]}
+        # ... and what it cost: this step's own tracing, lowering and
+        # wait for the backend, not the three compiles before it
+        assert all(0 < cost[k] < ev.t0 - step.t0
+                   for k in ("trace_s", "lower_s", "compile_s"))
+        assert cost["cache_hits"] + cost["cache_misses"] <= 1
         rec = [e for e in fr.events() if e.name == "trainer.compile"]
         assert len(rec) == 1 and rec[0].attrs["step"] == 4
+        assert rec[0].attrs["compile_s"] == cost["compile_s"]
     finally:
         obs.disable_flight_recorder()
+
+
+# ---------------------------------------------------------- compile log
+
+def _since(t0, kind=None):
+    """This thread's records of the compile log that ended after t0."""
+    me = threading.get_ident()
+    return [r for r in compiles.log() if r[1] > t0 and r[3] == me
+            and kind in (None, r[0])]
+
+
+def _counter(name):
+    return sum(s["value"] for s in default_registry().collect()["samples"]
+               if s["name"] == name)
+
+
+def test_compile_log_keeps_a_record_by_kind():
+    """One jit on the CPU: a ``trace``, a ``lower`` and a ``compile``
+    record with positive seconds, ending in that order, none starting
+    before the call; a second call is an in-memory hit and adds none."""
+    import jax
+
+    f = jax.jit(lambda x: x * 3 + 1)
+    t0 = time.monotonic()
+    f(onp.arange(5, dtype="float32")).block_until_ready()
+    recs = _since(t0)
+    assert [r[0] for r in recs if r[0] in ("trace", "lower", "compile")] \
+        == ["trace", "lower", "compile"]
+    assert all(r[2] > 0 and r[1] - r[2] >= t0 - 1e-3 for r in recs
+               if not r[0].startswith("cache_"))
+    assert [r[1] for r in recs] == sorted(r[1] for r in recs)
+    assert {r[0]: r[4] for r in recs if r[4]} == {
+        "trace": "<lambda>", "lower": "jit(<lambda>)",
+        "compile": "jit(<lambda>)"}
+    f(onp.arange(5, dtype="float32")).block_until_ready()
+    assert _since(t0) == recs
+
+
+def test_compile_log_counts_a_nested_trace_once():
+    """A jit traced inside another reports both to ``jax.monitoring``;
+    the log keeps the outer alone and the counter moves by its seconds,
+    so neither a reader's union nor a scraper's sum counts the inner
+    twice."""
+    import jax
+
+    inner = jax.jit(lambda x: x + 2)
+    told = []
+
+    def listen(name, secs, **_kw):
+        if name.endswith("jaxpr_trace_duration"):
+            told.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        before = _counter("mxtpu_jax_trace_seconds_total")
+        t0 = time.monotonic()
+        jax.jit(lambda x: inner(x) * inner(x * 2))(
+            onp.arange(3, dtype="float32")).block_until_ready()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert len(told) >= 2                     # jax told of inner and outer
+    rec, = _since(t0, "trace")
+    assert rec[2] == max(told)
+    assert _counter("mxtpu_jax_trace_seconds_total") - before \
+        == pytest.approx(rec[2])
+
+
+def test_compile_log_until_cuts():
+    import jax
+
+    t0 = time.monotonic()
+    jax.jit(lambda x: x - 7)(onp.ones(3, "float32")).block_until_ready()
+    cut = time.monotonic()
+    jax.jit(lambda x: x - 9)(onp.ones(3, "float32")).block_until_ready()
+    whole = [r for r in compiles.log() if r[1] > t0]
+    early = [r for r in compiles.log(until=cut) if r[1] > t0]
+    assert [r[0] for r in whole].count("compile") == 2
+    assert [r[0] for r in early].count("compile") == 1
+    assert early == whole[:len(early)] and all(r[1] <= cut for r in early)
+
+
+def test_compile_log_is_bounded_and_counts_what_fell_off():
+    import jax
+
+    cap = compiles._CAPACITY
+    held, lost = len(compiles.log()), compiles.dropped()
+    for _ in range(cap + 10):
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/jaxpr_to_mlir_module_duration", 0.0)
+    assert len(compiles.log()) == cap
+    assert compiles.dropped() - lost == held + 10
+    assert {r[0] for r in compiles.log()} == {"lower"}
+
+
+def test_compile_log_tells_a_cache_hit_from_a_miss(tmp_path):
+    """With a persistent cache that takes every program: the first
+    compile is a ``cache_miss`` (compiled and written), the same program
+    after ``jax.clear_caches()`` — what a second process would find — is
+    a ``cache_hit`` with a ``cache_load`` inside its ``compile``.  The
+    five registry names a scraper reads are exported."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cc.reset_cache()
+    try:
+        def f(x):
+            return (x * 5).sum() - 11
+
+        hits = _counter("mxtpu_compile_cache_hits_total")
+        misses = _counter("mxtpu_compile_cache_misses_total")
+        t0 = time.monotonic()
+        jax.jit(f)(onp.ones(4, "float32")).block_until_ready()
+        first = [r[0] for r in _since(t0)]
+        assert first.count("cache_miss") == 1 and "cache_hit" not in first
+        jax.clear_caches()
+        t1 = time.monotonic()
+        jax.jit(f)(onp.ones(4, "float32")).block_until_ready()
+        second = _since(t1)
+        kinds = [r[0] for r in second]
+        assert kinds.count("cache_hit") == 1 and "cache_miss" not in kinds
+        load, = [r for r in second if r[0] == "cache_load"]
+        comp, = [r for r in second if r[0] == "compile"]
+        assert comp[1] - comp[2] <= load[1] - load[2] and load[1] <= comp[1]
+        assert _counter("mxtpu_compile_cache_hits_total") == hits + 1
+        assert _counter("mxtpu_compile_cache_misses_total") == misses + 1
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    text = to_prometheus(default_registry().collect())
+    for name in ("mxtpu_jax_trace_seconds_total",
+                 "mxtpu_jax_lower_seconds_total",
+                 "mxtpu_xla_compile_seconds_total",
+                 "mxtpu_compile_cache_hits_total",
+                 "mxtpu_compile_cache_misses_total"):
+        assert name in text
+
+
+def test_trainer_build_is_a_span_with_three_children():
+    """``build`` under a ``Tracer``: ``trainer.build`` with ``settle``,
+    ``state`` and ``place`` as its children, in order and inside it; the
+    step is only wrapped, so nothing named ``jit_trainer_step`` compiles
+    before the first ``step``."""
+    trainer, batch = _tiny_trainer()
+    tr = obs.enable_tracing()
+    t0 = time.monotonic()
+    trainer.build(*batch())
+    build, = tr.spans(name="trainer.build")
+    kids = [s for s in tr.spans() if s.parent_id == build.span_id]
+    assert [k.name for k in kids] == ["trainer.build.settle",
+                                      "trainer.build.state",
+                                      "trainer.build.place"]
+    assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+    assert build.t0 <= kids[0].t0 and kids[-1].t1 <= build.t1
+    got = trainer.stats()["build"]
+    assert got["seconds"] == pytest.approx(build.duration_s, abs=2e-3)
+    for kid in kids:
+        assert got[kid.name.rsplit(".", 1)[1] + "_s"] == pytest.approx(
+            kid.duration_s, abs=2e-3)
+    in_build = len(_since(t0, "compile"))
+    trainer.step(*batch()).asnumpy()
+    assert len(_since(t0, "compile")) > in_build
+    assert len(tr.spans(name="trainer.build")) == 1       # once a trainer
+
+
+def test_trainer_build_leaves_its_record_with_no_tracer():
+    """What the benchmark's readers take: with nothing listening, one
+    record a trainer where the compile log lives, the same numbers as
+    ``stats()["build"]``."""
+    trainer, batch = _tiny_trainer()
+    assert "build" not in trainer.stats()
+    had = len(compiles.builds())
+    t0 = time.monotonic()
+    trainer.build(*batch())
+    t1 = time.monotonic()
+    trainer.build(*batch())                     # built already: no record
+    trainer.step(*batch()).asnumpy()
+    start, end, settle_s, state_s, place_s = compiles.builds()[-1]
+    assert len(compiles.builds()) == had + 1
+    assert t0 <= start < end <= t1
+    assert trainer.stats()["build"] == {
+        "seconds": end - start, "settle_s": settle_s, "state_s": state_s,
+        "place_s": place_s}
+    assert min(settle_s, state_s, place_s) > 0
+    assert settle_s + state_s + place_s <= end - start
+
+
+def test_warm_step_adds_nothing_to_the_compile_log():
+    """Everything this module keeps is paid for when a program is got:
+    a warm step with the ``Tracer`` off appends no record and no build."""
+    trainer, batch = _tiny_trainer()
+    b = batch()
+    for _ in range(2):
+        trainer.step(*b).asnumpy()
+    before = (compiles.log(), compiles.dropped(), compiles.builds())
+    for _ in range(3):
+        trainer.step(*b).asnumpy()
+    assert (compiles.log(), compiles.dropped(), compiles.builds()) == before
 
 
 def test_names_inside_the_compiled_step():
