@@ -143,28 +143,93 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_pairs(x, order, inv, valid, top_k):
+def _row_major(x):
+    """``x`` (N, D) with D minor, on the TPU.  The buffer is row-major
+    because its rows are gathered; where the chip's compiler lays the
+    stream around the layer out the other way (it does, tokens minor), the
+    re-laying is asked for HERE, on (N, D), and not left to fall on the
+    ``top_k`` times larger buffer.  Elsewhere nothing is re-laid, and the
+    constraint would only stand between XLA:CPU's fusions (a recomputed
+    pass then rounds its float32 sums otherwise than the plain one)."""
+    from ..base import resolve_exec_platform
+    if resolve_exec_platform(x) != "tpu":
+        return x
+    from jax.experimental.layout import Layout, with_layout_constraint
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+
+
+def _slots(buf, top_k):
+    """The ``top_k`` slots of a buffer in pair order, each (N, D): static
+    slices of its rows, which a fusion reads in place (a reshape to
+    (top_k, N, D) stands between the fusions and keeps them apart)."""
+    n = buf.shape[0] // top_k
+    return [buf[j * n:(j + 1) * n] for j in range(top_k)]
+
+
+def _sum_held(terms, held):
+    """``sum_j terms[j]`` in float32 over the slots ``j`` whose pair is on
+    a held expert (``held`` (top_k, N)): the others are selected away."""
+    return functools.reduce(jnp.add, [jnp.where(held[j][:, None], t, 0.0)
+                                      for j, t in enumerate(terms)])
+
+
+@jax.custom_vjp
+def _rows_of_pairs(x, order, inv, held):
     """The buffer of token rows in sorted-pair order: row r is the token
-    of pair ``order[r]``.  Backward: rows past the routed ones are
-    dropped (they hold whatever the kernel left), the rest return to
-    pair order and a token's ``top_k`` pairs are summed."""
-    return x[order // top_k]
+    of pair ``order[r]``, and pair ``p`` is ``slot * N + token``.
+    Backward: the rows return to pair order, where slot ``j`` is rows
+    ``j N .. (j + 1) N``, and a token's pairs ON A HELD EXPERT (``held``
+    (top_k, N)) are summed in float32; the others hold whatever the kernel
+    left and are selected away inside that sum."""
+    return x[order % x.shape[0]]
 
 
-def _rows_fwd(x, order, inv, valid, top_k):
-    return x[order // top_k], (order, inv, valid, x.shape)
+def _rows_fwd(x, order, inv, held):
+    return _rows_of_pairs(x, order, inv, held), (order, inv, held)
 
 
-def _rows_bwd(top_k, res, g):
-    order, inv, valid, shape = res
-    g = jnp.where(valid[:, None], g, jnp.zeros_like(g))[inv]
-    dx = g.reshape(shape[0], top_k, shape[1]).astype(jnp.float32).sum(axis=1)
-    return (dx.astype(g.dtype), _zero_int(order), _zero_int(inv),
-            _zero_int(valid))
+def _rows_bwd(res, g):
+    order, inv, held = res
+    dx = _sum_held([s.astype(jnp.float32)
+                    for s in _slots(g[inv], held.shape[0])], held)
+    return (_row_major(dx.astype(g.dtype)), _zero_int(order), _zero_int(inv),
+            _zero_int(held))
 
 
 _rows_of_pairs.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(w, y, held):
+    """``sum_j w[j] * y[j N:(j + 1) N]`` in float32 over the pairs on a
+    held expert: ``w`` and ``held`` (top_k, N), ``y`` (top_k N, D) in pair
+    order and the compute type, the result (N, D) float32.  One pass over
+    ``y``; the backward makes ``dy`` slot by slot straight in ``y``'s
+    type, zero where the pair is not held, so no float32 array of the
+    buffer's size exists."""
+    return _row_major(_sum_held(
+        [w[j][:, None] * s.astype(jnp.float32)
+         for j, s in enumerate(_slots(y, w.shape[0]))], held))
+
+
+def _combine_fwd(w, y, held):
+    return _combine(w, y, held), (w, y, held)
+
+
+def _combine_bwd(res, ct):
+    w, y, held = res
+    ct = _row_major(ct)
+    dy = jnp.concatenate(
+        [jnp.where(held[j][:, None], w[j][:, None] * ct, 0.0).astype(y.dtype)
+         for j in range(w.shape[0])], axis=0)
+    dw = jnp.stack(
+        [jnp.sum(jnp.where(held[j][:, None], s.astype(jnp.float32) * ct,
+                           0.0), axis=-1)
+         for j, s in enumerate(_slots(y, w.shape[0]))])
+    return dw, dy, _zero_int(held)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def route_sigmoid_topk(x, w_router, choice_bias, *, top_k, norm_topk=True,
@@ -223,10 +288,10 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
     chosen (N, k), sizes (H,) int32)``: the sum over a token's chosen
     experts THAT ARE HELD of weight x expert output, and how many pairs
     each held expert got.  Every pair on a held expert is computed: the
-    buffer holds ``N * top_k`` rows."""
+    buffer holds ``N * top_k`` rows, an expert's rows slot by slot."""
     from ..ops.flash import plan_event
     from ..ops.gmm import grouped_matmul
-    n, d = x.shape
+    n = x.shape[0]
     held = w_up.shape[0]
     cd = jnp.dtype(compute_dtype or x.dtype)
     plan_event("moe.plan", form="relu2" if w_gate is None else "swiglu",
@@ -242,30 +307,37 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
     else:
         raise ValueError(f"scoring must be sigmoid or softmax, got "
                          f"{scoring!r}")
-    local = jnp.logical_and(chosen >= first, chosen < first + held)
-    key = jnp.where(local, chosen - first, held).reshape(-1)   # (N k,)
+    # pair p = slot * N + token: the buffer in pair order is its top_k
+    # slots of (N, D) one after another, read in place; token-major pairs
+    # made it (N, top_k, D), top_k in the tiled place: a padded copy
+    local = jnp.logical_and(chosen >= first, chosen < first + held).T
+    key = jnp.where(local, chosen.T - first, held).reshape(-1)   # (k N,)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     inv = jnp.argsort(order).astype(jnp.int32)
     sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                     dtype=jnp.int32)
     valid = jnp.arange(n * top_k) < jnp.sum(sizes)
-    # every read of a buffer the kernel wrote masks the rows past the
-    # routed ones BEFORE anything else touches them
+    # Rows past the routed ones hold whatever the kernel left (NaN, too).
+    # They may be gathered and may pass row-wise elementwise work; they are
+    # SELECTED away (never multiplied) before any sum that crosses rows or
+    # leaves the buffer.  The narrow (rows, F) outputs are masked here,
+    # where the select fuses with the activation; the wide (rows, D) ones
+    # where they are consumed: after the gather back to pair order, by
+    # ``local``, inside the combine's sum and inside ``_rows_of_pairs``'s.
     def product(lhs, rhs):
         out = grouped_matmul(lhs, rhs.astype(cd), sizes, impl=impl)
         return jnp.where(valid[:, None], out, jnp.zeros_like(out))
 
-    rows = _rows_of_pairs(x.astype(cd), order, inv, valid, top_k)
+    rows = _rows_of_pairs(x.astype(cd), order, inv, local)
     u = product(rows, w_up).astype(jnp.float32)
     if w_gate is None:
         h = jnp.square(jax.nn.relu(u)).astype(cd)
     else:
         h = (jax.nn.silu(product(rows, w_gate).astype(jnp.float32))
              * u).astype(cd)
-    y = product(h, w_down)
-    y = _permute(y, inv, order).reshape(n, top_k, d)
-    out = jnp.sum(w[:, :, None] * y.astype(jnp.float32), axis=1)
-    return out, chosen, sizes
+    y = grouped_matmul(h, w_down.astype(cd), sizes, impl=impl)
+    y = _permute(y, inv, order)
+    return _combine(w.T, y, local), chosen, sizes
 
 
 def amp_compute_dtype(x):

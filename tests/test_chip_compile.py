@@ -7,7 +7,9 @@ times.  They run under the package-default matmul precision ('highest'),
 the setting every user process has.  Real widths of GPT-2 124M: 12 heads
 of 64.  No whole-model compile here (tier-1's time budget).
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -268,3 +270,66 @@ def test_flash_head_size_256_grouped_queries_compile_for_v5e(chip):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
     assert _kernels(compiled) == 3
+
+
+# -------------------- the expert layer around its grouped products (PR 38)
+
+def _entry_arrays(compiled):
+    """(type, shape) of every array an instruction of the entry
+    computation produces, the members of a tuple each."""
+    text = compiled.as_text()
+    found = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) [a-z][a-z\-]*\(",
+                     line)
+        if m:
+            found += [(ty, tuple(int(s) for s in dims.split(",") if s))
+                      for ty, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                                 m.group(1))]
+    return found
+
+
+@pytest.mark.parametrize("top_k,d,f,held,experts,form", [
+    (10, 2048, 512, 32, 512, "swiglu"),     # qwen3_next_80b_a3b_share
+    (6, 2688, 1856, 8, 128, "relu2"),       # nemotron_tt_30b_a3b_ep16
+])
+def test_the_expert_layer_lays_its_buffer_once_for_v5e(
+        chip, monkeypatch, top_k, d, f, held, experts, form):
+    """One routed expert layer recomputed under ``jax.checkpoint`` with
+    its gradient, real widths, 2,048 tokens: a token's pairs are numbered
+    slot by slot, so the buffer in pair order IS its ``top_k`` slots of
+    (N, D) and is never re-laid as (N, top_k, D) (``top_k`` in the tiled
+    second-minor place: a copy padded to 16), and the weighted sum, its
+    backward and the sum of a token's row gradients read the bf16 buffer
+    and write (N, D): no float32 array of the buffer's size exists."""
+    from mxnet_tpu import base
+    from mxnet_tpu.models import moe
+
+    n, bf = 2048, jnp.bfloat16
+    # the layer and its grouped products choose by platform
+    monkeypatch.setattr(base, "resolve_exec_platform", lambda x=None: "tpu")
+
+    def layer(x, w_router, bias, w_up, w_gate, w_down):
+        kw = dict(top_k=top_k, first=0, compute_dtype=bf, impl="pallas")
+        if form == "swiglu":
+            kw.update(scoring="softmax", w_gate=w_gate)
+        return moe.dropless_ffn(x, w_router, bias, w_up, w_down, **kw)[0]
+
+    def loss(ct, *args):
+        return jnp.sum(jax.checkpoint(layer)(*args) * ct)
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(1, 2, 4, 5, 6))).lower(
+        sds((n, d), jnp.float32), sds((n, d), bf),
+        sds((experts, d), jnp.float32), sds((experts,), jnp.float32),
+        sds((held, d, f), bf), sds((held, d, f), bf),
+        sds((held, f, d), bf)).compile()
+    products = {"relu2": 2, "swiglu": 3}[form]
+    assert _kernels(compiled) == 4 * products   # forward twice, backward x 2
+    arrays = _entry_arrays(compiled)
+    assert ("bf16", (n * top_k, d)) in arrays   # the parser sees the buffer
+    for ty, shape in arrays:
+        assert shape != (n, top_k, d), (ty, shape)
+        assert not (ty == "f32" and math.prod(shape) >= n * top_k * d), \
+            (ty, shape)

@@ -2,6 +2,8 @@
 deployment) against a plain loop over experts: routing as published, no
 token dropped under any skew, and the shares of a deployment adding up to
 the uncut layer."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -13,9 +15,9 @@ from mxnet_tpu.models import moe
 N, D, F, E, K = 96, 32, 24, 16, 3
 
 
-def _weights(seed=0, skew=0.0):
+def _weights(seed=0, skew=0.0, n=N):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    x = jax.random.normal(ks[0], (N, D))
+    x = jax.random.normal(ks[0], (n, D))
     wr = 0.3 * jax.random.normal(ks[1], (E, D))
     bias = 0.05 * jax.random.normal(ks[2], (E,))
     if skew:
@@ -27,11 +29,11 @@ def _weights(seed=0, skew=0.0):
     return x, wr, bias, w_up, w_down
 
 
-def _loop(x, wr, bias, w_up, w_down, first=0, held=E, scaling=2.5):
+def _loop(x, wr, bias, w_up, w_down, first=0, held=E, scaling=2.5, k=K):
     """The published routing and a plain loop over the experts held."""
     s = jax.nn.sigmoid(jnp.einsum("nd,ed->ne", x, wr,
                                   precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias, K)
+    _, idx = jax.lax.top_k(s + bias, k)
     w = jnp.take_along_axis(s, idx, axis=1)
     w = w / jnp.sum(w, axis=1, keepdims=True) * scaling
     y = jnp.zeros_like(x)
@@ -127,6 +129,108 @@ def test_dropless_gradients_match_the_loop(impl):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                     rtol=1e-3, atol=2e-4)
     assert float(jnp.max(jnp.abs(got[2][:4]))) == 0.0   # experts not held
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("n,k", [(50, 5), (37, 9)])
+def test_sizes_that_fill_no_tile_agree_with_the_loop(impl, n, k):
+    """A token's pairs are numbered slot by slot (pair ``slot * N +
+    token``), so ``N`` rows stand between a token's pairs.  Nothing in
+    that needs ``N`` to be a multiple of 16 or ``top_k`` of 8 (on the
+    chip such sizes are only slower): values and gradients against the
+    loop, experts 4..9 held."""
+    x, wr, bias, w_up, w_down = _weights(seed=4, skew=1.0, n=n)
+    g = jax.random.normal(jax.random.PRNGKey(12), (n, D))
+
+    def ours(x, wr, w_up, w_down):
+        y, chosen, sizes = moe.dropless_ffn(
+            x, wr, bias, w_up[4:10], w_down[4:10], top_k=k, first=4,
+            scaling=2.5, impl=impl)
+        return jnp.sum(y * g), (chosen, sizes)
+
+    def loop(x, wr, w_up, w_down):
+        y, idx = _loop(x, wr, bias, w_up, w_down, 4, 6, k=k)
+        return jnp.sum(y * g), idx
+
+    (got, (chosen, sizes)), d_got = jax.value_and_grad(
+        ours, argnums=(0, 1, 2, 3), has_aux=True)(x, wr, w_up, w_down)
+    (want, idx), d_want = jax.value_and_grad(
+        loop, argnums=(0, 1, 2, 3), has_aux=True)(x, wr, w_up, w_down)
+    assert chosen.shape == (n, k)
+    assert onp.array_equal(onp.sort(onp.asarray(chosen), 1),
+                           onp.sort(onp.asarray(idx), 1))
+    assert onp.asarray(sizes).tolist() == [
+        int(jnp.sum(idx == e)) for e in range(4, 10)]
+    onp.testing.assert_allclose(float(got), float(want), rtol=1e-4)
+    for a, b in zip(d_got, d_want):
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=1e-3, atol=2e-4)
+
+
+def _nan_past_the_routed_rows(real):
+    """``grouped_matmul`` as the kernel may leave its output: every row
+    past ``sum(sizes)`` NaN, in the product and in the ``lhs`` gradient."""
+    def fill(out, sizes):
+        routed = jnp.arange(out.shape[0]) < jnp.sum(sizes)
+        return jnp.where(routed[:, None], out, jnp.nan)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def patched(lhs, rhs, sizes, impl):
+        return fill(real(lhs, rhs, sizes, impl=impl), sizes)
+
+    def fwd(lhs, rhs, sizes, impl):
+        out, vjp = jax.vjp(lambda a, b: real(a, b, sizes, impl=impl),
+                           lhs, rhs)
+        return fill(out, sizes), (vjp, sizes)
+
+    def bwd(impl, res, g):
+        vjp, sizes = res
+        d_lhs, d_rhs = vjp(g)
+        return (fill(d_lhs, sizes), d_rhs,
+                onp.zeros(sizes.shape, jax.dtypes.float0))
+
+    patched.defvjp(fwd, bwd)
+    return lambda lhs, rhs, sizes, impl="auto": patched(lhs, rhs, sizes,
+                                                        impl)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_rows_the_kernel_left_reach_no_sum(impl, form, monkeypatch):
+    """The rule of ``dropless_ffn``'s buffer: rows past the routed ones
+    hold whatever the kernel left, they may be gathered and may pass
+    row-wise work, and they are SELECTED away before any sum that crosses
+    rows or leaves the buffer.  With every such row NaN in every grouped
+    product and in every gradient a product hands back, the value and
+    every gradient are finite and what they are without the NaNs.
+    Experts 4..7 of 16 held: most of the buffer is past the routed rows."""
+    from mxnet_tpu.ops import gmm
+
+    x, wr, bias, w_up, w_down = _weights(seed=5)
+    w_gate = 0.2 * jax.random.normal(jax.random.PRNGKey(13), (E, D, F))
+    g = jax.random.normal(jax.random.PRNGKey(14), (N, D))
+
+    def loss(x, wr, w_up, w_gate, w_down):
+        kw = dict(top_k=K, first=4, impl=impl)
+        if form == "swiglu":
+            kw.update(scoring="softmax", w_gate=w_gate[4:8])
+        y, _, sizes = moe.dropless_ffn(x, wr, bias, w_up[4:8], w_down[4:8],
+                                       **kw)
+        return jnp.sum(y * g), (y, sizes)
+
+    run = lambda: jax.value_and_grad(  # noqa: E731
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(x, wr, w_up, w_gate,
+                                                     w_down)
+    (_, (want, sizes)), d_want = run()
+    assert 0 < int(jnp.sum(sizes)) < N * K // 2
+    monkeypatch.setattr(gmm, "grouped_matmul",
+                        _nan_past_the_routed_rows(gmm.grouped_matmul))
+    (_, (got, _)), d_got = run()
+    assert onp.isfinite(onp.asarray(got)).all()
+    assert onp.array_equal(onp.asarray(got), onp.asarray(want))
+    for a, b in zip(d_got, d_want):
+        assert onp.isfinite(onp.asarray(a)).all()
+        assert onp.array_equal(onp.asarray(a), onp.asarray(b))
 
 
 def test_layer_is_told_what_it_holds_and_counts_what_it_routes():
