@@ -10,6 +10,7 @@ from .gpt2 import GPT2Model, get_gpt2, gpt2_lm_loss
 from .granite_hybrid import GraniteHybridModel, get_granite_hybrid
 from .moe import MoELayer, MoETransformerBlock, pop_aux_losses
 from .nemotron_h import NemotronHModel, get_nemotron_h
+from .phi4_flash import Phi4FlashModel, get_phi4_flash
 from .qwen3_next import Qwen3NextModel, get_qwen3_next
 from .nmt import TransformerDecoderBlock, TransformerNMT, get_nmt, nmt_loss
 from .stacked import StackedGPT2Model, get_stacked_gpt2
@@ -24,4 +25,5 @@ __all__ = ["vision", "get_model", "BERTModel", "BERTForPretrain", "get_bert",
            "TransformerBlock", "TransformerEncoderLayer",
            "TransformerNMT", "TransformerDecoderBlock", "get_nmt",
            "nmt_loss", "NemotronHModel", "get_nemotron_h", "Qwen3NextModel",
-           "get_qwen3_next", "GraniteHybridModel", "get_granite_hybrid"]
+           "get_qwen3_next", "GraniteHybridModel", "get_granite_hybrid",
+           "Phi4FlashModel", "get_phi4_flash"]

@@ -716,6 +716,15 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
     rematerialization for long sequences / deep stacks); ``remat="dots"``
     uses the checkpoint_dots policy (save matmul outputs, recompute only
     elementwise — the usual best memory/FLOP point on TPU).
+
+    A layer may hand later layers more than the stream (one layer's keys
+    and values for a whole cross-decoder, a scan's output as a memory):
+    a block with ``side_out`` (names) returns ``(stream, *those)``, and a
+    block with ``side_in`` is called with the named values after the
+    mask.  They travel the loop paths beside the stream, differentiable:
+    under ``remat`` they leave the emitting layer's checkpoint as outputs
+    and enter each reader's as arguments, so their cotangents flow back
+    and no recomputed layer's internals are kept for them.
     """
     use_scan = scan if scan is not None else len(blocks) >= 8
     if use_scan and _scan_eligible(blocks, x):
@@ -734,7 +743,10 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
             providers = _random._trace_providers()
             base_key = providers[-1].key if providers else None
 
+            side = {}
             for i, blk in enumerate(blocks):
+                reads = tuple(getattr(blk, "side_in", ()))
+                emits = tuple(getattr(blk, "side_out", ()))
                 # payloads the layer rebinds in its forward (a buffer
                 # that is not trained: running statistics, routing
                 # counters) leave the checkpointed function as outputs
@@ -745,16 +757,20 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
 
                 moved = []      # which of them this layer rebound
 
-                def f(h, _blk=blk, _i=i, _aux=aux, _moved=moved):
+                def f(h, *given, _blk=blk, _i=i, _aux=aux, _moved=moved,
+                      _emits=emits):
                     if base_key is not None:
                         _random.push_trace_key(
                             jax.random.fold_in(base_key, _i))
                     saved = [(a._data, a._node) for a in _aux]
                     try:
-                        out = _blk(NDArray(h), mask).jax
+                        out = _blk(NDArray(h), mask,
+                                   *(NDArray(v) for v in given))
+                        out, *emitted = out if _emits else (out,)
                         _moved[:] = [j for j, a in enumerate(_aux)
                                      if a._data is not saved[j][0]]
-                        return out, tuple(_aux[j]._data for j in _moved)
+                        return (out.jax, tuple(e.jax for e in emitted),
+                                tuple(_aux[j]._data for j in _moved))
                     finally:
                         for a, (d, n) in zip(_aux, saved):
                             a._data, a._node = d, n
@@ -762,13 +778,19 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
                             _random.pop_trace_key()
                 policy = (jax.checkpoint_policies.checkpoint_dots
                           if remat == "dots" else None)
-                out, new_aux = jax.checkpoint(f, policy=policy)(x.jax)
+                out, emitted, new_aux = jax.checkpoint(f, policy=policy)(
+                    x.jax, *(side[name].jax for name in reads))
                 for j, v in zip(moved, new_aux):
                     aux[j]._rebind(v)
+                side.update(zip(emits, map(NDArray, emitted)))
                 x = NDArray(out)
             return x
+    side = {}
     for blk in blocks:
-        x = blk(x, mask)
+        x = blk(x, mask, *(side[name] for name in getattr(blk, "side_in", ())))
+        if getattr(blk, "side_out", ()):
+            x, *emitted = x
+            side.update(zip(blk.side_out, emitted))
     return x
 
 

@@ -26,14 +26,17 @@ _NEG_INF = -1e30
 # ----------------------------------------------------------------- reference
 
 def _attention_ref(q, k, v, *, causal=False, mask=None, scale=None,
-                   dropout=0.0, dropout_key=None):
+                   dropout=0.0, dropout_key=None, window=None):
     """Pure-jax attention; q/k/v are (B, T, H, D).  XLA fuses this well for
     moderate T; the Pallas kernel takes over for long sequences.  Fewer
-    K/V heads than query heads are repeated here (grouped queries)."""
+    K/V heads than query heads are repeated here (grouped queries; the
+    values may have a head count and a head dim of their own).  ``window``
+    (with ``causal``): query t sees keys s with ``0 <= t - s < window``."""
     d = q.shape[-1]
     if k.shape[2] != q.shape[2]:
-        share = q.shape[2] // k.shape[2]
-        k, v = jnp.repeat(k, share, axis=2), jnp.repeat(v, share, axis=2)
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+    if v.shape[2] != q.shape[2]:
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
@@ -41,7 +44,10 @@ def _attention_ref(q, k, v, *, causal=False, mask=None, scale=None,
         tq, tk = logits.shape[-2], logits.shape[-1]
         idx_q = jnp.arange(tq)[:, None] + (tk - tq)
         idx_k = jnp.arange(tk)[None, :]
-        logits = jnp.where(idx_k <= idx_q, logits, _NEG_INF)
+        seen = idx_k <= idx_q
+        if window is not None:
+            seen = jnp.logical_and(seen, idx_q - idx_k < window)
+        logits = jnp.where(seen, logits, _NEG_INF)
     if mask is not None:
         logits = jnp.where(mask, logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -110,7 +116,8 @@ def _use_flash(q_shape, causal, mask, dropout, k_shape=None,
     return (platform or jax.default_backend()) == "tpu"
 
 
-def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None):
+def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None,
+                  window=None):
     """The Pallas kernel, run per device.  GSPMD cannot partition a Mosaic
     kernel (jax refuses to lower one into a multi-device program), and
     batch rows and heads attend independently — so under an ambient
@@ -124,7 +131,8 @@ def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None):
     def body(q, k, v, *seg):
         return _pallas(q, k, v, causal=causal, scale=scale,
                        segment_ids=seg[0] if seg else None,
-                       kv_segment_ids=seg[1] if seg else None)
+                       kv_segment_ids=seg[1] if seg else None,
+                       window=window)
 
     seg = () if q_seg is None else (q_seg, kv_seg)
     mesh = current_mesh()
@@ -137,18 +145,21 @@ def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None):
             dim % axis_size(mesh, axis) == 0 else None
 
     b_ax, h_ax = over("dp", q.shape[0]), over("tp", q.shape[2])
-    if over("tp", k.shape[2]) is None:   # fewer K/V heads than tp shards
-        h_ax = None
+    if None in (over("tp", k.shape[2]), over("tp", v.shape[2])):
+        h_ax = None                      # fewer K/V heads than tp shards
     return shard_mapped_qkv(body, mesh, P(b_ax, None, h_ax, None), q, k, v,
                             *seg, extra_specs=(P(b_ax, None),) * len(seg))
 
 
-def flash_attention(q, k, v, *, causal=False, scale=None):
-    """Jax-level flash attention entry (Pallas on TPU, reference on CPU)."""
+def flash_attention(q, k, v, *, causal=False, scale=None, window=None):
+    """Jax-level flash attention entry (Pallas on TPU, reference on CPU).
+    ``v`` may be (B, T, H_v, Dv), its head count and head dim its own;
+    ``window`` is a causal window in keys (see ``ops.flash``)."""
     if _use_flash(q.shape, causal, None, 0.0, k.shape,
                   platform=_base.resolve_exec_platform(q)):
-        return _pallas_flash(q, k, v, causal=causal, scale=scale)
-    return _attention_ref(q, k, v, causal=causal, scale=scale)
+        return _pallas_flash(q, k, v, causal=causal, scale=scale,
+                             window=window)
+    return _attention_ref(q, k, v, causal=causal, scale=scale, window=window)
 
 
 def dot_product_attention(query, key, value, *, causal=False, mask=None,

@@ -197,11 +197,33 @@ def _schedule(i, block: int, chunk: int, slab: int, total: int,
     return diagonal, ((0, i * per) if up else ((i + 1) * per, total))
 
 
-def _count_tiles(tq, tk, block, chunk, slab, causal, whole):
+def _window_chunks(i, block: int, chunk: int, total: int, window: int,
+                   up: bool):
+    """The (lo, hi) range of chunks block ``i`` visits under a causal
+    ``window`` (query t sees keys s with 0 <= t - s < window), each with
+    the mask: for a block of q rows walking kv (``up``) from the chunk of
+    the oldest key its first row sees to the end of its own square; for
+    a block of kv rows walking q from its own square to the chunk of the
+    last query that sees its last row.  ``i`` may be traced."""
+    per = block // chunk
+    if up:
+        return jnp.maximum(i * block - window + 1, 0) // chunk, (i + 1) * per
+    return i * per, jnp.minimum(((i + 1) * block + window - 2) // chunk + 1,
+                                total)
+
+
+def _count_tiles(tq, tk, block, chunk, slab, causal, whole, window=None):
     """(visited, whole square, masked) in (slab, slab) tiles of one head,
     by the schedule the kernels run; without the ``whole`` operand in
-    one stretch every chunk the diagonal crosses takes every row."""
+    one stretch every chunk the diagonal crosses takes every row.  Under
+    a ``window`` every visit is a masked chunk of every row."""
     run = masked = 0
+    if window:
+        for i in range(tq // block):
+            lo = max(i * block - window + 1, 0) // chunk
+            run += ((i + 1) * (block // chunk) - lo) * chunk * block
+        run //= slab * slab
+        return run, (tq // slab) * (tk // slab), run
     for i in range(tq // block):
         diagonal, (lo, hi) = _schedule(i, block, chunk, slab, tk // chunk,
                                        causal, True)
@@ -220,7 +242,9 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
               has_seg: bool = False, *, heads: int = 1,
               kv_heads: Optional[int] = None,
               block_q: Optional[int] = None,
-              chunk: Optional[int] = None) -> TilePlan:
+              chunk: Optional[int] = None,
+              window: Optional[int] = None, dv: Optional[int] = None,
+              v_heads: Optional[int] = None) -> TilePlan:
     """The one place a flash call's sizes are computed: from the sequence
     lengths, head dim, dtype, the masks in play and the number of heads.
     ``block_q`` / ``chunk`` override the starting sizes (tests only; a
@@ -237,9 +261,18 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     pair and wants both as fine as ``DEFAULT_SEG`` rows.  A grid step
     takes up to ``DEFAULT_GROUP`` heads of one batch row where the head
     count divides and VMEM allows.  With fewer key/value heads than query
-    heads (``kv_heads``) a step takes one query head, and reads the K/V
-    head it shares by an index map."""
+    heads (``kv_heads``; ``v_heads`` where the values' differ from the
+    keys') a step takes one query head, and reads the K/V head it shares
+    by an index map.  ``dv`` is the values' head dim where it is not the
+    keys' (VMEM is reckoned at the wider).  Under a causal ``window`` a
+    block is no taller than the window (what its rows see of older keys
+    is then at most a window wide) and walks only the chunks
+    :func:`_window_chunks` names, all with the mask."""
     span = math.gcd(tq, tk)
+    if window is not None and (not causal or has_seg or window < 1):
+        raise ValueError("a window needs causal=True, no segment ids and "
+                         f"at least one key (got {window})")
+    d = max(d, dv or d)
     fine = DEFAULT_SEG if has_seg else None
 
     def fit(size, within):             # halve until it divides
@@ -253,6 +286,8 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     # doubles (half as many updates of the per-row state)
     chunk = fit(chunk or fine or DEFAULT_CHUNK * (1 if causal else 2), span)
     cap = min(block_q or fine or DEFAULT_BLOCK_Q, span)
+    if window and not block_q:
+        cap = min(cap, max(chunk, window - window % chunk))
     # whole numbers of chunks that divide both sequences, largest first;
     # a block smaller than the chunk (tests) takes the chunk down with it
     blocks = [b for b in range(cap - cap % chunk, 0, -chunk)
@@ -267,11 +302,12 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     group = DEFAULT_GROUP
     while heads % group:
         group //= 2
-    if kv_heads is not None and kv_heads != heads:
-        if heads % kv_heads:
-            raise ValueError(f"{heads} query heads do not divide over "
-                             f"{kv_heads} key/value heads")
-        group = 1
+    for shared in (kv_heads, v_heads):
+        if shared is not None and shared != heads:
+            if heads % shared:
+                raise ValueError(f"{heads} query heads do not divide over "
+                                 f"{shared} key/value heads")
+            group = 1
     # the widest slice a step holds scores of: a chunk, or a forward slab
     # of the smallest block
     wide = max(chunk, fit(slabs[0], blocks[-1]))
@@ -280,9 +316,9 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
         wide)
     slab, slab_bwd = (fit(x, block) for x in slabs)
     run, full, masked = _count_tiles(tq, tk, block, chunk, slab, causal,
-                                     major == tk)
+                                     major == tk, window)
     run_bwd, full_bwd, _ = _count_tiles(tq, tk, block, chunk, slab_bwd,
-                                        causal, major_q == tq)
+                                        causal, major_q == tq, window)
     return TilePlan(block, chunk, slab, slab_bwd, major, major_q, group,
                     run, full, run if has_seg else masked, run_bwd,
                     full_bwd)
@@ -300,10 +336,18 @@ def plan_event(name: str, **attrs):
         tr.event(name, **attrs)
 
 
-def _report_plan(plan: TilePlan, tq, tk, d, dtype, causal, has_seg):
+def _report_plan(plan: TilePlan, tq, tk, d, dtype, causal, has_seg,
+                 window=None, dv=None):
+    """``window`` and ``dv`` are attributes only of a call that has a
+    window, or values wider than its keys."""
+    more = {}
+    if window is not None:
+        more["window"] = int(window)
+    if dv is not None and dv != d:
+        more["dv"] = int(dv)
     plan_event("flash.plan", **plan._asdict(), tq=tq, tk=tk, d=d,
                dtype=jnp.dtype(dtype).name, causal=bool(causal),
-               has_seg=bool(has_seg))
+               has_seg=bool(has_seg), **more)
 
 
 def matmul_precision(dtype):
@@ -338,7 +382,7 @@ def _default_interpret(x) -> bool:
 # ------------------------------------------------------- the walk, shared
 
 def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
-          seg):
+          seg, window=None):
     """Run ``step(start, width, rows, masked, fresh)`` over the slices
     ``[start, start + width)`` of this grid step's major stretch that
     block ``i`` has to visit: ``rows`` the block's rows that take part,
@@ -363,7 +407,12 @@ def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
     operand's (1, 1, major) id ref): a slice whose id range cannot meet
     the block's is skipped — exact for the packed layout (ids
     non-decreasing along the row), conservative (never skips a slice
-    that could match) for arbitrary ids."""
+    that could match) for arbitrary ids.
+
+    Under a ``window`` the visits are the chunks of
+    :func:`_window_chunks`, every one with the mask and every row,
+    clipped to the stretch; the chunks older than the window are never
+    visited."""
     whole = (0, block)
 
     def visit(start, width, rows, masked, fresh):
@@ -384,6 +433,11 @@ def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
             return carry
         jax.lax.fori_loop(lo, hi, body, 0)
 
+    if window:
+        a, b = _window_chunks(i, block, chunk, nm * cpm, window, up)
+        lo = mi * cpm
+        loop(jnp.clip(a - lo, 0, cpm), jnp.clip(b - lo, 0, cpm), whole)
+        return
     diagonal, plain = _schedule(i, block, chunk, slab, nm * cpm, causal, up)
     if nm > 1:
         lo, per = mi * cpm, block // chunk
@@ -406,14 +460,16 @@ def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
     loop(*plain, None)
 
 
-def _keep(rows, masked, width, own_is_q, own0, walk0, seg_own, seg_walk):
+def _keep(rows, masked, width, own_is_q, own0, walk0, seg_own, seg_walk,
+          window=None):
     """The mask of one visit's score tile — (block rows ``rows``, ``width``
     walked columns) — as ``(keep, sub)``: ``keep`` covers the tile's
     rows ``sub`` = (lo, hi) only, or is None when nothing masks the tile.
     Rows ``masked`` of the block (None: none) lie on the diagonal and
     compare global positions, ``kv <= q``: the block's own run down the
     tile from ``own0``, the walked operand's along it from ``walk0``, and
-    ``own_is_q`` says which of the two are q's.  Segment ids
+    ``own_is_q`` says which of the two are q's; under a ``window`` they
+    also compare ``q - kv < window``.  Segment ids
     (``seg_own`` a (block, 1) column, ``seg_walk`` a (1, width) row) mask
     every row, where the call packs segments."""
     if seg_own is None and masked is None:
@@ -425,6 +481,9 @@ def _keep(rows, masked, width, own_is_q, own0, walk0, seg_own, seg_walk):
         own = own0 + m0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         walk = walk0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         keep = walk <= own if own_is_q else own <= walk
+        if window:
+            keep = jnp.logical_and(
+                keep, (own - walk if own_is_q else walk - own) < window)
     if seg_own is not None:
         same = seg_own[m0:m1] == seg_walk
         keep = same if keep is None else jnp.logical_and(keep, same)
@@ -497,7 +556,8 @@ def _lanes_to_rows(row, rows: int):
     return jnp.broadcast_to(row, (_LANES, rows)).T
 
 
-def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
+def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
+                window=None):
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
@@ -505,8 +565,9 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     qi = pl.program_id(1)
     mi = pl.program_id(2)
-    group, major, d = k_ref.shape
-    fold = nm == 1 and not has_seg
+    group, major, _ = k_ref.shape
+    dv = v_ref.shape[2]
+    fold = nm == 1 and not has_seg and not window
 
     if not fold:
         @pl.when(mi == 0)
@@ -522,7 +583,7 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
         keep, sub = _keep(rows, masked, width, True, qi * block_q,
                           mi * major + start, qs,
                           kseg_ref[0, :, pl.ds(start, width)] if has_seg
-                          else None)
+                          else None, window)
         # the group's heads are independent chains of matmul → softmax →
         # matmul: side by side in one region they fill each other's
         # latencies
@@ -536,9 +597,10 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
                 else m_ref[g, r0:r1, :]                # (rows, 128)
             m_next = jnp.maximum(m_prev, m_cur)
             p = jnp.exp(s - _lanes(m_next, width))
-            if has_seg:
+            if has_seg or window:
                 # masked-safe exp: a row whose every entry so far is
-                # masked (its segment starts in a LATER chunk) has
+                # masked (its segment starts in a LATER chunk; the oldest
+                # chunk of its block's window lies before its own) has
                 # m_next == _MASK, and bare exp(s - m_next) would
                 # contribute exp(0)=1 per masked entry.  Zero masked
                 # entries explicitly.  The causal mask alone never needs
@@ -556,12 +618,12 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
                 corr = jnp.exp(m_prev - m_next)        # (rows, 128)
                 l_ref[g, r0:r1, :] = corr * l_ref[g, r0:r1, :] + _fold(p)
                 acc_ref[g, r0:r1, :] = \
-                    acc_ref[g, r0:r1, :] * _lanes(corr, d) + pv
+                    acc_ref[g, r0:r1, :] * _lanes(corr, dv) + pv
             m_ref[g, r0:r1, :] = m_next
 
     _walk(step, causal=causal, up=True, i=qi, mi=mi, nm=nm, block=block_q,
           chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
-          seg=(qs, kseg_ref) if has_seg else None)
+          seg=(qs, kseg_ref) if has_seg else None, window=window)
 
     @_when(nm == 1, mi == nm - 1)
     def _finish():
@@ -599,7 +661,8 @@ def _kv_row(nheads: int, kv_heads: int):
     return lambda b: (b // nheads) * kv_heads + (b % nheads) // share
 
 
-def _walked_spec(shape, block, major, causal, up, row=lambda b: b):
+def _walked_spec(shape, block, major, causal, up, row=lambda b: b,
+                 window=None):
     """BlockSpec of a walked operand: its major stretch, with an index map
     that ignores the block axis (so one head's stretch is fetched once),
     clamped under ``causal`` to the stretches the block's rows can see —
@@ -607,14 +670,20 @@ def _walked_spec(shape, block, major, causal, up, row=lambda b: b):
     holds and fetches nothing.  ``shape`` is the block shape with -1 for
     the sequence axis; ``up`` says the walk ends at the diagonal (fwd,
     dq) rather than starts there (dkv); ``row`` maps the grid's first
-    coordinate to the operand's."""
+    coordinate to the operand's.  A ``window`` clamps the other end too,
+    to the stretch of the oldest key (the last query) the block meets."""
     axis = shape.index(-1)
 
     def index(b, i, m):
         if causal and up:
             m = jnp.minimum(m, ((i + 1) * block - 1) // major)
+            if window:
+                m = jnp.maximum(
+                    m, jnp.maximum(i * block - window + 1, 0) // major)
         elif causal:
             m = jnp.maximum(m, (i * block) // major)
+            if window:
+                m = jnp.minimum(m, ((i + 1) * block + window - 2) // major)
         return tuple(row(b) if a == 0 else m if a == axis else 0
                      for a in range(len(shape)))
     return pl.BlockSpec(tuple(major if n == -1 else n for n in shape), index)
@@ -634,20 +703,24 @@ _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
+def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
+         window=None):
     bh, tq, d = q.shape
     kv_row = _kv_row(nheads, k.shape[0] * nheads // bh)
-    tk = k.shape[1]
+    v_row = _kv_row(nheads, v.shape[0] * nheads // bh)
+    tk, dv = k.shape[1], v.shape[2]
     block_q, chunk, major, group = (plan.block_q, plan.chunk, plan.major,
                                     plan.group)
     nm = tk // major
     has_seg = q_seg is not None
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_seg=has_seg,
-        block_q=block_q, chunk=chunk, slab=plan.slab, nm=nm)
-    walked = _walked_spec((group, -1, d), block_q, major, causal, True,
-                          row=kv_row)
-    in_specs = [_own_spec((group, block_q, d)), walked, walked]
+        block_q=block_q, chunk=chunk, slab=plan.slab, nm=nm, window=window)
+    in_specs = [_own_spec((group, block_q, d)),
+                _walked_spec((group, -1, d), block_q, major, causal, True,
+                             row=kv_row, window=window),
+                _walked_spec((group, -1, dv), block_q, major, causal, True,
+                             row=v_row, window=window)]
     args = [q, k, v]
     if has_seg:
         in_specs += _seg_specs(lambda b: b * group // nheads, block_q,
@@ -660,7 +733,7 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
         grid=(bh // group, tq // block_q, nm),
         in_specs=in_specs,
         out_specs=[
-            _own_spec((group, block_q, d)),
+            _own_spec((group, block_q, dv)),
             # lse is (bh, 1, tq) so each qi owns its own (g, 1, block_q)
             # tile — TPU block rules demand last-two dims divisible by
             # (8, 128) or equal to the array dims, and a shared full-row
@@ -668,18 +741,18 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
             _own_spec((group, 1, block_q)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((group, block_q, d), jnp.float32),
+            pltpu.VMEM((group, block_q, dv), jnp.float32),
             pltpu.VMEM((group, block_q, _LANES), jnp.float32),
             pltpu.VMEM((group, block_q, _LANES), jnp.float32),
         ],
         compiler_params=_PARAMS,
         # what the algorithm needs: under a causal mask half the square
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * tq * tk * d // half,
+            flops=2 * bh * tq * tk * (d + dv) // half,
             transcendentals=bh * tq * tk // half,
             bytes_accessed=2 * (q.size + k.size + v.size) * q.dtype.itemsize),
         interpret=interpret,
@@ -689,7 +762,8 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
 
 # --------------------------------------------------------------------- bwd
 
-def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
+def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
+               window=None):
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          qseg_ref, kseg_ref, dq_ref, acc_ref, lse_b, delta_b) = refs
@@ -699,7 +773,7 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
     qi = pl.program_id(1)
     mi = pl.program_id(2)
     group, major, _ = k_ref.shape
-    fold = nm == 1 and not has_seg
+    fold = nm == 1 and not has_seg and not window
 
     @_when(nm == 1, mi == 0)
     def _init():
@@ -718,7 +792,7 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
         keep, sub = _keep(rows, masked, width, True, qi * block_q,
                           mi * major + start, qs,
                           kseg_ref[0, :, pl.ds(start, width)] if has_seg
-                          else None)
+                          else None, window)
         for g in range(group):
             k = k_ref[g, pl.ds(start, width), :]       # (width, d)
             s = _dot(q_ref[g, r0:r1, :], k, 1, 1) * scale  # (rows, width)
@@ -733,14 +807,15 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm):
 
     _walk(step, causal=causal, up=True, i=qi, mi=mi, nm=nm, block=block_q,
           chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
-          seg=(qs, kseg_ref) if has_seg else None)
+          seg=(qs, kseg_ref) if has_seg else None, window=window)
 
     @_when(nm == 1, mi == nm - 1)
     def _finish():
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm):
+def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
+                window=None):
     """Tiles are held transposed, (kv rows, q columns): lse and delta are
     stored along lanes, so they broadcast down the tile as they are, and
     both accumulating products contract the tile's columns with the rows
@@ -754,7 +829,7 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm):
     ki = pl.program_id(1)
     mi = pl.program_id(2)
     group, major, _ = q_ref.shape
-    fold = nm == 1 and not has_seg
+    fold = nm == 1 and not has_seg and not window
 
     if not fold:
         @pl.when(mi == 0)
@@ -769,7 +844,7 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm):
         keep, sub = _keep(rows, masked, width, False, ki * block_k,
                           mi * major + start, ks,
                           qseg_ref[0, :, pl.ds(start, width)] if has_seg
-                          else None)
+                          else None, window)
         for g in range(group):
             q = q_ref[g, pl.ds(start, width), :]       # (width, d)
             do = do_ref[g, pl.ds(start, width), :]
@@ -790,7 +865,7 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm):
 
     _walk(step, causal=causal, up=False, i=ki, mi=mi, nm=nm, block=block_k,
           chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
-          seg=(ks, qseg_ref) if has_seg else None)
+          seg=(ks, qseg_ref) if has_seg else None, window=window)
 
     @_when(nm == 1, mi == nm - 1)
     def _finish():
@@ -799,23 +874,26 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm):
 
 
 def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
-              plan, interpret):
+              plan, interpret, window=None):
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     block, chunk, group = plan.block_q, plan.chunk, plan.group
     has_seg = q_seg is not None
-    kv_heads = k.shape[0] * nheads // bh
-    kv_row = _kv_row(nheads, kv_heads)
+    kv_heads, v_heads = (x.shape[0] * nheads // bh for x in (k, v))
+    kv_row, v_row = _kv_row(nheads, kv_heads), _kv_row(nheads, v_heads)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]               # (bh, 1, tq)
     tile, row = _own_spec((group, block, d)), _own_spec((group, 1, block))
+    tile_v = _own_spec((group, block, dv))
 
     def rows(b):
         return b * group // nheads
 
-    kv_walk = _walked_spec((group, -1, d), block, plan.major, causal, True,
-                           row=kv_row)
-    dq_in_specs = [tile, kv_walk, kv_walk, tile, row, row]
+    k_walk = _walked_spec((group, -1, d), block, plan.major, causal, True,
+                          row=kv_row, window=window)
+    v_walk = _walked_spec((group, -1, dv), block, plan.major, causal, True,
+                          row=v_row, window=window)
+    dq_in_specs = [tile, k_walk, v_walk, tile_v, row, row]
     args = [q, k, v, do, lse, delta]
     if has_seg:
         dq_in_specs += _seg_specs(rows, block, plan.major, causal, True)
@@ -823,7 +901,8 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           has_seg=has_seg, block_q=block, chunk=chunk,
-                          slab=plan.slab_bwd, nm=tk // plan.major),
+                          slab=plan.slab_bwd, nm=tk // plan.major,
+                          window=window),
         name="flash_bwd_dq",
         grid=(bh // group, tq // block, tk // plan.major),
         in_specs=dq_in_specs,
@@ -837,44 +916,48 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     )(*args)
 
     q_walk = _walked_spec((group, -1, d), block, plan.major_q, causal,
-                          False)
+                          False, window=window)
+    do_walk = _walked_spec((group, -1, dv), block, plan.major_q, causal,
+                           False, window=window)
     row_walk = _walked_spec((group, 1, -1), block, plan.major_q, causal,
-                            False)
+                            False, window=window)
     # each query head reads the K/V head it shares and writes that head's
     # dK/dV of its own, in float32; the heads of a share are summed after
-    kv_tile = _own_spec((group, block, d), row=kv_row)
-    shared = kv_heads != nheads
-    dkv_in_specs = [q_walk, kv_tile, kv_tile, q_walk, row_walk, row_walk]
+    shared = kv_heads != nheads or v_heads != nheads
+    dkv_in_specs = [q_walk, _own_spec((group, block, d), row=kv_row),
+                    _own_spec((group, block, dv), row=v_row), do_walk,
+                    row_walk, row_walk]
     if has_seg:
         dkv_in_specs += _seg_specs(rows, block, plan.major_q, causal,
                                    False)[::-1]
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           has_seg=has_seg, block_k=block, chunk=chunk,
-                          slab=plan.slab_bwd, nm=tq // plan.major_q),
+                          slab=plan.slab_bwd, nm=tq // plan.major_q,
+                          window=window),
         name="flash_bwd_dkv",
         grid=(bh // group, tk // block, tq // plan.major_q),
         in_specs=dkv_in_specs,
-        out_specs=[tile, tile],
+        out_specs=[tile, tile_v],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d),
                                  jnp.float32 if shared else k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d),
+            jax.ShapeDtypeStruct((bh, tk, dv),
                                  jnp.float32 if shared else v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, block, d), jnp.float32),
-            pltpu.VMEM((group, block, d), jnp.float32),
+            pltpu.VMEM((group, block, dv), jnp.float32),
         ],
         compiler_params=_PARAMS,
         interpret=interpret,
     )(*args)
     if shared:
-        def over_share(x, like):
-            x = x.reshape(bh // nheads, kv_heads, nheads // kv_heads, tk, d)
+        def over_share(x, like, held):
+            x = x.reshape(bh // nheads, held, nheads // held, tk, x.shape[-1])
             return x.sum(axis=2).reshape(like.shape).astype(like.dtype)
-        dk, dv = over_share(dk, k), over_share(dv, v)
-    return dq, dk, dv
+        dk, dv_ = over_share(dk, k, kv_heads), over_share(dv_, v, v_heads)
+    return dq, dk, dv_
 
 
 # ----------------------------------------------------------- custom_vjp glue
@@ -891,24 +974,25 @@ def _int_zero_cotangent(x):
     return _np.zeros(x.shape, _dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
+           window=None):
     out, _ = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
-                  interpret)
+                  interpret, window)
     return out
 
 
 def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
-               interpret):
+               interpret, window=None):
     out, lse = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
-                    interpret)
+                    interpret, window)
     return out, (q, k, v, q_seg, kv_seg, out, lse)
 
 
-def _flash_bwd(nheads, causal, scale, plan, interpret, res, do):
+def _flash_bwd(nheads, causal, scale, plan, interpret, window, res, do):
     q, k, v, q_seg, kv_seg, out, lse = res
     dq, dk, dv = _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads,
-                           causal, scale, plan, interpret)
+                           causal, scale, plan, interpret, window)
     return (dq, dk, dv,
             _int_zero_cotangent(q_seg), _int_zero_cotangent(kv_seg))
 
@@ -921,15 +1005,22 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     segment_ids=None, kv_segment_ids=None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
-    """Flash attention on (B, T, H, D) inputs → (B, T, H, D).
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """Flash attention on (B, T, H, D) inputs → (B, T, H, Dv).
 
     T must be a multiple of 128 and D one of 64/128/256 (the dispatcher
     in :mod:`mxnet_tpu.ops.attention` guarantees this before routing
     here).  ``k`` and ``v`` may carry fewer heads than ``q`` (grouped
     queries): query head ``h`` reads K/V head ``h // (H / H_kv)`` through
     the kernels' index maps, nothing is repeated in memory, and dK/dV are
-    summed over the query heads of a share after the ``dkv`` kernel.  ``interpret`` defaults to True off-TPU so the same kernel is
+    summed over the query heads of a share after the ``dkv`` kernel.
+    ``v`` may be (B, T, H_v, Dv) with a head dim and a head count of its
+    own (a differential head's values are two key heads wide and shared
+    by the pair's two score matrices): the result then has ``Dv``.
+    ``window`` (with ``causal``): query t sees keys s with
+    ``0 <= t - s < window``, and the chunks older than that are never
+    visited.  ``interpret`` defaults to True off-TPU so the same kernel is
     unit-testable on the CPU backend.  ``block_q`` / ``block_k`` (the
     block a grid step owns and the chunk its loop walks) are for tests:
     a call leaves them to :func:`tile_plan`.
@@ -946,14 +1037,17 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """
     b, tq, h, d = q.shape
     tk, h_kv = k.shape[1], k.shape[2]
+    h_v, dv = v.shape[2], v.shape[3]
     if causal and tq != tk:
         raise ValueError("causal flash attention requires tq == tk "
                          f"(got {tq} vs {tk})")
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     has_seg = segment_ids is not None
+    window = None if window is None else int(window)
     plan = tile_plan(tq, tk, d, q.dtype, causal, has_seg, heads=h,
-                     kv_heads=h_kv, block_q=block_q, chunk=block_k)
-    _report_plan(plan, tq, tk, d, q.dtype, causal, has_seg)
+                     kv_heads=h_kv, block_q=block_q, chunk=block_k,
+                     window=window, dv=dv, v_heads=h_v)
+    _report_plan(plan, tq, tk, d, q.dtype, causal, has_seg, window, dv)
     if interpret is None:
         interpret = _default_interpret(q)
 
@@ -972,8 +1066,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError("kv_segment_ids requires segment_ids")
 
     def flat(x, t):
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t,
+                                               x.shape[3])
 
     out = _flash(flat(q, tq), flat(k, tk), flat(v, tk), q_seg, kv_seg,
-                 h, causal, scale, plan, bool(interpret))
-    return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+                 h, causal, scale, plan, bool(interpret), window)
+    return out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)

@@ -333,3 +333,53 @@ def test_the_expert_layer_lays_its_buffer_once_for_v5e(
         assert shape != (n, top_k, d), (ty, shape)
         assert not (ty == "f32" and math.prod(shape) >= n * top_k * d), \
             (ty, shape)
+
+
+# --------------------------------- the SambaY stage's kernels (PR 39)
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_selective_scan_compiles_for_v5e(chip, dtype):
+    """One Mamba-1 layer's scan at the published width: 5,120 channels of
+    16 states, chunks of 128 steps; forward and its own backward, the
+    backward holding a chunk's (128, 16, 512) states in VMEM."""
+    from mxnet_tpu import base
+    from mxnet_tpu import observability as obs
+    from mxnet_tpu.ops.sscan import selective_scan
+
+    def loss(x, dt, a, bm, cm):
+        return selective_scan(x, dt, a, bm, cm).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = (sds((1, 2048, 5120), dtype), sds((1, 2048, 5120), jnp.float32),
+            sds((5120, 16), jnp.float32), sds((1, 2048, 16), dtype),
+            sds((1, 2048, 16), dtype))
+    tr = obs.enable_tracing()
+    try:
+        # as on the chip: nothing says which path, the platform decides
+        with base.executing_on("tpu"):
+            compiled = jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+        plans = tr.spans(name="sscan.plan")
+    finally:
+        obs.disable_tracing()
+    assert [e.attrs["impl"] for e in plans] == ["pallas"]
+    assert _kernels(compiled) == 2          # sscan_fwd, sscan_bwd
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_differential_flash_compiles_for_v5e(chip, window):
+    """40 query heads of 64 over 20 key heads, the values 10 heads of 128
+    (a differential head's two key heads side by side), T 8,192, under the
+    512 window and without: the walk's third grid axis, its clamps at
+    both ends."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,  # noqa: E731
+                                             sharding=chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((1, 8192, 40, 64)), sds((1, 8192, 20, 64)),
+        sds((1, 8192, 10, 128))).compile()
+    assert _kernels(compiled) == 3
