@@ -144,25 +144,68 @@ def test_flash_under_a_mesh_runs_per_device(chips, monkeypatch):
 
 # ---------------------------------------- the hybrid stack's kernels (PR 26)
 
-def test_ssd_chunk_kernel_compiles_for_v5e(chip):
-    """One Mamba-2 layer's scan at the hybrid cell's size: 64 heads of 64
-    in 8 groups, state 128, chunks of 128 over 8,192 steps; forward by the
-    kernel, backward by the chunked XLA form."""
+@pytest.mark.parametrize("groups,chunk", [
+    (8, 128),       # nemotron_tt_30b_a3b_ep16
+    (1, 128),       # granite_4h_micro_p10 as its cell runs it (scan_chunk)
+    (1, 256),       # ... at the published mamba_chunk_size
+])
+def test_ssd_chunk_kernel_compiles_for_v5e(chip, groups, chunk):
+    """One Mamba-2 layer's scan at the two cells' sizes: 64 heads of 64,
+    state 128, 8,192 steps, in 8 B/C groups or in one (cut into head
+    blocks); forward by ``ssd_chunk_fwd``, backward by ``ssd_chunk_bwd``,
+    which keeps every (chunk, chunk) square in VMEM and writes no output
+    of the forward's dims (the benchmark's readers find the forward
+    kernel by them)."""
     from mxnet_tpu.ops.ssd import ssd_scan
+
+    def loss(x, dt, a, bm, cm):
+        return ssd_scan(x, dt, a, bm, cm, chunk=chunk, impl="pallas",
+                        interpret=False).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    args = (sds((1, 8192, 64, 64), jnp.bfloat16),
+            sds((1, 8192, 64), jnp.float32), sds((64,), jnp.float32),
+            sds((1, 8192, groups, 128), jnp.bfloat16),
+            sds((1, 8192, groups, 128), jnp.bfloat16))
+    # the value keeps the forward alive: a sum's gradient needs no output
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    assert _kernels(compiled) == 2          # ssd_chunk_fwd, ssd_chunk_bwd
+    results = [line.split(" custom-call(")[0]
+               for line in compiled.as_text().splitlines()
+               if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(results) == 2
+    assert sum("f32[1,8192,4096]" in r for r in results) == 1
+    # nothing lays the heads' squares out in HBM any more
+    squares = 64 * chunk * chunk * (8192 // chunk)
+    arrays = _entry_arrays(compiled)
+    assert ("f32", (1, 8192, 4096)) in arrays   # the parser sees the arrays
+    for ty, shape in arrays:
+        assert not (ty == "f32" and math.prod(shape) >= squares), (ty, shape)
+
+
+def test_ssd_backward_at_its_vmem_limit_compiles_for_v5e(chip):
+    """The widest call ``ssd_plan`` lets through at state 256 (352 heads
+    of 64: ``ssd_chunk_bwd``'s step counts 31.6 of its 32 MiB, most of it
+    the state cotangent of all the heads): the chip's compiler takes what
+    the plan takes, so a shape is refused by the plan's own error or not
+    at all."""
+    from mxnet_tpu.ops.ssd import (BWD_VMEM_LIMIT, bwd_step_vmem_bytes,
+                                   ssd_scan)
+    assert bwd_step_vmem_bytes(128, 8, 64, 256, 2, 352) > 0.95 * BWD_VMEM_LIMIT
 
     def loss(x, dt, a, bm, cm):
         return ssd_scan(x, dt, a, bm, cm, chunk=128, impl="pallas",
                         interpret=False).sum()
 
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
-    args = (sds((1, 8192, 64, 64), jnp.bfloat16),
-            sds((1, 8192, 64), jnp.float32), sds((64,), jnp.float32),
-            sds((1, 8192, 8, 128), jnp.bfloat16),
-            sds((1, 8192, 8, 128), jnp.bfloat16))
-    # the value keeps the forward alive: a sum's gradient needs no output
+    args = (sds((1, 512, 352, 64), jnp.bfloat16),
+            sds((1, 512, 352), jnp.float32), sds((352,), jnp.float32),
+            sds((1, 512, 1, 256), jnp.bfloat16),
+            sds((1, 512, 1, 256), jnp.bfloat16))
     compiled = jax.jit(jax.value_and_grad(
         loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
-    assert _kernels(compiled) == 1          # ssd_chunk_fwd
+    assert _kernels(compiled) == 2
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])

@@ -1,18 +1,29 @@
 #!/usr/bin/env python3
 """Time the hybrid stack's kernels alone on the chip, at the sizes of
-``train_nemotron_tt_seq8192`` (builder's tool; fails without a TPU):
+``train_nemotron_tt_seq8192`` and, for the scan, of
+``train_granite_4h_p10`` too (builder's tool; fails without a TPU):
 
 * ``moe_gmm``: up -> relu^2 -> down over the worst-case buffer of 49,152
   rows with 8 experts held, forward and backward, as the ROUTED rows vary:
   even and skewed at the expected 3,072 rows, then 6,144, 12,288 and the
   full buffer — time has to follow the rows routed, not the buffer;
-* ``ssd_chunk_fwd`` against the chunked XLA form, and the XLA backward;
+* the scan at both cells' sizes (8 B/C groups at chunk 128; one group at
+  chunk 128, which the Granite cell runs, and at its published blocking
+  of 256): the kernel path (``ssd_chunk_fwd``, ``ssd_chunk_bwd``)
+  against the chunked XLA form and JAX's derivative of it — forward,
+  forward + backward, and the backward ALONE (the pullback of ``jax.vjp``,
+  its residuals computed outside the timing), then each path's gradients
+  against the chunked form in float32.  Until PR 41 the kernel path's
+  backward was the chunked form run once more and differentiated;
 * the flash kernels with 32 query heads over 2 key/value heads at T 8,192.
 
-    chiprun -- python3 tools/hybrid_kernels_bench.py
+    chiprun -- python3 tools/hybrid_kernels_bench.py [gmm] [ssd] [flash]
+
+(no name: all three parts).
 """
 from __future__ import annotations
 
+import functools
 import json
 import statistics
 import sys
@@ -35,16 +46,9 @@ def timed(fn, *args, n=10):
     return 1e3 * statistics.median(ts)
 
 
-def main():
-    if jax.devices()[0].platform != "tpu":
-        print("no TPU", file=sys.stderr)
-        return 2
-    import mxnet_tpu  # noqa: F401
-    from mxnet_tpu.ops.attention import flash_attention
+def bench_gmm(key):
     from mxnet_tpu.ops.gmm import gmm_plan, grouped_matmul
-    from mxnet_tpu.ops.ssd import ssd_chunked, ssd_scan
 
-    key = jax.random.PRNGKey(0)
     m, u, f, held = 49152, 2688, 1856, 8
     bf = jnp.bfloat16
     rows = jax.random.normal(key, (m, u), bf)
@@ -77,28 +81,57 @@ def main():
                           "fwd_bwd_ms": timed(both, rows, w_up, w_dn, s)}),
               flush=True)
 
-    b, t, h, p, g, n = 1, 8192, 64, 64, 8, 128
-    ks = jax.random.split(key, 5)
+
+def bench_ssd(key):
+    from mxnet_tpu.ops.ssd import ssd_scan
+
+    bf = jnp.bfloat16
+    b, t, h, p, n = 1, 8192, 64, 64, 128
+    ks = jax.random.split(key, 6)
     x = jax.random.normal(ks[0], (b, t, h, p), bf)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, t, h)) - 4.0)
     a = -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.7))
-    bm = jax.random.normal(ks[3], (b, t, g, n), bf)
-    cm = jax.random.normal(ks[4], (b, t, g, n), bf)
-    for impl in ("pallas", "xla"):
-        fn = jax.jit(lambda *v, impl=impl: ssd_scan(*v, chunk=128,
-                                                    impl=impl))
-        gr = jax.jit(jax.grad(lambda *v, impl=impl: ssd_scan(
-            *v, chunk=128, impl=impl).sum(), argnums=(0, 1, 2, 3, 4)))
-        print(json.dumps({"ssd": impl,
-                          "fwd_ms": timed(fn, x, dt, a, bm, cm),
-                          "fwd_bwd_ms": timed(gr, x, dt, a, bm, cm)}),
-              flush=True)
-    y1 = jax.jit(lambda *v: ssd_scan(*v, impl="pallas"))(x, dt, a, bm, cm)
-    y2 = jax.jit(lambda *v: ssd_chunked(*v))(x, dt, a, bm, cm)
-    print(json.dumps({"ssd_pallas_vs_xla_max_gap":
-                      float(jnp.max(jnp.abs(y1 - y2))),
-                      "max": float(jnp.max(jnp.abs(y2)))}))
+    dy = jax.random.normal(ks[5], (b, t, h, p))
+    for g, chunk in ((8, 128), (1, 128), (1, 256)):
+        bm = jax.random.normal(ks[3], (b, t, g, n), bf)
+        cm = jax.random.normal(ks[4], (b, t, g, n), bf)
+        args = (x, dt, a, bm, cm)
+        grads = {}
+        for impl in ("pallas", "xla"):
+            scan = functools.partial(ssd_scan, chunk=chunk, impl=impl)
+            fn = jax.jit(scan)
+            gr = jax.jit(jax.grad(lambda *v: jnp.sum(scan(*v) * dy),
+                                  argnums=(0, 1, 2, 3, 4)))
+            y, pull = jax.jit(lambda *v: jax.vjp(scan, *v))(*args)
+            back = jax.jit(lambda pull, dy: pull(dy))
+            print(json.dumps({"ssd": impl, "groups": g, "chunk": chunk,
+                              "fwd_ms": timed(fn, *args),
+                              "fwd_bwd_ms": timed(gr, *args),
+                              "bwd_ms": timed(back, pull, dy)}), flush=True)
+            grads[impl] = (y,) + back(pull, dy)
+            del pull
+        # both paths against the chunked form in float32 on the same
+        # (bf16-rounded) operands: largest gap over largest value
+        wide = tuple(v.astype(jnp.float32) for v in args)
+        scan = functools.partial(ssd_scan, chunk=chunk, impl="xla")
+        y, pull = jax.jit(lambda *v: jax.vjp(scan, *v))(*wide)
+        ref = (y,) + jax.jit(lambda pull, dy: pull(dy))(pull, dy)
+        del pull
+        names = ("y", "dx", "ddt", "da", "db", "dc")
+        print(json.dumps({
+            "ssd_gap_to_float32": {
+                impl: {name: float(jnp.max(jnp.abs(
+                    k.astype(jnp.float32) - r)) / jnp.max(jnp.abs(r)))
+                    for name, k, r in zip(names, got, ref)}
+                for impl, got in grads.items()},
+            "groups": g, "chunk": chunk}), flush=True)
 
+
+def bench_flash(key):
+    from mxnet_tpu.ops.attention import flash_attention
+
+    bf = jnp.bfloat16
+    ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (1, 8192, 32, 128), bf)
     for hk in (2, 32):
         k = jax.random.normal(ks[1], (1, 8192, hk, 128), bf)
@@ -110,8 +143,25 @@ def main():
         print(json.dumps({"flash_kv_heads": hk,
                           "fwd_ms": timed(fn, q, k, v),
                           "fwd_bwd_ms": timed(gr, q, k, v)}), flush=True)
+
+
+def main(argv):
+    if jax.devices()[0].platform != "tpu":
+        print("no TPU", file=sys.stderr)
+        return 2
+    import mxnet_tpu  # noqa: F401
+
+    parts = {"gmm": bench_gmm, "ssd": bench_ssd, "flash": bench_flash}
+    unknown = [name for name in argv if name not in parts]
+    if unknown:
+        print(f"unknown part {unknown}: one of {list(parts)}",
+              file=sys.stderr)
+        return 2
+    key = jax.random.PRNGKey(0)
+    for name in argv or parts:
+        parts[name](key)
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))
