@@ -8,6 +8,7 @@ from . import vision
 from .bert import BERTForPretrain, BERTModel, get_bert
 from .gpt2 import GPT2Model, get_gpt2, gpt2_lm_loss
 from .granite_hybrid import GraniteHybridModel, get_granite_hybrid
+from .mellum import MellumModel, get_mellum
 from .moe import MoELayer, MoETransformerBlock, pop_aux_losses
 from .nemotron_h import NemotronHModel, get_nemotron_h
 from .phi4_flash import Phi4FlashModel, get_phi4_flash
@@ -26,4 +27,4 @@ __all__ = ["vision", "get_model", "BERTModel", "BERTForPretrain", "get_bert",
            "TransformerNMT", "TransformerDecoderBlock", "get_nmt",
            "nmt_loss", "NemotronHModel", "get_nemotron_h", "Qwen3NextModel",
            "get_qwen3_next", "GraniteHybridModel", "get_granite_hybrid",
-           "Phi4FlashModel", "get_phi4_flash"]
+           "Phi4FlashModel", "get_phi4_flash", "MellumModel", "get_mellum"]

@@ -10,6 +10,7 @@ XLA reference elsewhere.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -68,12 +69,66 @@ def _attention_ref(q, k, v, *, causal=False, mask=None, scale=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def rotary_embedding(x, positions=None, *, theta=10000.0, rotary_dim=None):
+def rope_frequencies(rope_parameters: dict, head_dim: int):
+    """``(inv_freq, amplitude)`` of one rotary table from a
+    ``rope_parameters`` entry as HF configurations publish it: a float32
+    ``(head_dim / 2,)`` array of the angles a position turns each pair by,
+    computed in float64 on the host, and the factor on cos and sin.
+
+    ``rope_type`` ``"default"``: ``rope_theta^(-2 j / head_dim)``, 1.
+    ``"yarn"`` (Peng et al. 2023, as HF ``_compute_yarn_parameters``): with
+    ``e_j`` the default angles and ``e_j / factor`` the interpolated ones,
+    ``c(b) = head_dim ln(original_max_position_embeddings / (2 pi b)) / (2
+    ln rope_theta)`` the dimension that turns ``b`` times over the original
+    context, ``low = floor(c(beta_fast))`` and ``high = ceil(c(beta_slow))``
+    clipped to the head, and ``r_j = clip((j - low) / (high - low), 0, 1)``:
+    ``e_j (1 - r_j) + e_j / factor r_j``; the amplitude is
+    ``attention_factor`` (default ``0.1 ln(factor) + 1``).  The table does
+    not depend on the sequence length.  Leaves a ``rope.plan`` event for
+    each distinct table."""
+    import numpy as np
+
+    from .flash import plan_event
+    p = rope_parameters
+    kind, theta = p.get("rope_type", "default"), float(p["rope_theta"])
+    half = head_dim // 2
+    j = np.arange(half, dtype=np.float64)
+    inv = theta ** (-2.0 * j / head_dim)
+    factor, low, high, amplitude = 1.0, 0, 0, 1.0
+    if kind == "yarn":
+        factor = float(p["factor"])
+        original = float(p["original_max_position_embeddings"])
+
+        def turns(b):
+            return (head_dim * math.log(original / (2.0 * math.pi * b))
+                    / (2.0 * math.log(theta)))
+
+        low = max(math.floor(turns(p.get("beta_fast") or 32.0)), 0)
+        high = min(math.ceil(turns(p.get("beta_slow") or 1.0)), head_dim - 1)
+        ramp = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv * (1.0 - ramp) + inv / factor * ramp
+        amplitude = p.get("attention_factor")
+        amplitude = (0.1 * math.log(factor) + 1.0 if amplitude is None
+                     else float(amplitude))
+    elif kind != "default":
+        raise ValueError(f"rope_type {kind!r} is not default or yarn")
+    plan_event("rope.plan", kind=kind, theta=theta, factor=factor, low=low,
+               high=high, amplitude=amplitude, dim=int(head_dim))
+    return inv.astype(np.float32), amplitude
+
+
+def rotary_embedding(x, positions=None, *, theta=10000.0, rotary_dim=None,
+                     inv_freq=None, amplitude=1.0):
     """Rotary positions on the first ``rotary_dim`` of x's (B, T, H, D)
     head dimensions (default: all D), the others untouched, in the
     half-rotation layout: dimensions ``i`` and ``i + rotary_dim / 2`` are
-    a pair turned by ``position * theta^(-2 i / rotary_dim)``.  Float32
-    angles; returns x's dtype.  ``positions`` (T,), default 0..T-1."""
+    a pair ``(a, b)`` turned to ``(a cos - b sin, b cos + a sin)`` by the
+    angle ``position * theta^(-2 i / rotary_dim)``.  ``inv_freq``
+    (``rotary_dim / 2`` angles a position, :func:`rope_frequencies`)
+    replaces that table, ``theta`` is then unused; ``amplitude`` multiplies
+    cos and sin BOTH, so a score of two turned vectors carries its square.
+    Float32 angles; returns x's dtype.  ``positions`` (T,), default
+    0..T-1."""
     d = x.shape[-1]
     rd = d if rotary_dim is None else int(rotary_dim)
     if rd % 2 or not 0 < rd <= d:
@@ -81,11 +136,19 @@ def rotary_embedding(x, positions=None, *, theta=10000.0, rotary_dim=None):
     half = rd // 2
     if positions is None:
         positions = jnp.arange(x.shape[1])
-    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
-                                * 2.0 / rd))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                    * 2.0 / rd))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
+        if inv_freq.shape != (half,):
+            raise ValueError(f"inv_freq {inv_freq.shape} is not the "
+                             f"({half},) pairs of rotary_dim {rd}")
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:rd]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,

@@ -426,3 +426,64 @@ def test_differential_flash_compiles_for_v5e(chip, window):
         sds((1, 8192, 40, 64)), sds((1, 8192, 20, 64)),
         sds((1, 8192, 10, 128))).compile()
     assert _kernels(compiled) == 3
+
+
+# ------------- the sliding-window expert cell's kernels at its sizes (PR 42)
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_flash_share_of_eight_compiles_for_v5e(chip, window):
+    """32 query heads over 4 key/value heads of 128 at T 8,192, under the
+    1,024 window (three layers of four) and without: blocks no taller than
+    the window, the ``dkv`` sum over a share of 8."""
+    from mxnet_tpu.ops.flash import tile_plan
+
+    plan = tile_plan(8192, 8192, 128, jnp.bfloat16, True, heads=32,
+                     kv_heads=4, window=window)
+    assert plan.block_q == 1024 and plan.group == 1
+    assert plan.tiles_run == (60 if window else 144)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16, sharding=chip)
+    from mxnet_tpu import observability as obs
+    tr = obs.enable_tracing()
+    try:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+        (event,) = tr.spans(name="flash.plan")
+    finally:
+        obs.disable_tracing()
+    assert _kernels(compiled) == 3
+    # the plan tells a windowed call from a full one by `window` alone
+    assert event.attrs.get("window") == window
+    assert (event.attrs["d"], event.attrs["tiles_run"]) == (128, plan.tiles_run)
+
+
+def test_moe_gmm_sixteen_small_experts_compile_for_v5e(chip):
+    """up, gate -> silu * -> down over the worst-case buffer of 8 x 8,192
+    rows with 16 experts of 2,304 x 896 held: a contraction of 2,304 is no
+    multiple of the plan's 512 and tiles by 384."""
+    from mxnet_tpu.ops.gmm import gmm_plan, grouped_matmul
+
+    m, u, f, held = 65536, 2304, 896, 16
+    assert gmm_plan(m, u, f) == (256, 384, 896)
+    assert gmm_plan(m, f, u) == (256, 128, 768)
+
+    def loss(rows, w_up, w_gate, w_down, sizes):
+        valid = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+        product = lambda a, b: jnp.where(valid, grouped_matmul(  # noqa: E731
+            a, b, sizes, impl="pallas", interpret=False), 0)
+        h = (jax.nn.silu(product(rows, w_gate).astype(jnp.float32))
+             * product(rows, w_up)).astype(rows.dtype)
+        return product(h, w_down).astype(jnp.float32).sum()
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)  # noqa: E731,E501
+    bf = jnp.bfloat16
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        sds((m, u), bf), sds((held, u, f), bf), sds((held, u, f), bf),
+        sds((held, f, u), bf), sds((held,), jnp.int32)).compile()
+    assert _kernels(compiled) == 9

@@ -57,6 +57,11 @@ CASES = [
     (512, 4, 2, 1, 64, 128, 160, 256, 128),     # both
     (1024, 2, 2, 2, 64, 64, 512, None, None),   # the plan's own sizes
     (512, 8, 4, 2, 128, 256, 256, 128, 128),    # head size 128
+    # head size 128, eight query heads to a key head: the window smaller
+    # than, equal to and larger than a block
+    (512, 8, 1, 1, 128, 128, 96, 128, 128),
+    (512, 8, 1, 1, 128, 128, 128, 128, 128),
+    (512, 8, 1, 1, 128, 128, 300, 128, 128),
 ]
 
 
