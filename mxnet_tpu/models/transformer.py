@@ -15,6 +15,7 @@ from typing import Optional
 from ..gluon.block import HybridBlock
 from ..gluon.nn import Dense, Dropout, GELU, LayerNorm
 from ..ops import dot_product_attention
+from ..ops.flash import KEPT_NAMES, named_residuals, plan_event
 from ..parallel.sharding import annotate
 from .. import parallel as _par
 
@@ -693,12 +694,37 @@ def _scan_blocks(blocks, x, mask, remat):
         # recomputes the whole layer.  Without remat a deep scanned stack
         # saves every intermediate per layer and OOMs HBM (BERT-large
         # batch 8 seq 512 wants >16GB of scan-saved activations).
-        policy = (jax.checkpoint_policies.checkpoint_dots
-                  if remat == "dots" else None)
-        body = jax.checkpoint(body, policy=policy)
+        body = jax.checkpoint(body, policy=_remat_policy(remat))
     idxs = jnp.arange(len(blocks), dtype=jnp.int32)
-    h, _ = jax.lax.scan(body, x.jax, (idxs, *stacked))
+    with named_residuals() as kept:
+        h, _ = jax.lax.scan(body, x.jax, (idxs, *stacked))
+    if remat:
+        for i in range(len(blocks)):    # one body: every layer keeps alike
+            _report_kept(i, kept)
     return NDArray(h)
+
+
+def _remat_policy(remat):
+    """What a recomputed block keeps beside its inputs (``remat`` is True
+    or "dots"; see :func:`run_blocks`): the residuals ``ops.flash`` names,
+    and under "dots" the matrix products' outputs as well."""
+    import jax
+
+    keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+    if remat == "dots":
+        return jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.checkpoint_dots, keep)
+    return keep
+
+
+def _report_kept(layer, kept):
+    """Event ``remat.plan`` for one checkpointed block: the named
+    residuals its differentiation met (``ops.flash.named_residuals``) and
+    their bytes; ``kept_bytes`` 0 for a block with no flash call, and for
+    a trace that is not differentiated, which keeps nothing."""
+    plan_event("remat.plan", layer=layer,
+               kept=tuple(sorted({name for name, _ in kept})),
+               kept_bytes=sum(size for _, size in kept))
 
 
 # diagnostic: how many times the scan fast path actually compiled in
@@ -712,10 +738,22 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
     homogeneous stacks under jit (one compiled body), python loop otherwise.
 
     ``scan=None`` auto-enables scanning at >=8 layers; pass True/False to
-    force.  ``remat`` wraps the scan body in jax.checkpoint (activation
-    rematerialization for long sequences / deep stacks); ``remat="dots"``
-    uses the checkpoint_dots policy (save matmul outputs, recompute only
-    elementwise — the usual best memory/FLOP point on TPU).
+    force.  ``remat`` wraps the scan body, or on the loop path each layer,
+    in jax.checkpoint (activation rematerialization for long sequences /
+    deep stacks); ``remat="dots"`` also saves matmul outputs and recomputes
+    only elementwise — the usual best memory/FLOP point on TPU.
+
+    ``remat=True`` means: recompute the block but for what only a kernel
+    can remake and costs under a few MB a ms, which is the flash kernels'
+    output and logsumexp (``ops.flash.KEPT_NAMES``; :func:`_remat_policy`).
+    At 8,192 tokens that is 35-85 MB a layer for the 2.2-6.1 ms a second
+    run of the forward kernel takes (12.0 of 294 ms a step with four
+    attention layers, 14.5 of 503 with three, 3.8-4.9 of 363-680 with
+    one: PERF.md section 6, PR 43); a scan's or a delta rule's states
+    cost ten times the bytes a ms and are recomputed.  There is no
+    argument for it: the rule depends only on whether a block holds a
+    flash call.  With a tracer on, each checkpointed block says what it
+    kept in one ``remat.plan`` event (``layer``, ``kept``, ``kept_bytes``).
 
     A layer may hand later layers more than the stream (one layer's keys
     and values for a whole cross-decoder, a scan's output as a memory):
@@ -744,6 +782,7 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
             base_key = providers[-1].key if providers else None
 
             side = {}
+            policy = _remat_policy(remat)
             for i, blk in enumerate(blocks):
                 reads = tuple(getattr(blk, "side_in", ()))
                 emits = tuple(getattr(blk, "side_out", ()))
@@ -776,10 +815,10 @@ def run_blocks(blocks, x, mask=None, scan=None, remat=False):
                             a._data, a._node = d, n
                         if base_key is not None:
                             _random.pop_trace_key()
-                policy = (jax.checkpoint_policies.checkpoint_dots
-                          if remat == "dots" else None)
-                out, emitted, new_aux = jax.checkpoint(f, policy=policy)(
-                    x.jax, *(side[name].jax for name in reads))
+                with named_residuals() as kept:
+                    out, emitted, new_aux = jax.checkpoint(f, policy=policy)(
+                        x.jax, *(side[name].jax for name in reads))
+                _report_kept(i, kept)
                 for j, v in zip(moved, new_aux):
                     aux[j]._rebind(v)
                 side.update(zip(emits, map(NDArray, emitted)))

@@ -40,15 +40,29 @@ per-row lse/delta broadcast along sublanes as they are stored.
 Sizes (``block_q``, ``chunk``, slab widths, ``major``, heads a step) are
 computed in ONE place, :func:`tile_plan`, from what the call can see:
 sequence lengths, head dim, dtype, masks, head count.
+
+Of the five residuals the backward reads (q, k, v, out, lse), ``out`` and
+``lse`` are the two only the forward KERNEL can remake, and the smallest
+arrays of an attention layer.  The differentiation rule's forward
+(:func:`_flash_fwd`) therefore passes them through
+``jax.ad_checkpoint.checkpoint_name`` as :data:`KEPT_NAMES`, so that a
+``jax.checkpoint`` whose policy saves those names
+(``models.transformer.run_blocks`` under ``remat``) recomputes a layer's
+projections but not its attention.  Outside such a checkpoint a name is
+the identity and nothing in plain use shows it: the names are not
+decoration, and a step without them runs every forward kernel twice.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -982,10 +996,42 @@ def _flash(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
     return out
 
 
+# What a recomputed layer keeps of a flash call (the module docstring says
+# why; ``models.transformer.run_blocks`` what it buys).
+FLASH_OUT, FLASH_LSE = KEPT_NAMES = ("flash_out", "flash_lse")
+
+_named = contextvars.ContextVar("flash_named_residuals", default=None)
+
+
+@contextlib.contextmanager
+def named_residuals():
+    """Yields a list that receives ``(name, bytes)`` for every residual
+    :func:`_flash_fwd` names while the body runs, which is while a call
+    under it is DIFFERENTIATED (the rule's forward is traced then and
+    not before).  ``run_blocks`` reports a checkpoint's ``remat.plan``
+    from it; trace time only."""
+    found = []
+    token = _named.set(found)
+    try:
+        yield found
+    finally:
+        _named.reset(token)
+
+
+def _name(x, name):
+    found = _named.get()
+    if found is not None:
+        found.append((name, x.size * x.dtype.itemsize))
+    return checkpoint_name(x, name)
+
+
 def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
                interpret, window=None):
     out, lse = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
                     interpret, window)
+    # named HERE and nowhere else (the rule's forward: the one place
+    # whose outputs are the backward's residuals): do not tidy away
+    out, lse = _name(out, FLASH_OUT), _name(lse, FLASH_LSE)
     return out, (q, k, v, q_seg, kv_seg, out, lse)
 
 
