@@ -30,6 +30,18 @@ of 64 128-wide tiles backward — where a grid of one 1,024 x 1,024 tile a
 head skipped nothing.  With segment ids every slice masks by segment,
 and a slice whose id range cannot meet the block's is skipped.
 
+A sliding ``window`` gives the band a block sees a second edge,
+``window - 1`` behind the diagonal, and that edge is the diagonal
+mirrored (:func:`_band`): the columns older than the block's own square
+are cut into the same slabs, each run on the rows that still see any of
+it, and only the rows that see a slab in part compare ``q - k <
+window``; between the two edges (a window wider than the block) runs the
+same plain loop; what is older than the window is never visited.  At
+T = 8,192 under a 1,024-key window a head runs 45 of 256 forward tiles
+(its mask lets 30 tiles' worth of pairs through) and 540 of 4,096
+backward, where walking the band's bounding box in masked chunks of
+every row ran 60 and 960.
+
 The backward pass is the standard flash-attention-2 split: a ``dq``
 kernel (q blocks, walking kv) and a ``dkv`` kernel (kv blocks, walking
 q), both re-computing the tile of probabilities from the saved per-row
@@ -57,6 +69,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import itertools
 import math
 from typing import NamedTuple, Optional
 
@@ -84,6 +97,18 @@ from jax.experimental.pallas import tpu as pltpu
 # 6.4 ms, chunks of 512 in 7.0 (the parent: 8.5).  Packed documents of
 # 128-512 tokens want block and chunk both at 256 (1.16 ms; 1.35 at block
 # 512, 1.65 at 1,024).
+# Under a window (PERF.md §6, PR 44: T 8,192 bf16, one call of fwd / dq /
+# dkv in ms, dq with its delta and dkv with the sum over a share of query
+# heads).  32 heads of 128 over 4, window 1,024, blocks of 1,024: the
+# band's bounding box in masked 256-chunks of every row 2.46 / 3.09 /
+# 4.31; by the two edges with slabs of 512 fwd and 128 bwd 1.52 / 1.95 /
+# 2.69; fwd slabs of 256 / 128 give 1.46 / 1.51, bwd slabs of 256 2.02 /
+# 2.85.  40 heads of 64 over 20, values 10 heads of 128, window 512,
+# blocks of 512: 2.48 / 3.29 / 4.36 before; 1.81 / 2.71 / 3.44 with 512
+# and 128; fwd slabs of 256 / 128 give 2.05 / 2.00 (fewer tiles, and
+# slower: at D = 64 the forward's time is in the per-row state each slab
+# updates), bwd slabs of 256 2.73 / 3.59.  So a window keeps the widths
+# the diagonal alone chose: no reading moved by more than 4% for them.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_CHUNK = 256
 DEFAULT_SLAB = 512
@@ -92,6 +117,9 @@ DEFAULT_SEG = 256
 DEFAULT_GROUP = 4
 _MASK = -1e30
 _LANES = 128
+# which comparisons a visit's masked rows make, (diagonal, trailing edge):
+# without a window only ever the first
+_DIAGONAL = (True, False)
 
 # ~16 MiB VMEM per v4/v5e core; budget leaves headroom for compiler
 # temporaries/semaphores so the clamp errs safe rather than tight.
@@ -201,7 +229,8 @@ def _schedule(i, block: int, chunk: int, slab: int, total: int,
     walking kv at or before it (fwd, dq) rather than of kv rows walking q
     at or after it (dkv).  Without ``causal`` there is no diagonal: chunk
     0 leads, unmasked, so that something is assigned before the loop
-    adds."""
+    adds.  Under a window the band has a second edge and the schedule is
+    :func:`_band`'s."""
     whole = (0, block)
     if not causal:
         return [(0, chunk, whole, None)], (1, total)
@@ -211,33 +240,89 @@ def _schedule(i, block: int, chunk: int, slab: int, total: int,
     return diagonal, ((0, i * per) if up else ((i + 1) * per, total))
 
 
-def _window_chunks(i, block: int, chunk: int, total: int, window: int,
-                   up: bool):
-    """The (lo, hi) range of chunks block ``i`` visits under a causal
-    ``window`` (query t sees keys s with 0 <= t - s < window), each with
-    the mask: for a block of q rows walking kv (``up``) from the chunk of
-    the oldest key its first row sees to the end of its own square; for
-    a block of kv rows walking q from its own square to the chunk of the
-    last query that sees its last row.  ``i`` may be traced."""
-    per = block // chunk
-    if up:
-        return jnp.maximum(i * block - window + 1, 0) // chunk, (i + 1) * per
-    return i * per, jnp.minimum(((i + 1) * block + window - 2) // chunk + 1,
-                                total)
+def _band(block: int, chunk: int, slab: int, window: int, up: bool):
+    """What a block visits under a causal ``window`` (query t sees keys s
+    with 0 <= t - s < window), as ``(slabs, plain)``, every number static
+    and every column counted from the block's own first position.
+
+    The band a block sees has two edges: the diagonal, and ``window - 1``
+    behind it the trailing edge, which is the diagonal mirrored.  For a
+    block of q rows walking kv (``up``) row r sees columns
+    r - window + 1 .. r, so:
+
+    * its own square, columns 0 .. block, is cut into slabs as
+      :func:`_schedule` cuts it, each on the rows that see any of it
+      (where the window is shorter than the block the rows far below a
+      slab have left it behind and stay out);
+    * ``plain`` columns straight behind the square, a whole number of
+      chunks AND of slabs, are what every row sees whole (nothing unless
+      the window is wider than the block): the loop whose body holds no
+      mask code;
+    * the trailing edge, from there back to column 1 - window, is cut the
+      same way: a slab takes the rows from the block's first to the last
+      that still sees any of it, and only the rows that see it in part
+      compare ``q - k < window``.
+
+    A slab is ``(first column, width, rows, masked, sides)``: ``rows`` the
+    (lo, hi) rows that take part, ``masked`` the (lo, hi) among them that
+    build a mask (None: none), ``sides`` = (diagonal, trailing) which
+    comparisons that mask holds.  Row ranges are rounded outwards to whole
+    lane tiles, so a window that is no multiple of anything costs at most
+    127 rows a slab and a rounded-in row is always a masked one.  The
+    own square's slabs come first.  For a block of kv rows walking q the
+    picture is the same one turned round (row r is seen by columns
+    r .. r + window - 1): the slabs are mirrored in the block's centre and
+    ``plain`` lies straight after the square."""
+    def down(x):                       # to whole lane tiles
+        return x // _LANES * _LANES
+
+    def up_to(x):
+        return -down(-x)
+
+    def mirror(lo, hi):
+        return block - hi, block - lo
+
+    step = math.lcm(chunk, slab)
+    plain = max(window - block, 0) // step * step
+    older = -(-(window - 1 - plain) // slab)       # slabs of trailing edge
+    slabs = []
+    for c0 in [*range(0, block, slab),
+               *(-plain - slab * j for j in range(1, older + 1))]:
+        last = c0 + slab - 1               # row r sees r - window + 1 .. r
+        r0, r1 = max(c0, 0), min(block, up_to(last + window))
+        # rows r0..d1 meet the diagonal, rows e0..r1 the trailing edge
+        d1 = min(max(up_to(last), r0), r1)
+        e0 = min(max(down(c0 + window), r0), r1)
+        sides = (r0 < d1, e0 < r1)
+        masked = ((r0, r1) if all(sides) else (r0, d1) if sides[0]
+                  else (e0, r1) if sides[1] else None)
+        rows = (r0, r1)
+        if not up:
+            c0, rows = block - c0 - slab, mirror(*rows)
+            masked = masked and mirror(*masked)
+        slabs.append((c0, slab, rows, masked, sides))
+    return slabs, plain
 
 
 def _count_tiles(tq, tk, block, chunk, slab, causal, whole, window=None):
     """(visited, whole square, masked) in (slab, slab) tiles of one head,
     by the schedule the kernels run; without the ``whole`` operand in
     one stretch every chunk the diagonal crosses takes every row.  Under
-    a ``window`` every visit is a masked chunk of every row."""
+    a ``window`` the visits are :func:`_band`'s, in one stretch or in
+    several: each slab by the rows that take part in it and masked by the
+    rows that build a mask, the slabs that would lie before key 0 left
+    out."""
     run = masked = 0
     if window:
-        for i in range(tq // block):
-            lo = max(i * block - window + 1, 0) // chunk
-            run += ((i + 1) * (block // chunk) - lo) * chunk * block
-        run //= slab * slab
-        return run, (tq // slab) * (tk // slab), run
+        slabs, plain = _band(block, chunk, slab, window, True)
+        for first in range(0, tq, block):
+            run += min(plain, first) * block
+            for c0, width, (r0, r1), rows, _ in slabs:
+                if first + c0 >= 0:
+                    run += (r1 - r0) * width
+                    masked += (rows[1] - rows[0]) * width if rows else 0
+        return (run // slab ** 2, (tq // slab) * (tk // slab),
+                masked // slab ** 2)
     for i in range(tq // block):
         diagonal, (lo, hi) = _schedule(i, block, chunk, slab, tk // chunk,
                                        causal, True)
@@ -280,8 +365,10 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     by an index map.  ``dv`` is the values' head dim where it is not the
     keys' (VMEM is reckoned at the wider).  Under a causal ``window`` a
     block is no taller than the window (what its rows see of older keys
-    is then at most a window wide) and walks only the chunks
-    :func:`_window_chunks` names, all with the mask."""
+    is then at most a window wide) and walks its band by the two edges
+    (:func:`_band`): the trailing edge is cut into the same slabs as the
+    diagonal, narrowed where they must be to lie in one stretch whole,
+    and the counts are of that schedule."""
     span = math.gcd(tq, tk)
     if window is not None and (not causal or has_seg or window < 1):
         raise ValueError("a window needs causal=True, no segment ids and "
@@ -328,7 +415,9 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     block, major, major_q, group = _clamp_blocks(
         blocks, chunk, tq, tk, d, jnp.dtype(dtype).itemsize, has_seg, group,
         wide)
-    slab, slab_bwd = (fit(x, block) for x in slabs)
+    # a slab under a window lies in one stretch whole (_walk's sections)
+    within = math.gcd(block, major, major_q) if window else block
+    slab, slab_bwd = (fit(x, within) for x in slabs)
     run, full, masked = _count_tiles(tq, tk, block, chunk, slab, causal,
                                      major == tk, window)
     run_bwd, full_bwd, _ = _count_tiles(tq, tk, block, chunk, slab_bwd,
@@ -397,12 +486,13 @@ def _default_interpret(x) -> bool:
 
 def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
           seg, window=None):
-    """Run ``step(start, width, rows, masked, fresh)`` over the slices
-    ``[start, start + width)`` of this grid step's major stretch that
-    block ``i`` has to visit: ``rows`` the block's rows that take part,
-    ``masked`` those of them that need the position mask (None: none),
-    ``fresh`` those whose accumulators this visit is the first to touch,
-    so that it assigns them where later visits add (None: none).
+    """Run ``step(start, width, rows, masked, fresh, sides)`` over the
+    slices ``[start, start + width)`` of this grid step's major stretch
+    that block ``i`` has to visit: ``rows`` the block's rows that take
+    part, ``masked`` those of them that need the position mask (None:
+    none), ``fresh`` those whose accumulators this visit is the first to
+    touch, so that it assigns them where later visits add (None: none),
+    ``sides`` which comparisons the mask makes (:func:`_keep`).
 
     With the whole operand in one stretch (``nm == 1``) the visits are
     those of :func:`_schedule`: the diagonal's slabs — a static number —
@@ -423,22 +513,28 @@ def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
     non-decreasing along the row), conservative (never skips a slice
     that could match) for arbitrary ids.
 
-    Under a ``window`` the visits are the chunks of
-    :func:`_window_chunks`, every one with the mask and every row,
-    clipped to the stretch; the chunks older than the window are never
-    visited."""
+    Under a ``window`` the visits are :func:`_band`'s, in one stretch or
+    in several: the slabs of the own square and of the trailing edge
+    straight-line, each on its own rows, ``step`` told which of the two
+    comparisons its masked rows need (``sides``); then the loop over what
+    every row sees whole between the two.  Only a slab's start in the stretch
+    is traced.  The slabs are run a *section* at a time — an aligned run
+    of columns that divides block and stretch, so that it lies in one
+    stretch whole — under one branch a section: "is it in this stretch"
+    (which also says no to what would lie before key 0 or after the last
+    query).  What is older than the window is never visited."""
     whole = (0, block)
 
-    def visit(start, width, rows, masked, fresh):
+    def visit(start, width, rows, masked, fresh, sides=_DIAGONAL):
         if seg is None:
-            return step(start, width, rows, masked, fresh)
+            return step(start, width, rows, masked, fresh, sides)
         mine, ref = seg
         theirs = ref[0, :, pl.ds(start, width)]
 
         @pl.when(jnp.logical_and(jnp.min(theirs) <= jnp.max(mine),
                                  jnp.max(theirs) >= jnp.min(mine)))
         def _():
-            step(start, width, rows, masked, fresh)
+            step(start, width, rows, masked, fresh, sides)
 
     def loop(lo, hi, masked):
         def body(c, carry):
@@ -448,9 +544,25 @@ def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
         jax.lax.fori_loop(lo, hi, body, 0)
 
     if window:
-        a, b = _window_chunks(i, block, chunk, nm * cpm, window, up)
-        lo = mi * cpm
-        loop(jnp.clip(a - lo, 0, cpm), jnp.clip(b - lo, 0, cpm), whole)
+        stretch = cpm * chunk
+        section = math.gcd(block, stretch)
+        slabs, plain = _band(block, chunk, slab, window, up)
+        own0 = i * block - mi * stretch    # the block's first row, in here
+
+        def run(slabs):
+            for c0, width, rows, masked, sides in slabs:
+                visit(pl.multiple_of(own0 + c0, width), width, rows, masked,
+                      None, sides)
+
+        for k, held in itertools.groupby(slabs, lambda x: x[0] // section):
+            first = own0 + k * section
+            own = nm == 1 and 0 <= k * section < block
+            _when(own, jnp.logical_and(first >= 0, first < stretch))(
+                functools.partial(run, list(held)))
+        if plain:                          # straight behind / after the square
+            a = (own0 - plain if up else own0 + block) // chunk
+            loop(jnp.clip(a, 0, cpm), jnp.clip(a + plain // chunk, 0, cpm),
+                 None)
         return
     diagonal, plain = _schedule(i, block, chunk, slab, nm * cpm, causal, up)
     if nm > 1:
@@ -475,15 +587,17 @@ def _walk(step, *, causal, up, i, mi, nm, block, chunk, slab, cpm, fold,
 
 
 def _keep(rows, masked, width, own_is_q, own0, walk0, seg_own, seg_walk,
-          window=None):
+          window=None, sides=_DIAGONAL):
     """The mask of one visit's score tile — (block rows ``rows``, ``width``
     walked columns) — as ``(keep, sub)``: ``keep`` covers the tile's
     rows ``sub`` = (lo, hi) only, or is None when nothing masks the tile.
     Rows ``masked`` of the block (None: none) lie on the diagonal and
     compare global positions, ``kv <= q``: the block's own run down the
     tile from ``own0``, the walked operand's along it from ``walk0``, and
-    ``own_is_q`` says which of the two are q's; under a ``window`` they
-    also compare ``q - kv < window``.  Segment ids
+    ``own_is_q`` says which of the two are q's.  ``sides`` =
+    (diagonal, trailing) says which comparisons they make: ``kv <= q``,
+    and under a ``window`` ``q - kv < window`` on the band's other edge
+    (:func:`_band` knows which rows of a slab meet which).  Segment ids
     (``seg_own`` a (block, 1) column, ``seg_walk`` a (1, width) row) mask
     every row, where the call packs segments."""
     if seg_own is None and masked is None:
@@ -494,10 +608,12 @@ def _keep(rows, masked, width, own_is_q, own0, walk0, seg_own, seg_walk,
         shape = (m1 - m0, width)
         own = own0 + m0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         walk = walk0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        keep = walk <= own if own_is_q else own <= walk
-        if window:
-            keep = jnp.logical_and(
-                keep, (own - walk if own_is_q else walk - own) < window)
+        diagonal, trailing = sides
+        if diagonal:
+            keep = walk <= own if own_is_q else own <= walk
+        if trailing:
+            near = (own - walk if own_is_q else walk - own) < window
+            keep = near if keep is None else jnp.logical_and(keep, near)
     if seg_own is not None:
         same = seg_own[m0:m1] == seg_walk
         keep = same if keep is None else jnp.logical_and(keep, same)
@@ -592,12 +708,12 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
 
     qs = qseg_ref[0, 0, :][:, None] if has_seg else None   # (bq, 1)
 
-    def step(start, width, rows, masked, fresh):
+    def step(start, width, rows, masked, fresh, sides):
         r0, r1 = rows
         keep, sub = _keep(rows, masked, width, True, qi * block_q,
                           mi * major + start, qs,
                           kseg_ref[0, :, pl.ds(start, width)] if has_seg
-                          else None, window)
+                          else None, window, sides)
         # the group's heads are independent chains of matmul → softmax →
         # matmul: side by side in one region they fill each other's
         # latencies
@@ -611,16 +727,23 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
                 else m_ref[g, r0:r1, :]                # (rows, 128)
             m_next = jnp.maximum(m_prev, m_cur)
             p = jnp.exp(s - _lanes(m_next, width))
-            if has_seg or window:
+            if has_seg:
                 # masked-safe exp: a row whose every entry so far is
-                # masked (its segment starts in a LATER chunk; the oldest
-                # chunk of its block's window lies before its own) has
+                # masked (its segment starts in a LATER chunk) has
                 # m_next == _MASK, and bare exp(s - m_next) would
                 # contribute exp(0)=1 per masked entry.  Zero masked
                 # entries explicitly.  The causal mask alone never needs
                 # this: every row sees a column of the first chunk it
                 # visits (see _walk), so m_next is finite from then on
-                # and exp(_MASK - m_next) is exactly 0.
+                # and exp(_MASK - m_next) is exactly 0.  Nor does a
+                # window.  A visit takes the rows that see part of it and
+                # the few that rounding to lane tiles brings in; such a
+                # row can meet a slab it sees nothing of before any key it
+                # does see only where its trailing edge lies in an
+                # earlier stretch than its own square.  What it gathers
+                # there (1 a masked entry, under m == _MASK) is finite,
+                # and every row sees its own position: that visit
+                # multiplies it by exp(_MASK - m_next) == 0.
                 p = jnp.where(keep, p, 0.0)
             v = v_ref[g, pl.ds(start, width), :]
             pv = _dot(p.astype(v.dtype), v, 1, 0)      # (rows, d)
@@ -801,12 +924,12 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
 
     qs = qseg_ref[0, 0, :][:, None] if has_seg else None
 
-    def step(start, width, rows, masked, fresh):
+    def step(start, width, rows, masked, fresh, sides):
         r0, r1 = rows
         keep, sub = _keep(rows, masked, width, True, qi * block_q,
                           mi * major + start, qs,
                           kseg_ref[0, :, pl.ds(start, width)] if has_seg
-                          else None, window)
+                          else None, window, sides)
         for g in range(group):
             k = k_ref[g, pl.ds(start, width), :]       # (width, d)
             s = _dot(q_ref[g, r0:r1, :], k, 1, 1) * scale  # (rows, width)
@@ -853,12 +976,12 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
 
     ks = kseg_ref[0, 0, :][:, None] if has_seg else None   # (bk, 1)
 
-    def step(start, width, rows, masked, fresh):
+    def step(start, width, rows, masked, fresh, sides):
         r0, r1 = rows
         keep, sub = _keep(rows, masked, width, False, ki * block_k,
                           mi * major + start, ks,
                           qseg_ref[0, :, pl.ds(start, width)] if has_seg
-                          else None, window)
+                          else None, window, sides)
         for g in range(group):
             q = q_ref[g, pl.ds(start, width), :]       # (width, d)
             do = do_ref[g, pl.ds(start, width), :]
@@ -988,11 +1111,26 @@ def _int_zero_cotangent(x):
     return _np.zeros(x.shape, _dtypes.float0)
 
 
+# A windowed block's body is straight-line code for two edges, and a step
+# traces it once a CALL: its layers, their backward, the abstract forward
+# of a trainer's ``settle``, every trace of the step.  Mellum's three
+# windowed layers so added 8 s to a 51 s set-up.  Under ``jax.jit`` a
+# kernel's body is traced once a shape; ``inline``, so that the calling
+# jaxpr holds the kernels' own equations as it did.  Only under a window:
+# the same wrappers around a call without one cost GPT-2's set-up 1.3 s
+# of tracing on the chip's host in every pair of ten read, and with them
+# off it read the parent's to 0.2 s (PERF.md §6, PR 44).
+_fwd_once = jax.jit(_fwd, static_argnums=(5, 6, 7, 8, 9, 10), inline=True)
+_bwd_once = jax.jit(_bwd_impl, static_argnums=(8, 9, 10, 11, 12, 13),
+                    inline=True)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
            window=None):
-    out, _ = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
-                  interpret, window)
+    out, _ = (_fwd_once if window else _fwd)(
+        q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
+        window)
     return out
 
 
@@ -1027,8 +1165,9 @@ def _name(x, name):
 
 def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
                interpret, window=None):
-    out, lse = _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
-                    interpret, window)
+    out, lse = (_fwd_once if window else _fwd)(
+        q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
+        window)
     # named HERE and nowhere else (the rule's forward: the one place
     # whose outputs are the backward's residuals): do not tidy away
     out, lse = _name(out, FLASH_OUT), _name(lse, FLASH_LSE)
@@ -1037,8 +1176,9 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
 
 def _flash_bwd(nheads, causal, scale, plan, interpret, window, res, do):
     q, k, v, q_seg, kv_seg, out, lse = res
-    dq, dk, dv = _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads,
-                           causal, scale, plan, interpret, window)
+    dq, dk, dv = (_bwd_once if window else _bwd_impl)(
+        q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale, plan,
+        interpret, window)
     return (dq, dk, dv,
             _int_zero_cotangent(q_seg), _int_zero_cotangent(kv_seg))
 

@@ -3,8 +3,13 @@ the kernels in interpret mode against the masked XLA path, forward and
 gradients, at windows smaller than, equal to and larger than a chunk,
 over one stretch and several, with grouped queries and with a
 differential head's layout (values half as many heads, twice as wide);
-the chunks the window hides are not visited (``tile_plan``'s counts); a
-call without either plans bit for bit as it did."""
+a windowed block walks its band by the two edges (trapezoid slabs on the
+diagonal and on the trailing edge, the plain loop between), so windows
+that are no multiple of anything, wider than a block, as wide as the
+sequence, of one key, and bands that straddle two stretches; the tiles a
+windowed call runs stay close to the pairs its mask lets through
+(``tile_plan``'s counts); a call without either plans bit for bit as it
+did."""
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -62,6 +67,18 @@ CASES = [
     (512, 8, 1, 1, 128, 128, 96, 128, 128),
     (512, 8, 1, 1, 128, 128, 128, 128, 128),
     (512, 8, 1, 1, 128, 128, 300, 128, 128),
+    # what the two-edged walk can get wrong.  The plan's own sizes under
+    # windows that are no multiple of the chunk: blocks of 256 with two
+    # slabs of trailing edge, and blocks of 512 whose backward has a
+    # plain chunk between the edges
+    (1024, 2, 2, 2, 64, 64, 300, None, None),
+    (1024, 1, 1, 1, 64, 64, 1000, None, None),
+    (512, 2, 2, 2, 64, 64, 512, 128, 128),      # the sequence: three plain
+    (512, 2, 1, 1, 64, 64, 384, 128, 128),      # a whole number of blocks
+    (256, 2, 2, 2, 64, 64, 1, 128, 128),        # one key
+    (256, 2, 2, 2, 64, 64, 128, 256, 128),      # one block: no older key
+    (256, 2, 2, 2, 64, 64, 40, 256, 128),       # both edges in every slab
+    (512, 40, 20, 10, 64, 128, 160, 128, 128),  # SambaY's heads and widths
 ]
 
 
@@ -73,20 +90,58 @@ def test_against_the_masked_xla_path(t, h, hk, hv, d, dv, window, block_q,
                   block_k=chunk))
 
 
-@pytest.mark.parametrize("t,h,hk,hv,d,dv,window", [
-    (1024, 4, 2, 1, 64, 128, 200), (1024, 2, 2, 2, 64, 64, 384),
-    (1024, 4, 2, 1, 64, 128, None)])
-def test_over_several_stretches(monkeypatch, t, h, hk, hv, d, dv, window):
-    """A VMEM budget that holds one chunk of the walked operand: the third
-    grid axis walks eight stretches, the window's clamps name the ones a
-    block needs."""
-    monkeypatch.setattr(flash, "_VMEM_BUDGET", 1_300_000)
+@pytest.mark.parametrize("t,h,hk,hv,d,dv,window,budget,major", [
+    (1024, 4, 2, 1, 64, 128, 200, 1_300_000, 128),
+    (1024, 2, 2, 2, 64, 64, 384, 1_300_000, 128),
+    (1024, 4, 2, 1, 64, 128, None, 1_300_000, 128),
+    # a window of one block: every block's trailing edge lies in the
+    # stretch before its own square, where its last row sees nothing
+    (1024, 2, 2, 2, 64, 64, 128, 1_300_000, 128),
+    # stretches of two and of four blocks: a band lies in its block's own
+    # stretch or straddles the one before; the plain loop crosses three
+    (1024, 2, 2, 2, 64, 64, 300, 1_500_000, 256),
+    (1024, 2, 2, 2, 64, 64, 1000, 2_500_000, 512),
+    (1024, 4, 2, 1, 64, 128, 200, 2_500_000, 512)])
+def test_over_several_stretches(monkeypatch, t, h, hk, hv, d, dv, window,
+                                budget, major):
+    """A VMEM budget that holds a chunk or a few of the walked operand:
+    the third grid axis walks the stretches, the window's clamps name the
+    ones a block needs."""
+    monkeypatch.setattr(flash, "_VMEM_BUDGET", budget)
     plan = tile_plan(t, t, d, jnp.float32, True, heads=h, kv_heads=hk,
                      block_q=128, chunk=128, window=window, dv=dv,
                      v_heads=hv)
-    assert plan.major == 128 and plan.major_q == 128
+    assert plan.major == major and plan.major_q == major
     q, k, v, w = _qkv(t, h, hk, hv, d, dv)
     _close(*_both(q, k, v, w, window=window, block_q=128, block_k=128))
+
+
+@pytest.mark.parametrize("window", [300, 640])
+def test_a_stretch_that_is_no_whole_number_of_blocks(window):
+    """1,536 keys in stretches of 384 under blocks of 256: a block's own
+    square can straddle two stretches, so the slabs run in sections of
+    128, each in one stretch whole."""
+    t, heads = 1536, 2
+    q, k, v, w = _qkv(t, heads, heads, heads, 64, 64)
+    plan = tile_plan(t, t, 64, jnp.float32, True, heads=heads, block_q=256,
+                     chunk=128, window=window)._replace(major=384,
+                                                        major_q=384)
+    assert (plan.block_q, plan.slab, plan.slab_bwd) == (256, 128, 128)
+
+    def flat(x):
+        return x.transpose(0, 2, 1, 3).reshape(heads, t, 64)
+
+    def kernel(q, k, v):
+        out = flash._flash(flat(q), flat(k), flat(v), None, None, heads,
+                           True, 0.125, plan, True, window)
+        return out.reshape(1, heads, t, 64).transpose(0, 2, 1, 3)
+
+    def run(f):
+        return f(q, k, v), jax.grad(lambda *o: jnp.sum(f(*o) * w),
+                                    argnums=(0, 1, 2))(q, k, v)
+
+    _close(run(kernel), run(lambda *o: _attention_ref(
+        *o, causal=True, window=window)))
 
 
 def test_window_of_one_key_is_the_value_itself():
@@ -99,18 +154,64 @@ def test_the_chunks_outside_the_window_are_not_visited():
     """At the benchmark's size (8,192 tokens, 40 heads of 64 over 20, a
     value of 128) a 512-key window runs 31 of the 256 (512, 512) tiles a
     head where the causal mask alone runs 144, in blocks no taller than
-    the window."""
+    the window; backward, in (128, 128) tiles, the two edges' trapezoids
+    run 310 where the band's bounding boxes held 496."""
     kw = dict(heads=40, kv_heads=20, dv=128, v_heads=10)
     full = tile_plan(8192, 8192, 64, jnp.bfloat16, True, **kw)
     win = tile_plan(8192, 8192, 64, jnp.bfloat16, True, window=512, **kw)
     assert (full.block_q, full.tiles_run, full.tiles_full) == (1024, 144, 256)
-    assert (win.block_q, win.chunk) == (512, 256)
+    assert (win.block_q, win.chunk, win.slab) == (512, 256, 512)
     assert (win.tiles_run, win.tiles_full) == (31, 256)
+    # a block as wide as its forward slab: each edge is one tile
     assert win.tiles_masked == win.tiles_run
-    assert win.tiles_run_bwd * 16 == win.tiles_run * 256   # (128, 128) tiles
-    # a window as wide as the sequence skips nothing more than the mask
-    wide = tile_plan(1024, 1024, 64, jnp.bfloat16, True, window=1024)
-    assert wide.tiles_run == wide.tiles_full
+    assert (win.tiles_run_bwd, win.tiles_full_bwd) == (310, 4096)
+    # a window as wide as the sequence skips what the mask alone skips
+    assert tile_plan(1024, 1024, 64, jnp.bfloat16, True, window=1024) \
+        == tile_plan(1024, 1024, 64, jnp.bfloat16, True)
+
+
+# heads, key heads, value heads, d, dv, window, and how many times the
+# pairs the mask lets through the tiles may hold, forward and backward:
+# the two windowed cells.  SambaY's block is one forward slab wide, so
+# each of its forward edges is a whole (512, 512) tile (the chip ran two
+# 256-wide slabs an edge slower, flash.py's table), and four 128-wide
+# backward slabs cut a 512-key edge more coarsely than eight a 1,024-key
+# one
+@pytest.mark.parametrize("h,hk,hv,d,dv,window,fwd,bwd", [
+    (32, 4, 4, 128, 128, 1024, 1.6, 1.2),
+    (40, 20, 10, 64, 128, 512, 2.0, 1.25)])
+def test_a_windowed_call_runs_little_more_than_its_mask_lets_through(
+        h, hk, hv, d, dv, window, fwd, bwd):
+    """From ``tile_plan`` alone: the (query, key) pairs of the tiles an
+    8,192-token call runs against the pairs ``0 <= q - k < window`` holds
+    (the band's bounding boxes, which the walk before this one ran, held
+    2.0 times forward and backward at Mellum's sizes and 2.0 at
+    SambaY's)."""
+    t = 8192
+    kw = dict(heads=h, kv_heads=hk, v_heads=hv, dv=dv)
+    plan = tile_plan(t, t, d, jnp.bfloat16, True, window=window, **kw)
+    pairs = sum(min(i + 1, window) for i in range(t))
+    assert pairs <= plan.tiles_run * plan.slab ** 2 <= fwd * pairs
+    assert pairs <= plan.tiles_run_bwd * plan.slab_bwd ** 2 <= bwd * pairs
+    assert plan.tiles_masked <= plan.tiles_run
+    full = tile_plan(t, t, d, jnp.bfloat16, True, **kw)
+    assert plan.tiles_run_bwd < 0.25 * full.tiles_run_bwd
+
+
+def test_mellums_windowed_call_by_the_new_schedule():
+    """32 heads of 128 over 4 under a 1,024-key window at T 8,192 (the
+    call of three layers in four of ``train_mellum2_seq8192``): 45 of 256
+    forward tiles where the bounding boxes ran 60, of which the 30 on an
+    edge build a mask; the same call without the window as before."""
+    kw = dict(heads=32, kv_heads=4)
+    win = tile_plan(8192, 8192, 128, jnp.bfloat16, True, window=1024, **kw)
+    assert (win.block_q, win.chunk, win.slab, win.slab_bwd, win.major) \
+        == (1024, 256, 512, 128, 4096)
+    assert (win.tiles_run, win.tiles_masked, win.tiles_run_bwd) \
+        == (45, 30, 540)
+    full = tile_plan(8192, 8192, 128, jnp.bfloat16, True, **kw)
+    assert (full.tiles_run, full.tiles_run_bwd, full.tiles_masked) \
+        == (144, 2304, 32)
 
 
 def test_a_window_needs_a_causal_unpacked_call():

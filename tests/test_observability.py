@@ -1114,3 +1114,36 @@ def test_flash_plan_is_an_event_once_per_plan():
                 for s in tr.spans(name="flash.plan")] == [10, 16]
     finally:
         obs.disable_tracing()
+
+
+def test_flash_plan_of_a_windowed_call_masks_less_than_it_runs():
+    """Under a window a block walks its band by the two edges, and only
+    the tiles ON an edge build a mask: ``tiles_masked`` of the
+    ``flash.plan`` event is smaller than ``tiles_run`` (before PR 44
+    every visit was masked and the two were equal), and both are under
+    the causal mask's own."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.flash import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.float32)
+
+    def call(x, window):
+        return flash_attention(x, x, x, causal=True, window=window,
+                               interpret=True)
+
+    tr = obs.enable_tracing()
+    try:
+        for window in (1024, None):            # traced only: nothing runs
+            jax.eval_shape(lambda x: call(x, window), q)
+        windowed, causal = (e.attrs for e in tr.spans(name="flash.plan"))
+    finally:
+        obs.disable_tracing()
+    assert windowed["window"] == 1024 and "window" not in causal
+    assert (windowed["block_q"], windowed["slab"]) == (512, 512)
+    # (512, 512) tiles of four blocks: a block's own square, the square
+    # behind it that every row sees whole, and one on the trailing edge
+    assert (windowed["tiles_run"], windowed["tiles_masked"]) == (9, 6)
+    assert (causal["tiles_run"], causal["tiles_masked"]) == (10, 4)
+    assert (windowed["tiles_run_bwd"], causal["tiles_run_bwd"]) == (108, 136)
