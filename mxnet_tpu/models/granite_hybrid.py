@@ -31,14 +31,11 @@ from __future__ import annotations
 
 import jax
 
-from .. import parallel as _par
 from ..gluon.block import HybridBlock
-from ..gluon.nn import Embedding, RMSNorm
-from ..ndarray.ops import invoke
+from ..gluon.nn import RMSNorm
 from ..ops.flash import plan_event
-from ..parallel.sharding import annotate
-from .hybrid_common import dense as _dense, lm_loss, rms as _rms
-from .moe import amp_compute_dtype as _compute_dtype
+from .hybrid_common import (HybridDecoder, TiedHead, dense as _dense, fused,
+                            gated_mlp, lm_loss, rms as _rms)
 from .nemotron_h import GroupedQueryAttention, Mamba2Mixer
 
 __all__ = ["GraniteHybridModel", "GraniteHybridLayer", "gated_mlp",
@@ -55,14 +52,6 @@ _CONFIGS = {
         embedding_multiplier=12.0, residual_multiplier=0.22,
         attention_multiplier=0.015625, logits_scaling=8.0, eps=1e-5),
 }
-
-
-def gated_mlp(x, w_in, w_out, cd):
-    """``(silu(g) * v) W_out^T`` with ``(g, v)`` the two halves, in that
-    order, of ``x W_in^T``; (out, in) weights, operands in ``cd``."""
-    u = _dense(x, w_in, cd)
-    half = u.shape[-1] // 2
-    return _dense(jax.nn.silu(u[..., :half]) * u[..., half:], w_out, cd)
 
 
 class GraniteHybridLayer(HybridBlock):
@@ -96,8 +85,7 @@ class GraniteHybridLayer(HybridBlock):
     def forward(self, x, mask=None):
         mixer, eps, r = self.mixer, self._eps, self._r
 
-        def f(xv, g1, g2, w_in, w_out, *ws):
-            cd = _compute_dtype(xv)
+        def body(xv, g1, g2, w_in, w_out, *ws, cd):
             with jax.named_scope("mixer"):
                 a = xv + r * mixer.mix(_rms(xv, g1, eps), *ws,
                                        cd).astype(xv.dtype)
@@ -105,58 +93,40 @@ class GraniteHybridLayer(HybridBlock):
                 return a + r * gated_mlp(_rms(a, g2, eps), w_in, w_out,
                                          cd).astype(xv.dtype)
 
-        out = invoke(f"granite_{self.kind}_layer", f,
-                     [x, self.norm1.gamma.data(), self.norm2.gamma.data(),
-                      self.mlp_in.data(), self.mlp_out.data()]
-                     + [p.data() for p in mixer.params_in_order()])
-        return _par.with_sharding_constraint(out, "batch", None, None)
+        return fused(f"granite_{self.kind}_layer", body, x,
+                     [self.norm1.gamma, self.norm2.gamma, self.mlp_in,
+                      self.mlp_out] + mixer.params_in_order())
 
 
-class GraniteHybridModel(HybridBlock):
-    """tokens (B, T) int32 -> logits (B, T, vocab_held) float32."""
+def _tied_logits(net, xv, gain, w, cd):
+    return _dense(_rms(xv, gain, net._eps), w, cd) / net._logits
+
+
+class GraniteHybridModel(HybridDecoder):
+    """tokens (B, T) int32 -> logits (B, T, vocab_held) float32;
+    ``layer_types`` is the list of kinds."""
 
     def __init__(self, layer_types, vocab_size, units, vocab_held=None,
                  remat=False, dtype="float32", **cfg):
-        super().__init__()
         cfg = dict(cfg, units=units)
-        self.layer_types = tuple(layer_types)
-        self.vocab_size = vocab_size
-        self.vocab_held = int(vocab_held or vocab_size)
-        self._remat, self._eps = remat, cfg["eps"]
-        self._emb = float(cfg["embedding_multiplier"])
+        layer_types = tuple(layer_types)
+        super().__init__(
+            ((f"l{i}", GraniteHybridLayer(kind, cfg, dtype=dtype))
+             for i, kind in enumerate(layer_types)),
+            RMSNorm, TiedHead("granite_tied_head", _tied_logits),
+            vocab_size, units, cfg["eps"], vocab_held=vocab_held,
+            remat=remat, dtype=dtype,
+            embed_multiplier=float(cfg["embedding_multiplier"]))
+        self.layer_types = layer_types
         self._logits = float(cfg["logits_scaling"])
-        self.embed = Embedding(self.vocab_held, units, dtype=dtype)
-        annotate(self.embed.weight, "vocab", "embed")
-        self.blocks = []
-        for i, kind in enumerate(self.layer_types):
-            blk = GraniteHybridLayer(kind, cfg, dtype=dtype)
-            self.register_child(blk, f"l{i}")
-            self.blocks.append(blk)
-        self.norm_f = RMSNorm(epsilon=cfg["eps"], in_channels=units)
         plan_event("granite.plan",
-                   mamba_layers=self.layer_types.count("mamba"),
-                   attention_layers=self.layer_types.count("attention"),
+                   mamba_layers=layer_types.count("mamba"),
+                   attention_layers=layer_types.count("attention"),
                    embedding_multiplier=self._emb,
                    residual_multiplier=float(cfg["residual_multiplier"]),
                    attention_multiplier=float(cfg["attention_multiplier"]),
                    logits_scaling=self._logits, vocab_held=self.vocab_held,
                    tied=True)
-
-    def forward(self, tokens):
-        from .transformer import run_blocks
-        emb, eps, scaling = self._emb, self._eps, self._logits
-        x = self.embed(tokens) * emb
-        x = _par.with_sharding_constraint(x, "batch", None, None)
-        x = run_blocks(self.blocks, x, scan=False, remat=self._remat)
-
-        def head(xv, gain, w):
-            cd = _compute_dtype(xv)
-            return _dense(_rms(xv, gain, eps), w, cd) / scaling
-
-        logits = invoke("granite_tied_head", head,
-                        [x, self.norm_f.gamma.data(),
-                         self.embed.weight.data()])
-        return _par.with_sharding_constraint(logits, "batch", None, "vocab")
 
 
 def get_granite_hybrid(name="granite_4_0_h_micro", **kwargs):

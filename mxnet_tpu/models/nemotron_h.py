@@ -29,14 +29,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .. import parallel as _par
 from ..gluon.block import HybridBlock
-from ..gluon.nn import Embedding, RMSNorm
-from ..ndarray import ops as F
-from ..ndarray.ops import invoke
-from ..parallel.sharding import annotate
-from .hybrid_common import dense as _dense, lm_loss, rms as _rms
-from .moe import MoELayer, amp_compute_dtype as _compute_dtype
+from ..gluon.nn import RMSNorm
+from .hybrid_common import (ExpertBlock, HalfLayer, HybridDecoder, OwnHead,
+                            QKVOProjections, dense as _dense, lm_loss)
 
 __all__ = ["NemotronHModel", "Mamba2Mixer", "GroupedQueryAttention",
            "HybridLayer", "get_nemotron_h", "lm_loss"]
@@ -122,128 +118,74 @@ class Mamba2Mixer(HybridBlock):
                 self.out_proj]
 
 
-class GroupedQueryAttention(HybridBlock):
+class GroupedQueryAttention(QKVOProjections):
     """Causal attention, ``num_kv_heads`` <= ``num_heads``, no bias, no
     rotary embedding.  ``scale`` multiplies the scores before the softmax
     (``head_dim ** -0.5`` when None)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  scale=None, dtype="float32", **kwargs):
-        super().__init__(**kwargs)
+        super().__init__(units, num_heads, num_kv_heads, head_dim,
+                         dtype=dtype, **kwargs)
         self._scale = scale
-        if num_heads % num_kv_heads:
-            raise ValueError(f"{num_heads} query heads do not divide over "
-                             f"{num_kv_heads} key/value heads")
-        self._h, self._hk, self._d = num_heads, num_kv_heads, head_dim
-        g = self.params.get
-        self.q_proj = g("q_proj", shape=(num_heads * head_dim, units),
-                        dtype=dtype, init="xavier")
-        self.k_proj = g("k_proj", shape=(num_kv_heads * head_dim, units),
-                        dtype=dtype, init="xavier")
-        self.v_proj = g("v_proj", shape=(num_kv_heads * head_dim, units),
-                        dtype=dtype, init="xavier")
-        self.o_proj = g("o_proj", shape=(units, num_heads * head_dim),
-                        dtype=dtype, init="xavier")
 
     def mix(self, hn, wq, wk, wv, wo, cd):
         from ..ops.attention import flash_attention
-        b, t, _u = hn.shape
-        q = _dense(hn, wq, cd).astype(cd).reshape(b, t, self._h, self._d)
-        k = _dense(hn, wk, cd).astype(cd).reshape(b, t, self._hk, self._d)
-        v = _dense(hn, wv, cd).astype(cd).reshape(b, t, self._hk, self._d)
+        q = self.heads(hn, wq, self._h, cd)
+        k = self.heads(hn, wk, self._hk, cd)
+        v = self.heads(hn, wv, self._hk, cd)
         a = flash_attention(q, k, v, causal=True, scale=self._scale)
-        return _dense(a.reshape(b, t, self._h * self._d), wo, cd)
-
-    def params_in_order(self):
-        return [self.q_proj, self.k_proj, self.v_proj, self.o_proj]
+        return self.merged(a, wo, cd)
 
 
-class HybridLayer(HybridBlock):
-    """``x + mixer(RMSNorm(x))`` for one letter of the pattern."""
+class HybridLayer(HalfLayer):
+    """``x + mixer(RMSNorm(x))`` for an ``M`` or a ``*`` of the pattern
+    (an ``E`` is :class:`~mxnet_tpu.models.hybrid_common.ExpertBlock`)."""
 
-    def __init__(self, kind, cfg, experts_held=None, record_choice_rows=0,
-                 dtype="float32", **kwargs):
-        super().__init__(**kwargs)
-        self.kind = kind
-        self._eps = cfg["eps"]
+    def __init__(self, kind, cfg, dtype="float32", **kwargs):
         u = cfg["units"]
-        self.norm = RMSNorm(epsilon=cfg["eps"], in_channels=u)
         if kind == "M":
-            self.mixer = Mamba2Mixer(
+            op, mixer = "mamba2_layer", Mamba2Mixer(
                 u, cfg["mamba_heads"], cfg["mamba_head_dim"],
                 cfg["mamba_groups"], cfg["state_size"],
                 conv_kernel=cfg["conv_kernel"],
                 chunk_size=cfg["chunk_size"], eps=cfg["eps"], dtype=dtype)
         elif kind == "*":
-            self.mixer = GroupedQueryAttention(
+            op, mixer = "gqa_layer", GroupedQueryAttention(
                 u, cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"],
                 dtype=dtype)
-        elif kind == "E":
-            self.mixer = MoELayer(
-                u, cfg["expert_hidden"], cfg["num_experts"],
-                top_k=cfg["top_k"], routing="dropless",
-                experts_held=experts_held,
-                shared_hidden=cfg["shared_hidden"],
-                routed_scaling=cfg["routed_scaling"],
-                norm_topk=cfg["norm_topk"],
-                record_choice_rows=record_choice_rows, dtype=dtype)
         else:
-            raise ValueError(f"layer kind {kind!r} is not M, E or *")
-
-    def forward(self, x, mask=None):
-        if self.kind == "E":
-            return x + self.mixer(self.norm(x))
-        mixer, eps = self.mixer, self._eps
-        ps = mixer.params_in_order()
-
-        def f(xv, gain, *ws):
-            cd = _compute_dtype(xv)
-            hn = _rms(xv, gain, eps)
-            return xv + mixer.mix(hn, *ws, cd).astype(xv.dtype)
-
-        name = "mamba2_layer" if self.kind == "M" else "gqa_layer"
-        out = invoke(name, f, [x, self.norm.gamma.data()]
-                     + [p.data() for p in ps])
-        return _par.with_sharding_constraint(out, "batch", None, None)
+            raise ValueError(f"layer kind {kind!r} is not M or * (nor E, "
+                             f"which is an ExpertBlock)")
+        super().__init__(op, cfg, mixer, **kwargs)
+        self.kind = kind
 
 
-class NemotronHModel(HybridBlock):
-    """tokens (B, T) int32 -> logits (B, T, vocab_held)."""
+class NemotronHModel(HybridDecoder):
+    """tokens (B, T) int32 -> logits (B, T, vocab_held); ``pattern`` is
+    the list of kinds."""
 
     def __init__(self, pattern, vocab_size, units, vocab_held=None,
                  experts_held=None, record_choice_rows=0, remat=False,
                  dtype="float32", **cfg):
-        super().__init__()
         cfg = dict(cfg, units=units)
-        self.pattern = pattern
-        self.vocab_size = vocab_size
-        self.vocab_held = int(vocab_held or vocab_size)
-        self._remat = remat
-        self.embed = Embedding(self.vocab_held, units, dtype=dtype)
-        annotate(self.embed.weight, "vocab", "embed")
-        self.blocks = []
-        for i, kind in enumerate(pattern):
-            blk = HybridLayer(kind, cfg, experts_held=experts_held,
-                              record_choice_rows=record_choice_rows,
-                              dtype=dtype)
-            self.register_child(blk, f"l{i}")
-            self.blocks.append(blk)
-        self.norm_f = RMSNorm(epsilon=cfg["eps"], in_channels=units)
-        self.lm_head = self.params.get(
-            "lm_head", shape=(self.vocab_held, units), dtype=dtype,
-            init="xavier")
-        annotate(self.lm_head, "vocab", "embed")
 
-    def forward(self, tokens):
-        from .transformer import run_blocks
-        x = self.embed(tokens)
-        x = _par.with_sharding_constraint(x, "batch", None, None)
-        x = run_blocks(self.blocks, x, scan=False, remat=self._remat)
-        x = self.norm_f(x)
-        logits = F.FullyConnected(x, self.lm_head.data(), None,
-                                  num_hidden=self.vocab_held, no_bias=True,
-                                  flatten=False)
-        return _par.with_sharding_constraint(logits, "batch", None, "vocab")
+        def block(kind):
+            if kind != "E":
+                return HybridLayer(kind, cfg, dtype=dtype)
+            blk = ExpertBlock(
+                cfg, experts="mixer", experts_held=experts_held,
+                shared_hidden=cfg["shared_hidden"],
+                routed_scaling=cfg["routed_scaling"],
+                record_choice_rows=record_choice_rows, dtype=dtype)
+            blk.kind = kind
+            return blk
+
+        super().__init__(
+            ((f"l{i}", block(kind)) for i, kind in enumerate(pattern)),
+            RMSNorm, OwnHead(), vocab_size, units, cfg["eps"],
+            vocab_held=vocab_held, remat=remat, dtype=dtype)
+        self.pattern = pattern
 
 
 def get_nemotron_h(name="nemotron_h_tt_30b_a3b", **kwargs):

@@ -47,15 +47,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .. import parallel as _par
 from ..gluon.block import HybridBlock
-from ..gluon.nn import Embedding, LayerNorm
-from ..ndarray.ops import _layer_norm as _layer_norm_op, invoke
+from ..gluon.nn import LayerNorm
+from ..ndarray.ops import _layer_norm as _layer_norm_op
 from ..ops.flash import plan_event
-from ..parallel.sharding import annotate
-from .granite_hybrid import gated_mlp
-from .hybrid_common import dense as _dense, lm_loss, rms as _rms
-from .moe import amp_compute_dtype as _compute_dtype
+from .hybrid_common import (HybridDecoder, TiedHead, dense as _dense, fused,
+                            gated_mlp, lm_loss, rms as _rms)
 
 __all__ = ["Phi4FlashModel", "Phi4FlashLayer", "Mamba1Mixer",
            "DifferentialAttention", "GatedMemoryUnit", "layer_kinds",
@@ -292,9 +289,8 @@ class Phi4FlashLayer(HybridBlock):
             raise ValueError(f"a {kind} layer reads {self.side_in}, got "
                              f"{len(side)} values")
 
-        def f(xv, g1, b1, g2, b2, w1, w2, *rest):
+        def body(xv, g1, b1, g2, b2, w1, w2, *rest, cd):
             ws, given = rest[:len(ps)], rest[len(ps):]
-            cd = _compute_dtype(xv)
             emitted = ()
             with jax.named_scope("mixer"):
                 hn = _layer_norm(xv, g1, b1, eps)
@@ -314,18 +310,16 @@ class Phi4FlashLayer(HybridBlock):
                                     cd).astype(xv.dtype)
             return (out,) + emitted if emitted else out
 
-        out = invoke(f"phi4flash_{kind}_layer", f,
-                     [x, self.norm1.gamma.data(), self.norm1.beta.data(),
-                      self.norm2.gamma.data(), self.norm2.beta.data(),
-                      self.fc1.data(), self.fc2.data()]
-                     + [p.data() for p in ps] + list(side))
-        if not self.side_out:
-            return _par.with_sharding_constraint(out, "batch", None, None)
-        return (_par.with_sharding_constraint(out[0], "batch", None, None),
-                *out[1:])
+        return fused(f"phi4flash_{kind}_layer", body, x,
+                     [self.norm1.gamma, self.norm1.beta, self.norm2.gamma,
+                      self.norm2.beta, self.fc1, self.fc2] + ps, side)
 
 
-class Phi4FlashModel(HybridBlock):
+def _tied_logits(net, xv, gain, bias, w, cd):
+    return _dense(_layer_norm(xv, gain, bias, net._eps), w, cd)
+
+
+class Phi4FlashModel(HybridDecoder):
     """tokens (B, T) int32 -> logits (B, T, vocab_held) float32.
     ``layers``: the published indices of the layers held, in order (all
     of them when None); a layer that reads the memory or the keys and
@@ -333,46 +327,25 @@ class Phi4FlashModel(HybridBlock):
 
     def __init__(self, num_layers, vocab_size, units, layers=None,
                  vocab_held=None, remat=False, dtype="float32", **cfg):
-        super().__init__()
         cfg = dict(cfg, units=units)
-        kinds = layer_kinds(num_layers)
-        self.layers = tuple(range(num_layers) if layers is None else layers)
-        self.kinds = tuple(kinds[i] for i in self.layers)
+        every = layer_kinds(num_layers)
+        layers = tuple(range(num_layers) if layers is None else layers)
+        kinds = tuple(every[i] for i in layers)
         for want, by in (("memory", "mamba_mem"), ("keys", "full")):
-            readers = [k for k in self.kinds if want in _SIDES[k][0]]
-            if readers and by not in self.kinds:
-                raise ValueError(f"layers {self.layers} read the {want} "
+            readers = [k for k in kinds if want in _SIDES[k][0]]
+            if readers and by not in kinds:
+                raise ValueError(f"layers {layers} read the {want} "
                                  f"and hold no {by} layer")
-        self.vocab_size = vocab_size
-        self.vocab_held = int(vocab_held or vocab_size)
-        self._remat, self._eps = remat, cfg["eps"]
-        self.embed = Embedding(self.vocab_held, units, dtype=dtype)
-        annotate(self.embed.weight, "vocab", "embed")
-        self.blocks = []
-        for i, kind in zip(self.layers, self.kinds):
-            blk = Phi4FlashLayer(kind, i, cfg, dtype=dtype)
-            self.register_child(blk, f"l{i}")
-            self.blocks.append(blk)
-        self.norm_f = LayerNorm(epsilon=cfg["eps"], in_channels=units)
-        plan_event("phi4flash.plan", layers=self.layers, kinds=self.kinds,
+        super().__init__(
+            ((f"l{i}", Phi4FlashLayer(kind, i, cfg, dtype=dtype))
+             for i, kind in zip(layers, kinds)),
+            LayerNorm, TiedHead("phi4flash_tied_head", _tied_logits),
+            vocab_size, units, cfg["eps"], vocab_held=vocab_held,
+            remat=remat, dtype=dtype)
+        self.layers, self.kinds = layers, kinds
+        plan_event("phi4flash.plan", layers=layers, kinds=kinds,
                    window=cfg["window"], vocab_held=self.vocab_held,
                    tied=True)
-
-    def forward(self, tokens):
-        from .transformer import run_blocks
-        eps = self._eps
-        x = self.embed(tokens)
-        x = _par.with_sharding_constraint(x, "batch", None, None)
-        x = run_blocks(self.blocks, x, scan=False, remat=self._remat)
-
-        def head(xv, gain, bias, w):
-            cd = _compute_dtype(xv)
-            return _dense(_layer_norm(xv, gain, bias, eps), w, cd)
-
-        logits = invoke("phi4flash_tied_head", head,
-                        [x, self.norm_f.gamma.data(),
-                         self.norm_f.beta.data(), self.embed.weight.data()])
-        return _par.with_sharding_constraint(logits, "batch", None, "vocab")
 
 
 def get_phi4_flash(name="phi4_mini_flash_reasoning", **kwargs):

@@ -32,17 +32,16 @@ prediction head is in no configuration key and is not built.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from .. import parallel as _par
 from ..gluon.block import HybridBlock
-from ..gluon.nn import Embedding, RMSNorm
-from ..ndarray import ops as F
-from ..ndarray.ops import invoke
-from ..parallel.sharding import annotate
-from .hybrid_common import dense as _dense, lm_loss, rms as _rms
-from .moe import MoELayer, amp_compute_dtype as _compute_dtype
+from ..gluon.nn import RMSNorm
+from .hybrid_common import (ExpertBlock, HalfLayer, HybridDecoder, OwnHead,
+                            QKVOProjections, dense as _dense, lm_loss,
+                            positioned, rms as _rms, two_halves)
 
 __all__ = ["Qwen3NextModel", "GatedDeltaNet", "GatedAttention",
            "MixerBlock", "ExpertBlock", "get_qwen3_next", "lm_loss"]
@@ -123,160 +122,81 @@ class GatedDeltaNet(HybridBlock):
                 self.dt_bias, self.A_log, self.norm_weight, self.out_proj]
 
 
-class GatedAttention(HybridBlock):
+class GatedAttention(QKVOProjections):
     """Causal attention as HF ``Qwen3NextAttention`` computes it:
     ``num_kv_heads`` <= ``num_heads``, no bias, q/k norm, partial rotary
-    positions, an output gate from ``q_proj``."""
+    positions, an output gate from ``q_proj`` (per head [query | gate])."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  partial_rotary_factor=1.0, rope_theta=10000.0, eps=1e-6,
                  dtype="float32", **kwargs):
-        super().__init__(**kwargs)
-        if num_heads % num_kv_heads:
-            raise ValueError(f"{num_heads} query heads do not divide over "
-                             f"{num_kv_heads} key/value heads")
-        self._h, self._hk, self._d = num_heads, num_kv_heads, head_dim
+        super().__init__(units, num_heads, num_kv_heads, head_dim, q_width=2,
+                         qk_norm="zeros", dtype=dtype, **kwargs)
         self._rotary_dim = int(head_dim * partial_rotary_factor)
         self._theta, self._eps = float(rope_theta), eps
-        g = self.params.get
-        # per head [query | gate]
-        self.q_proj = g("q_proj", shape=(num_heads * head_dim * 2, units),
-                        dtype=dtype, init="xavier")
-        self.k_proj = g("k_proj", shape=(num_kv_heads * head_dim, units),
-                        dtype=dtype, init="xavier")
-        self.v_proj = g("v_proj", shape=(num_kv_heads * head_dim, units),
-                        dtype=dtype, init="xavier")
-        self.q_norm = g("q_norm", shape=(head_dim,), dtype=dtype,
-                        init="zeros")
-        self.k_norm = g("k_norm", shape=(head_dim,), dtype=dtype,
-                        init="zeros")
-        self.o_proj = g("o_proj", shape=(units, num_heads * head_dim),
-                        dtype=dtype, init="xavier")
 
     def mix(self, hn, wq, wk, wv, q_gain, k_gain, wo, cd):
-        from ..ops.attention import flash_attention, rotary_embedding
+        from ..ops.attention import flash_attention
         b, t, _u = hn.shape
         h, hk, d = self._h, self._hk, self._d
-        qg = _dense(hn, wq, cd).reshape(b, t, h, 2 * d)
+        qg = self.heads(hn, wq, h, cd, cast=False)
         gate = qg[..., d:].reshape(b, t, h * d)
 
-        def positioned(x, gain):
-            return rotary_embedding(_rms(x, gain, self._eps, unit_offset=True),
-                                    theta=self._theta,
-                                    rotary_dim=self._rotary_dim).astype(cd)
+        def at(x, gain):
+            return positioned(x, gain, self._eps, cd, unit_offset=True,
+                              theta=self._theta, rotary_dim=self._rotary_dim)
 
-        q = positioned(qg[..., :d], q_gain)
-        k = positioned(_dense(hn, wk, cd).reshape(b, t, hk, d), k_gain)
-        v = _dense(hn, wv, cd).astype(cd).reshape(b, t, hk, d)
+        q = at(qg[..., :d], q_gain)
+        k = at(self.heads(hn, wk, hk, cd, cast=False), k_gain)
+        v = self.heads(hn, wv, hk, cd)
         a = flash_attention(q, k, v, causal=True).astype(jnp.float32)
         return _dense(a.reshape(b, t, h * d) * jax.nn.sigmoid(gate), wo, cd)
 
-    def params_in_order(self):
-        return [self.q_proj, self.k_proj, self.v_proj, self.q_norm,
-                self.k_norm, self.o_proj]
 
-
-class MixerBlock(HybridBlock):
+class MixerBlock(HalfLayer):
     """``x + mixer(norm(x))``: the first half of a decoder layer."""
 
     def __init__(self, kind, cfg, dtype="float32", **kwargs):
-        super().__init__(**kwargs)
-        self.kind = kind
-        self._eps = cfg["eps"]
         u = cfg["units"]
-        self.norm = RMSNorm(epsilon=cfg["eps"], in_channels=u,
-                            unit_offset=True)
         if kind == "linear":
-            self.mixer = GatedDeltaNet(
+            op, mixer = "gdn_layer", GatedDeltaNet(
                 u, cfg["linear_key_heads"], cfg["linear_value_heads"],
                 cfg["linear_key_dim"], cfg["linear_value_dim"],
                 conv_kernel=cfg["conv_kernel"],
                 chunk_size=cfg["chunk_size"], eps=cfg["eps"], dtype=dtype)
         elif kind == "full":
-            self.mixer = GatedAttention(
+            op, mixer = "gated_attn_layer", GatedAttention(
                 u, cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"],
                 partial_rotary_factor=cfg["partial_rotary_factor"],
                 rope_theta=cfg["rope_theta"], eps=cfg["eps"], dtype=dtype)
         else:
             raise ValueError(f"mixer kind {kind!r} is not linear or full")
-
-    def forward(self, x, mask=None):
-        mixer, eps = self.mixer, self._eps
-        ps = mixer.params_in_order()
-
-        def f(xv, gain, *ws):
-            cd = _compute_dtype(xv)
-            return xv + mixer.mix(_rms(xv, gain, eps, unit_offset=True), *ws,
-                                  cd).astype(xv.dtype)
-
-        name = "gdn_layer" if self.kind == "linear" else "gated_attn_layer"
-        out = invoke(name, f, [x, self.norm.gamma.data()]
-                     + [p.data() for p in ps])
-        return _par.with_sharding_constraint(out, "batch", None, None)
+        super().__init__(op, cfg, mixer, unit_offset=True, **kwargs)
+        self.kind = kind
 
 
-class ExpertBlock(HybridBlock):
-    """``x + experts(norm(x))``: the second half of a decoder layer."""
-
-    def __init__(self, cfg, experts_held=None, record_choice_rows=0,
-                 dtype="float32", **kwargs):
-        super().__init__(**kwargs)
-        self.norm = RMSNorm(epsilon=cfg["eps"], in_channels=cfg["units"],
-                            unit_offset=True)
-        self.moe = MoELayer(
-            cfg["units"], cfg["expert_hidden"], cfg["num_experts"],
-            top_k=cfg["top_k"], routing="dropless", scoring="softmax",
-            expert_form="swiglu", experts_held=experts_held,
-            shared_hidden=cfg["shared_hidden"], shared_gate=True,
-            norm_topk=cfg["norm_topk"],
-            record_choice_rows=record_choice_rows, dtype=dtype)
-
-    def forward(self, x, mask=None):
-        return x + self.moe(self.norm(x))
-
-
-class Qwen3NextModel(HybridBlock):
-    """tokens (B, T) int32 -> logits (B, T, vocab_held)."""
+class Qwen3NextModel(HybridDecoder):
+    """tokens (B, T) int32 -> logits (B, T, vocab_held); every
+    ``full_attention_interval``-th of ``num_layers`` is full attention."""
 
     def __init__(self, num_layers, full_attention_interval, vocab_size,
                  units, vocab_held=None, experts_held=None,
                  record_choice_rows=0, remat=False, dtype="float32", **cfg):
-        super().__init__()
         cfg = dict(cfg, units=units)
-        self.kinds = ["full" if (i + 1) % full_attention_interval == 0
-                      else "linear" for i in range(num_layers)]
-        self.vocab_size = vocab_size
-        self.vocab_held = int(vocab_held or vocab_size)
-        self._remat = remat
-        self.embed = Embedding(self.vocab_held, units, dtype=dtype)
-        annotate(self.embed.weight, "vocab", "embed")
-        # a decoder layer is two blocks, each recomputed on its own
-        self.blocks = []
-        for i, kind in enumerate(self.kinds):
-            halves = (MixerBlock(kind, cfg, dtype=dtype),
-                      ExpertBlock(cfg, experts_held=experts_held,
-                                  record_choice_rows=record_choice_rows,
-                                  dtype=dtype))
-            for half, name in zip(halves, ("mixer", "experts")):
-                self.register_child(half, f"l{i}_{name}")
-                self.blocks.append(half)
-        self.norm_f = RMSNorm(epsilon=cfg["eps"], in_channels=units,
-                              unit_offset=True)
-        self.lm_head = self.params.get(
-            "lm_head", shape=(self.vocab_held, units), dtype=dtype,
-            init="xavier")
-        annotate(self.lm_head, "vocab", "embed")
-
-    def forward(self, tokens):
-        from .transformer import run_blocks
-        x = self.embed(tokens)
-        x = _par.with_sharding_constraint(x, "batch", None, None)
-        x = run_blocks(self.blocks, x, scan=False, remat=self._remat)
-        x = self.norm_f(x)
-        logits = F.FullyConnected(x, self.lm_head.data(), None,
-                                  num_hidden=self.vocab_held, no_bias=True,
-                                  flatten=False)
-        return _par.with_sharding_constraint(logits, "batch", None, "vocab")
+        kinds = ["full" if (i + 1) % full_attention_interval == 0
+                 else "linear" for i in range(num_layers)]
+        super().__init__(
+            two_halves(
+                kinds, lambda kind: MixerBlock(kind, cfg, dtype=dtype),
+                lambda: ExpertBlock(
+                    cfg, unit_offset=True, scoring="softmax",
+                    expert_form="swiglu", experts_held=experts_held,
+                    shared_hidden=cfg["shared_hidden"], shared_gate=True,
+                    record_choice_rows=record_choice_rows, dtype=dtype)),
+            functools.partial(RMSNorm, unit_offset=True), OwnHead(),
+            vocab_size, units, cfg["eps"], vocab_held=vocab_held,
+            remat=remat, dtype=dtype)
+        self.kinds = kinds
 
 
 def get_qwen3_next(name="qwen3_next_80b_a3b", **kwargs):

@@ -1,0 +1,116 @@
+"""The seam PR 45 cut: the five hybrid families are ONE decoder shell
+(``models.hybrid_common.HybridDecoder``) handed their blocks, their norm
+and their head, and a sixth family costs its mixer and its sizes."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import mxnet_tpu as mx  # noqa: E402
+from mxnet_tpu import models, parallel as par  # noqa: E402
+from mxnet_tpu.gluon.block import HybridBlock  # noqa: E402
+from mxnet_tpu.gluon.nn import RMSNorm  # noqa: E402
+from mxnet_tpu.models import hybrid_common as hc  # noqa: E402
+
+# each factory at the tiny sizes its own test file builds it at
+TINY = {
+    "get_nemotron_h": dict(
+        pattern="ME*", vocab_size=512, vocab_held=64, units=32, num_heads=4,
+        num_kv_heads=2, head_dim=8, mamba_heads=4, mamba_head_dim=8,
+        mamba_groups=2, state_size=16, chunk_size=16, num_experts=16,
+        top_k=3, expert_hidden=24, shared_hidden=48, experts_held=(8, 4)),
+    "get_qwen3_next": dict(
+        num_layers=8, vocab_size=512, vocab_held=64, units=32, num_heads=4,
+        num_kv_heads=2, head_dim=16, linear_key_heads=2,
+        linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
+        chunk_size=16, num_experts=16, top_k=3, expert_hidden=24,
+        shared_hidden=24, experts_held=(8, 4)),
+    "get_granite_hybrid": dict(
+        layer_types=("mamba", "attention"), vocab_size=256, vocab_held=32,
+        units=32, num_heads=4, num_kv_heads=2, head_dim=8, mamba_heads=4,
+        mamba_head_dim=16, state_size=8, chunk_size=16, mlp_hidden=48),
+    "get_phi4_flash": dict(
+        num_layers=32, vocab_size=64, units=32, num_heads=8, num_kv_heads=4,
+        head_dim=4, window=8, mlp_hidden=48, d_inner=64, state_size=4,
+        conv_kernel=4, dt_rank=2),
+    "get_mellum": dict(
+        num_layers=8, vocab_size=512, vocab_held=64, units=32, num_heads=8,
+        num_kv_heads=1, head_dim=16, sliding_window=8, num_experts=16,
+        top_k=3, expert_hidden=24, experts_held=(8, 4)),
+}
+
+
+@pytest.mark.parametrize("factory", sorted(TINY))
+def test_family_is_the_one_shell_and_has_no_forward_of_its_own(factory):
+    net = getattr(models, factory)(**TINY[factory])
+    assert isinstance(net, hc.HybridDecoder)
+    assert type(net).forward is hc.HybridDecoder.forward
+    assert net.vocab_held == TINY[factory].get("vocab_held",
+                                                TINY[factory]["vocab_size"])
+    assert net.embed.weight.shape == (net.vocab_held, 32)
+    names = list(net._children)
+    assert names[0] == "embed" and names[-1] == "norm_f"
+    assert len(names) == len(net.blocks) + 2
+
+
+def test_one_expert_half_serves_the_three_expert_families():
+    from mxnet_tpu.models import mellum, nemotron_h, qwen3_next
+
+    assert qwen3_next.ExpertBlock is mellum.ExpertBlock is hc.ExpertBlock
+    net = models.get_nemotron_h(**TINY["get_nemotron_h"])
+    assert isinstance(net.blocks[1], hc.ExpertBlock)
+    assert isinstance(net.blocks[0], nemotron_h.HybridLayer)
+
+
+# ---- a sixth family, whole: a mixer, its half-layer, its sizes ----------
+class RunningMean(HybridBlock):
+    """``(mean of the tokens so far) W^T``: causal, one weight."""
+
+    def __init__(self, units):
+        super().__init__()
+        self.w = self.params.get("w", shape=(units, units), init="xavier")
+
+    def mix(self, hn, w, cd):
+        steps = jnp.arange(1, hn.shape[1] + 1, dtype=hn.dtype)[:, None]
+        return hc.dense(jnp.cumsum(hn, 1) / steps, w, cd)
+
+    def params_in_order(self):
+        return [self.w]
+
+
+class ToyModel(hc.HybridDecoder):
+    def __init__(self, num_layers, vocab_size, units, eps, remat=False):
+        cfg = dict(units=units, eps=eps)
+        super().__init__(
+            ((f"l{i}", hc.HalfLayer("toy_layer", cfg, RunningMean(units)))
+             for i in range(num_layers)),
+            RMSNorm, hc.OwnHead(), vocab_size, units, eps, remat=remat)
+
+
+TOY = dict(num_layers=3, vocab_size=32, units=16, eps=1e-5)
+
+
+def test_a_sixth_family_is_its_mixer_and_its_sizes_and_trains():
+    net = ToyModel(remat=True, **TOY)
+    net.initialize(mx.init.Xavier())
+    assert list(net._collect_params_with_prefix()) == [
+        "lm_head", "embed.weight", "l0.norm.gamma", "l0.mixer.w",
+        "l1.norm.gamma", "l1.mixer.w", "l2.norm.gamma", "l2.mixer.w",
+        "norm_f.gamma"]
+    rng = onp.random.default_rng(0)
+    tok = rng.integers(0, TOY["vocab_size"], (2, 16)).astype("int32")
+    data, labels = (mx.nd.array(a, dtype="int32")
+                    for a in (tok, onp.roll(tok, -1, 1)))
+    mesh = par.make_mesh(devices=jax.devices()[:1])
+    with par.use_mesh(mesh):
+        tr = par.ShardedTrainer(net, "adam", loss=hc.lm_loss,
+                                optimizer_params={"learning_rate": 1e-2},
+                                mesh=mesh)
+        losses = [float(tr.step(data, labels).asnumpy()) for _ in range(2)]
+    assert all(onp.isfinite(losses)) and losses[1] < losses[0], losses
