@@ -11,6 +11,7 @@ from .granite_hybrid import GraniteHybridModel, get_granite_hybrid
 from .mellum import MellumModel, get_mellum
 from .moe import MoELayer, MoETransformerBlock, pop_aux_losses
 from .nemotron_h import NemotronHModel, get_nemotron_h
+from .ouro import OuroModel, get_ouro
 from .phi4_flash import Phi4FlashModel, get_phi4_flash
 from .qwen3_next import Qwen3NextModel, get_qwen3_next
 from .nmt import TransformerDecoderBlock, TransformerNMT, get_nmt, nmt_loss
@@ -27,4 +28,5 @@ __all__ = ["vision", "get_model", "BERTModel", "BERTForPretrain", "get_bert",
            "TransformerNMT", "TransformerDecoderBlock", "get_nmt",
            "nmt_loss", "NemotronHModel", "get_nemotron_h", "Qwen3NextModel",
            "get_qwen3_next", "GraniteHybridModel", "get_granite_hybrid",
-           "Phi4FlashModel", "get_phi4_flash", "MellumModel", "get_mellum"]
+           "Phi4FlashModel", "get_phi4_flash", "MellumModel", "get_mellum",
+           "OuroModel", "get_ouro"]

@@ -487,3 +487,31 @@ def test_moe_gmm_sixteen_small_experts_compile_for_v5e(chip):
         sds((m, u), bf), sds((held, u, f), bf), sds((held, u, f), bf),
         sds((held, f, u), bf), sds((held,), jnp.int32)).compile()
     assert _kernels(compiled) == 9
+
+
+# --------------- the looped decoder cell's attention at its sizes (PR 46)
+
+def test_flash_share_of_one_at_head_size_128_compiles_for_v5e(chip):
+    """Plain multi-head attention, 16 query heads over 16 key/value heads
+    of 128 at T 8,192: a share of ONE.  ``tile_plan`` gives it the plan of
+    the grouped-query calls at this head size (blocks of 1,024 rows,
+    chunks of 256, one head a grid step, 144 tiles of 256 run), each
+    query head reading a key/value head of its own."""
+    from mxnet_tpu.ops.flash import tile_plan
+
+    plan = tile_plan(8192, 8192, 128, jnp.bfloat16, True, heads=16,
+                     kv_heads=16)
+    shared = tile_plan(8192, 8192, 128, jnp.bfloat16, True, heads=32,
+                       kv_heads=2)
+    assert plan == shared
+    assert (plan.block_q, plan.chunk, plan.group, plan.tiles_run) == \
+        (1024, 256, 1, 144)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16, sharding=chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert _kernels(compiled) == 3

@@ -85,15 +85,25 @@ class RunningMean(HybridBlock):
 
 
 class ToyModel(hc.HybridDecoder):
-    def __init__(self, num_layers, vocab_size, units, eps, remat=False):
+    def __init__(self, num_layers, vocab_size, units, eps, remat=False,
+                 post_norm=False, passes=1):
         cfg = dict(units=units, eps=eps)
         super().__init__(
-            ((f"l{i}", hc.HalfLayer("toy_layer", cfg, RunningMean(units)))
+            ((f"l{i}", hc.HalfLayer("toy_layer", cfg, RunningMean(units),
+                                    post_norm=post_norm))
              for i in range(num_layers)),
-            RMSNorm, hc.OwnHead(), vocab_size, units, eps, remat=remat)
+            RMSNorm, hc.OwnHead(), vocab_size, units, eps, remat=remat,
+            passes=passes, exit_beta=0.05)
 
 
 TOY = dict(num_layers=3, vocab_size=32, units=16, eps=1e-5)
+
+
+def _toy_batch():
+    rng = onp.random.default_rng(0)
+    tok = rng.integers(0, TOY["vocab_size"], (2, 16)).astype("int32")
+    return tuple(mx.nd.array(a, dtype="int32")
+                 for a in (tok, onp.roll(tok, -1, 1)))
 
 
 def test_a_sixth_family_is_its_mixer_and_its_sizes_and_trains():
@@ -103,10 +113,7 @@ def test_a_sixth_family_is_its_mixer_and_its_sizes_and_trains():
         "lm_head", "embed.weight", "l0.norm.gamma", "l0.mixer.w",
         "l1.norm.gamma", "l1.mixer.w", "l2.norm.gamma", "l2.mixer.w",
         "norm_f.gamma"]
-    rng = onp.random.default_rng(0)
-    tok = rng.integers(0, TOY["vocab_size"], (2, 16)).astype("int32")
-    data, labels = (mx.nd.array(a, dtype="int32")
-                    for a in (tok, onp.roll(tok, -1, 1)))
+    data, labels = _toy_batch()
     mesh = par.make_mesh(devices=jax.devices()[:1])
     with par.use_mesh(mesh):
         tr = par.ShardedTrainer(net, "adam", loss=hc.lm_loss,
@@ -114,3 +121,33 @@ def test_a_sixth_family_is_its_mixer_and_its_sizes_and_trains():
                                 mesh=mesh)
         losses = [float(tr.step(data, labels).asnumpy()) for _ in range(2)]
     assert all(onp.isfinite(losses)) and losses[1] < losses[0], losses
+
+
+def test_the_sixth_family_sandwiched_and_run_twice_trains():
+    """The shell's two newer words: a norm on the mixer's OUTPUT, and the
+    same blocks run ``passes`` times with a gate a pass; the net then
+    takes the labels and returns its objective."""
+    net = ToyModel(remat=True, post_norm=True, passes=2, **TOY)
+    net.initialize(mx.init.Xavier())
+    assert list(net._collect_params_with_prefix()) == [
+        "lm_head", "exit_gate", "exit_bias", "loop_stats", "embed.weight",
+        "l0.norm.gamma", "l0.mixer.w", "l0.post_norm.gamma",
+        "l1.norm.gamma", "l1.mixer.w", "l1.post_norm.gamma",
+        "l2.norm.gamma", "l2.mixer.w", "l2.post_norm.gamma",
+        "norm_f.gamma"]
+    data, labels = _toy_batch()
+    logits, gates = net(data)
+    assert logits.shape == (2, 2, 16, TOY["vocab_size"])
+    assert gates.shape == (2, 2, 16)
+    assert onp.allclose(gates.asnumpy(), 0.5)       # a gate starts at zero
+    mesh = par.make_mesh(devices=jax.devices()[:1])
+    with par.use_mesh(mesh):
+        tr = par.ShardedTrainer(net, "adam", loss=None,
+                                optimizer_params={"learning_rate": 1e-2},
+                                mesh=mesh)
+        losses = [float(tr.step((data, labels)).asnumpy())
+                  for _ in range(2)]
+    assert all(onp.isfinite(losses)) and losses[1] < losses[0], losses
+    read = hc.read_loop_counters(net)
+    assert read["steps"] == 2 and len(read["loop.exit_mass"]) == 2
+    assert net.exit_gate.data().asnumpy().any()     # the gate learns
