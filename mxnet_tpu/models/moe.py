@@ -124,20 +124,65 @@ def _zero_int(x):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
+# rows a turn of the walk below gathers.  On the chip the walk's time does
+# not depend on it between 256 and 8,192 rows (tools/hybrid_kernels_bench.py
+# gather; PERF.md section 6, PR 47): 1,024 rounds the routed rows up by
+# little and keeps a turn's gather (4-5 MB) in fast memory
+_GATHER_CHUNK_ROWS = 1024
+
+
+# gathers INTO sorted order a layer runs in a step: the rows in, forward
+# and recomputed, and ``dy`` (the three that walk; those into pair order
+# read the whole buffer)
+_SORTED_GATHERS = 3
+
+
+def gather_chunk_rows(rows: int) -> int:
+    """Rows one turn of :func:`_gather_routed` reads into a buffer of
+    ``rows`` rows: one size for every family, from the shape alone."""
+    return min(_GATHER_CHUNK_ROWS, rows)
+
+
+def _gather_routed(src, idx, count):
+    """``src[idx]`` on the rows ``r < count`` of a sorted buffer, zeros
+    (or more gathered rows, up to a whole turn) past them: a walk over
+    ``ceil(count / chunk)`` chunks of ``idx``, each turn one gather of
+    ``chunk`` rows written into the buffer in place.  The rows READ follow
+    the count the step observes, from none to all of ``idx``, as
+    ``ops.gmm``'s grid follows the tiles that hold a routed row; no row is
+    left out at any count.  A last chunk that would overhang the buffer
+    starts earlier (both slices clamp alike) and re-reads rows already
+    there.  Never differentiated: it stands inside ``custom_vjp`` rules."""
+    rows = idx.shape[0]
+    chunk = gather_chunk_rows(rows)
+
+    def turn(i, buf):
+        ids = jax.lax.dynamic_slice_in_dim(idx, i * chunk, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(buf, src[ids], i * chunk,
+                                                   axis=0)
+
+    turns = jax.lax.div(count + (chunk - 1), jnp.asarray(chunk, count.dtype))
+    return jax.lax.fori_loop(
+        0, turns, turn, jnp.zeros((rows,) + src.shape[1:], src.dtype))
+
+
 @jax.custom_vjp
-def _permute(x, idx, inv):
+def _permute(x, idx, inv, count):
     """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv``: the
-    backward is a gather too, never a scatter."""
+    backward is a gather too, never a scatter, and it is a gather INTO
+    sorted order, of which only the first ``count`` rows are read by
+    anything: it walks those (:func:`_gather_routed`)."""
     return x[idx]
 
 
-def _permute_fwd(x, idx, inv):
-    return x[idx], (idx, inv)
+def _permute_fwd(x, idx, inv, count):
+    return x[idx], (idx, inv, count)
 
 
 def _permute_bwd(res, g):
-    idx, inv = res
-    return g[inv], _zero_int(idx), _zero_int(inv)
+    idx, inv, count = res
+    return (_gather_routed(g, inv, count), _zero_int(idx), _zero_int(inv),
+            _zero_int(count))
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
@@ -174,26 +219,30 @@ def _sum_held(terms, held):
 
 
 @jax.custom_vjp
-def _rows_of_pairs(x, order, inv, held):
+def _rows_of_pairs(x, order, inv, held, count):
     """The buffer of token rows in sorted-pair order: row r is the token
-    of pair ``order[r]``, and pair ``p`` is ``slot * N + token``.
+    of pair ``order[r]``, and pair ``p`` is ``slot * N + token``.  Only
+    the ``count`` rows routed to a held expert (the sort puts them first)
+    are read from ``x``, a chunk at a time (:func:`_gather_routed`); the
+    rows past the last chunk are zeros, and no product's tile reads them.
     Backward: the rows return to pair order, where slot ``j`` is rows
     ``j N .. (j + 1) N``, and a token's pairs ON A HELD EXPERT (``held``
     (top_k, N)) are summed in float32; the others hold whatever the kernel
     left and are selected away inside that sum."""
-    return x[order % x.shape[0]]
+    return _gather_routed(x, order % x.shape[0], count)
 
 
-def _rows_fwd(x, order, inv, held):
-    return _rows_of_pairs(x, order, inv, held), (order, inv, held)
+def _rows_fwd(x, order, inv, held, count):
+    return (_rows_of_pairs(x, order, inv, held, count),
+            (order, inv, held, count))
 
 
 def _rows_bwd(res, g):
-    order, inv, held = res
+    order, inv, held, count = res
     dx = _sum_held([s.astype(jnp.float32)
                     for s in _slots(g[inv], held.shape[0])], held)
     return (_row_major(dx.astype(g.dtype)), _zero_int(order), _zero_int(inv),
-            _zero_int(held))
+            _zero_int(held), _zero_int(count))
 
 
 _rows_of_pairs.defvjp(_rows_fwd, _rows_bwd)
@@ -288,7 +337,12 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
     chosen (N, k), sizes (H,) int32)``: the sum over a token's chosen
     experts THAT ARE HELD of weight x expert output, and how many pairs
     each held expert got.  Every pair on a held expert is computed: the
-    buffer holds ``N * top_k`` rows, an expert's rows slot by slot."""
+    buffer holds ``N * top_k`` rows, an expert's rows slot by slot.  What
+    the layer pays follows ``sum(sizes)`` and not the buffer: the grouped
+    products' grid (``ops.gmm``) and the gathers that write the sorted
+    buffer (:func:`_gather_routed`) both stop at the rows routed; rows
+    past them hold zeros, other tokens' rows up to a whole chunk, or what
+    a kernel left, and reach no sum."""
     from ..ops.flash import plan_event
     from ..ops.gmm import grouped_matmul
     n = x.shape[0]
@@ -296,7 +350,8 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
     cd = jnp.dtype(compute_dtype or x.dtype)
     plan_event("moe.plan", form="relu2" if w_gate is None else "swiglu",
                scoring=scoring, top_k=top_k, buffer_rows=n * top_k,
-               experts_held=held)
+               experts_held=held,
+               gather_chunk_rows=gather_chunk_rows(n * top_k))
     if scoring == "sigmoid":
         w, chosen = route_sigmoid_topk(x, w_router, choice_bias, top_k=top_k,
                                        norm_topk=norm_topk, scaling=scaling,
@@ -316,8 +371,11 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
     inv = jnp.argsort(order).astype(jnp.int32)
     sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                     dtype=jnp.int32)
-    valid = jnp.arange(n * top_k) < jnp.sum(sizes)
-    # Rows past the routed ones hold whatever the kernel left (NaN, too).
+    routed = jnp.sum(sizes)
+    valid = jnp.arange(n * top_k) < routed
+    # Rows past the routed ones hold whatever the kernel left (NaN, too),
+    # or what the gathers into sorted order left (zeros; rows of other
+    # tokens up to a whole chunk).
     # They may be gathered and may pass row-wise elementwise work; they are
     # SELECTED away (never multiplied) before any sum that crosses rows or
     # leaves the buffer.  The narrow (rows, F) outputs are masked here,
@@ -328,7 +386,7 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
         out = grouped_matmul(lhs, rhs.astype(cd), sizes, impl=impl)
         return jnp.where(valid[:, None], out, jnp.zeros_like(out))
 
-    rows = _rows_of_pairs(x.astype(cd), order, inv, local)
+    rows = _rows_of_pairs(x.astype(cd), order, inv, local, routed)
     u = product(rows, w_up).astype(jnp.float32)
     if w_gate is None:
         h = jnp.square(jax.nn.relu(u)).astype(cd)
@@ -336,7 +394,7 @@ def dropless_ffn(x, w_router, choice_bias, w_up, w_down, *, top_k, first,
         h = (jax.nn.silu(product(rows, w_gate).astype(jnp.float32))
              * u).astype(cd)
     y = grouped_matmul(h, w_down.astype(cd), sizes, impl=impl)
-    y = _permute(y, inv, order)
+    y = _permute(y, inv, order, routed)
     return _combine(w.T, y, local), chosen, sizes
 
 
@@ -381,14 +439,19 @@ def read_routing_counters(net) -> dict:
     here in the last step), ``moe.pairs_total`` (top_k a token) and
     ``moe.load_max`` (the largest count on one held expert, largest
     layer), and the running sums since the layers were built
-    (``sum_*``, with ``steps``).  The registry counter
+    (``sum_*``, with ``steps``); ``moe.gather_rows_walked`` (the rows the
+    three gathers into sorted order read in the last step: the routed
+    rows rounded up to whole chunks, a layer's three alike) and
+    ``moe.gather_rows_buffer`` (the rows they would read whole), worked
+    out here from each layer's counts.  The registry counter
     ``mxtpu_moe_pairs_local_total`` is brought up to the layers' running
     sums: a step is counted once however often it is read, and a step no
     read fell on is counted by the next."""
     import numpy as np
 
     out = {"moe.pairs_local": 0.0, "moe.pairs_total": 0.0,
-           "moe.load_max": 0.0, "sum_pairs_local": 0.0,
+           "moe.load_max": 0.0, "moe.gather_rows_walked": 0.0,
+           "moe.gather_rows_buffer": 0.0, "sum_pairs_local": 0.0,
            "sum_pairs_total": 0.0, "sum_load_max": 0.0, "steps": 0.0,
            "layers": 0, "experts_held": 0, "per_layer_sum_pairs_local": []}
     fresh = 0.0
@@ -402,6 +465,11 @@ def read_routing_counters(net) -> dict:
         out["moe.pairs_local"] += v[0]
         out["moe.pairs_total"] += v[1]
         out["moe.load_max"] = max(out["moe.load_max"], v[2])
+        if v[1]:
+            chunk = gather_chunk_rows(int(v[1]))
+            out["moe.gather_rows_walked"] += \
+                _SORTED_GATHERS * math.ceil(v[0] / chunk) * chunk
+            out["moe.gather_rows_buffer"] += _SORTED_GATHERS * v[1]
         out["sum_pairs_local"] += v[3]
         out["sum_pairs_total"] += v[4]
         out["sum_load_max"] += v[5]

@@ -264,7 +264,8 @@ def test_three_amp_steps_counters_and_plans(tiny):
     assert layer == [{"form": "swiglu", "scoring": "softmax",
                       "top_k": sizes["top_k"],
                       "buffer_rows": B * T * sizes["top_k"],
-                      "experts_held": sizes["experts_held"]}]
+                      "experts_held": sizes["experts_held"],
+                      "gather_chunk_rows": B * T * sizes["top_k"]}]
     got = read_routing_counters(net)
     assert got["layers"] == 4 and got["steps"] == 3
     assert got["moe.pairs_total"] == 4 * B * T * sizes["top_k"]

@@ -3,6 +3,7 @@ deployment) against a plain loop over experts: routing as published, no
 token dropped under any skew, and the shares of a deployment adding up to
 the uncut layer."""
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,19 +46,42 @@ def _loop(x, wr, bias, w_up, w_down, first=0, held=E, scaling=2.5, k=K):
     return y, idx
 
 
+CHUNK = 64   # of the 288 buffer rows, so a walk takes 0 to 5 turns
+# what the chip holds, as (first, experts held): every pair held, a share,
+# and NO pair held (the bias keeps every token off the one expert held)
+LOADS = {"every_pair": (0, E), "share": (4, 4), "no_pair": (E - 1, 1)}
+
+
+def _weights_at(load, **kw):
+    """``_weights`` and the (first, held) of ``LOADS[load]``."""
+    x, wr, bias, w_up, w_down = _weights(**kw)
+    if load == "no_pair":
+        bias = bias.at[E - 1].add(-50.0)
+    return (x, wr, bias, w_up, w_down), LOADS[load]
+
+
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("skew", [0.0, 5.0])
-def test_dropless_routing_equals_the_loop(impl, skew):
-    x, wr, bias, w_up, w_down = _weights(skew=skew)
-    got, chosen, sizes = moe.dropless_ffn(
-        x, wr, bias, w_up, w_down, top_k=K, first=0, scaling=2.5, impl=impl)
-    want, idx = _loop(x, wr, bias, w_up, w_down)
+@pytest.mark.parametrize("load", ["every_pair", "no_pair"])
+def test_dropless_routing_equals_the_loop(impl, skew, load, monkeypatch):
+    """No token dropped at any load, by value: the gathers into sorted
+    order walk the routed rows in several turns (chunks of 64 of the 288
+    rows) where every pair is held, and in none where no pair is."""
+    monkeypatch.setattr(moe, "_GATHER_CHUNK_ROWS", CHUNK)
+    (x, wr, bias, w_up, w_down), (first, held) = _weights_at(load, skew=skew)
+    # one program a side (jitted): an eager layer is a hundred small ones
+    got, chosen, sizes = jax.jit(functools.partial(
+        moe.dropless_ffn, top_k=K, first=first, scaling=2.5, impl=impl))(
+        x, wr, bias, w_up[first:first + held], w_down[first:first + held])
+    want, idx = jax.jit(functools.partial(_loop, first=first, held=held))(
+        x, wr, bias, w_up, w_down)
     assert onp.array_equal(onp.sort(onp.asarray(chosen), 1),
                            onp.sort(onp.asarray(idx), 1))
-    assert int(jnp.sum(sizes)) == N * K            # every pair computed
+    # every pair on a held expert computed
+    assert int(jnp.sum(sizes)) == {"every_pair": N * K, "no_pair": 0}[load]
     onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                 rtol=1e-4, atol=1e-4)
-    if skew:
+    if skew and load == "every_pair":
         assert int(jnp.max(sizes)) == N            # every token on expert 0..2
 
 
@@ -111,24 +135,34 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_dropless_gradients_match_the_loop(impl):
-    x, wr, bias, w_up, w_down = _weights(seed=3, skew=1.0)
+@pytest.mark.parametrize("load", ["share", "every_pair", "no_pair"])
+def test_dropless_gradients_match_the_loop(impl, load, monkeypatch):
+    """No token dropped at any load, by gradient (chunks of 64 rows: the
+    backward's walk takes several turns, or none)."""
+    monkeypatch.setattr(moe, "_GATHER_CHUNK_ROWS", CHUNK)
+    (x, wr, bias, w_up, w_down), (first, held) = _weights_at(
+        load, seed=3, skew=1.0)
     g = jax.random.normal(jax.random.PRNGKey(11), (N, D))
+    here = slice(first, first + held)
 
     def ours(x, wr, w_up, w_down):
         return jnp.sum(moe.dropless_ffn(
-            x, wr, bias, w_up[4:8], w_down[4:8], top_k=K, first=4,
+            x, wr, bias, w_up[here], w_down[here], top_k=K, first=first,
             scaling=2.5, impl=impl)[0] * g)
 
     def loop(x, wr, w_up, w_down):
-        return jnp.sum(_loop(x, wr, bias, w_up, w_down, 4, 4)[0] * g)
+        return jnp.sum(_loop(x, wr, bias, w_up, w_down, first, held)[0] * g)
 
-    got = jax.grad(ours, argnums=(0, 1, 2, 3))(x, wr, w_up, w_down)
-    want = jax.grad(loop, argnums=(0, 1, 2, 3))(x, wr, w_up, w_down)
+    got = jax.jit(jax.grad(ours, argnums=(0, 1, 2, 3)))(x, wr, w_up, w_down)
+    want = jax.jit(jax.grad(loop, argnums=(0, 1, 2, 3)))(x, wr, w_up, w_down)
     for a, b in zip(got, want):
+        assert onp.isfinite(onp.asarray(a)).all()
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                     rtol=1e-3, atol=2e-4)
-    assert float(jnp.max(jnp.abs(got[2][:4]))) == 0.0   # experts not held
+    if load == "share":
+        assert float(jnp.max(jnp.abs(got[2][:4]))) == 0.0   # not held
+    if load == "no_pair":
+        assert all(float(jnp.max(jnp.abs(a))) == 0.0 for a in got)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -152,10 +186,10 @@ def test_sizes_that_fill_no_tile_agree_with_the_loop(impl, n, k):
         y, idx = _loop(x, wr, bias, w_up, w_down, 4, 6, k=k)
         return jnp.sum(y * g), idx
 
-    (got, (chosen, sizes)), d_got = jax.value_and_grad(
-        ours, argnums=(0, 1, 2, 3), has_aux=True)(x, wr, w_up, w_down)
-    (want, idx), d_want = jax.value_and_grad(
-        loop, argnums=(0, 1, 2, 3), has_aux=True)(x, wr, w_up, w_down)
+    (got, (chosen, sizes)), d_got = jax.jit(jax.value_and_grad(
+        ours, argnums=(0, 1, 2, 3), has_aux=True))(x, wr, w_up, w_down)
+    (want, idx), d_want = jax.jit(jax.value_and_grad(
+        loop, argnums=(0, 1, 2, 3), has_aux=True))(x, wr, w_up, w_down)
     assert chosen.shape == (n, k)
     assert onp.array_equal(onp.sort(onp.asarray(chosen), 1),
                            onp.sort(onp.asarray(idx), 1))
@@ -165,6 +199,49 @@ def test_sizes_that_fill_no_tile_agree_with_the_loop(impl, n, k):
     for a, b in zip(d_got, d_want):
         onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                     rtol=1e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+@pytest.mark.parametrize("routed", [0, 1, 2 * CHUNK, 2 * CHUNK + 1, N * K],
+                         ids=["none", "one", "two_chunks", "one_over",
+                              "every_pair"])
+def test_sorted_order_gathers_walk_the_routed_rows(routed, form,
+                                                   monkeypatch):
+    """The three gathers INTO sorted order (``_rows_of_pairs`` in its
+    primal and in its ``_fwd``, ``_permute``'s backward) against the
+    whole-buffer gathers they replace, ``x[order % n]`` and ``g[order]``:
+    equal bit for bit on the rows routed, finite past them, zero past the
+    last whole chunk; in the compute type of each expert form's cell
+    (``relu2`` float32 here, ``swiglu`` bfloat16)."""
+    monkeypatch.setattr(moe, "_GATHER_CHUNK_ROWS", CHUNK)
+    dt = {"relu2": jnp.float32, "swiglu": jnp.bfloat16}[form]
+    ks = jax.random.split(jax.random.PRNGKey(routed), 3)
+    x = jax.random.normal(ks[0], (N, D)).astype(dt)
+    g = jax.random.normal(ks[1], (N * K, D)).astype(dt)
+    order = jax.random.permutation(ks[2], N * K).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    held = jnp.ones((K, N), bool)
+    count = jnp.asarray(routed, jnp.int32)
+    walked = -(-routed // CHUNK) * CHUNK
+
+    @jax.jit
+    def sites(x, g):
+        primal = moe._rows_of_pairs(x, order, inv, held, count)
+        fwd, pull = jax.vjp(
+            lambda x: moe._rows_of_pairs(x, order, inv, held, count), x)
+        _, back = jax.vjp(lambda y: moe._permute(y, inv, order, count), g)
+        return ((primal, fwd, back(g)[0]), (x[order % N], g[order]),
+                pull(g)[0])
+
+    (primal, fwd, back), (rows, dy), dx = sites(x, g)
+    for got, want in ((primal, rows), (fwd, rows), (back, dy)):
+        got, want = onp.asarray(got), onp.asarray(want)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        assert onp.array_equal(got[:routed], want[:routed])
+        assert onp.isfinite(got.astype(onp.float32)).all()
+        assert not got[walked:].any()
+    # the derivative of the rows is the parent's: a sum over a token's pairs
+    assert dx.shape == x.shape and dx.dtype == x.dtype
 
 
 def _nan_past_the_routed_rows(real):
@@ -218,9 +295,9 @@ def test_rows_the_kernel_left_reach_no_sum(impl, form, monkeypatch):
                                        **kw)
         return jnp.sum(y * g), (y, sizes)
 
-    run = lambda: jax.value_and_grad(  # noqa: E731
-        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(x, wr, w_up, w_gate,
-                                                     w_down)
+    run = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(x, wr, w_up, w_gate,
+                                                      w_down)
     (_, (want, sizes)), d_want = run()
     assert 0 < int(jnp.sum(sizes)) < N * K // 2
     monkeypatch.setattr(gmm, "grouped_matmul",
@@ -231,6 +308,96 @@ def test_rows_the_kernel_left_reach_no_sum(impl, form, monkeypatch):
     for a, b in zip(d_got, d_want):
         assert onp.isfinite(onp.asarray(a)).all()
         assert onp.array_equal(onp.asarray(a), onp.asarray(b))
+
+
+@pytest.mark.parametrize("form", ["relu2", "swiglu"])
+def test_three_whole_buffer_gathers_a_layer_in_the_lowered_step(
+        form, monkeypatch):
+    """The differentiated, recomputed layer of a small twin, lowered for
+    the TPU on this CPU (no chip, nothing compiled): of the six gathers a
+    layer application had over forward, recomputed forward and backward,
+    the three into PAIR order still take an index of ``N * top_k`` rows;
+    the three into sorted order take a chunk's, inside a loop.  And the
+    yardstick's handle: every ``moe_gmm`` call has the buffer's shape
+    ``(N * top_k, .)`` among its operands and results, as before."""
+    from mxnet_tpu import base
+
+    monkeypatch.setattr(base, "resolve_exec_platform", lambda x=None: "tpu")
+    n, d, f, bf = 1024, 128, 128, jnp.bfloat16
+    rows, chunk = n * K, moe.gather_chunk_rows(n * K)
+    assert chunk < rows
+
+    def layer(x, w_router, bias, w_up, w_gate, w_down):
+        kw = dict(top_k=K, first=4, compute_dtype=bf, impl="pallas")
+        if form == "swiglu":
+            kw.update(scoring="softmax", w_gate=w_gate)
+        return moe.dropless_ffn(x, w_router, bias, w_up, w_down, **kw)[0]
+
+    def loss(ct, *args):
+        return jnp.sum(jax.checkpoint(layer)(*args) * ct)
+
+    sds = jax.ShapeDtypeStruct
+    text = jax.jit(jax.value_and_grad(loss, argnums=(1, 2, 4, 5, 6))).trace(
+        sds((n, d), jnp.float32), sds((n, d), bf), sds((E, d), jnp.float32),
+        sds((E,), jnp.float32), sds((4, d, f), bf), sds((4, d, f), bf),
+        sds((4, f, d), bf)).lower(lowering_platforms=("tpu",)).as_text()
+    index_rows = [int(m) for m in re.findall(
+        r'"stablehlo\.gather"\(.*?: \(tensor<[^>]*>, tensor<(\d+)x1xi32>\)',
+        text)]
+    assert index_rows.count(rows) == 3, index_rows      # the parent: 6
+    assert index_rows.count(chunk) == 3, index_rows
+    assert text.count("stablehlo.while") == 3
+    calls = re.findall(r"custom_call @tpu_custom_call\(.*?: (\(.*)", text)
+    products = {"relu2": 2, "swiglu": 3}[form]
+    assert len(calls) == 4 * products and text.count('"moe_gmm"') == len(calls)
+    for types in calls:
+        assert f"tensor<{rows}x" in types, types
+
+
+def test_gather_counters_follow_the_rows_each_layer_routed(monkeypatch):
+    """``moe.gather_rows_walked`` over ``moe.gather_rows_buffer`` is
+    ``ceil(pairs_local / chunk) * chunk / pairs_total`` layer by layer on
+    a seeded step: two layers that hold different shares, each with three
+    gathers into sorted order (1.0 where every pair is held and the chunk
+    divides the buffer; a last turn that overhangs re-reads rows, which
+    are counted); and ``moe.plan`` names the chunk."""
+    from mxnet_tpu import observability as obs
+
+    chunk = 32
+    monkeypatch.setattr(moe, "_GATHER_CHUNK_ROWS", chunk)
+    layers = [moe.MoELayer(D, F, E, top_k=K, routing="dropless",
+                           experts_held=held) for held in ((4, 4), (0, E))]
+    x = mx.nd.array(onp.random.RandomState(2).randn(2, N // 2, D))
+    ratios, walked, whole = [], 0.0, 0.0
+    tracer = obs.enable_tracing()
+    try:
+        for layer in layers:
+            layer.initialize()
+            with mx.autograd.train_mode():
+                layer(x)
+            got = moe.read_routing_counters(layer)
+            want = -(-got["moe.pairs_local"] // chunk) * chunk
+            assert got["moe.gather_rows_walked"] == 3 * want
+            assert got["moe.gather_rows_buffer"] == 3 * N * K
+            ratios.append(want / got["moe.pairs_total"])
+            walked += 3 * want
+            whole += 3 * N * K
+        plans = [e.attrs for e in tracer.spans(name="moe.plan")
+                 if "gather_chunk_rows" in e.attrs]
+    finally:
+        obs.disable_tracing()
+    assert 0 < ratios[0] < ratios[1] == 1.0
+    assert plans and all(e["gather_chunk_rows"] == chunk
+                         and e["buffer_rows"] == N * K for e in plans)
+    # a net of both: the sums over the layers
+    net = mx.gluon.nn.HybridSequential()
+    net.add(*layers)
+    both = moe.read_routing_counters(net)
+    assert both["moe.gather_rows_walked"] == walked
+    assert both["moe.gather_rows_buffer"] == whole
+    monkeypatch.setattr(moe, "_GATHER_CHUNK_ROWS", CHUNK)   # 288 = 4.5 x 64
+    assert moe.read_routing_counters(layers[1])[
+        "moe.gather_rows_walked"] == 3 * 5 * CHUNK
 
 
 def test_layer_is_told_what_it_holds_and_counts_what_it_routes():

@@ -15,11 +15,17 @@
   its residuals computed outside the timing), then each path's gradients
   against the chunked form in float32.  Until PR 41 the kernel path's
   backward was the chunked form run once more and differentiated;
-* the flash kernels with 32 query heads over 2 key/value heads at T 8,192.
+* the flash kernels with 32 query heads over 2 key/value heads at T 8,192;
+* ``gather``: the expert buffer's gather into sorted order
+  (``models.moe._gather_routed``: a walk over the routed rows, a chunk a
+  turn) alone, at the three expert cells' buffers, with 1/16, 1/4 and ALL
+  of the pairs held, from the stream (N, D) and from a buffer-sized source
+  (``dy``), against the whole-buffer gather it replaced; then the walk at
+  other chunk sizes, at Qwen3-Next's buffer.
 
-    chiprun -- python3 tools/hybrid_kernels_bench.py [gmm] [ssd] [flash]
+    chiprun -- python3 tools/hybrid_kernels_bench.py [gmm] [ssd] [flash] [gather]
 
-(no name: all three parts).
+(no name: all four parts).
 """
 from __future__ import annotations
 
@@ -145,13 +151,58 @@ def bench_flash(key):
                           "fwd_bwd_ms": timed(gr, q, k, v)}), flush=True)
 
 
+def bench_gather(key):
+    from mxnet_tpu.models import moe
+
+    bf, n = jnp.bfloat16, 8192
+    whole = jax.jit(lambda src, idx: src[idx])
+    walk = jax.jit(moe._gather_routed)
+
+    def sources(top_k, d):
+        rows = n * top_k
+        order = jax.random.permutation(jax.random.fold_in(key, d), rows)
+        return {"stream": (jax.random.normal(key, (n, d), bf),
+                           (order % n).astype(jnp.int32)),
+                "buffer": (jax.random.normal(key, (rows, d), bf),
+                           order.astype(jnp.int32))}
+
+    cells = {"nemotron_tt": (6, 2688), "qwen3_next": (10, 2048),
+             "mellum2": (8, 2304)}
+    for cell, (top_k, d) in cells.items():
+        rows = n * top_k
+        for name, (src, idx) in sources(top_k, d).items():
+            line = {"gather": cell, "source": name, "buffer_rows": rows,
+                    "d": d, "chunk_rows": moe.gather_chunk_rows(rows),
+                    "whole_ms": timed(whole, src, idx, n=20)}
+            for share in (16, 4, 1):
+                line[f"held_1/{share}_ms"] = timed(
+                    walk, src, idx, jnp.asarray(rows // share, jnp.int32),
+                    n=20)
+            print(json.dumps(line), flush=True)
+    top_k, d = cells["qwen3_next"]
+    rows, default = n * top_k, moe._GATHER_CHUNK_ROWS
+    src, idx = sources(top_k, d)["buffer"]
+    try:
+        for chunk in (256, 512, 1024, 2048, 4096, 8192):
+            moe._GATHER_CHUNK_ROWS = chunk
+            walk = jax.jit(moe._gather_routed)   # the size is read at trace
+            # 5,100 rows: what the cell's seeded routers send this chip
+            print(json.dumps({"gather_chunk_rows": chunk, **{
+                f"rows_{count}_ms": timed(walk, src, idx,
+                                          jnp.asarray(count, jnp.int32), n=20)
+                for count in (0, 5100, rows // 4, rows)}}), flush=True)
+    finally:
+        moe._GATHER_CHUNK_ROWS = default
+
+
 def main(argv):
     if jax.devices()[0].platform != "tpu":
         print("no TPU", file=sys.stderr)
         return 2
     import mxnet_tpu  # noqa: F401
 
-    parts = {"gmm": bench_gmm, "ssd": bench_ssd, "flash": bench_flash}
+    parts = {"gmm": bench_gmm, "ssd": bench_ssd, "flash": bench_flash,
+             "gather": bench_gather}
     unknown = [name for name in argv if name not in parts]
     if unknown:
         print(f"unknown part {unknown}: one of {list(parts)}",
