@@ -14,6 +14,8 @@ the list of layer kinds: docs/hybrid.md, "Adding a family".
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -50,12 +52,37 @@ def dense(x, w, cd):
                       preferred_element_type=jnp.float32)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rounded_back(u, cd):
+    """``u`` itself; its cotangent goes back rounded to ``cd`` and in its
+    own type, so the product that makes it writes it in ``cd``.  Nothing
+    is kept for the backward pass."""
+    return u
+
+
+_rounded_back.defvjp(
+    lambda u, cd: (u, None),
+    lambda cd, _, ct: (ct.astype(cd).astype(ct.dtype),))
+
+
 def gated_mlp(x, w_in, w_out, cd):
-    """``(silu(g) * v) W_out^T`` with ``(g, v)`` the two halves, in that
-    order, of ``x W_in^T``; (out, in) weights, operands in ``cd``."""
-    u = dense(x, w_in, cd)
-    half = u.shape[-1] // 2
-    return dense(jax.nn.silu(u[..., :half]) * u[..., half:], w_out, cd)
+    """``(silu(g) * v) W_out^T`` with ``g = x W_in[:F]^T`` and ``v = x
+    W_in[F:]^T``: TWO products over the halves, gate rows first, of the
+    ONE leaf ``w_in`` (2F, D); (out, in) weights, operands in ``cd``,
+    float32 sums, the gate in float32.  No (rows, 2F) value exists, so
+    ``silu(g) * v`` is a product's epilogue and leaves in ``cd``; going
+    back, ``dg`` and ``dv`` are rounded to ``cd`` ONCE, where they are
+    made (a float32 form bought no digit: the MXU's single pass rounds
+    its operands on entry), and nothing beyond what JAX keeps for the
+    products is held.  Event ``mlp.plan`` a distinct shape."""
+    half = w_in.shape[0] // 2
+    rows = x.size // x.shape[-1]
+    plan_event("mlp.plan", form="halves", rows=rows, half=half,
+               compute_dtype=jnp.dtype(cd).name,
+               wide_bytes_a_call=rows * half * 4)
+    g = _rounded_back(dense(x, w_in[:half], cd), cd)
+    v = _rounded_back(dense(x, w_in[half:], cd), cd)
+    return dense(jax.nn.silu(g) * v, w_out, cd)
 
 
 def lm_loss(logits, labels):

@@ -5,7 +5,8 @@ for a DESCRIBED TPU v5e (no chip attached): what interpret mode cannot show
 These are compiles, not chip runs: they say nothing about results or
 times.  They run under the package-default matmul precision ('highest'),
 the setting every user process has.  Real widths of GPT-2 124M: 12 heads
-of 64.  No whole-model compile here (tier-1's time budget).
+of 64.  No whole-model compile at a real size here (tier-1's time budget):
+the whole steps at the end are tiny twins, read for what their text holds.
 """
 import math
 import os
@@ -515,3 +516,115 @@ def test_flash_share_of_one_at_head_size_128_compiles_for_v5e(chip):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     assert _kernels(compiled) == 3
+
+
+# ------- the dense gated feed-forward inside a whole step's text (PR 48)
+
+_T, _UNITS, _HALF = 64, 32, 48
+_DENSE_TWINS = {        # the tiny sizes of tests/test_decoder_shell.py
+    "granite": ("get_granite_hybrid", dict(
+        layer_types=("mamba", "attention"), vocab_size=256, vocab_held=32,
+        units=_UNITS, num_heads=4, num_kv_heads=2, head_dim=8, mamba_heads=4,
+        mamba_head_dim=16, state_size=8, chunk_size=16, mlp_hidden=_HALF)),
+    "sambay": ("get_phi4_flash", dict(
+        num_layers=4, vocab_size=64, units=_UNITS, num_heads=8,
+        num_kv_heads=4, head_dim=4, window=8, mlp_hidden=_HALF, d_inner=64,
+        state_size=4, conv_kernel=4, dt_rank=2)),
+    "ouro": ("get_ouro", dict(
+        num_layers=2, vocab_size=512, vocab_held=128, units=_UNITS,
+        num_heads=2, num_kv_heads=2, head_dim=16, mlp_hidden=_HALF)),
+}
+_EXPERT_TWIN = ("get_qwen3_next", dict(
+    num_layers=4, vocab_size=512, vocab_held=64, units=_UNITS, num_heads=4,
+    num_kv_heads=2, head_dim=16, linear_key_heads=2, linear_value_heads=4,
+    linear_key_dim=8, linear_value_dim=8, chunk_size=16, num_experts=16,
+    top_k=3, expert_hidden=24, shared_hidden=_HALF, experts_held=(8, 4)))
+
+
+def _lowered_twin_step(chips, monkeypatch, factory, kwargs):
+    """``(lowered, mlp.plan events)`` of one bf16 Adam step of a family's
+    tiny twin, recomputed block by block, lowered for ONE described chip
+    as ``chipbench/rehearse_hybrid.py`` lowers a cell's: shapes on the
+    described device in the arrays' place, nothing placed there.  The
+    program still takes its CPU branches (interpreted kernels): what is
+    read is what the chip's compiler makes of the products around them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, models, observability, parallel as par
+    from mxnet_tpu.models.hybrid_common import lm_loss
+    from mxnet_tpu.parallel import sharding, trainer as trainer_mod
+
+    keep = lambda value, sh: value
+    monkeypatch.setattr(sharding, "mesh_device_put", keep)
+    monkeypatch.setattr(trainer_mod, "_mesh_device_put", keep)
+    amp.init("bfloat16")
+    tracer = observability.enable_tracing()
+    try:
+        net = getattr(models, factory)(remat=True, **kwargs)
+        net.initialize()
+        mesh = par.make_mesh(devices=chips[:1])
+        tok = mx.nd.array(jnp.zeros((1, _T), jnp.int32), dtype="int32")
+        # a looped net takes the labels itself and returns its objective
+        own_loss = getattr(net, "passes", 1) > 1
+        with par.use_mesh(mesh):
+            t = par.ShardedTrainer(
+                net, "adam", loss=None if own_loss else lm_loss,
+                optimizer_params={"learning_rate": 1e-3}, mesh=mesh)
+            batch = ((tok, tok), ()) if own_loss else ((tok,), (tok,))
+            t.build(*batch)
+            params, aux, states, batch_v = t._device_args(*batch)
+            repl = NamedSharding(mesh, P())
+
+            def sds(vals, shs):
+                return tuple(jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                                  sharding=s)
+                             for v, s in zip(vals, shs))
+
+            of = lambda ps: tuple(sharding.param_sharding(p, mesh, t.rules)
+                                  for _n, p in ps)
+            scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=repl)
+            lowered = t._step_fn.lower(
+                sds(params, of(t._trainable)), sds(aux, of(t._aux)),
+                sds(states, t._state_shardings),
+                sds(batch_v, t.batch_shardings),
+                jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl),
+                scalar(jnp.float32), scalar(jnp.int32))
+        plans = [s.attrs for s in tracer.spans(name="mlp.plan")]
+    finally:
+        observability.disable_tracing()
+        amp.reset()
+    return lowered, plans
+
+
+@pytest.mark.parametrize("family", sorted(_DENSE_TWINS))
+def test_gated_mlp_leaves_no_wide_float32_in_a_step_for_v5e(
+        chips, monkeypatch, family):
+    """``gated_mlp`` inside a whole recomputed bf16 step of each family
+    that runs it: the chip's compiler is handed two products over the
+    halves of ``w_in``, so no float32 value of (T, 2F) is in the step's
+    text (the parent wrote ``f32[1,64,96]`` here, 537 MB a call at the
+    Granite cell's size), and ``dg``, ``dv`` leave the product that makes
+    them rounded, never as a float32 pair."""
+    lowered, plans = _lowered_twin_step(chips, monkeypatch,
+                                        *_DENSE_TWINS[family])
+    assert plans == [{"form": "halves", "rows": _T, "half": _HALF,
+                      "compute_dtype": "bfloat16",
+                      "wide_bytes_a_call": _T * _HALF * 4}]
+    text = lowered.compile().as_text()
+    # the parser sees the step: the weight's optimizer state is there
+    assert f"f32[{2 * _HALF},{_UNITS}]" in text
+    wide = re.findall(rf"f32\[(?:1,)?{_T},{2 * _HALF}\]", text)
+    assert not wide, sorted(set(wide))
+    f32_half = rf"f32\[(?:1,)?{_T},{_HALF}\](?:\{{[^}}]*\}})?"
+    pairs = re.findall(rf"\({f32_half}, {f32_half}\)", text)
+    assert not pairs, sorted(set(pairs))
+
+
+def test_an_expert_family_never_reaches_gated_mlp(chips, monkeypatch):
+    """The bypass, held statically: an expert family's shared expert is
+    ``models/moe.py``'s own, so lowering its whole step traces no
+    ``gated_mlp`` (which would leave an ``mlp.plan`` event under the
+    tracer) and its text is what it was before PR 48."""
+    _lowered, plans = _lowered_twin_step(chips, monkeypatch, *_EXPERT_TWIN)
+    assert plans == []
