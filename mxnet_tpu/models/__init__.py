@@ -6,6 +6,7 @@ transformer) — all built on TP/SP-aware blocks (see models.transformer).
 """
 from . import vision
 from .bert import BERTForPretrain, BERTModel, get_bert
+from .deepseek_v3 import DeepseekV3Model, get_deepseek_v3
 from .gpt2 import GPT2Model, get_gpt2, gpt2_lm_loss
 from .granite_hybrid import GraniteHybridModel, get_granite_hybrid
 from .mellum import MellumModel, get_mellum
@@ -29,4 +30,4 @@ __all__ = ["vision", "get_model", "BERTModel", "BERTForPretrain", "get_bert",
            "nmt_loss", "NemotronHModel", "get_nemotron_h", "Qwen3NextModel",
            "get_qwen3_next", "GraniteHybridModel", "get_granite_hybrid",
            "Phi4FlashModel", "get_phi4_flash", "MellumModel", "get_mellum",
-           "OuroModel", "get_ouro"]
+           "OuroModel", "get_ouro", "DeepseekV3Model", "get_deepseek_v3"]

@@ -1,12 +1,14 @@
 """How a hybrid decoder (``nemotron_h``, ``qwen3_next``, ``granite_hybrid``,
-``phi4_flash``, ``mellum``, ``ouro``) is put together, written once: the
+``phi4_flash``, ``mellum``, ``ouro``, ``deepseek_v3``) is put together,
+written once: the
 float32 RMS norm, the dense product in the compute type and the loss over
 the vocabulary rows a chip holds; the shell (:class:`HybridDecoder`) with
 its two heads, run once or, on ONE set of weights, several times with an
 exit gate a pass (:func:`exit_distribution`, :func:`exit_objective`); the
 residual half-layer over a mixer (:class:`HalfLayer`, over :func:`fused`);
-the expert half (:class:`ExpertBlock`); and grouped-query attention's
-projections (:class:`QKVOProjections`, :func:`positioned`).
+the expert half (:class:`ExpertBlock`) and the dense gated one
+(:class:`GatedMLP`); and grouped-query attention's projections
+(:class:`QKVOProjections`, :func:`positioned`).
 
 A family is then its mixers (a block with ``mix(normed, *weights, cd)``
 and ``params_in_order()``), its sizes, and how its configuration spells
@@ -32,7 +34,8 @@ from .transformer import run_blocks
 
 __all__ = ["rms", "dense", "gated_mlp", "lm_loss", "token_loss",
            "exit_distribution", "exit_objective", "fused", "HalfLayer",
-           "ExpertBlock", "two_halves", "QKVOProjections", "positioned",
+           "ExpertBlock", "two_halves", "GatedMLP", "QKVOProjections",
+           "positioned",
            "OwnHead", "TiedHead", "HybridDecoder", "read_loop_counters"]
 
 
@@ -201,14 +204,36 @@ class ExpertBlock(HybridBlock):
         return x + getattr(self, self._experts)(self.norm(x))
 
 
-def two_halves(kinds, mixer_half, expert_half, second="experts"):
+def two_halves(kinds, mixer_half, second_half, second="experts"):
     """``(name, block)`` for decoder layers of TWO blocks, each recomputed
     on its own: ``l{i}_mixer = mixer_half(kinds[i])`` and ``l{i}_experts =
-    expert_half()`` (``second`` names a second half that is no expert
-    half)."""
+    second_half(i)``.  The second half is made BY LAYER: its factory is
+    handed the layer's index (a stack whose leading layers are dense and
+    whose others hold experts answers with a block of either kind), and
+    ``second``, a name or a function of that index, names a second half
+    that is no expert half."""
     for i, kind in enumerate(kinds):
         yield f"l{i}_mixer", mixer_half(kind)
-        yield f"l{i}_{second}", expert_half()
+        name = second(i) if callable(second) else second
+        yield f"l{i}_{name}", second_half(i)
+
+
+class GatedMLP(HybridBlock):
+    """The dense SwiGLU feed-forward as a mixer: ``gate_up`` (2 F, U),
+    gate rows first, and ``down`` (U, F); no bias."""
+
+    def __init__(self, units, hidden, dtype="float32", **kwargs):
+        super().__init__(**kwargs)
+        self.gate_up = self.params.get("gate_up", shape=(2 * hidden, units),
+                                       dtype=dtype, init="xavier")
+        self.down = self.params.get("down", shape=(units, hidden),
+                                    dtype=dtype, init="xavier")
+
+    def mix(self, hn, w_in, w_out, cd):
+        return gated_mlp(hn, w_in, w_out, cd)
+
+    def params_in_order(self):
+        return [self.gate_up, self.down]
 
 
 class QKVOProjections(HybridBlock):
@@ -343,12 +368,16 @@ class HybridDecoder(HybridBlock):
     each trace leaves a ``loop.plan`` event, whose ``layers`` counts the
     blocks' names before an underscore (``l3_mixer`` and ``l3_experts``
     are one layer).
+
+    ``plan`` = ``(name, attributes)``: an event a family wants left as its
+    forward is traced (what it holds of the published stack).
     """
 
     def __init__(self, blocks, norm, head, vocab_size, units, eps,
                  vocab_held=None, remat=False, dtype="float32",
-                 embed_multiplier=None, passes=1, exit_beta=None):
+                 embed_multiplier=None, passes=1, exit_beta=None, plan=None):
         super().__init__()
+        self._plan = plan
         self.vocab_size = vocab_size
         self.vocab_held = int(vocab_held or vocab_size)
         self._remat, self._eps, self._emb = remat, eps, embed_multiplier
@@ -368,6 +397,8 @@ class HybridDecoder(HybridBlock):
             self._declare_loop(units, dtype)
 
     def forward(self, tokens, labels=None):
+        if self._plan is not None:
+            plan_event(self._plan[0], **self._plan[1])
         x = self.embed(tokens)
         if self._emb is not None:
             x = x * self._emb

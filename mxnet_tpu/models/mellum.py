@@ -105,7 +105,7 @@ class MellumModel(HybridDecoder):
         super().__init__(
             two_halves(
                 kinds, lambda kind: AttentionBlock(kind, cfg, dtype=dtype),
-                lambda: ExpertBlock(
+                lambda _: ExpertBlock(
                     cfg, scoring="softmax", expert_form="swiglu",
                     experts_held=experts_held, shared_hidden=0,
                     record_choice_rows=record_choice_rows, dtype=dtype)),

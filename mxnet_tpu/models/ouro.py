@@ -16,8 +16,9 @@ layer, plain gains)::
   head's dimensions (``rope_theta``, the same positions in every pass);
   causal softmax attention over all earlier keys OF THIS PASS
   (:mod:`mxnet_tpu.ops.flash` on the TPU); ``o_proj``.
-* :class:`GatedMLP` — the dense gated feed-forward ``(silu(x Wg^T) * (x
-  Wu^T)) Wd^T``, gate and up one ``gate_up`` matrix halved in that order.
+* :class:`~mxnet_tpu.models.hybrid_common.GatedMLP` — the dense gated
+  feed-forward ``(silu(x Wg^T) * (x Wu^T)) Wd^T``, gate and up one
+  ``gate_up`` matrix halved in that order.
 
 Pass ``t``: ``h_t = RMSNorm_f(stack(h_{t-1}))``, the final norm closing
 EVERY pass and its output entering the next; ``logits_t = h_t W_head^T``
@@ -31,11 +32,9 @@ not built.
 """
 from __future__ import annotations
 
-from ..gluon.block import HybridBlock
 from ..gluon.nn import RMSNorm
-from .hybrid_common import (HalfLayer, HybridDecoder, OwnHead,
-                            QKVOProjections, gated_mlp, read_loop_counters,
-                            two_halves)
+from .hybrid_common import (GatedMLP, HalfLayer, HybridDecoder, OwnHead,
+                            QKVOProjections, read_loop_counters, two_halves)
 
 __all__ = ["OuroModel", "OuroAttention", "GatedMLP", "get_ouro",
            "read_loop_counters"]
@@ -73,24 +72,6 @@ class OuroAttention(QKVOProjections):
         return self.merged(flash_attention(q, k, v, causal=True), wo, cd)
 
 
-class GatedMLP(HybridBlock):
-    """The dense SwiGLU feed-forward as a mixer: ``gate_up`` (2 F, U),
-    gate rows first, and ``down`` (U, F); no bias."""
-
-    def __init__(self, units, hidden, dtype="float32", **kwargs):
-        super().__init__(**kwargs)
-        self.gate_up = self.params.get("gate_up", shape=(2 * hidden, units),
-                                       dtype=dtype, init="xavier")
-        self.down = self.params.get("down", shape=(units, hidden),
-                                    dtype=dtype, init="xavier")
-
-    def mix(self, hn, w_in, w_out, cd):
-        return gated_mlp(hn, w_in, w_out, cd)
-
-    def params_in_order(self):
-        return [self.gate_up, self.down]
-
-
 class OuroModel(HybridDecoder):
     """tokens (B, T) int32 -> ``(logits (P, B, T, vocab_held), gates (P, B,
     T))``; with labels, the looped objective.  ``total_ut_steps`` is P.
@@ -109,7 +90,7 @@ class OuroModel(HybridDecoder):
                         units, cfg["num_heads"], cfg["num_kv_heads"],
                         cfg["head_dim"], cfg["rope_theta"], dtype=dtype),
                     post_norm=True),
-                lambda: HalfLayer(
+                lambda _: HalfLayer(
                     "ouro_mlp_layer", cfg,
                     GatedMLP(units, cfg["mlp_hidden"], dtype=dtype),
                     post_norm=True),

@@ -188,7 +188,7 @@ class Qwen3NextModel(HybridDecoder):
         super().__init__(
             two_halves(
                 kinds, lambda kind: MixerBlock(kind, cfg, dtype=dtype),
-                lambda: ExpertBlock(
+                lambda _: ExpertBlock(
                     cfg, unit_offset=True, scoring="softmax",
                     expert_form="swiglu", experts_held=experts_held,
                     shared_hidden=cfg["shared_hidden"], shared_gate=True,

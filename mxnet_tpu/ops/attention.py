@@ -27,20 +27,30 @@ _NEG_INF = -1e30
 # ----------------------------------------------------------------- reference
 
 def _attention_ref(q, k, v, *, causal=False, mask=None, scale=None,
-                   dropout=0.0, dropout_key=None, window=None):
+                   dropout=0.0, dropout_key=None, window=None, q2=None,
+                   k2=None):
     """Pure-jax attention; q/k/v are (B, T, H, D).  XLA fuses this well for
     moderate T; the Pallas kernel takes over for long sequences.  Fewer
     K/V heads than query heads are repeated here (grouped queries; the
     values may have a head count and a head dim of their own).  ``window``
-    (with ``causal``): query t sees keys s with ``0 <= t - s < window``."""
-    d = q.shape[-1]
-    if k.shape[2] != q.shape[2]:
-        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
-    if v.shape[2] != q.shape[2]:
-        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
+    (with ``causal``): query t sees keys s with ``0 <= t - s < window``.
+    ``q2`` / ``k2`` (B, T, H or fewer, D2): a second product added to the
+    score, which is then over D + D2 dimensions."""
+    d = q.shape[-1] + (0 if q2 is None else q2.shape[-1])
+
+    def per_query_head(x):
+        return x if x.shape[2] == q.shape[2] else \
+            jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+
+    k, v = per_query_head(k), per_query_head(v)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
+                        preferred_element_type=jnp.float32)
+    if q2 is not None:
+        logits = logits + jnp.einsum("bqhd,bkhd->bhqk", q2,
+                                     per_query_head(k2),
+                                     preferred_element_type=jnp.float32)
+    logits = logits * scale
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         idx_q = jnp.arange(tq)[:, None] + (tk - tq)
@@ -158,16 +168,24 @@ def rotary_embedding(x, positions=None, *, theta=10000.0, rotary_dim=None,
 # ------------------------------------------------------------------ dispatch
 
 def _use_flash(q_shape, causal, mask, dropout, k_shape=None,
-               platform=None) -> bool:
+               platform=None, q2_shape=None, k2_shape=None) -> bool:
     """Flash kernel handles: SELF-attention (tq == tk — cross-attention
     with a different source length falls back to the XLA path), no
     explicit mask, no attention dropout, long 128-aligned sequences,
-    head dims the MXU tiles well (64/128/256).  ``platform`` is where the
-    op will execute (resolved per-call — a cpu()-context op on a TPU host
-    must take the XLA reference path, not compiled Pallas)."""
+    head dims the MXU tiles well (64/128/256), and a score wider than
+    that by a second pair of operands of head dim 64 or 128 whose key has
+    as many heads as the query or a whole share of them (``q2_shape``,
+    ``k2_shape``: 128 + 64 = 192 is latent attention's).  ``platform`` is
+    where the op will execute (resolved per-call — a cpu()-context op on a
+    TPU host must take the XLA reference path, not compiled Pallas)."""
     if mask is not None or dropout > 0.0:
         return False
     b, t, h, d = q_shape
+    if q2_shape is not None:
+        d2, h2 = q2_shape[3], k2_shape[2]
+        if tuple(q2_shape) != (b, t, h, d2) or d2 not in (64, 128) or \
+                tuple(k2_shape) != (b, t, h2, d2) or h2 == 0 or h % h2:
+            return False
     if k_shape is not None and tuple(k_shape) != tuple(q_shape):
         # fewer key/value heads than query heads (grouped queries) is
         # the kernel's; another length or head dim is not
@@ -180,7 +198,7 @@ def _use_flash(q_shape, causal, mask, dropout, k_shape=None,
 
 
 def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None,
-                  window=None):
+                  window=None, q2=None, k2=None):
     """The Pallas kernel, run per device.  GSPMD cannot partition a Mosaic
     kernel (jax refuses to lower one into a multi-device program), and
     batch rows and heads attend independently — so under an ambient
@@ -191,17 +209,20 @@ def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None,
     from ..parallel.mesh import axis_size, current_mesh
     from .flash import flash_attention as _pallas
 
-    def body(q, k, v, *seg):
-        return _pallas(q, k, v, causal=causal, scale=scale,
-                       segment_ids=seg[0] if seg else None,
-                       kv_segment_ids=seg[1] if seg else None,
-                       window=window)
+    # beside q, k and v go the segment ids or the second pair of operands
+    names = ("q2", "k2") if q2 is not None else \
+        ("segment_ids", "kv_segment_ids")
+    more = (q2, k2) if q2 is not None else \
+        () if q_seg is None else (q_seg, kv_seg)
 
-    seg = () if q_seg is None else (q_seg, kv_seg)
+    def body(q, k, v, *more):
+        return _pallas(q, k, v, causal=causal, scale=scale, window=window,
+                       **dict(zip(names, more)))
+
     mesh = current_mesh()
     if mesh is None or mesh.size == 1 or \
             jax.sharding.get_abstract_mesh().manual_axes:
-        return body(q, k, v, *seg)
+        return body(q, k, v, *more)
 
     def over(axis, dim):
         return axis if axis in mesh.axis_names and \
@@ -210,19 +231,31 @@ def _pallas_flash(q, k, v, *, causal, scale, q_seg=None, kv_seg=None,
     b_ax, h_ax = over("dp", q.shape[0]), over("tp", q.shape[2])
     if None in (over("tp", k.shape[2]), over("tp", v.shape[2])):
         h_ax = None                      # fewer K/V heads than tp shards
+    specs = (P(b_ax, None),) * len(more)
+    if q2 is not None:
+        one = k2.shape[2] == 1           # ONE key head is every shard's
+        if not one and over("tp", k2.shape[2]) is None:
+            h_ax = None
+        specs = (P(b_ax, None, h_ax, None),
+                 P(b_ax, None, None if one else h_ax, None))
     return shard_mapped_qkv(body, mesh, P(b_ax, None, h_ax, None), q, k, v,
-                            *seg, extra_specs=(P(b_ax, None),) * len(seg))
+                            *more, extra_specs=specs)
 
 
-def flash_attention(q, k, v, *, causal=False, scale=None, window=None):
+def flash_attention(q, k, v, *, causal=False, scale=None, window=None,
+                    q2=None, k2=None):
     """Jax-level flash attention entry (Pallas on TPU, reference on CPU).
     ``v`` may be (B, T, H_v, Dv), its head count and head dim its own;
-    ``window`` is a causal window in keys (see ``ops.flash``)."""
+    ``window`` is a causal window in keys; ``q2`` / ``k2`` a second pair of
+    score operands whose product is added to ``q k^T`` (see
+    ``ops.flash``)."""
+    second = {} if q2 is None else dict(q2_shape=q2.shape, k2_shape=k2.shape)
     if _use_flash(q.shape, causal, None, 0.0, k.shape,
-                  platform=_base.resolve_exec_platform(q)):
+                  platform=_base.resolve_exec_platform(q), **second):
         return _pallas_flash(q, k, v, causal=causal, scale=scale,
-                             window=window)
-    return _attention_ref(q, k, v, causal=causal, scale=scale, window=window)
+                             window=window, q2=q2, k2=k2)
+    return _attention_ref(q, k, v, causal=causal, scale=scale, window=window,
+                          q2=q2, k2=k2)
 
 
 def dot_product_attention(query, key, value, *, causal=False, mask=None,

@@ -154,7 +154,7 @@ class TilePlan(NamedTuple):
 
 
 def _vmem_bytes(block: int, chunk: int, major: int, d: int, itemsize: int,
-                has_seg: bool = False, group: int = 1) -> int:
+                has_seg: bool = False, group: int = 1, d2: int = 0) -> int:
     """Working-set model of one grid step, sized for the WORST of the
     three kernels, per head of the step's ``group``: two live
     (block, chunk) f32 score-tile temporaries (s→p and dp→ds are reused
@@ -164,7 +164,9 @@ def _vmem_bytes(block: int, chunk: int, major: int, d: int, itemsize: int,
     and output tiles (dq reads q, do; dkv writes dk, dv); and the largest
     f32 scratch set (fwd and dq: an accumulator and two lane-replicated
     row tiles).  The minor dim pads to 128 lanes.  Segments add their
-    resident int32 row."""
+    resident int32 row.  A second pair of score operands ``d2`` wide adds
+    one more walked operand, one more own tile, one more output tile (in
+    float32: dkv's, of a shared head) and its accumulator."""
     dl = max(d, _LANES)
     score = 3 * 4 * block * chunk
     resident = 2 * (2 * major * dl * itemsize + 2 * 8 * major * 4)
@@ -173,7 +175,12 @@ def _vmem_bytes(block: int, chunk: int, major: int, d: int, itemsize: int,
     scratch = 4 * max(block * dl + 2 * block * _LANES,   # fwd, dq
                       2 * block * dl)                    # dkv: dk + dv
     seg = 2 * 8 * 4 * (major + block) if has_seg else 0
-    return group * (score + resident + tiles + outs + scratch) + seg
+    second = 0
+    if d2:
+        d2l = max(d2, _LANES)
+        second = (2 * (major + block) * d2l * itemsize
+                  + (2 + 1) * 4 * block * d2l)
+    return group * (score + resident + tiles + outs + scratch + second) + seg
 
 
 def _largest_stretch(t: int, chunk: int, fits) -> int:
@@ -187,7 +194,7 @@ def _largest_stretch(t: int, chunk: int, fits) -> int:
 
 def _clamp_blocks(blocks, chunk: int, tq: int, tk: int, d: int,
                   itemsize: int, has_seg: bool = False, group: int = 1,
-                  wide: Optional[int] = None):
+                  wide: Optional[int] = None, d2: int = 0):
     """(block, major, major_q, group) that fit the VMEM budget, from the
     candidate ``blocks`` (largest first).  What is kept longest is what
     the chip showed to matter most: the largest block (fewest grid steps,
@@ -201,7 +208,7 @@ def _clamp_blocks(blocks, chunk: int, tq: int, tk: int, d: int,
     of, where that is not the chunk."""
     def fits(block, major, group=1):
         return _vmem_bytes(block, wide or chunk, major, d, itemsize,
-                           has_seg, group) <= _VMEM_BUDGET
+                           has_seg, group, d2) <= _VMEM_BUDGET
 
     longest = max(tq, tk)
     block = next((b for b in blocks if fits(b, min(longest, 2 * b))),
@@ -343,7 +350,8 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
               block_q: Optional[int] = None,
               chunk: Optional[int] = None,
               window: Optional[int] = None, dv: Optional[int] = None,
-              v_heads: Optional[int] = None) -> TilePlan:
+              v_heads: Optional[int] = None, d2: int = 0,
+              k2_heads: Optional[int] = None) -> TilePlan:
     """The one place a flash call's sizes are computed: from the sequence
     lengths, head dim, dtype, the masks in play and the number of heads.
     ``block_q`` / ``chunk`` override the starting sizes (tests only; a
@@ -363,9 +371,14 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     heads (``kv_heads``; ``v_heads`` where the values' differ from the
     keys') a step takes one query head, and reads the K/V head it shares
     by an index map.  ``dv`` is the values' head dim where it is not the
-    keys' (VMEM is reckoned at the wider).  Under a causal ``window`` a
-    block is no taller than the window (what its rows see of older keys
-    is then at most a window wide) and walks its band by the two edges
+    keys' (VMEM is reckoned at the wider).  ``d2`` is the width of a
+    second pair of score operands whose product is added to ``q k^T``
+    (their lanes are reckoned in VMEM beside q's and k's) and
+    ``k2_heads`` the heads its key has: fewer than the query heads make a
+    step take one query head, as shared K/V heads do.  Under a causal
+    ``window`` a block is no taller than the window (what its rows see of
+    older keys is then at most a window wide) and walks its band by the
+    two edges
     (:func:`_band`): the trailing edge is cut into the same slabs as the
     diagonal, narrowed where they must be to lie in one stretch whole,
     and the counts are of that schedule."""
@@ -403,7 +416,7 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     group = DEFAULT_GROUP
     while heads % group:
         group //= 2
-    for shared in (kv_heads, v_heads):
+    for shared in (kv_heads, v_heads, k2_heads):
         if shared is not None and shared != heads:
             if heads % shared:
                 raise ValueError(f"{heads} query heads do not divide over "
@@ -414,7 +427,7 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     wide = max(chunk, fit(slabs[0], blocks[-1]))
     block, major, major_q, group = _clamp_blocks(
         blocks, chunk, tq, tk, d, jnp.dtype(dtype).itemsize, has_seg, group,
-        wide)
+        wide, d2)
     # a slab under a window lies in one stretch whole (_walk's sections)
     within = math.gcd(block, major, major_q) if window else block
     slab, slab_bwd = (fit(x, within) for x in slabs)
@@ -440,10 +453,13 @@ def plan_event(name: str, **attrs):
 
 
 def _report_plan(plan: TilePlan, tq, tk, d, dtype, causal, has_seg,
-                 window=None, dv=None):
-    """``window`` and ``dv`` are attributes only of a call that has a
-    window, or values wider than its keys."""
+                 window=None, dv=None, d2=0, k2_heads=None):
+    """``window``, ``dv``, and ``d2`` with ``k2_heads`` are attributes
+    only of a call that has a window, values wider than its keys, or a
+    second pair of score operands."""
     more = {}
+    if d2:
+        more.update(d2=int(d2), k2_heads=int(k2_heads))
     if window is not None:
         more["window"] = int(window)
     if dv is not None and dv != d:
@@ -686,8 +702,32 @@ def _lanes_to_rows(row, rows: int):
     return jnp.broadcast_to(row, (_LANES, rows)).T
 
 
+def _second(refs, at: int, two: bool):
+    """``(q2_ref, k2_ref, the other refs)``: the second pair of score
+    operands, which a call that has one hands in at place ``at``."""
+    if not two:
+        return None, None, refs
+    return refs[at], refs[at + 1], refs[:at] + refs[at + 2:]
+
+
+def _score(own_ref, walked, own2_ref, walk2_ref, g, rows, start, width,
+           scale):
+    """The (rows, width) score tile of head ``g``: the block's ``rows``
+    against ``walked``, the (width, d) slice ``[start, start + width)``
+    of the walked operand, times ``scale``; with a second pair of
+    operands their product over the same rows and slice is added BEFORE
+    the scale: one score over both widths."""
+    r0, r1 = rows
+    s = _dot(own_ref[g, r0:r1, :], walked, 1, 1)
+    if own2_ref is not None:
+        s = s + _dot(own2_ref[g, r0:r1, :],
+                     walk2_ref[g, pl.ds(start, width), :], 1, 1)
+    return s * scale
+
+
 def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
-                window=None):
+                window=None, two=False):
+    q2_ref, k2_ref, refs = _second(refs, 3, two)
     if has_seg:
         (q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
          o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
@@ -719,7 +759,8 @@ def _fwd_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
         # latencies
         for g in range(group):
             k = k_ref[g, pl.ds(start, width), :]       # (width, d)
-            s = _dot(q_ref[g, r0:r1, :], k, 1, 1) * scale  # (rows, width)
+            s = _score(q_ref, k, q2_ref, k2_ref, g, rows, start, width,
+                       scale)                          # (rows, width)
             if keep is not None:
                 s = _where_rows(keep, s, _MASK, sub)
             m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -841,7 +882,7 @@ _PARAMS = pltpu.CompilerParams(
 
 
 def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
-         window=None):
+         window=None, q2=None, k2=None):
     bh, tq, d = q.shape
     kv_row = _kv_row(nheads, k.shape[0] * nheads // bh)
     v_row = _kv_row(nheads, v.shape[0] * nheads // bh)
@@ -852,13 +893,23 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
     has_seg = q_seg is not None
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, has_seg=has_seg,
-        block_q=block_q, chunk=chunk, slab=plan.slab, nm=nm, window=window)
+        block_q=block_q, chunk=chunk, slab=plan.slab, nm=nm, window=window,
+        two=q2 is not None)
     in_specs = [_own_spec((group, block_q, d)),
                 _walked_spec((group, -1, d), block_q, major, causal, True,
                              row=kv_row, window=window),
                 _walked_spec((group, -1, dv), block_q, major, causal, True,
                              row=v_row, window=window)]
     args = [q, k, v]
+    if q2 is not None:
+        d2 = q2.shape[2]
+        in_specs += [_own_spec((group, block_q, d2)),
+                     _walked_spec((group, -1, d2), block_q, major, causal,
+                                  True, window=window, row=_kv_row(
+                                      nheads, k2.shape[0] * nheads // bh))]
+        args += [q2, k2]
+        d += d2                             # the score's width, for the cost
+    moved = sum(x.size for x in args)       # q, k, v and a second pair
     if has_seg:
         in_specs += _seg_specs(lambda b: b * group // nheads, block_q,
                                major, causal, True)
@@ -891,7 +942,7 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * bh * tq * tk * (d + dv) // half,
             transcendentals=bh * tq * tk // half,
-            bytes_accessed=2 * (q.size + k.size + v.size) * q.dtype.itemsize),
+            bytes_accessed=2 * moved * q.dtype.itemsize),
         interpret=interpret,
     )(*args)
     return out, lse
@@ -900,7 +951,12 @@ def _fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan, interpret,
 # --------------------------------------------------------------------- bwd
 
 def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
-               window=None):
+               window=None, two=False):
+    q2_ref, k2_ref, refs = _second(refs, 6, two)
+    dq2_ref = acc2_ref = None
+    if two:             # the second operand's dq and its accumulator
+        *refs, acc2_ref = refs
+        dq2_ref = refs.pop(-4)
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          qseg_ref, kseg_ref, dq_ref, acc_ref, lse_b, delta_b) = refs
@@ -916,6 +972,8 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
     def _init():
         if not fold:
             acc_ref[:] = jnp.zeros_like(acc_ref)
+            if two:
+                acc2_ref[:] = jnp.zeros_like(acc2_ref)
         # the block's lse and delta, stored along lanes, turned into
         # lane-replicated row tiles once, not at every chunk
         for g in range(group):
@@ -932,15 +990,19 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
                           else None, window, sides)
         for g in range(group):
             k = k_ref[g, pl.ds(start, width), :]       # (width, d)
-            s = _dot(q_ref[g, r0:r1, :], k, 1, 1) * scale  # (rows, width)
+            s = _score(q_ref, k, q2_ref, k2_ref, g, rows, start, width,
+                       scale)                          # (rows, width)
             p = jnp.exp(s - _lanes(lse_b[g, r0:r1, :], width))
             if keep is not None:
                 p = _where_rows(keep, p, 0.0, sub)
             dp = _dot(do_ref[g, r0:r1, :],
                       v_ref[g, pl.ds(start, width), :], 1, 1)
-            ds = p * (dp - _lanes(delta_b[g, r0:r1, :], width)) * scale
-            _accumulate(acc_ref, g, rows, fresh,
-                        _dot(ds.astype(k.dtype), k, 1, 0))
+            ds = (p * (dp - _lanes(delta_b[g, r0:r1, :], width))
+                  * scale).astype(k.dtype)
+            _accumulate(acc_ref, g, rows, fresh, _dot(ds, k, 1, 0))
+            if two:
+                _accumulate(acc2_ref, g, rows, fresh, _dot(
+                    ds, k2_ref[g, pl.ds(start, width), :], 1, 0))
 
     _walk(step, causal=causal, up=True, i=qi, mi=mi, nm=nm, block=block_q,
           chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
@@ -949,14 +1011,21 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
     @_when(nm == 1, mi == nm - 1)
     def _finish():
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
+        if two:
+            dq2_ref[:] = acc2_ref[:].astype(dq2_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
-                window=None):
+                window=None, two=False):
     """Tiles are held transposed, (kv rows, q columns): lse and delta are
     stored along lanes, so they broadcast down the tile as they are, and
     both accumulating products contract the tile's columns with the rows
     of dO and Q — no transposed operand."""
+    q2_ref, k2_ref, refs = _second(refs, 6, two)
+    dk2_ref = dk2_acc = None
+    if two:             # the second operand's dk and its accumulator
+        *refs, dk2_acc = refs
+        dk2_ref = refs.pop(-3)
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -973,6 +1042,8 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
         def _init():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
+            if two:
+                dk2_acc[:] = jnp.zeros_like(dk2_acc)
 
     ks = kseg_ref[0, 0, :][:, None] if has_seg else None   # (bk, 1)
 
@@ -987,18 +1058,21 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
             do = do_ref[g, pl.ds(start, width), :]
             lse = lse_ref[g, :, pl.ds(start, width)]   # (1, width)
             delta = delta_ref[g, :, pl.ds(start, width)]
-            st = _dot(k_ref[g, r0:r1, :], q, 1, 1) * scale  # S^T: (rows,
-            p = jnp.exp(st - lse)                           #   width)
+            st = _score(k_ref, q, k2_ref, q2_ref, g, rows, start, width,
+                        scale)                         # S^T: (rows, width)
+            p = jnp.exp(st - lse)
             if keep is not None:
                 p = _where_rows(keep, p, 0.0, sub)
             # dV += P^T @ dO
             _accumulate(dv_acc, g, rows, fresh,
                         _dot(p.astype(do.dtype), do, 1, 0))
             dp = _dot(v_ref[g, r0:r1, :], do, 1, 1)    # dP^T
-            ds = p * (dp - delta) * scale
+            ds = (p * (dp - delta) * scale).astype(q.dtype)
             # dK += dS^T @ Q
-            _accumulate(dk_acc, g, rows, fresh,
-                        _dot(ds.astype(q.dtype), q, 1, 0))
+            _accumulate(dk_acc, g, rows, fresh, _dot(ds, q, 1, 0))
+            if two:
+                _accumulate(dk2_acc, g, rows, fresh, _dot(
+                    ds, q2_ref[g, pl.ds(start, width), :], 1, 0))
 
     _walk(step, causal=causal, up=False, i=ki, mi=mi, nm=nm, block=block_k,
           chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
@@ -1008,10 +1082,14 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
     def _finish():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+        if two:
+            dk2_ref[:] = dk2_acc[:].astype(dk2_ref.dtype)
 
 
 def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
-              plan, interpret, window=None):
+              plan, interpret, window=None, q2=None, k2=None):
+    """``(dq, dk, dv)``, and with a second pair of score operands
+    ``(dq, dk, dv, dq2, dk2)``."""
     bh, tq, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     block, chunk, group = plan.block_q, plan.chunk, plan.group
@@ -1032,6 +1110,23 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
                           row=v_row, window=window)
     dq_in_specs = [tile, k_walk, v_walk, tile_v, row, row]
     args = [q, k, v, do, lse, delta]
+    two = q2 is not None
+    dq_out, dq_shape = tile, jax.ShapeDtypeStruct((bh, tq, d), q.dtype)
+    dq_scratch = [pltpu.VMEM((group, block, d), jnp.float32),
+                  pltpu.VMEM((group, block, _LANES), jnp.float32),
+                  pltpu.VMEM((group, block, _LANES), jnp.float32)]
+    if two:
+        d2 = q2.shape[2]
+        k2_heads = k2.shape[0] * nheads // bh
+        k2_row = _kv_row(nheads, k2_heads)
+        tile2 = _own_spec((group, block, d2))
+        dq_in_specs += [tile2, _walked_spec(
+            (group, -1, d2), block, plan.major, causal, True, row=k2_row,
+            window=window)]
+        args += [q2, k2]
+        dq_out = [tile, tile2]
+        dq_shape = [dq_shape, jax.ShapeDtypeStruct((bh, tq, d2), q2.dtype)]
+        dq_scratch.append(pltpu.VMEM((group, block, d2), jnp.float32))
     if has_seg:
         dq_in_specs += _seg_specs(rows, block, plan.major, causal, True)
         args += [q_seg, kv_seg]
@@ -1039,15 +1134,13 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           has_seg=has_seg, block_q=block, chunk=chunk,
                           slab=plan.slab_bwd, nm=tk // plan.major,
-                          window=window),
+                          window=window, two=two),
         name="flash_bwd_dq",
         grid=(bh // group, tq // block, tk // plan.major),
         in_specs=dq_in_specs,
-        out_specs=tile,
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((group, block, d), jnp.float32),
-                        pltpu.VMEM((group, block, _LANES), jnp.float32),
-                        pltpu.VMEM((group, block, _LANES), jnp.float32)],
+        out_specs=dq_out,
+        out_shape=dq_shape,
+        scratch_shapes=dq_scratch,
         compiler_params=_PARAMS,
         interpret=interpret,
     )(*args)
@@ -1064,37 +1157,53 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     dkv_in_specs = [q_walk, _own_spec((group, block, d), row=kv_row),
                     _own_spec((group, block, dv), row=v_row), do_walk,
                     row_walk, row_walk]
+    dkv_out = [tile, tile_v]
+    dkv_shape = [
+        jax.ShapeDtypeStruct((bh, tk, d), jnp.float32 if shared else k.dtype),
+        jax.ShapeDtypeStruct((bh, tk, dv),
+                             jnp.float32 if shared else v.dtype),
+    ]
+    dkv_scratch = [pltpu.VMEM((group, block, d), jnp.float32),
+                   pltpu.VMEM((group, block, dv), jnp.float32)]
+    if two:
+        dkv_in_specs += [
+            _walked_spec((group, -1, d2), block, plan.major_q, causal, False,
+                         window=window),
+            _own_spec((group, block, d2), row=k2_row)]
+        dkv_out.append(tile2)
+        dkv_shape.append(jax.ShapeDtypeStruct(
+            (bh, tk, d2), k2.dtype if k2_heads == nheads else jnp.float32))
+        dkv_scratch.append(pltpu.VMEM((group, block, d2), jnp.float32))
     if has_seg:
         dkv_in_specs += _seg_specs(rows, block, plan.major_q, causal,
                                    False)[::-1]
-    dk, dv_ = pl.pallas_call(
+    dk, dv_, *dk2 = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           has_seg=has_seg, block_k=block, chunk=chunk,
                           slab=plan.slab_bwd, nm=tq // plan.major_q,
-                          window=window),
+                          window=window, two=two),
         name="flash_bwd_dkv",
         grid=(bh // group, tk // block, tq // plan.major_q),
         in_specs=dkv_in_specs,
-        out_specs=[tile, tile_v],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d),
-                                 jnp.float32 if shared else k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, dv),
-                                 jnp.float32 if shared else v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((group, block, d), jnp.float32),
-            pltpu.VMEM((group, block, dv), jnp.float32),
-        ],
+        out_specs=dkv_out,
+        out_shape=dkv_shape,
+        scratch_shapes=dkv_scratch,
         compiler_params=_PARAMS,
         interpret=interpret,
     )(*args)
+
+    def over_share(x, like, held):
+        x = x.reshape(bh // nheads, held, nheads // held, tk, x.shape[-1])
+        return x.sum(axis=2).reshape(like.shape).astype(like.dtype)
+
     if shared:
-        def over_share(x, like, held):
-            x = x.reshape(bh // nheads, held, nheads // held, tk, x.shape[-1])
-            return x.sum(axis=2).reshape(like.shape).astype(like.dtype)
         dk, dv_ = over_share(dk, k, kv_heads), over_share(dv_, v, v_heads)
-    return dq, dk, dv_
+    if not two:
+        return dq, dk, dv_
+    dk2, = dk2
+    if k2_heads != nheads:
+        dk2 = over_share(dk2, k2, k2_heads)
+    return dq[0], dk, dv_, dq[1], dk2
 
 
 # ----------------------------------------------------------- custom_vjp glue
@@ -1186,18 +1295,55 @@ def _flash_bwd(nheads, causal, scale, plan, interpret, window, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+# The call with a second pair of score operands, a rule of its own so that
+# the calls without one are traced as they were.  Its bodies go under the
+# same ``jax.jit`` wrappers: a stack's layers trace them once a shape.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash2(q, k, v, q2, k2, nheads, causal, scale, plan, interpret):
+    out, _ = _fwd_once(q, k, v, None, None, nheads, causal, scale, plan,
+                       interpret, None, q2, k2)
+    return out
+
+
+def _flash2_fwd(q, k, v, q2, k2, nheads, causal, scale, plan, interpret):
+    out, lse = _fwd_once(q, k, v, None, None, nheads, causal, scale, plan,
+                         interpret, None, q2, k2)
+    out, lse = _name(out, FLASH_OUT), _name(lse, FLASH_LSE)
+    return out, (q, k, v, q2, k2, out, lse)
+
+
+def _flash2_bwd(nheads, causal, scale, plan, interpret, res, do):
+    q, k, v, q2, k2, out, lse = res
+    return _bwd_once(q, k, v, None, None, out, lse, do, nheads, causal,
+                     scale, plan, interpret, None, q2, k2)
+
+
+_flash2.defvjp(_flash2_fwd, _flash2_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     segment_ids=None, kv_segment_ids=None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, q2=None, k2=None):
     """Flash attention on (B, T, H, D) inputs → (B, T, H, Dv).
 
     T must be a multiple of 128 and D one of 64/128/256 (the dispatcher
     in :mod:`mxnet_tpu.ops.attention` guarantees this before routing
-    here).  ``k`` and ``v`` may carry fewer heads than ``q`` (grouped
+    here); a score may be wider than that by a SECOND pair of operands:
+    ``q2`` (B, T, H, D2) and ``k2`` (B, T, H2, D2), D2 64 or 128, whose
+    product is added to ``q k^T`` inside the tile before the softmax, so
+    that the score is over D + D2 dimensions (128 + 64 = 192: latent
+    attention's head product plus its rotary product) under the ONE
+    ``scale`` (default ``1 / sqrt(D + D2)``).  ``k2`` may have fewer
+    heads than ``q2`` (one rotary key head shared by all query heads): it
+    is read through the index map at its own heads, never broadcast or
+    padded in memory; ``dq2`` comes from the ``dq`` kernel and ``dk2``
+    from the ``dkv`` kernel, summed over the query heads of a share as
+    grouped queries' ``dK`` is.  Such a call takes no window and no
+    segment ids.  ``k`` and ``v`` may carry fewer heads than ``q`` (grouped
     queries): query head ``h`` reads K/V head ``h // (H / H_kv)`` through
     the kernels' index maps, nothing is repeated in memory, and dK/dV are
     summed over the query heads of a share after the ``dkv`` kernel.
@@ -1227,13 +1373,26 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if causal and tq != tk:
         raise ValueError("causal flash attention requires tq == tk "
                          f"(got {tq} vs {tk})")
-    scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     has_seg = segment_ids is not None
     window = None if window is None else int(window)
+    d2 = h2 = 0
+    if (q2 is None) != (k2 is None):
+        raise ValueError("q2 and k2 come together")
+    if q2 is not None:
+        d2, h2 = q2.shape[3], k2.shape[2]
+        if has_seg or window is not None:
+            raise ValueError("a second pair of score operands takes no "
+                             "window and no segment ids")
+        if q2.shape[:3] != (b, tq, h) or k2.shape != (b, tk, h2, d2):
+            raise ValueError(f"q2 {q2.shape} / k2 {k2.shape} do not go "
+                             f"with q {q.shape} / k {k.shape}")
+    scale = float(scale) if scale is not None else 1.0 / ((d + d2) ** 0.5)
     plan = tile_plan(tq, tk, d, q.dtype, causal, has_seg, heads=h,
                      kv_heads=h_kv, block_q=block_q, chunk=block_k,
-                     window=window, dv=dv, v_heads=h_v)
-    _report_plan(plan, tq, tk, d, q.dtype, causal, has_seg, window, dv)
+                     window=window, dv=dv, v_heads=h_v, d2=d2,
+                     k2_heads=h2 or None)
+    _report_plan(plan, tq, tk, d, q.dtype, causal, has_seg, window, dv, d2,
+                 h2)
     if interpret is None:
         interpret = _default_interpret(q)
 
@@ -1255,6 +1414,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], t,
                                                x.shape[3])
 
-    out = _flash(flat(q, tq), flat(k, tk), flat(v, tk), q_seg, kv_seg,
-                 h, causal, scale, plan, bool(interpret), window)
+    if q2 is not None:
+        out = _flash2(flat(q, tq), flat(k, tk), flat(v, tk), flat(q2, tq),
+                      flat(k2, tk), h, causal, scale, plan, bool(interpret))
+    else:
+        out = _flash(flat(q, tq), flat(k, tk), flat(v, tk), q_seg, kv_seg,
+                     h, causal, scale, plan, bool(interpret), window)
     return out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)

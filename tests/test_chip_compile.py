@@ -518,9 +518,40 @@ def test_flash_share_of_one_at_head_size_128_compiles_for_v5e(chip):
     assert _kernels(compiled) == 3
 
 
+def test_flash_latent_two_operand_score_compiles_for_v5e(chip):
+    """Latent attention at its published sizes: 16 heads, a score that is
+    a 128-wide head product plus a 64-wide rotary product on ONE shared
+    key head, values of 128, T 8,192, bf16: forward, dq (with dq2) and dkv
+    (with dk2 a query head, summed after)."""
+    from mxnet_tpu.ops.flash import tile_plan
+
+    plan = tile_plan(8192, 8192, 128, jnp.bfloat16, True, heads=16,
+                     kv_heads=16, dv=128, v_heads=16, d2=64, k2_heads=1)
+    assert (plan.block_q, plan.chunk, plan.major, plan.group) == \
+        (1024, 256, 2048, 1)
+
+    def loss(q, k, v, q2, k2):
+        out = flash_attention(q, k, v, q2=q2, k2=k2, causal=True,
+                              scale=192 ** -0.5, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    def x(heads, d):
+        return jax.ShapeDtypeStruct((1, 8192, heads, d), jnp.bfloat16,
+                                    sharding=chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x(16, 128), x(16, 128), x(16, 128), x(16, 64), x(1, 64)).compile()
+    assert _kernels(compiled) == 3
+
+
 # ------- the dense gated feed-forward inside a whole step's text (PR 48)
 
 _T, _UNITS, _HALF = 64, 32, 48
+_LATENT = dict(
+    vocab_size=512, vocab_held=64, units=_UNITS, num_heads=4, qk_nope_dim=16,
+    qk_rope_dim=8, v_head_dim=16, kv_lora_rank=24, mlp_hidden=_HALF,
+    num_experts=16, top_k=3, expert_hidden=24, shared_hidden=40,
+    experts_held=(8, 4))
 _DENSE_TWINS = {        # the tiny sizes of tests/test_decoder_shell.py
     "granite": ("get_granite_hybrid", dict(
         layer_types=("mamba", "attention"), vocab_size=256, vocab_held=32,
@@ -533,6 +564,12 @@ _DENSE_TWINS = {        # the tiny sizes of tests/test_decoder_shell.py
     "ouro": ("get_ouro", dict(
         num_layers=2, vocab_size=512, vocab_held=128, units=_UNITS,
         num_heads=2, num_kv_heads=2, head_dim=16, mlp_hidden=_HALF)),
+    # the latent-attention family's leading dense layers alone (its expert
+    # layers take ``ragged_dot`` off the TPU, whose float32 cotangent
+    # against bf16 weights the chip's compiler refuses: the expert twins
+    # are lowered, never compiled)
+    "deepseek_v3": ("get_deepseek_v3", dict(_LATENT, num_layers=2,
+                                            first_k_dense=2)),
 }
 _EXPERT_TWIN = ("get_qwen3_next", dict(
     num_layers=4, vocab_size=512, vocab_held=64, units=_UNITS, num_heads=4,
@@ -628,3 +665,18 @@ def test_an_expert_family_never_reaches_gated_mlp(chips, monkeypatch):
     tracer) and its text is what it was before PR 48."""
     _lowered, plans = _lowered_twin_step(chips, monkeypatch, *_EXPERT_TWIN)
     assert plans == []
+
+
+def test_a_dense_first_expert_stack_lowers_with_its_scopes(chips,
+                                                           monkeypatch):
+    """The latent-attention family as its cell runs it, a dense layer and
+    then expert layers: ONE ``mlp.plan`` event (the dense layer's; the
+    shared experts are ``models/moe.py``'s own) and the mixer's three
+    scopes in the lowered step's locations."""
+    lowered, plans = _lowered_twin_step(
+        chips, monkeypatch, "get_deepseek_v3", dict(_LATENT, num_layers=3))
+    assert [p["half"] for p in plans] == [_HALF]
+    text = lowered.as_text(debug_info=True)
+    for scope in ("mla_latent", "mla_expand", "mla_scores"):
+        assert scope in text, scope
+

@@ -43,6 +43,11 @@ TINY = {
         num_layers=8, vocab_size=512, vocab_held=64, units=32, num_heads=8,
         num_kv_heads=1, head_dim=16, sliding_window=8, num_experts=16,
         top_k=3, expert_hidden=24, experts_held=(8, 4)),
+    "get_deepseek_v3": dict(
+        num_layers=3, vocab_size=512, vocab_held=64, units=32, num_heads=4,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, kv_lora_rank=24,
+        mlp_hidden=48, num_experts=16, top_k=3, expert_hidden=24,
+        shared_hidden=48, experts_held=(8, 4)),
 }
 
 
@@ -66,6 +71,35 @@ def test_one_expert_half_serves_the_three_expert_families():
     net = models.get_nemotron_h(**TINY["get_nemotron_h"])
     assert isinstance(net.blocks[1], hc.ExpertBlock)
     assert isinstance(net.blocks[0], nemotron_h.HybridLayer)
+
+
+def test_a_second_half_is_made_by_layer():
+    """``two_halves`` hands the second half's factory the layer's index:
+    one stack may hold a dense half in some layers and an expert half in
+    the others, each under its own name; the callers that give every
+    layer the same half take the index and ignore it."""
+    cfg = dict(units=32, eps=1e-5, expert_hidden=24, num_experts=8, top_k=2,
+               norm_topk=True)
+    made = list(hc.two_halves(
+        range(3), lambda _: hc.HalfLayer("toy_layer", cfg, RunningMean(32)),
+        lambda i: hc.HalfLayer("toy_mlp", cfg, hc.GatedMLP(32, 48))
+        if i == 0 else hc.ExpertBlock(cfg, scoring="softmax",
+                                      expert_form="swiglu"),
+        second=lambda i: "mlp" if i == 0 else "experts"))
+    assert [n for n, _ in made] == ["l0_mixer", "l0_mlp", "l1_mixer",
+                                    "l1_experts", "l2_mixer", "l2_experts"]
+    assert [type(b).__name__ for _, b in made[1::2]] == [
+        "HalfLayer", "ExpertBlock", "ExpertBlock"]
+    same = list(hc.two_halves("ab", str.upper, lambda i: i * 10,
+                              second="mlp"))
+    assert same == [("l0_mixer", "A"), ("l0_mlp", 0), ("l1_mixer", "B"),
+                    ("l1_mlp", 10)]
+    net = models.get_deepseek_v3(**TINY["get_deepseek_v3"])
+    assert list(net._children)[1:7] == ["l0_mixer", "l0_mlp", "l1_mixer",
+                                        "l1_experts", "l2_mixer",
+                                        "l2_experts"]
+    from mxnet_tpu.models import ouro
+    assert ouro.GatedMLP is hc.GatedMLP          # two families' one block
 
 
 # ---- a sixth family, whole: a mixer, its half-layer, its sizes ----------
