@@ -9,9 +9,9 @@ long sequences are bounded by HBM for Q/K/V only.
 
 Layout: public entry takes (B, T, H, D) and flattens to (B*H, T, D).
 The grid is (groups of heads, blocks, major stretches).  One grid step
-owns one ``block_q``-row block of q (fwd, dq) or of k/v (dkv) and is
-handed the operand it walks — K and V for fwd/dq; Q, dO, lse and delta
-for dkv — as one *major* stretch of the sequence: all of it whenever
+owns one ``block_q``-row block of q (fwd) or of k/v (bwd) and is handed
+the operand it walks — K and V forward; Q, dO, lse and delta backward —
+as one *major* stretch of the sequence: all of it whenever
 that fits VMEM (at T = 1,024, D = 64, bf16 one head's K is 128 KB), so
 the third grid axis has one step and Pallas fetches the stretch once per
 head, not once per block.  Only a sequence too long for that walks the
@@ -42,12 +42,23 @@ T = 8,192 under a 1,024-key window a head runs 45 of 256 forward tiles
 backward, where walking the band's bounding box in masked chunks of
 every row ran 60 and 960.
 
-The backward pass is the standard flash-attention-2 split: a ``dq``
-kernel (q blocks, walking kv) and a ``dkv`` kernel (kv blocks, walking
-q), both re-computing the tile of probabilities from the saved per-row
-logsumexp.  ``dkv`` holds its tiles transposed, (kv rows, q columns), so
-that dV = P^T dO and dK = dS^T Q are plain row-by-column products and the
-per-row lse/delta broadcast along sublanes as they are stored.
+The backward pass is ONE kernel, ``flash_bwd`` (:func:`_bwd_kernel`): a
+grid step owns a block of k/v rows and walks Q and dO, makes each visited
+tile's probabilities again from the saved per-row logsumexp, ONCE, and
+takes all three gradients from it: five products a tile (``S``, ``dP``,
+``dV``, ``dK``, ``dQ``), one exp, one mask.  It holds its tiles
+transposed, (kv rows, q columns), so that dV = P^T dO and dK = dS^T Q are
+plain row-by-column products and the per-row lse/delta broadcast along
+sublanes as they are stored; dQ = dS K is the one product that contracts
+the tile's rows.  ``dk`` and ``dv`` are the block's own, in block-sized
+scratch; ``dq`` belongs to the rows walked, and is summed in float32 VMEM
+over the heads' WHOLE query sequence (4 MB a head at T = 8,192, D = 128)
+across the block axis of the grid and written once, after the head's last
+k/v block: no partial ``dq`` goes through HBM.  Only where a sequence's
+``dq`` cannot be held (:data:`_VMEM_FUSED`) does the plan keep the
+flash-attention-2 split: a ``dq`` kernel (q blocks, walking kv) beside
+the same body without its ``dq``, each making the tiles for itself —
+seven products, two exps.
 
 Sizes (``block_q``, ``chunk``, slab widths, ``major``, heads a step) are
 computed in ONE place, :func:`tile_plan`, from what the call can see:
@@ -121,24 +132,36 @@ _LANES = 128
 # without a window only ever the first
 _DIAGONAL = (True, False)
 
-# ~16 MiB VMEM per v4/v5e core; budget leaves headroom for compiler
-# temporaries/semaphores so the clamp errs safe rather than tight.
+# ~16 MiB of VMEM is what Mosaic gives a call unasked on v4/v5e; the
+# budget leaves headroom for compiler temporaries/semaphores so the clamp
+# errs safe rather than tight.
 _VMEM_BUDGET = 12 * 2 ** 20
+# The fused backward holds a head's whole float32 dq beside a step's
+# working set and asks for the scoped limit it needs (a v5e core has 128
+# MiB; ``ops/sscan.py`` asks for 48): up to this much, past which the
+# plan keeps the two calls.  T = 32,768 at D = 128 still fits.
+_VMEM_FUSED = 48 * 2 ** 20
 
 
 class TilePlan(NamedTuple):
     """Sizes of one flash call and how much of the score square they run.
 
     ``block_q``: rows of the block a grid step owns (q rows in fwd/dq, kv
-    rows in dkv).  ``chunk``: rows of one slice of the walked operand in
+    rows in the backward call).  ``chunk``: rows of one slice of the walked operand in
     the loop over what the block sees whole.  ``slab`` / ``slab_bwd``:
     width of the slices the diagonal's own square is cut into, in fwd and
-    in dq/dkv.  ``major`` / ``major_q``: the stretch of kv (fwd, dq) / of
-    q (dkv) a grid step holds in VMEM.  ``group``: heads of one batch row
-    a grid step works on side by side.  ``tiles_*`` count (slab, slab)
+    backward.  ``major`` / ``major_q``: the stretch of kv (fwd, dq) / of
+    q (the backward call) a grid step holds in VMEM; the fused backward's
+    is cut by the limit it asks for and not by the budget.  ``group``:
+    heads of one batch row a grid step works on side by side.  ``tiles_*`` count (slab, slab)
     tiles of one head's forward: in the whole square, visited, and
     visited with a mask; ``tiles_*_bwd`` the same in (slab_bwd, slab_bwd)
-    tiles for dq and dkv."""
+    tiles for the backward.  ``backward``: "fused", one call that takes
+    dq, dk and dv from each score tile, its ``dq`` summed in ``dq_bytes``
+    of float32 VMEM over the group's whole query sequence, or "split",
+    a dq call and a dkv call, where that does not fit (``dq_bytes`` 0).
+    ``bwd_vmem``: the scoped VMEM the fused call asks Mosaic for, 0
+    where the default will do."""
     block_q: int
     chunk: int
     slab: int
@@ -151,6 +174,9 @@ class TilePlan(NamedTuple):
     tiles_masked: int
     tiles_run_bwd: int
     tiles_full_bwd: int
+    backward: str
+    dq_bytes: int
+    bwd_vmem: int
 
 
 def _vmem_bytes(block: int, chunk: int, major: int, d: int, itemsize: int,
@@ -181,6 +207,16 @@ def _vmem_bytes(block: int, chunk: int, major: int, d: int, itemsize: int,
         second = (2 * (major + block) * d2l * itemsize
                   + (2 + 1) * 4 * block * d2l)
     return group * (score + resident + tiles + outs + scratch + second) + seg
+
+
+def _dq_vmem(group: int, tq: int, d: int, d2: int, itemsize: int):
+    """What the fused backward holds of a group's WHOLE query sequence,
+    in bytes: (the float32 accumulators of dq and, with a second pair of
+    score operands ``d2`` wide, of dq2; the block they are written out
+    through, which Pallas buffers twice).  The minor dim pads to 128
+    lanes."""
+    lanes = max(d, _LANES) + (max(d2, _LANES) if d2 else 0)
+    return 4 * group * tq * lanes, 2 * group * tq * lanes * itemsize
 
 
 def _largest_stretch(t: int, chunk: int, fits) -> int:
@@ -386,7 +422,7 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     if window is not None and (not causal or has_seg or window < 1):
         raise ValueError("a window needs causal=True, no segment ids and "
                          f"at least one key (got {window})")
-    d = max(d, dv or d)
+    d_k, d = d, max(d, dv or d)
     fine = DEFAULT_SEG if has_seg else None
 
     def fit(size, within):             # halve until it divides
@@ -428,6 +464,24 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     block, major, major_q, group = _clamp_blocks(
         blocks, chunk, tq, tk, d, jnp.dtype(dtype).itemsize, has_seg, group,
         wide, d2)
+    # the fused backward holds, beside a step's working set, the float32
+    # dq (and dq2) of the group's whole query sequence and, twice, the
+    # block they are written out through.  It asks Mosaic for the VMEM
+    # that takes, so its walked stretch is cut by THAT limit and not by
+    # the budget: whole at the cells' T = 8,192, where the block's own
+    # square then runs as slabs on their trapezoids (36 of 64 tiles)
+    itemsize = jnp.dtype(dtype).itemsize
+    dq_bytes, dq_out = _dq_vmem(group, tq, d_k, d2, itemsize)
+
+    def need(stretch):
+        return dq_bytes + dq_out + _vmem_bytes(
+            block, wide, stretch, d, itemsize, has_seg, group, d2)
+
+    held = _largest_stretch(tq, chunk, lambda m: need(m) <= _VMEM_FUSED)
+    asked = need(held)
+    fused = asked <= _VMEM_FUSED
+    if fused:
+        major_q = held
     # a slab under a window lies in one stretch whole (_walk's sections)
     within = math.gcd(block, major, major_q) if window else block
     slab, slab_bwd = (fit(x, within) for x in slabs)
@@ -437,7 +491,10 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
                                         causal, major_q == tq, window)
     return TilePlan(block, chunk, slab, slab_bwd, major, major_q, group,
                     run, full, run if has_seg else masked, run_bwd,
-                    full_bwd)
+                    full_bwd, "fused" if fused else "split",
+                    dq_bytes if fused else 0,
+                    asked + _VMEM_BUDGET // 3
+                    if fused and asked > _VMEM_BUDGET else 0)
 
 
 def plan_event(name: str, **attrs):
@@ -1015,23 +1072,35 @@ def _dq_kernel(*refs, scale, causal, has_seg, block_q, chunk, slab, nm,
             dq2_ref[:] = acc2_ref[:].astype(dq2_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
-                window=None, two=False):
-    """Tiles are held transposed, (kv rows, q columns): lse and delta are
+def _bwd_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
+                window=None, two=False, fused=True):
+    """One k/v block of a group of heads against the q/dO stretch it
+    walks: ``dk`` and ``dv`` (``dk2``) of the block, and with ``fused``
+    the block's share of ``dq`` (``dq2``) too, all from the ONE ``S``,
+    ``P``, ``dP``, ``dS`` of every visited tile.
+
+    Tiles are held transposed, (kv rows, q columns): lse and delta are
     stored along lanes, so they broadcast down the tile as they are, and
-    both accumulating products contract the tile's columns with the rows
-    of dO and Q — no transposed operand."""
+    both of the block's own accumulating products contract the tile's
+    columns with the rows of dO and Q: no transposed operand.  ``dq`` is
+    the one product that contracts the tile's ROWS, ``dS^T^T K``; it is
+    added into a float32 accumulator that holds the heads' WHOLE query
+    sequence and lives across the block axis of the grid (which is
+    therefore "arbitrary"): zeroed at a head's first grid step, written
+    out in q's type at its last.  No partial ``dq`` goes through HBM.
+
+    ``refs``: q, k, v, do, lse, delta [, q2, k2] [, q's segment ids,
+    k's]; then the outputs [dq [, dq2]], dk, dv [, dk2]; then one
+    float32 accumulator for each output, in the same order."""
     q2_ref, k2_ref, refs = _second(refs, 6, two)
-    dk2_ref = dk2_acc = None
-    if two:             # the second operand's dk and its accumulator
-        *refs, dk2_acc = refs
-        dk2_ref = refs.pop(-3)
-    if has_seg:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
+    ins = 8 if has_seg else 6
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    qseg_ref, kseg_ref = refs[6:ins] if has_seg else (None, None)
+    walked = ["dq"] + ["dq2"] * two if fused else []   # the walk's rows'
+    own = ["dk", "dv"] + ["dk2"] * two                 # the block's
+    names = walked + own
+    out = dict(zip(names, refs[ins:]))
+    acc = dict(zip(names, refs[ins + len(names):]))
     ki = pl.program_id(1)
     mi = pl.program_id(2)
     group, major, _ = q_ref.shape
@@ -1040,10 +1109,25 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
     if not fold:
         @pl.when(mi == 0)
         def _init():
-            dk_acc[:] = jnp.zeros_like(dk_acc)
-            dv_acc[:] = jnp.zeros_like(dv_acc)
-            if two:
-                dk2_acc[:] = jnp.zeros_like(dk2_acc)
+            for x in own:
+                acc[x][:] = jnp.zeros_like(acc[x])
+
+    def over_q(f):
+        """``f(rows)`` over the query sequence a block's worth at a time:
+        a loop, not megabytes of straight-line code."""
+        def body(j, carry):
+            f(pl.ds(pl.multiple_of(j * block_k, block_k), block_k))
+            return carry
+        jax.lax.fori_loop(0, nm * major // block_k, body, 0)
+
+    if fused:
+        @pl.when(jnp.logical_and(ki == 0, mi == 0))
+        def _init_dq():
+            for x in walked:
+                def zero(at, a=acc[x]):
+                    a[:, at, :] = jnp.zeros((group, block_k, a.shape[2]),
+                                            a.dtype)
+                over_q(zero)
 
     ks = kseg_ref[0, 0, :][:, None] if has_seg else None   # (bk, 1)
 
@@ -1053,6 +1137,8 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
                           mi * major + start, ks,
                           qseg_ref[0, :, pl.ds(start, width)] if has_seg
                           else None, window, sides)
+        # the slice's rows of the whole query sequence
+        at = pl.ds(pl.multiple_of(mi * major + start, width), width)
         for g in range(group):
             q = q_ref[g, pl.ds(start, width), :]       # (width, d)
             do = do_ref[g, pl.ds(start, width), :]
@@ -1064,15 +1150,20 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
             if keep is not None:
                 p = _where_rows(keep, p, 0.0, sub)
             # dV += P^T @ dO
-            _accumulate(dv_acc, g, rows, fresh,
+            _accumulate(acc["dv"], g, rows, fresh,
                         _dot(p.astype(do.dtype), do, 1, 0))
             dp = _dot(v_ref[g, r0:r1, :], do, 1, 1)    # dP^T
             ds = (p * (dp - delta) * scale).astype(q.dtype)
             # dK += dS^T @ Q
-            _accumulate(dk_acc, g, rows, fresh, _dot(ds, q, 1, 0))
+            _accumulate(acc["dk"], g, rows, fresh, _dot(ds, q, 1, 0))
             if two:
-                _accumulate(dk2_acc, g, rows, fresh, _dot(
+                _accumulate(acc["dk2"], g, rows, fresh, _dot(
                     ds, q2_ref[g, pl.ds(start, width), :], 1, 0))
+            if fused:                                  # dQ += dS @ K
+                acc["dq"][g, at, :] += _dot(ds, k_ref[g, r0:r1, :], 0, 0)
+                if two:
+                    acc["dq2"][g, at, :] += _dot(
+                        ds, k2_ref[g, r0:r1, :], 0, 0)
 
     _walk(step, causal=causal, up=False, i=ki, mi=mi, nm=nm, block=block_k,
           chunk=chunk, slab=slab, cpm=major // chunk, fold=fold,
@@ -1080,70 +1171,86 @@ def _dkv_kernel(*refs, scale, causal, has_seg, block_k, chunk, slab, nm,
 
     @_when(nm == 1, mi == nm - 1)
     def _finish():
-        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
-        if two:
-            dk2_ref[:] = dk2_acc[:].astype(dk2_ref.dtype)
+        for x in own:
+            out[x][:] = acc[x][:].astype(out[x].dtype)
+
+    if fused:
+        @pl.when(jnp.logical_and(ki == pl.num_programs(1) - 1,
+                                 mi == nm - 1))
+        def _finish_dq():
+            for x in walked:
+                def write(at, o=out[x], a=acc[x]):
+                    o[:, at, :] = a[:, at, :].astype(o.dtype)
+                over_q(write)
 
 
 def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
               plan, interpret, window=None, q2=None, k2=None):
     """``(dq, dk, dv)``, and with a second pair of score operands
-    ``(dq, dk, dv, dq2, dk2)``."""
+    ``(dq, dk, dv, dq2, dk2)``: ONE call, ``flash_bwd``, where the plan
+    says ``backward="fused"`` (:func:`_bwd_kernel`); where the query
+    sequence's float32 ``dq`` would not fit VMEM, ``flash_bwd_dq`` (q
+    blocks walking K/V) and ``flash_bwd_dkv`` (the same kernel body
+    without its ``dq``), each making the score tiles for itself."""
     bh, tq, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     block, chunk, group = plan.block_q, plan.chunk, plan.group
     has_seg = q_seg is not None
+    fused = plan.backward == "fused"
     kv_heads, v_heads = (x.shape[0] * nheads // bh for x in (k, v))
     kv_row, v_row = _kv_row(nheads, kv_heads), _kv_row(nheads, v_heads)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]               # (bh, 1, tq)
-    tile, row = _own_spec((group, block, d)), _own_spec((group, 1, block))
-    tile_v = _own_spec((group, block, dv))
-
-    def rows(b):
-        return b * group // nheads
-
-    k_walk = _walked_spec((group, -1, d), block, plan.major, causal, True,
-                          row=kv_row, window=window)
-    v_walk = _walked_spec((group, -1, dv), block, plan.major, causal, True,
-                          row=v_row, window=window)
-    dq_in_specs = [tile, k_walk, v_walk, tile_v, row, row]
+    tile, tile_v = _own_spec((group, block, d)), _own_spec((group, block, dv))
     args = [q, k, v, do, lse, delta]
     two = q2 is not None
-    dq_out, dq_shape = tile, jax.ShapeDtypeStruct((bh, tq, d), q.dtype)
-    dq_scratch = [pltpu.VMEM((group, block, d), jnp.float32),
-                  pltpu.VMEM((group, block, _LANES), jnp.float32),
-                  pltpu.VMEM((group, block, _LANES), jnp.float32)]
+    dq_shape = [jax.ShapeDtypeStruct((bh, tq, d), q.dtype)]
     if two:
         d2 = q2.shape[2]
         k2_heads = k2.shape[0] * nheads // bh
         k2_row = _kv_row(nheads, k2_heads)
         tile2 = _own_spec((group, block, d2))
-        dq_in_specs += [tile2, _walked_spec(
-            (group, -1, d2), block, plan.major, causal, True, row=k2_row,
-            window=window)]
         args += [q2, k2]
-        dq_out = [tile, tile2]
-        dq_shape = [dq_shape, jax.ShapeDtypeStruct((bh, tq, d2), q2.dtype)]
-        dq_scratch.append(pltpu.VMEM((group, block, d2), jnp.float32))
+        dq_shape.append(jax.ShapeDtypeStruct((bh, tq, d2), q2.dtype))
     if has_seg:
-        dq_in_specs += _seg_specs(rows, block, plan.major, causal, True)
         args += [q_seg, kv_seg]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg, block_q=block, chunk=chunk,
-                          slab=plan.slab_bwd, nm=tk // plan.major,
-                          window=window, two=two),
-        name="flash_bwd_dq",
-        grid=(bh // group, tq // block, tk // plan.major),
-        in_specs=dq_in_specs,
-        out_specs=dq_out,
-        out_shape=dq_shape,
-        scratch_shapes=dq_scratch,
-        compiler_params=_PARAMS,
-        interpret=interpret,
-    )(*args)
+
+    def rows(b):
+        return b * group // nheads
+
+    def scratch(shapes, length=block):
+        return [pltpu.VMEM((group, length, x.shape[2]), jnp.float32)
+                for x in shapes]
+
+    if not fused:
+        row = _own_spec((group, 1, block))
+        dq_in_specs = [tile, _walked_spec(
+            (group, -1, d), block, plan.major, causal, True, row=kv_row,
+            window=window), _walked_spec(
+            (group, -1, dv), block, plan.major, causal, True, row=v_row,
+            window=window), tile_v, row, row]
+        if two:
+            dq_in_specs += [tile2, _walked_spec(
+                (group, -1, d2), block, plan.major, causal, True, row=k2_row,
+                window=window)]
+        if has_seg:
+            dq_in_specs += _seg_specs(rows, block, plan.major, causal, True)
+        row_tiles = [pltpu.VMEM((group, block, _LANES), jnp.float32)] * 2
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, scale=scale, causal=causal,
+                              has_seg=has_seg, block_q=block, chunk=chunk,
+                              slab=plan.slab_bwd, nm=tk // plan.major,
+                              window=window, two=two),
+            name="flash_bwd_dq",
+            grid=(bh // group, tq // block, tk // plan.major),
+            in_specs=dq_in_specs,
+            out_specs=[tile, tile2] if two else [tile],
+            out_shape=dq_shape,
+            scratch_shapes=(scratch(dq_shape[:1]) + row_tiles
+                            + scratch(dq_shape[1:])),
+            compiler_params=_PARAMS,
+            interpret=interpret,
+        )(*args)
 
     q_walk = _walked_spec((group, -1, d), block, plan.major_q, causal,
                           False, window=window)
@@ -1154,43 +1261,54 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     # each query head reads the K/V head it shares and writes that head's
     # dK/dV of its own, in float32; the heads of a share are summed after
     shared = kv_heads != nheads or v_heads != nheads
-    dkv_in_specs = [q_walk, _own_spec((group, block, d), row=kv_row),
-                    _own_spec((group, block, dv), row=v_row), do_walk,
-                    row_walk, row_walk]
-    dkv_out = [tile, tile_v]
-    dkv_shape = [
+    in_specs = [q_walk, _own_spec((group, block, d), row=kv_row),
+                _own_spec((group, block, dv), row=v_row), do_walk,
+                row_walk, row_walk]
+    out_specs = [tile, tile_v]
+    out_shape = [
         jax.ShapeDtypeStruct((bh, tk, d), jnp.float32 if shared else k.dtype),
         jax.ShapeDtypeStruct((bh, tk, dv),
                              jnp.float32 if shared else v.dtype),
     ]
-    dkv_scratch = [pltpu.VMEM((group, block, d), jnp.float32),
-                   pltpu.VMEM((group, block, dv), jnp.float32)]
     if two:
-        dkv_in_specs += [
+        in_specs += [
             _walked_spec((group, -1, d2), block, plan.major_q, causal, False,
                          window=window),
             _own_spec((group, block, d2), row=k2_row)]
-        dkv_out.append(tile2)
-        dkv_shape.append(jax.ShapeDtypeStruct(
+        out_specs.append(tile2)
+        out_shape.append(jax.ShapeDtypeStruct(
             (bh, tk, d2), k2.dtype if k2_heads == nheads else jnp.float32))
-        dkv_scratch.append(pltpu.VMEM((group, block, d2), jnp.float32))
     if has_seg:
-        dkv_in_specs += _seg_specs(rows, block, plan.major_q, causal,
-                                   False)[::-1]
-    dk, dv_, *dk2 = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+        in_specs += _seg_specs(rows, block, plan.major_q, causal,
+                               False)[::-1]
+    accs, params = scratch(out_shape), _PARAMS
+    if fused:
+        # the heads' whole query sequence, held across the block axis
+        out_specs = [pl.BlockSpec((group, tq, x.shape[2]),
+                                  lambda b, i, m: (b, 0, 0))
+                     for x in dq_shape] + out_specs
+        out_shape = dq_shape + out_shape
+        accs = scratch(dq_shape, tq) + accs
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan.bwd_vmem or None)
+    outs = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           has_seg=has_seg, block_k=block, chunk=chunk,
                           slab=plan.slab_bwd, nm=tq // plan.major_q,
-                          window=window, two=two),
-        name="flash_bwd_dkv",
+                          window=window, two=two, fused=fused),
+        name="flash_bwd" if fused else "flash_bwd_dkv",
         grid=(bh // group, tk // block, tq // plan.major_q),
-        in_specs=dkv_in_specs,
-        out_specs=dkv_out,
-        out_shape=dkv_shape,
-        scratch_shapes=dkv_scratch,
-        compiler_params=_PARAMS,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=accs,
+        compiler_params=params,
         interpret=interpret,
     )(*args)
+    if fused:
+        dq, outs = outs[:len(dq_shape)], outs[len(dq_shape):]
+    dk, dv_, *dk2 = outs
 
     def over_share(x, like, held):
         x = x.reshape(bh // nheads, held, nheads // held, tk, x.shape[-1])
@@ -1199,7 +1317,7 @@ def _bwd_impl(q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale,
     if shared:
         dk, dv_ = over_share(dk, k, kv_heads), over_share(dv_, v, v_heads)
     if not two:
-        return dq, dk, dv_
+        return dq[0], dk, dv_
     dk2, = dk2
     if k2_heads != nheads:
         dk2 = over_share(dk2, k2, k2_heads)
@@ -1225,10 +1343,14 @@ def _int_zero_cotangent(x):
 # of a trainer's ``settle``, every trace of the step.  Mellum's three
 # windowed layers so added 8 s to a 51 s set-up.  Under ``jax.jit`` a
 # kernel's body is traced once a shape; ``inline``, so that the calling
-# jaxpr holds the kernels' own equations as it did.  Only under a window:
-# the same wrappers around a call without one cost GPT-2's set-up 1.3 s
-# of tracing on the chip's host in every pair of ten read, and with them
-# off it read the parent's to 0.2 s (PERF.md §6, PR 44).
+# jaxpr holds the kernels' own equations as it did.  The forward only
+# under a window or with a second operand: the same wrappers around a
+# call without one cost GPT-2's set-up 1.3 s of tracing on the chip's
+# host in every pair of ten read, and with them off it read the parent's
+# to 0.2 s (PERF.md §6, PR 44).  The backward always: the one call's body
+# holds a block's own square as eight slabs at every length, and Ouro's
+# six call sites, each traced for itself, added 2 s to a 39 s set-up
+# (PERF.md §6, PR 50).
 _fwd_once = jax.jit(_fwd, static_argnums=(5, 6, 7, 8, 9, 10), inline=True)
 _bwd_once = jax.jit(_bwd_impl, static_argnums=(8, 9, 10, 11, 12, 13),
                     inline=True)
@@ -1285,9 +1407,8 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, nheads, causal, scale, plan,
 
 def _flash_bwd(nheads, causal, scale, plan, interpret, window, res, do):
     q, k, v, q_seg, kv_seg, out, lse = res
-    dq, dk, dv = (_bwd_once if window else _bwd_impl)(
-        q, k, v, q_seg, kv_seg, out, lse, do, nheads, causal, scale, plan,
-        interpret, window)
+    dq, dk, dv = _bwd_once(q, k, v, q_seg, kv_seg, out, lse, do, nheads,
+                           causal, scale, plan, interpret, window)
     return (dq, dk, dv,
             _int_zero_cotangent(q_seg), _int_zero_cotangent(kv_seg))
 
@@ -1340,13 +1461,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``scale`` (default ``1 / sqrt(D + D2)``).  ``k2`` may have fewer
     heads than ``q2`` (one rotary key head shared by all query heads): it
     is read through the index map at its own heads, never broadcast or
-    padded in memory; ``dq2`` comes from the ``dq`` kernel and ``dk2``
-    from the ``dkv`` kernel, summed over the query heads of a share as
-    grouped queries' ``dK`` is.  Such a call takes no window and no
+    padded in memory; ``dq2`` and ``dk2`` come from the backward kernel
+    beside ``dq`` and ``dk``, ``dk2`` summed over the query heads of a
+    share as grouped queries' ``dK`` is.  Such a call takes no window and no
     segment ids.  ``k`` and ``v`` may carry fewer heads than ``q`` (grouped
     queries): query head ``h`` reads K/V head ``h // (H / H_kv)`` through
     the kernels' index maps, nothing is repeated in memory, and dK/dV are
-    summed over the query heads of a share after the ``dkv`` kernel.
+    summed over the query heads of a share after the backward kernel.
     ``v`` may be (B, T, H_v, Dv) with a head dim and a head count of its
     own (a differential head's values are two key heads wide and shared
     by the pair's two score matrices): the result then has ``Dv``.

@@ -331,7 +331,7 @@ def test_two_operand_flash_matches_the_plain_192_wide_score(
         # the forward kernel is not run again in the backward pass
         text = str(jax.make_jaxpr(jax.grad(
             lambda *a: kernel(*a).sum(), argnums=(0, 1, 2, 3, 4)))(*ops))
-        assert text.count("flash_fwd") == 1 and "flash_bwd_dkv" in text
+        assert text.count("flash_fwd") == 1 and "flash_bwd" in text
 
 
 def test_two_operand_flash_with_as_many_rotary_keys_as_heads_and_bf16():
@@ -369,25 +369,28 @@ def test_what_the_two_operand_call_refuses_and_the_gate_admits():
 
 
 # what tile_plan gave the seven cells' attention calls at the parent
-# commit (PR 48): this PR's new operands must not move them
-_FULL_128 = (1024, 256, 512, 128, 4096, 4096, 1, 144, 256, 32, 2304, 4096)
+# commit (PR 48): this PR's new operands must not move them.  (PR 50's
+# one backward call holds the walked Q and dO whole: ``major_q`` 8,192
+# where it was the forward's stretch, ``tiles_run_bwd`` 2,080 where the
+# two stretches ran 2,304 / 2,176.)
+_FULL_128 = (1024, 256, 512, 128, 4096, 8192, 1, 144, 256, 32, 2080, 4096)
 _CELL_PLANS = {
     "gpt2_124m": (dict(tq=1024, d=64, heads=12, kv_heads=12),
                   (1024, 256, 512, 128, 1024, 1024, 1, 3, 4, 2, 36, 64)),
     "nemotron_tt": (dict(tq=8192, d=128, heads=32, kv_heads=2), _FULL_128),
     "qwen3_next": (dict(tq=8192, d=256, heads=16, kv_heads=2),
-                   (512, 256, 512, 128, 2048, 2048, 1, 136, 256, 16, 2176,
+                   (512, 256, 512, 128, 2048, 8192, 1, 136, 256, 16, 2080,
                     4096)),
     "granite_4h": (dict(tq=8192, d=64, heads=32, kv_heads=8), _FULL_128),
     "phi4_flash_window": (dict(tq=8192, d=64, heads=40, kv_heads=20, dv=128,
                                v_heads=10, window=512),
-                          (512, 256, 512, 128, 4096, 4096, 1, 31, 256, 31,
+                          (512, 256, 512, 128, 4096, 8192, 1, 31, 256, 31,
                            310, 4096)),
     "phi4_flash_full": (dict(tq=8192, d=64, heads=40, kv_heads=20, dv=128,
                              v_heads=10), _FULL_128),
     "mellum2_window": (dict(tq=8192, d=128, heads=32, kv_heads=4,
                             window=1024),
-                       (1024, 256, 512, 128, 4096, 4096, 1, 45, 256, 30, 540,
+                       (1024, 256, 512, 128, 4096, 8192, 1, 45, 256, 30, 540,
                         4096)),
     "mellum2_full": (dict(tq=8192, d=128, heads=32, kv_heads=4), _FULL_128),
     "ouro": (dict(tq=8192, d=128, heads=16, kv_heads=16), _FULL_128),
@@ -402,7 +405,7 @@ def test_the_other_cells_plans_and_events_are_what_they_were(cell):
     call = dict(call)
     tq, d = call.pop("tq"), call.pop("d")
     plan = flash.tile_plan(tq, tq, d, jnp.bfloat16, True, **call)
-    assert tuple(plan) == want
+    assert plan[:12] == want and plan.backward == "fused"
     # no second operand: the plan does not know the new words, and a
     # second operand of width 0 is no second operand
     assert plan == flash.tile_plan(tq, tq, d, jnp.bfloat16, True, d2=0,
@@ -422,7 +425,7 @@ def test_the_other_cells_plans_and_events_are_what_they_were(cell):
 
 
 def test_a_call_without_a_second_operand_lowers_to_the_kernels_it_had():
-    """Three kernels with 3, 6 and 6 operands: nothing of the second pair
+    """Two kernels with 3 and 6 operands: nothing of the second pair
     reaches a call that has none."""
     q, k, v, *_ = _operands()
     text = jax.make_jaxpr(jax.grad(
@@ -440,8 +443,7 @@ def test_a_call_without_a_second_operand_lowers_to_the_kernels_it_had():
                 visit(sub)
 
     visit(text.jaxpr)
-    assert calls == {"flash_fwd": (3, 2), "flash_bwd_dq": (6, 1),
-                     "flash_bwd_dkv": (6, 2)}
+    assert calls == {"flash_fwd": (3, 2), "flash_bwd": (6, 3)}
 
 
 def test_the_latent_plan_at_the_published_sizes():
@@ -454,7 +456,8 @@ def test_the_latent_plan_at_the_published_sizes():
                            kv_heads=16, dv=128, v_heads=16, d2=64, k2_heads=1)
     assert (plan.block_q, plan.chunk, plan.slab, plan.slab_bwd, plan.group) \
         == (1024, 256, 512, 128, 1)
-    assert (plain.major, plan.major, plan.major_q) == (4096, 2048, 2048)
+    assert (plain.major, plan.major, plan.major_q) == (4096, 2048, 8192)
+    assert (plan.backward, plan.dq_bytes) == ("fused", 4 * 8192 * 256)
     assert plan.tiles_run == plain.tiles_run == 144
     assert flash._vmem_bytes(1024, 512, 2048, 128, 2, d2=64) > \
         flash._vmem_bytes(1024, 512, 2048, 128, 2)

@@ -410,9 +410,13 @@ def test_flash_walk_over_several_major_stretches(monkeypatch, causal, tq,
     from mxnet_tpu.ops import flash
 
     monkeypatch.setattr(flash, "_VMEM_BUDGET", 900 * 1024)
+    # and the fused backward's limit: one such chunk beside its whole dq
+    monkeypatch.setattr(flash, "_VMEM_FUSED", flash._vmem_bytes(
+        128, 128, 128, 64, 4, seg) + sum(flash._dq_vmem(1, tq, 64, 0, 4)))
     plan = flash.tile_plan(tq, tk, 64, jnp.float32, causal, seg,
                            block_q=128, chunk=128)
     assert plan.major < tk and plan.major_q < tq
+    assert plan.backward == "fused"
     q = _rand((1, tq, 2, 64), 60)
     k, v = (_rand((1, tk, 2, 64), s) for s in (61, 62))
     ids = jnp.asarray([[0] * 200 + [1] * (tq - 200)], jnp.int32) \
@@ -486,15 +490,23 @@ def test_tile_plan_counts_what_the_causal_walk_runs():
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 def test_tile_plan_fits_vmem(d, dtype):
-    from mxnet_tpu.ops.flash import _VMEM_BUDGET, _vmem_bytes, tile_plan
+    from mxnet_tpu.ops.flash import (_VMEM_BUDGET, _VMEM_FUSED, _dq_vmem,
+                                     _vmem_bytes, tile_plan)
 
+    itemsize = jnp.dtype(dtype).itemsize
     for t in (256, 384, 512, 768, 1024, 2048, 4096, 8192):
         for causal, has_seg in ((True, False), (True, True), (False, False)):
             plan = tile_plan(t, t, d, dtype, causal, has_seg, heads=12)
-            assert _vmem_bytes(plan.block_q, plan.chunk,
-                               max(plan.major, plan.major_q), d,
-                               jnp.dtype(dtype).itemsize, has_seg,
+            assert _vmem_bytes(plan.block_q, plan.chunk, plan.major, d,
+                               itemsize, has_seg,
                                plan.group) <= _VMEM_BUDGET, (t, plan)
+            # the one backward call: its stretch beside its whole dq,
+            # under the limit it may ask Mosaic for
+            assert plan.backward == "fused" and t % plan.major_q == 0
+            assert plan.major_q >= plan.major
+            assert _vmem_bytes(plan.block_q, plan.chunk, plan.major_q, d,
+                               itemsize, has_seg, plan.group) + sum(
+                _dq_vmem(plan.group, t, d, 0, itemsize)) <= _VMEM_FUSED
             assert t % plan.major == 0 and plan.major % plan.chunk == 0
             assert t % plan.block_q == 0 and plan.block_q % plan.chunk == 0
             assert plan.block_q % plan.slab == 0 == plan.slab % plan.slab_bwd
@@ -564,7 +576,190 @@ def test_tile_plan_of_the_gpt2_cell_is_pinned():
     want = TilePlan(block_q=1024, chunk=256, slab=512, slab_bwd=128,
                     major=1024, major_q=1024, group=1, tiles_run=3,
                     tiles_full=4, tiles_masked=2, tiles_run_bwd=36,
-                    tiles_full_bwd=64)
+                    tiles_full_bwd=64, backward="fused",
+                    dq_bytes=4 * 1024 * 128, bwd_vmem=0)
     assert tile_plan(1024, 1024, 64, jnp.bfloat16, True, heads=12) == want
     assert tile_plan(1024, 1024, 64, jnp.bfloat16, True, heads=12,
                      kv_heads=12) == want
+
+
+# ------------------------------------------ the one backward call (PR 50)
+# name: query heads, key heads, value heads, d, dv, then what the call
+# says (window, a second pair's width and heads, documents) and what the
+# plan is made to say (block, chunk, limits on VMEM that cut the walked
+# operand into stretches of so many rows, a fused limit nothing fits)
+_BACKWARD = {
+    "plain": dict(heads=(2, 2, 2), block=(256, 128)),
+    "plain-one-block-of-slabs": dict(heads=(2, 2, 2), block=(512, 128)),
+    "plain-not-causal": dict(heads=(2, 2, 2), block=(128, 128),
+                             causal=False),
+    "the-plans-own-sizes": dict(heads=(4, 4, 4), t=1024),
+    "shared-kv-heads": dict(heads=(8, 2, 2), block=(128, 128)),
+    "one-kv-head-at-128": dict(heads=(4, 1, 1), d=128, dv=128,
+                               block=(256, 128)),
+    "window-narrower-than-a-block": dict(heads=(4, 2, 2), window=96,
+                                         block=(256, 128)),
+    "window-wider-than-a-block": dict(heads=(2, 2, 2), window=300,
+                                      block=(128, 128)),
+    "values-wider-than-keys": dict(heads=(4, 2, 1), dv=128,
+                                   block=(256, 128)),
+    "values-wider-under-a-window": dict(heads=(4, 2, 1), dv=128, window=160,
+                                        block=(256, 128)),
+    "two-operands-one-rotary-key": dict(heads=(4, 4, 4), d=128, dv=128,
+                                        two=(64, 1), block=(128, 128)),
+    "two-operands-a-key-a-head": dict(heads=(4, 4, 4), d=128, dv=128,
+                                      two=(64, 4), block=(256, 128)),
+    "segments": dict(heads=(2, 2, 2), docs=(200, 312), block=(256, 128)),
+    "segments-not-causal": dict(heads=(2, 2, 2), docs=(128, 128, 256),
+                                block=(128, 128), causal=False),
+    "several-stretches": dict(heads=(2, 2, 2), t=1024, block=(128, 128),
+                              stretch=128),
+    "several-stretches-under-a-window": dict(
+        heads=(2, 2, 2), t=1024, window=300, block=(128, 128), stretch=256),
+    "several-stretches-two-operands": dict(
+        heads=(2, 2, 2), d=128, dv=128, two=(64, 1), t=1024,
+        block=(128, 128), stretch=256),
+    "sent-to-the-split": dict(heads=(4, 2, 2), block=(256, 128), fused=0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BACKWARD))
+def test_one_backward_call_against_ref_and_against_the_two(monkeypatch,
+                                                           case):
+    """``flash_bwd`` takes dq, dk and dv (dq2, dk2) from one pass over the
+    score tiles: each against ``_attention_ref``'s gradient, and against
+    what ``flash_bwd_dq`` + ``flash_bwd_dkv`` give on the same inputs,
+    which differs only in the order of dq's float32 additions."""
+    from mxnet_tpu.ops import flash
+
+    c = dict(_BACKWARD[case])
+    h, hk, hv = c["heads"]
+    t, d, dv = c.get("t", 512), c.get("d", 64), c.get("dv", 64)
+    causal, window = c.get("causal", True), c.get("window")
+    block_q, chunk = c.get("block") or (None, None)
+    ops = [_rand((1, t, h, d), 70), _rand((1, t, hk, d), 71),
+           _rand((1, t, hv, dv), 72)]
+    second = ()
+    if "two" in c:
+        d2, h2 = c["two"]
+        second = ("q2", "k2")
+        ops += [_rand((1, t, h, d2), 73), _rand((1, t, h2, d2), 74)]
+    ct = _rand((1, t, h, dv), 75)
+    seg = mask = None
+    if "docs" in c:
+        seg = jnp.asarray([sum(([i] * n for i, n in enumerate(c["docs"])),
+                               [])], jnp.int32)
+        mask = seg[:, None, :, None] == seg[:, None, None, :]
+    fits = 48 * 2 ** 20
+    if "stretch" in c:          # what holds such a stretch, one head a
+        d2 = c.get("two", (0,))[0]                  # step; fused: beside
+        held = flash._vmem_bytes(block_q, chunk, c["stretch"], d, 4,
+                                 d2=d2)             # its whole dq
+        fits = held + sum(flash._dq_vmem(1, t, d, d2, 4))
+        monkeypatch.setattr(flash, "_VMEM_BUDGET", held)
+        monkeypatch.setattr(flash, "_VMEM_FUSED", fits)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a) * ct),
+                        argnums=tuple(range(len(ops))))(*ops)
+
+    def kernel(*a):
+        return flash_attention(
+            *a[:3], causal=causal, window=window, segment_ids=seg,
+            block_q=block_q, block_k=chunk, interpret=True,
+            **dict(zip(second, a[3:])))
+
+    def calls():
+        found = set()
+
+        def visit(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.add(eqn.params["name"])
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    visit(sub)
+        visit(jax.make_jaxpr(lambda: grads(kernel))().jaxpr)
+        return found
+
+    want = grads(lambda *a: _attention_ref(
+        *a[:3], causal=causal, window=window, mask=mask,
+        **dict(zip(second, a[3:]))))
+    if "fused" in c:            # a limit the plan can see nothing fit
+        monkeypatch.setattr(flash, "_VMEM_FUSED", c["fused"])
+    plan = flash.tile_plan(t, t, d, jnp.float32, causal, seg is not None,
+                           heads=h, kv_heads=hk, block_q=block_q,
+                           chunk=chunk, window=window, dv=dv, v_heads=hv,
+                           d2=c.get("two", (0, None))[0],
+                           k2_heads=c.get("two", (0, None))[1])
+    if "stretch" in c:
+        assert plan.major_q == c["stretch"]
+    first = "split" if "fused" in c else "fused"
+    assert plan.backward == first
+    assert (plan.dq_bytes > 0) == (first == "fused")
+    one, two = {"flash_fwd", "flash_bwd"}, \
+        {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    got = grads(kernel)
+    assert calls() == (one if first == "fused" else two)
+    # the other form on the same inputs
+    monkeypatch.setattr(flash, "_VMEM_FUSED", 0 if first == "fused" else fits)
+    other = grads(kernel)
+    assert calls() == (two if first == "fused" else one)
+    names = ("dq", "dk", "dv", "dq2", "dk2")
+    for name, g, o, w in zip(names, got, other, want):
+        assert g.shape == o.shape == w.shape and g.dtype == o.dtype, name
+        assert not onp.isnan(onp.asarray(g)).any(), name
+        onp.testing.assert_allclose(g, w, rtol=2e-4, atol=5e-5,
+                                    err_msg=name)
+        top = float(jnp.abs(w).max())
+        assert float(jnp.abs(g - o).max()) <= (
+            2e-6 * top if name in ("dq", "dq2") else 0.0), name
+
+
+# the eight cells' backward call sites and two longer sequences: what
+# tile_plan says of the backward, from shapes alone
+_BACKWARD_PLANS = {
+    "gpt2": (dict(tq=1024, d=64, heads=12), "fused", 1024 * 128, False),
+    "ouro": (dict(tq=8192, d=128, heads=16), "fused", 8192 * 128, True),
+    "nemotron": (dict(tq=8192, d=128, heads=32, kv_heads=2), "fused",
+                 8192 * 128, True),
+    "qwen3_next": (dict(tq=8192, d=256, heads=16, kv_heads=2), "fused",
+                   8192 * 256, True),
+    "granite": (dict(tq=8192, d=64, heads=32, kv_heads=8), "fused",
+                8192 * 128, True),
+    "mellum2_window": (dict(tq=8192, d=128, heads=32, kv_heads=4,
+                            window=1024), "fused", 8192 * 128, True),
+    "phi4flash_window": (dict(tq=8192, d=64, heads=40, kv_heads=20, dv=128,
+                              v_heads=10, window=512), "fused", 8192 * 128,
+                         True),
+    "moonlight": (dict(tq=8192, d=128, heads=16, kv_heads=16, dv=128,
+                       v_heads=16, d2=64, k2_heads=1), "fused",
+                  8192 * (128 + 128), True),
+    "t32768": (dict(tq=32768, d=128, heads=16), "fused", 32768 * 128, True),
+    "t65536": (dict(tq=65536, d=128, heads=16), "split", 0, False),
+}
+
+
+@pytest.mark.parametrize("site", sorted(_BACKWARD_PLANS))
+def test_tile_plan_says_which_backward_a_call_gets(site):
+    """Fused wherever the float32 dq of the group's whole query sequence
+    fits beside a step's working set; the plan asks for more scoped VMEM
+    than a call has unasked exactly where that needs it, never for more
+    than its limit, and keeps the two calls past it."""
+    from mxnet_tpu.ops.flash import (_VMEM_BUDGET, _VMEM_FUSED, _vmem_bytes,
+                                     tile_plan)
+
+    call, backward, rows_by_lanes, asks = _BACKWARD_PLANS[site]
+    call = dict(call)
+    tq, d = call.pop("tq"), call.pop("d")
+    plan = tile_plan(tq, tq, d, jnp.bfloat16, True, **call)
+    assert plan.backward == backward
+    assert plan.dq_bytes == 4 * plan.group * rows_by_lanes
+    assert (plan.bwd_vmem > 0) == asks
+    if asks:
+        held = plan.dq_bytes + plan.dq_bytes      # and twice in q's type
+        step = _vmem_bytes(plan.block_q, plan.chunk, plan.major_q,
+                           max(d, call.get("dv", d)), 2, False, plan.group,
+                           call.get("d2", 0))
+        assert _VMEM_BUDGET < step + held <= _VMEM_FUSED
+        assert step + held < plan.bwd_vmem <= _VMEM_FUSED + _VMEM_BUDGET
+        assert plan.major_q == min(tq, 8192)

@@ -15,7 +15,9 @@
   its residuals computed outside the timing), then each path's gradients
   against the chunked form in float32.  Until PR 41 the kernel path's
   backward was the chunked form run once more and differentiated;
-* the flash kernels with 32 query heads over 2 key/value heads at T 8,192;
+* the flash kernels at the eight training cells' shapes
+  (``FLASH_SHAPES``): forward, and the backward alone as the one fused
+  call and as the two calls it falls back to;
 * ``gather``: the expert buffer's gather into sorted order
   (``models.moe._gather_routed``: a walk over the routed rows, a chunk a
   turn) alone, at the three expert cells' buffers, with 1/16, 1/4 and ALL
@@ -133,22 +135,71 @@ def bench_ssd(key):
             "groups": g, "chunk": chunk}), flush=True)
 
 
+# the eight training cells' attention calls: (batch, T, query heads, D),
+# key heads, (value heads, Dv), window, (second operand's width, its heads)
+FLASH_SHAPES = {
+    "ouro": ((1, 8192, 16, 128), 16, None, None, None),
+    "moonlight": ((1, 8192, 16, 128), 16, None, None, (64, 1)),
+    "mellum2_window": ((1, 8192, 32, 128), 4, None, 1024, None),
+    "phi4flash_window": ((1, 8192, 40, 64), 20, (10, 128), 512, None),
+    "gpt2": ((8, 1024, 12, 64), 12, None, None, None),
+    "nemotron": ((1, 8192, 32, 128), 2, None, None, None),
+    "qwen3_next": ((1, 8192, 16, 256), 2, None, None, None),
+    "granite": ((1, 8192, 32, 64), 8, None, None, None),
+    "phi4flash_full": ((1, 8192, 40, 64), 20, (10, 128), None, None),
+}
+
+
 def bench_flash(key):
-    from mxnet_tpu.ops.attention import flash_attention
+    """Forward, forward + backward and the backward ALONE (the pullback
+    of ``jax.vjp``, residuals computed outside the timing) at the cells'
+    shapes; the backward as the plan has it and, the plan's limit planted
+    at 0 from here, as the two calls it falls back to, with the largest
+    gap between the two forms' gradients."""
+    from mxnet_tpu.ops import flash
 
     bf = jnp.bfloat16
-    ks = jax.random.split(key, 5)
-    q = jax.random.normal(ks[0], (1, 8192, 32, 128), bf)
-    for hk in (2, 32):
-        k = jax.random.normal(ks[1], (1, 8192, hk, 128), bf)
-        v = jax.random.normal(ks[2], (1, 8192, hk, 128), bf)
-        fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
-        gr = jax.jit(jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, causal=True).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2)))
-        print(json.dumps({"flash_kv_heads": hk,
-                          "fwd_ms": timed(fn, q, k, v),
-                          "fwd_bwd_ms": timed(gr, q, k, v)}), flush=True)
+    ks = jax.random.split(key, 6)
+    for name, ((b, t, h, d), hk, values, window, second) in \
+            FLASH_SHAPES.items():
+        hv, dv = values or (hk, d)
+        args = [jax.random.normal(ks[0], (b, t, h, d), bf),
+                jax.random.normal(ks[1], (b, t, hk, d), bf),
+                jax.random.normal(ks[2], (b, t, hv, dv), bf)]
+        if second:
+            args += [jax.random.normal(ks[3], (b, t, h, second[0]), bf),
+                     jax.random.normal(ks[4], (b, t, second[1], second[0]),
+                                       bf)]
+        do = jax.random.normal(ks[5], (b, t, h, dv), bf)
+
+        def call(q, k, v, *more):
+            return flash.flash_attention(
+                q, k, v, causal=True, window=window,
+                **dict(zip(("q2", "k2"), more)))
+
+        line, grads, fits = {"flash": name}, {}, flash._VMEM_FUSED
+        for form, limit in (("fused", fits), ("split", 0)):
+            flash._VMEM_FUSED = limit
+            try:
+                plan = flash.tile_plan(
+                    t, t, d, bf, True, heads=h, kv_heads=hk, window=window,
+                    dv=dv, v_heads=hv, d2=second[0] if second else 0,
+                    k2_heads=second[1] if second else None)
+                if form == "fused":
+                    line["fwd_ms"] = timed(jax.jit(call), *args)
+                _, pull = jax.jit(lambda *a: jax.vjp(call, *a))(*args)
+                back = jax.jit(lambda pull, do: pull(do))
+                line[f"bwd_{form}_ms"] = timed(back, pull, do)
+                grads[form] = back(pull, do)
+                del pull
+            finally:
+                flash._VMEM_FUSED = fits
+            line[f"plan_{form}"] = (plan.backward, plan.dq_bytes,
+                                    plan.bwd_vmem)
+        line["gap"] = max(float(jnp.max(jnp.abs(
+            a.astype(jnp.float32) - b.astype(jnp.float32))))
+            for a, b in zip(grads["fused"], grads["split"]))
+        print(json.dumps(line), flush=True)
 
 
 def bench_gather(key):
