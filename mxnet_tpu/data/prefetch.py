@@ -46,6 +46,7 @@ from ..ndarray import NDArray
 from ..io import DataBatch
 from ..analysis.lockwitness import named_condition as _named_condition
 from ..resilience.faults import inject as _inject
+from ..observability import stalls as _stall_log
 from ..observability.flightrecorder import active as _fr_active
 from ..observability.registry import default_registry as _registry
 from ..observability.trace import host_range as _host_range
@@ -122,9 +123,12 @@ class DevicePrefetcher:
         the feeder AFTER device placement — the on-device augment hook
         (:class:`~mxnet_tpu.data.transforms.DeviceTransform`).
     stall_timeout : float
-        Seconds the consumer waits on an empty ring before recording a
-        ``data.stall`` flight-recorder event (diagnostic only; the wait
-        itself is unbounded).
+        Seconds the consumer waits on an empty ring before it counts a
+        stall and declares its ``input.next`` phase stalled to
+        ``observability.stalls``: when the wait ends that log takes one
+        record with its verdict (what the feeder was inside meanwhile),
+        and the flight recorder the event ``data.stall`` (diagnostic
+        only; the wait itself is unbounded).
     """
 
     def __init__(self, source, shardings=None, depth: int = 2,
@@ -259,7 +263,9 @@ class DevicePrefetcher:
                         for _ in range(self._skip):  # raceguard: unguarded(feeder-exclusive: see above)
                             self._pull()
                         self._skip = 0  # raceguard: unguarded(feeder-exclusive: see above)
-                    item = self._pull()
+                    # what a stall record says this thread was inside
+                    with _stall_log.phase("input.pull"):
+                        item = self._pull()
                 except StopIteration:
                     with self._cond:
                         self._ring.append(_END)
@@ -270,7 +276,8 @@ class DevicePrefetcher:
                     self._m_fallback.inc()
                     self._n_fallback += 1
                 else:
-                    data, labels, nbytes = self._ship(data, labels)
+                    with _stall_log.phase("input.ship"):
+                        data, labels, nbytes = self._ship(data, labels)
                     if nbytes:
                         self._m_shipped.inc()
                         self._m_bytes.inc(nbytes)
@@ -313,12 +320,10 @@ class DevicePrefetcher:
                     if not stalled:
                         stalled = True
                         self._stalls += 1
-                        fr = _fr_active()
-                        if fr is not None:
-                            fr.record("data.stall",
-                                      consumed=self._consumed,
-                                      waited=round(
-                                          time.perf_counter() - t0, 3))
+                        # recorded when next()'s phase ends, by the one
+                        # stall log, with the whole wait and a verdict
+                        _stall_log.declare("data.stall",
+                                           consumed=self._consumed)
             if self._ring:
                 item = self._ring.popleft()
                 self._cond.notify_all()

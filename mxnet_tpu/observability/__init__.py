@@ -23,6 +23,11 @@ resilience/guardrail/io counter dicts):
   and compiling and the persistent cache's hits and misses, what the
   calling thread's own calls compiled, and a bounded log of each event
   with its instant (``log()``), always on.
+- :mod:`.stalls` — the stalled step seen from inside: every thread's
+  open phase in a slot (from ``host_range``), a running median a phase,
+  one witness thread that keeps its own lateness and watches an overdue
+  phase, and a bounded log of stall records with a verdict each
+  (``log()``), always on once a ``ShardedTrainer`` was built.
 - :mod:`.export` — Prometheus text-format and JSON-lines exporters plus
   a :class:`BackgroundExporter` thread with graceful drain (wired into
   ``InferenceEngine.stop()`` and SIGTERM handling).

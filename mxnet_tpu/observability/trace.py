@@ -41,6 +41,7 @@ from typing import Dict, List, Optional
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from ..analysis.lockwitness import named_lock as _named_lock
+from . import stalls as _stalls
 
 __all__ = ["Span", "Tracer", "enable", "disable", "active", "host_range"]
 
@@ -358,6 +359,10 @@ class host_range:
     tracer is active (and ``span`` is left on) it also records the span
     ``<layer>.<phase>`` with ``parent`` as the span that caused it.
 
+    Entering and leaving also tell ``observability.stalls`` which phase
+    this thread is in (a slot write each, always on): what a stall
+    record later says the thread was doing.
+
     The annotation's prefix follows one rule, because a trace reader
     attributes a device program to the last ``marker:`` range opened
     before the program started: a phase that **launches** device
@@ -368,16 +373,16 @@ class host_range:
     serving engine: one retrospective span per batched call, carrying
     every rider's trace id)."""
 
-    __slots__ = ("_ann", "_live")
+    __slots__ = ("_ann", "_live", "_name", "_slot")
 
     def __init__(self, layer: str, phase: str, *, launches: bool,
                  parent=None, span: bool = True, **attrs):
+        name = self._name = f"{layer}.{phase}"
         self._ann = _TraceAnnotation(
-            f"marker:{layer}:{phase}" if launches
-            else f"span:{layer}.{phase}")
+            f"marker:{layer}:{phase}" if launches else "span:" + name)
         tr = _ACTIVE if span else None
         self._live = None if tr is None else \
-            tr.span(f"{layer}.{phase}", parent=parent, **attrs)
+            tr.span(name, parent=parent, **attrs)
 
     @property
     def span(self):
@@ -387,9 +392,11 @@ class host_range:
 
     def __enter__(self):
         self._ann.__enter__()
+        self._slot = _stalls.enter(self._name)
         return self
 
     def __exit__(self, etype, exc, tb):
+        _stalls.leave(self._slot, self._live)
         self._ann.__exit__(etype, exc, tb)
         if self._live is not None:
             self._live.__exit__(etype, exc, tb)

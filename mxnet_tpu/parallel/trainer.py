@@ -25,6 +25,7 @@ from .. import optimizer as opt_mod
 from .. import random as _random
 from ..ndarray import NDArray
 from ..observability import compiles as _compile_log
+from ..observability import stalls as _stall_log
 from ..observability.compiles import on_this_thread as _xla_compiles
 from ..observability.flightrecorder import active as _fr_active
 from ..observability.trace import active as _trace_active
@@ -233,7 +234,10 @@ class ShardedTrainer:
         and state put on the mesh).  The step is only WRAPPED in
         ``jax.jit`` here: it is traced and compiled by the first
         ``step``.  With no tracer on, the phases' seconds still reach
-        ``stats()["build"]`` and ``observability.compiles.builds()``."""
+        ``stats()["build"]`` and ``observability.compiles.builds()``.
+        A process that trains is one whose stalled steps are worth a
+        record: the first build turns ``observability.stalls`` on."""
+        _stall_log.start()
         start, phases = time.monotonic(), {}
         with _host_range("trainer", "build", launches=True) as build:
             for phase, run in (("settle", lambda: self._settle(data)),
@@ -846,6 +850,8 @@ class ShardedTrainer:
         compiled = _xla_compiles() - compiled0
         if compiled:
             self._note_compile(compiled, data, labels, span)
+        # what this thread will wait for next, should the wait run long
+        _stall_log.awaiting(opt.num_update, loss)
         if self._guarded:
             return NDArray(loss), NDArray(flag)
         return NDArray(loss)
@@ -968,7 +974,8 @@ class ShardedTrainer:
         out = {"num_update": int(self.optimizer.num_update),
                "built": self._built,
                "guarded": self._guarded,
-               "batch_puts": self._batch_puts}
+               "batch_puts": self._batch_puts,
+               "stalls": _stall_log.summary()}
         if self._build_stats is not None:
             out["build"] = dict(self._build_stats)
         src = self._data_source

@@ -895,14 +895,16 @@ def test_trainer_build_leaves_its_record_with_no_tracer():
     ``stats()["build"]``."""
     trainer, batch = _tiny_trainer()
     assert "build" not in trainer.stats()
-    had = len(compiles.builds())
+    had = compiles.builds()[-1:]
     t0 = time.monotonic()
     trainer.build(*batch())
     t1 = time.monotonic()
+    built = compiles.builds()[-1:]
+    assert built != had         # one record (the log keeps the newest 16)
     trainer.build(*batch())                     # built already: no record
     trainer.step(*batch()).asnumpy()
-    start, end, settle_s, state_s, place_s = compiles.builds()[-1]
-    assert len(compiles.builds()) == had + 1
+    assert compiles.builds()[-1:] == built
+    start, end, settle_s, state_s, place_s = built[0]
     assert t0 <= start < end <= t1
     assert trainer.stats()["build"] == {
         "seconds": end - start, "settle_s": settle_s, "state_s": state_s,

@@ -1998,6 +1998,13 @@ def corroboration_probes(net):
     trace.disable()
     probed.append(("obs.trace_global + obs.trace_ring",
                    "trace.enable/event/disable"))
+    # the stall log: its witness starts with the first trainer built,
+    # its ring lock is taken when a stall is recorded or its sums read
+    from mxnet_tpu.observability import stalls
+    stalls.start()
+    stalls.summary()
+    probed.append(("obs.stall_witness + obs.stall_log",
+                   "stalls.start/summary"))
     # process RNG reseed (the generator lock)
     import mxnet_tpu as mx
     mx.random.seed(20260804)
